@@ -97,6 +97,12 @@ def _base_overrides(workdir: str, windows: int) -> List[str]:
 
 
 def _child_env(devices: int) -> Dict[str, str]:
+    """Environment of one training child: `devices` virtual CPU devices.
+
+    One process per chip: the caller (bench.py --elastic) may already hold
+    the accelerator, which belongs to one process at a time. The children are
+    therefore pinned to the CPU backend here (and again in `_CHILD`) and never
+    ask for it."""
     env = dict(os.environ)
     env.pop("STOIX_TPU_FAULT", None)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")

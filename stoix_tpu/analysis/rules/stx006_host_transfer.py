@@ -199,7 +199,7 @@ RULE = register(
             "import jax\n\n\n@jax.jit\ndef f(x):\n"
             "    return float(x) + 1.0\n",
             # np.* materialization inside a shard_mapped learner.
-            "import numpy as np\nfrom stoix_tpu.parallel.mesh import shard_map\n\n\n"
+            "import numpy as np\nfrom jax import shard_map\n\n\n"
             "def make(mesh, specs):\n"
             "    def learner(state):\n"
             "        return np.asarray(state)\n"

@@ -237,7 +237,7 @@ RULE = register(
         flag_snippets=(
             # Arity: two specs into a three-arg per-shard function.
             "from jax.sharding import PartitionSpec as P\n"
-            "from stoix_tpu.parallel.mesh import shard_map\n\n\n"
+            "from jax import shard_map\n\n\n"
             "def per_shard(state, batch, key):\n"
             "    return state\n\n\n"
             "def build(mesh):\n"
@@ -245,7 +245,7 @@ RULE = register(
             '                     in_specs=(P(), P("data")), out_specs=P())\n',
             # Replication claimed with no reduction over the sharded axis.
             "from jax.sharding import PartitionSpec as P\n"
-            "from stoix_tpu.parallel.mesh import shard_map\n\n\n"
+            "from jax import shard_map\n\n\n"
             "def per_shard(batch):\n"
             "    return batch.mean()\n\n\n"
             "def build(mesh):\n"
@@ -256,7 +256,7 @@ RULE = register(
             # The blessed pattern: pmean over the sharded axis before a
             # replicated output; arity satisfiable via the default.
             "import jax\nfrom jax.sharding import PartitionSpec as P\n"
-            "from stoix_tpu.parallel.mesh import shard_map\n\n\n"
+            "from jax import shard_map\n\n\n"
             "def per_shard(batch, scale=1.0):\n"
             '    return jax.lax.pmean(batch.mean() * scale, axis_name="data")\n\n\n'
             "def build(mesh):\n"
@@ -264,7 +264,7 @@ RULE = register(
             '                     in_specs=(P("data"),), out_specs=P())\n',
             # Output stays sharded: no replication claim to prove.
             "from jax.sharding import PartitionSpec as P\n"
-            "from stoix_tpu.parallel.mesh import shard_map\n\n\n"
+            "from jax import shard_map\n\n\n"
             "def per_shard(batch):\n"
             "    return batch * 2\n\n\n"
             "def build(mesh):\n"
@@ -272,7 +272,7 @@ RULE = register(
             '                     in_specs=(P("data"),), out_specs=P("data"))\n',
             # Reduction via a module-local helper taking axis_names=.
             "from jax.sharding import PartitionSpec as P\n"
-            "from stoix_tpu.parallel.mesh import shard_map\n"
+            "from jax import shard_map\n"
             "from stoix_tpu.resilience import guards\n\n\n"
             "def per_shard(batch):\n"
             '    out, _ = guards.guard_update("skip", new=batch, old=batch,\n'

@@ -11,14 +11,17 @@ the 10x10x4-pixel MinAtar-class set "Breakout-minatar",
 "Breakout-atari", the FULL-RESOLUTION pixel workload: 84x84x4 frame-stacked
 grayscale observations, the exact tensor shape the reference's EnvPool Atari
 path trains on (reference configs/env/envpool/*.yaml). The shared library is
-compiled on first use with g++ and cached next to the source; no
-Python-level per-env loops exist anywhere on the hot path.
+compiled on first use with g++ into a git-ignored file next to the source,
+named by the source's content hash; no Python-level per-env loops exist
+anywhere on the hot path.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import shutil
 import subprocess
 import threading
 from typing import Any, Optional, Tuple
@@ -30,20 +33,43 @@ from stoix_tpu.envs.factory import EnvFactory
 from stoix_tpu.envs.types import Observation, TimeStep
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libcvec.so")
+_SOURCE = os.path.join(_NATIVE_DIR, "cvec.cpp")
 _BUILD_LOCK = threading.Lock()
 
 
 def _ensure_built() -> str:
-    src = os.path.join(_NATIVE_DIR, "cvec.cpp")
+    """Build `cvec.cpp` on first use into `native/libcvec-<hash>.so` and return
+    that path. The name is keyed by the SOURCE'S CONTENT (not an mtime, which
+    a copy to another machine makes arbitrary), so the pool that runs is always
+    the source that is read; the binaries are git-ignored, never committed.
+    A missing compiler is an error here, at the first pool, not a stale or
+    absent library later."""
+    with open(_SOURCE, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    lib_path = os.path.join(_NATIVE_DIR, f"libcvec-{digest}.so")
     with _BUILD_LOCK:
-        if not os.path.exists(_LIB_PATH) or os.path.getmtime(_LIB_PATH) < os.path.getmtime(src):
-            subprocess.run(
-                ["g++", "-O3", "-shared", "-fPIC", src, "-o", _LIB_PATH],
-                check=True,
-                capture_output=True,
+        if os.path.exists(lib_path):
+            return lib_path
+        compiler = shutil.which("g++")
+        if compiler is None:
+            raise RuntimeError(
+                "the native env pool (env.backend=cvec) is built from "
+                f"{_SOURCE} on first use and needs g++ on PATH; none found"
             )
-    return _LIB_PATH
+        # Compile to a private name, then rename: a concurrent process either
+        # sees the finished library or builds its own identical copy.
+        tmp_path = f"{lib_path}.{os.getpid()}.tmp"
+        proc = subprocess.run(
+            [compiler, "-O3", "-shared", "-fPIC", _SOURCE, "-o", tmp_path],
+            capture_output=True,
+            text=True,
+        )
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"g++ failed to build {_SOURCE} (rc {proc.returncode}):\n{proc.stderr}"
+            )
+        os.replace(tmp_path, lib_path)
+    return lib_path
 
 
 def _load_lib() -> ctypes.CDLL:

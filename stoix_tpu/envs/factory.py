@@ -10,6 +10,7 @@ actor thread draw unique env instances.
 
 from __future__ import annotations
 
+import os
 import threading
 from typing import Any, Optional
 
@@ -40,21 +41,40 @@ class EnvFactory:
         return seed
 
 
+def _host_cpu_device() -> jax.Device:
+    """The host CPU device Sebulba's pure-JAX env twins step on, BESIDE the
+    accelerator backend. That needs the CPU backend in the process, which a
+    machine-wide `JAX_PLATFORMS=tpu` removes: say so instead of letting
+    `jax.devices("cpu")` fail with an unknown-backend error."""
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError as exc:
+        raise RuntimeError(
+            "Sebulba steps its pure-JAX envs on the host CPU beside the "
+            "accelerator, so the CPU backend must be available, but "
+            f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r} excludes it. "
+            "Unset JAX_PLATFORMS or list cpu too (JAX_PLATFORMS=tpu,cpu), or "
+            "use a host env pool (env.backend=cvec)."
+        ) from exc
+
+
 class JaxToStateful:
     """Wraps a batched pure-JAX env as a stateful Sebulba env pinned to a
     device (reference stoix/wrappers/jax_to_factory.py:11-107): reset/step are
-    vmapped+jitted once; state lives inside this object."""
+    vmapped+jitted once; state lives inside this object. Placement follows the
+    data: keys, state and actions are committed to `device` with
+    `jax.device_put`, and the jitted programs run where their inputs live."""
 
     def __init__(self, env: Environment, num_envs: int, seed: int, device: Optional[jax.Device] = None):
         self._env = VmapWrapper(AutoResetWrapper(RecordEpisodeMetrics(env)))
         self._num_envs = num_envs
-        self._device = device or jax.devices("cpu")[0]
+        self._device = device or _host_cpu_device()
         self._state = None
         self._keys = jax.device_put(
             jax.random.split(jax.random.PRNGKey(seed), num_envs), self._device
         )
-        self._reset_fn = jax.jit(self._env.reset, device=self._device)
-        self._step_fn = jax.jit(self._env.step, device=self._device)
+        self._reset_fn = jax.jit(self._env.reset)
+        self._step_fn = jax.jit(self._env.step)
 
     @property
     def num_envs(self) -> int:
@@ -90,7 +110,7 @@ class JaxEnvFactory(EnvFactory):
 
     def __init__(self, task_id: str, init_seed: int = 42, device: Optional[jax.Device] = None, **kwargs: Any):
         super().__init__(task_id, init_seed, **kwargs)
-        self._device = device or jax.devices("cpu")[0]
+        self._device = device or _host_cpu_device()
 
     def __call__(self, num_envs: int) -> JaxToStateful:
         from stoix_tpu.envs.registry import make_single

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from stoix_tpu.envs.core import Environment
-from stoix_tpu.parallel.mesh import shard_map
 
 # act_fn(params, observation, key) -> action  (single unbatched observation)
 ActFn = Callable[[Any, Any, jax.Array], jax.Array]
@@ -177,7 +176,7 @@ def get_ff_evaluator_fn(
         return jax.vmap(eval_one_episode, in_axes=(None, 0, 0))(params, keys, idxs)
 
     sharded = jax.jit(
-        shard_map(
+        jax.shard_map(
             _shard_eval,
             mesh=mesh,
             in_specs=(P(), P("data"), P("data")),
@@ -280,7 +279,7 @@ def get_rnn_evaluator_fn(
         return jax.vmap(eval_one_episode, in_axes=(None, 0, 0))(params, keys, idxs)
 
     sharded = jax.jit(
-        shard_map(
+        jax.shard_map(
             _shard_eval, mesh=mesh, in_specs=(P(), P("data"), P("data")), out_specs=P("data"),
             check_vma=False,
         )

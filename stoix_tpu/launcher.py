@@ -57,6 +57,7 @@ import argparse
 import itertools
 import os
 import re
+import shlex
 import subprocess
 import sys
 from typing import Any, List, Optional
@@ -76,7 +77,7 @@ SBATCH_TEMPLATE = """#!/bin/bash
 # SLURM_PROCID is always 0).
 export JAX_COORDINATOR_ADDRESS="$(scontrol show hostnames "$SLURM_JOB_NODELIST" | head -n1):12345"
 export JAX_NUM_PROCESSES="$SLURM_NNODES"
-
+{cache_line}
 srun bash -c 'JAX_PROCESS_ID="$SLURM_PROCID" python -m {module} {overrides}'
 """
 
@@ -296,7 +297,16 @@ def run_supervised(
         bit-identical to what it was before this flag existed.
 
     Every OTHER exit code (clean 0, watchdog 86, crash 1) is final. Returns
-    the final exit code."""
+    the final exit code.
+
+    One process per chip: the accelerator belongs to one process at a time,
+    and here that process is the CHILD. This supervising parent must never
+    make a backend call (`jax.devices()`, any array op) — it imports only
+    host-side modules (observability, resilience, config; importing jax
+    initialises no backend), and the elastic re-probe below runs in its own
+    short-lived subprocess BETWEEN incarnations, when no child holds the
+    chip. A parent that touched jax would hold the chip and every child
+    would fail or hang at backend init."""
     from stoix_tpu.resilience import elastic as elastic_lib
     from stoix_tpu.resilience.exit_codes import (
         EXIT_CODE_ELASTIC_RESIZE,
@@ -701,19 +711,21 @@ def main(argv: List[str] | None = None) -> None:
         default=None,
         metavar="DIR",
         help="share ONE persistent XLA compilation cache directory across "
-        "every launched job (appends arch.compile_cache.enabled/dir "
-        "overrides; utils/compilecache.py, docs/DESIGN.md §2.7): the first "
-        "job/host pays each compile, the rest hit the cache — and a "
-        "--supervise relaunch recompiles nothing",
+        "every launched job: exports JAX_COMPILATION_CACHE_DIR=DIR to each "
+        "job (the cache is always on — without this flag a job uses the "
+        "variable it inherits, else <checkout>/xla_cache; "
+        "utils/compilecache.py, docs/DESIGN.md §2.7). The first job/host "
+        "pays each compile, the rest hit the cache — and a --supervise "
+        "relaunch recompiles nothing",
     )
     parser.add_argument(
         "--aot-export",
         default=None,
         metavar="DIR",
-        help="with --compile-cache semantics for the top-level learn "
-        "function: jax.export artifacts are serialized into DIR by the "
-        "first job and loaded (skipping trace+lower) by every later one "
-        "(appends arch.compile_cache.export_dir; requires --compile-cache)",
+        help="jax.export artifacts of the top-level learn function are "
+        "serialized into DIR by the first job and loaded (skipping "
+        "trace+lower) by every later one (appends "
+        "arch.compile_cache.export_dir)",
     )
     parser.add_argument("--nodes", type=int, default=1)
     parser.add_argument("--time", default="04:00:00")
@@ -743,23 +755,11 @@ def main(argv: List[str] | None = None) -> None:
         # An elastic policy with nothing supervising it would silently never
         # relaunch — exactly the surprise this pairing check prevents.
         parser.error("--elastic requires --supervise N (N > 0)")
-    if args.aot_export and not args.compile_cache:
-        # The export store exists to be shared alongside the cache dir; an
-        # export-only launch silently paying full per-job XLA compiles is
-        # exactly the surprise this flag pairing prevents.
-        parser.error("--aot-export requires --compile-cache")
-    if args.compile_cache:
-        # Ride the ordinary override mechanism so the same knobs reach SLURM
+    if args.aot_export:
+        # Ride the ordinary override mechanism so the knob reaches SLURM
         # scripts, --local runs, and --supervise relaunches identically.
         args.overrides = [
-            "arch.compile_cache.enabled=true",
-            f"arch.compile_cache.dir={args.compile_cache}",
-            *(
-                [f"arch.compile_cache.export_dir={args.aot_export}"]
-                if args.aot_export
-                else []
-            ),
-            *args.overrides,
+            f"arch.compile_cache.export_dir={args.aot_export}", *args.overrides
         ]
 
     jobs = build_jobs(args)
@@ -777,6 +777,8 @@ def main(argv: List[str] | None = None) -> None:
         repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         env = dict(os.environ)
         env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
+        if args.compile_cache:
+            env["JAX_COMPILATION_CACHE_DIR"] = args.compile_cache
         resume_overrides = [
             "logger.checkpointing.load_model=true",
             f"logger.checkpointing.load_args.load_path={args.fleet_resume_path}",
@@ -802,6 +804,11 @@ def main(argv: List[str] | None = None) -> None:
     os.makedirs(args.log_dir, exist_ok=True)
     partition_line = f"#SBATCH --partition={args.partition}\n" if args.partition else ""
     extra_lines = "".join(f"#SBATCH {line}\n" for line in args.sbatch_extra)
+    cache_line = (
+        f"export JAX_COMPILATION_CACHE_DIR={shlex.quote(args.compile_cache)}\n"
+        if args.compile_cache
+        else ""
+    )
     for job in jobs:
         script = SBATCH_TEMPLATE.format(
             job_name=job["name"],
@@ -811,6 +818,7 @@ def main(argv: List[str] | None = None) -> None:
             preempt_grace=args.preempt_grace,
             partition_line=partition_line,
             extra_lines=extra_lines,
+            cache_line=cache_line,
             module=job["module"],
             overrides=" ".join(job["overrides"]),
         )

@@ -53,6 +53,7 @@ from stoix_tpu.serve import PolicyServer
 from stoix_tpu.serve import checkpoint as serve_checkpoint
 from stoix_tpu.serve.client import RetryBudgetExhaustedError, policy_from_config
 from stoix_tpu.serve.errors import ServeError
+from stoix_tpu.utils import compilecache
 from stoix_tpu.utils.checkpointing import Checkpointer
 from stoix_tpu.utils.timing import TimingTracker
 
@@ -158,6 +159,8 @@ def run_loop(config: Any, frozen: bool = False) -> Dict[str, Any]:
     learner_cfg = loop_cfg.learner
     traffic_cfg = loop_cfg.traffic
 
+    # Compile economy (docs/DESIGN.md §2.7): before the first compile.
+    compilecache.configure(config)
     bundle = serve_checkpoint.load_policy(config)
     learner_on = bool(learner_cfg.enabled) and not frozen
     if bool(bundle.train_config.system.get("normalize_observations", False)):

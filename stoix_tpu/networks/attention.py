@@ -44,8 +44,8 @@ class MultiHeadSelfAttention(nn.Module):
         )(x)  # [B, T, 3, H, D]
         q, k, v = proj[:, :, 0], proj[:, :, 1], proj[:, :, 2]
         # Default dispatch: the Pallas flash kernel on TPU (fused online
-        # softmax, no [S, S] score matrix in HBM — 3x the XLA path at S=4k),
-        # pure-JAX full attention elsewhere.
+        # softmax forward, no [S, S] score matrix in HBM; plain-JAX backward
+        # through its custom_vjp), pure-JAX full attention elsewhere.
         attend = self.attention_fn or best_attention
         out = attend(q, k, v, causal=self.causal)  # [B, T, H, D]
         out = out.reshape(b, t, self.num_heads * self.head_dim)

@@ -4,8 +4,7 @@ The pure-JAX `full_attention` (ops/ring_attention.py) materializes the full
 [S, S] score matrix in HBM; XLA fuses some of it but the memory traffic still
 scales O(S^2). This kernel runs the online-softmax recurrence entirely in
 VMEM: each grid step holds one query block plus one (batch*head)'s K/V in
-VMEM, streams K/V blocks through the MXU, and never writes scores to HBM —
-attention becomes compute-bound on the MXU instead of HBM-bandwidth-bound.
+VMEM, streams K/V blocks through the MXU, and never writes scores to HBM.
 
 Layout notes (see /opt/skills/guides/pallas_guide.md):
   - grid = (B*H, ceil(S / block_q)); one kernel instance owns one query block;
@@ -17,12 +16,23 @@ Layout notes (see /opt/skills/guides/pallas_guide.md):
   - sequence padding to the block size is masked with statically-known
     lengths; causal masking uses 2-D `broadcasted_iota` (TPU needs ≥2-D iota).
 
+What is compiled where. Both forward kernels (`flash_attention`,
+`flash_attention_chunk`) are compiled by Mosaic on TPU (`interpret=False`,
+the default) and checked there against `full_attention` by `chip_smoke.py`.
+The BACKWARD of both is plain JAX: each has a `jax.custom_vjp` whose backward
+recomputes the reference (`full_attention` / `_block_attend`) and
+differentiates that — exact, but it materializes the [S, S] scores the
+forward avoids (a Pallas backward is a later optimisation). Without the
+`custom_vjp`, reverse-mode through a `pallas_call` whose body reads
+`pl.program_id` fails in JAX's generic pallas_call JVP rule, so the learner
+could not take a gradient step on the chip.
+
 `flash_attention` is a drop-in for `full_attention` ([B, S, H, D] in/out) and
-is the default `attention_fn` for the transformer torso on TPU; on non-TPU
-backends it falls back to the pure-JAX path (the Pallas interpreter is
-orders of magnitude slower than XLA's fused attention on CPU, so the
-fallback — not interpret mode — is the portable path; tests force interpret
-mode explicitly to validate the kernel itself).
+is the default `attention_fn` for the transformer torso on TPU; on other
+backends `best_attention` takes the pure-JAX path (the Pallas interpreter is
+orders of magnitude slower than XLA's fused attention on CPU). `interpret=True`
+is something a test asks for to validate the kernel body off-TPU; no training
+path selects it.
 """
 
 from __future__ import annotations
@@ -33,7 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from stoix_tpu.ops.ring_attention import full_attention
+from stoix_tpu.ops.ring_attention import _block_attend, full_attention
 
 _NEG_INF = float("-inf")
 
@@ -92,8 +102,9 @@ def _flash_kernel(
     )
 
     def body(j, carry):
-        k_blk = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
+        rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k_blk = k_ref[rows, :].astype(jnp.float32)
+        v_blk = v_ref[rows, :].astype(jnp.float32)
         k_pos = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1
         )
@@ -126,15 +137,12 @@ def _fold_heads(x: jax.Array, b: int, h: int, d: int) -> jax.Array:
 
 def _out_struct(shape, dtype, *arrays: jax.Array) -> jax.ShapeDtypeStruct:
     """ShapeDtypeStruct for a pallas_call out_shape, carrying the union of
-    the inputs' varying-mesh-axes where this JAX tracks them: under shard_map
-    (where vma checking applies) the out_shape must state how the output
-    varies; it varies wherever any input does. Legacy JAX (no `jax.typeof`,
-    no `vma=` kwarg) validates with check_rep instead and needs neither."""
-    if not hasattr(jax, "typeof"):
-        return jax.ShapeDtypeStruct(shape, dtype)
+    the inputs' varying-mesh-axes: under shard_map (where vma checking
+    applies) the out_shape must state how the output varies; it varies
+    wherever any input does."""
     vma: frozenset = frozenset()
     for a in arrays:
-        vma = vma | getattr(jax.typeof(a), "vma", frozenset())
+        vma = vma | jax.typeof(a).vma
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
@@ -148,23 +156,7 @@ def _pad_axis(x: jax.Array, axis: int, multiple: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
-)
-def flash_attention(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    causal: bool = False,
-    block_q: int = 128,
-    block_k: int = 128,
-    interpret: bool = False,
-) -> jax.Array:
-    """Fused online-softmax attention. [B, S, H, D] -> [B, S, H, D].
-
-    Self-attention shapes only (q and k share a sequence length). `interpret`
-    runs the Pallas interpreter (slow; for tests/debugging off-TPU).
-    """
+def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
     b, s, h, d = q.shape
     scale = d**-0.5
     fold = functools.partial(_fold_heads, b=b, h=h, d=d)
@@ -187,6 +179,7 @@ def flash_attention(
         ],
         out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=_out_struct((b * h, s_q_pad, d), q.dtype, qf, kf, vf),
+        name="flash_attention",
         interpret=interpret,
     )(qf, kf, vf)
 
@@ -194,8 +187,51 @@ def flash_attention(
     return jnp.transpose(out.reshape(b, h, s, d), (0, 2, 1, 3))
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, block_q, block_k, interpret):
+    return _flash_forward(q, k, v, causal, block_q, block_k, interpret)
+
+
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
+    return _flash_forward(q, k, v, causal, block_q, block_k, interpret), (q, k, v)
+
+
+def _flash_bwd(causal, block_q, block_k, interpret, residuals, g):
+    # Plain-JAX backward: recompute full_attention and differentiate it.
+    _, vjp = jax.vjp(functools.partial(full_attention, causal=causal), *residuals)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+)
+def flash_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    causal: bool = False,
+    block_q: int = 128,
+    block_k: int = 128,
+    interpret: bool = False,
+) -> jax.Array:
+    """Fused online-softmax attention. [B, S, H, D] -> [B, S, H, D].
+
+    Self-attention shapes only (q and k share a sequence length). The forward
+    is the Pallas kernel; the backward (`jax.custom_vjp`) recomputes
+    `full_attention` in plain JAX and returns ITS gradient, so `jax.grad`
+    through this function is the gradient of `full_attention` at (q, k, v).
+    `interpret` runs the Pallas interpreter (slow; a test asks for it).
+    """
+    return _flash(q, k, v, causal, block_q, block_k, interpret)
+
+
 def best_attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False):
-    """Backend dispatch: the Pallas kernel on TPU, pure-JAX elsewhere."""
+    """Backend dispatch: the Pallas kernel on TPU, pure-JAX elsewhere. Both
+    branches are differentiable; which one a program took is read from its
+    jaxpr (`pallas_call`), which is what chip_smoke.py checks."""
     if jax.default_backend() == "tpu":
         return flash_attention(q, k, v, causal=causal)
     return full_attention(q, k, v, causal=causal)
@@ -220,10 +256,11 @@ def _flash_chunk_kernel(
     q_pos = qpos_ref[:].reshape(block_q, 1)  # [Bq, 1] int32 global positions
 
     def body(j, carry):
-        k_blk = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
+        rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k_blk = k_ref[rows, :].astype(jnp.float32)
+        v_blk = v_ref[rows, :].astype(jnp.float32)
         if causal:
-            k_pos = kpos_ref[pl.ds(j * block_k, block_k), :].reshape(1, block_k)
+            k_pos = kpos_ref[rows, :].reshape(1, block_k)
             mask = q_pos >= k_pos
         else:
             mask = None
@@ -249,36 +286,9 @@ def _flash_chunk_kernel(
     l_ref[:] = l_acc
 
 
-@functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
-)
-def flash_attention_chunk(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    q_positions: jax.Array,
-    k_positions: jax.Array,
-    causal: bool = False,
-    block_q: int = 128,
-    block_k: int = 128,
-    interpret: bool = False,
-):
-    """Per-chunk streaming attention for ring composition.
-
-    q: [B, Sq, H, D]; k/v: [B, Sk, H, D]; q_positions [Sq] / k_positions [Sk]
-    are GLOBAL sequence positions (int32) for causal masking across rotated
-    blocks. Requires Sq % block_q == 0 and Sk % block_k == 0 (ring shards
-    are uniformly sized). Returns (pv [B, Sq, H, D] unnormalized fp32,
-    m [B, H, Sq] fp32 running max, l [B, H, Sq] fp32 normalizer) — the exact
-    contract of ring attention's per-block accumulator fold.
-    """
+def _chunk_forward(q, k, v, q_positions, k_positions, causal, block_q, block_k, interpret):
     b, s_q, h, d = q.shape
     s_kv = k.shape[1]
-    if s_q % block_q or s_kv % block_k:
-        raise ValueError(
-            f"block sizes must divide the chunk lengths: got Sq={s_q} vs "
-            f"block_q={block_q}, Sk={s_kv} vs block_k={block_k}"
-        )
     scale = d**-0.5
     fold = functools.partial(_fold_heads, b=b, h=h, d=d)
     qf, kf, vf = fold(q), fold(k), fold(v)
@@ -308,6 +318,7 @@ def flash_attention_chunk(
             _out_struct((b * h, s_q, 1), jnp.float32, qf, kf, vf, qpos, kpos),
             _out_struct((b * h, s_q, 1), jnp.float32, qf, kf, vf, qpos, kpos),
         ],
+        name="flash_attention_chunk",
         interpret=interpret,
     )(qf, kf, vf, qpos, kpos)
 
@@ -315,3 +326,84 @@ def flash_attention_chunk(
     m = m.reshape(b, h, s_q)
     l = l.reshape(b, h, s_q)
     return pv, m, l
+
+
+def _chunk_reference(q, k, v, q_positions, k_positions, causal):
+    """The chunk kernel's contract in plain JAX: fp32 `_block_attend` with the
+    causal mask built from the same global positions, outputs in the kernel's
+    (pv, m, l) order. The kernel's backward differentiates THIS."""
+    if causal:
+        mask = (q_positions[:, None] >= k_positions[None, :])[None, None]
+    else:
+        mask = None
+    f32 = lambda x: x.astype(jnp.float32)
+    m, pv, l = _block_attend(f32(q), f32(k), f32(v), q.shape[-1] ** -0.5, mask)
+    return pv, m, l
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _chunk(q, k, v, q_positions, k_positions, causal, block_q, block_k, interpret):
+    return _chunk_forward(
+        q, k, v, q_positions, k_positions, causal, block_q, block_k, interpret
+    )
+
+
+def _chunk_fwd(q, k, v, q_positions, k_positions, causal, block_q, block_k, interpret):
+    out = _chunk_forward(
+        q, k, v, q_positions, k_positions, causal, block_q, block_k, interpret
+    )
+    return out, (q, k, v, q_positions, k_positions)
+
+
+def _chunk_bwd(causal, block_q, block_k, interpret, residuals, cotangents):
+    q, k, v, q_positions, k_positions = residuals
+    _, vjp = jax.vjp(
+        lambda q_, k_, v_: _chunk_reference(
+            q_, k_, v_, q_positions, k_positions, causal
+        ),
+        q, k, v,
+    )
+    # Integer positions carry no gradient.
+    return (*vjp(cotangents), None, None)
+
+
+_chunk.defvjp(_chunk_fwd, _chunk_bwd)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+)
+def flash_attention_chunk(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    q_positions: jax.Array,
+    k_positions: jax.Array,
+    causal: bool = False,
+    block_q: int = 128,
+    block_k: int = 128,
+    interpret: bool = False,
+):
+    """Per-chunk streaming attention for ring composition.
+
+    q: [B, Sq, H, D]; k/v: [B, Sk, H, D]; q_positions [Sq] / k_positions [Sk]
+    are GLOBAL sequence positions (int32), contiguous and ascending, for
+    causal masking across rotated blocks. Requires Sq % block_q == 0 and
+    Sk % block_k == 0 (ring shards are uniformly sized). Returns
+    (pv [B, Sq, H, D] unnormalized fp32, m [B, H, Sq] fp32 running max,
+    l [B, H, Sq] fp32 normalizer) — the exact contract of ring attention's
+    per-block accumulator fold.
+
+    The forward is the Pallas kernel; the backward (`jax.custom_vjp`) is plain
+    JAX — it recomputes `_block_attend` in fp32 and differentiates that.
+    `interpret` runs the Pallas interpreter (slow; a test asks for it).
+    """
+    s_q, s_kv = q.shape[1], k.shape[1]
+    if s_q % block_q or s_kv % block_k:
+        raise ValueError(
+            f"block sizes must divide the chunk lengths: got Sq={s_q} vs "
+            f"block_q={block_q}, Sk={s_kv} vs block_k={block_k}"
+        )
+    return _chunk(
+        q, k, v, q_positions, k_positions, causal, block_q, block_k, interpret
+    )

@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from stoix_tpu.parallel.mesh import shard_map
 
 
 def full_attention(
@@ -77,6 +76,7 @@ def ring_attention(
     axis_name: str,
     causal: bool = False,
     use_flash: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Exact attention with sequence sharded over `axis_name` (call inside
     shard_map). Per-device shapes [B, S_local, H, D].
@@ -87,18 +87,26 @@ def ring_attention(
 
     `use_flash` routes each block's contribution through the Pallas
     flash-attention chunk kernel (ops/pallas_attention.flash_attention_chunk)
-    — same (m, pv, l) accumulator contract, fused in VMEM. Defaults to on
-    when the backend is TPU and the kernel block size (128) divides the
-    shard length; forcing it on elsewhere runs the Pallas interpreter
-    (slow — for tests).
+    — same (m, pv, l) accumulator contract, fused in VMEM, compiled by Mosaic;
+    its backward is plain JAX (see that module). Left at None it is on when
+    the backend is TPU and the kernel block size (128) divides the shard
+    length, and the pure-JAX `_block_attend` otherwise. `interpret=True` runs
+    the kernel in the Pallas interpreter: a test asks for that (together with
+    `use_flash=True`) to validate the kernel off-TPU; this function never
+    chooses it.
     """
     axis_size = jax.lax.psum(1, axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     scale = q.shape[-1] ** -0.5
     s_local = q.shape[1]
+    block = min(128, s_local)
     if use_flash is None:
         use_flash = jax.default_backend() == "tpu" and s_local % 128 == 0
-    use_flash = use_flash and s_local % min(128, s_local) == 0
+    elif use_flash and s_local % block:
+        raise ValueError(
+            f"use_flash=True needs the shard length ({s_local}) to be a "
+            f"multiple of the kernel block ({block})"
+        )
 
     # Online-softmax accumulators — always fp32 (both the pure-JAX and the
     # Pallas chunk paths fold fp32 block stats; bf16 inputs still accumulate
@@ -111,13 +119,8 @@ def ring_attention(
     m_acc = jnp.full((b, h, s), -jnp.inf, jnp.float32)  # running max
     l_acc = jnp.zeros((b, h, s), jnp.float32)  # running normalizer
     o_acc = jnp.zeros((b, s, h, d), jnp.float32)  # unnormalized output
-    if hasattr(jax, "typeof") and hasattr(jax.lax, "pcast"):
-        # Legacy JAX has neither vma tracking nor pcast; its check_rep
-        # validation needs no varying-ness cast here.
-        vma = tuple(getattr(jax.typeof(q), "vma", None) or (axis_name,))
-        m_acc, l_acc, o_acc = jax.lax.pcast(
-            (m_acc, l_acc, o_acc), vma, to="varying"
-        )
+    vma = tuple(jax.typeof(q).vma or (axis_name,))
+    m_acc, l_acc, o_acc = jax.lax.pcast((m_acc, l_acc, o_acc), vma, to="varying")
 
     q_pos = my_idx * s_local + jnp.arange(s_local)  # global query positions
 
@@ -129,8 +132,6 @@ def ring_attention(
         if use_flash:
             from stoix_tpu.ops.pallas_attention import flash_attention_chunk
 
-            interpret = jax.default_backend() != "tpu"
-            block = min(128, s_local)
             pv_blk, m_blk, l_blk = flash_attention_chunk(
                 q, k_blk, v_blk, q_pos, k_pos, causal=causal,
                 block_q=block, block_k=block, interpret=interpret,
@@ -181,7 +182,7 @@ def make_ring_attention(mesh: Mesh, axis: str = "data", causal: bool = False):
     seq_spec = P(None, axis)
 
     ring = jax.jit(
-        shard_map(
+        jax.shard_map(
             partial(ring_attention, axis_name=axis, causal=causal),
             mesh=mesh,
             in_specs=(seq_spec, seq_spec, seq_spec),

@@ -27,11 +27,15 @@ Three interchangeable implementations, selected per call or process-wide:
             VMEM block_t rows at a time with a cross-block carry, so HBM sees
             one stream read + one stream write instead of scan's per-step
             dispatch. Within a block the op ORDER is exactly `scan`'s, so
-            float32 results are bit-identical to `scan` (the accumulator is
-            fp32 even for bf16 inputs, which `scan` does not do — documented
-            divergence for low-precision inputs). Off-TPU this impl falls back
-            to `scan` (same values; the Pallas interpreter is far slower than
-            XLA's scan on CPU — same posture as ops/pallas_attention.py).
+            float32 results are bit-identical to `scan` — compiled by Mosaic
+            on the v5e and checked bitwise there at [16, 2048] and
+            [128, 2048] by chip_smoke.py. The kernel is float32 only: other
+            dtypes are widened around it (an fp32 accumulator, which `scan`
+            does not have — documented divergence for low-precision inputs).
+            The dispatch takes the kernel exactly when the backend is TPU;
+            elsewhere `pallas` evaluates `scan` (same values; the Pallas
+            interpreter is far slower than XLA's scan on CPU). Interpret mode
+            is something a test asks for by calling the kernel directly.
 
 `n`-step windowed folds (n_step_bootstrapped_returns) are not a suffix scan —
 each output composes exactly n maps — so the `assoc`/`pallas` route uses
@@ -160,28 +164,26 @@ def _assoc_reverse(weight_t: Array, delta_t: Array, init: Array) -> Array:
 
 
 def _recurrence_kernel(w_ref, d_ref, init_ref, o_ref, acc_ref, *, block_t: int):
-    """One time block, walked bottom row up with the carry in VMEM scratch.
+    """One float32 time block, walked bottom row up.
 
     The grid's time axis is iterated LAST-block-first (the index_map reverses
     it), and TPU grids execute sequentially, so `acc_ref` legally carries the
     accumulator across blocks; it is (re)seeded from `init_ref` at the first
-    grid step of each batch block."""
-    t_idx = pl.program_id(1)
+    grid step of each batch block. Rows are read and written as 2-D
+    (1, block_b) slices: Mosaic addresses a dynamic sublane of a 32-bit ref
+    directly, and rank-1 vectors are avoided."""
 
-    @pl.when(t_idx == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _seed():
-        acc_ref[:] = init_ref[:].astype(jnp.float32)
+        acc_ref[...] = init_ref[...]
 
-    def body(j, _):
-        row = block_t - 1 - j
-        acc = d_ref[row, :].astype(jnp.float32) + w_ref[row, :].astype(
-            jnp.float32
-        ) * acc_ref[0, :]
-        acc_ref[0, :] = acc
-        o_ref[row, :] = acc.astype(o_ref.dtype)
-        return 0
+    def body(j, acc):
+        row = pl.ds(block_t - 1 - j, 1)
+        acc = d_ref[row, :] + w_ref[row, :] * acc
+        o_ref[row, :] = acc
+        return acc
 
-    jax.lax.fori_loop(0, block_t, body, 0)
+    acc_ref[...] = jax.lax.fori_loop(0, block_t, body, acc_ref[...])
 
 
 def _pad_tail(x: Array, axis: int, multiple: int, value: float) -> Array:
@@ -192,6 +194,9 @@ def _pad_tail(x: Array, axis: int, multiple: int, value: float) -> Array:
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths, constant_values=value)
+
+
+_SUBLANES = 8  # rows of one float32 VMEM tile; time blocks are whole tiles
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_b", "interpret"))
@@ -206,19 +211,28 @@ def pallas_linear_recurrence_reverse(
     """Time-blocked Pallas evaluation of the reverse linear recurrence.
 
     Accepts [T, ...] inputs (trailing dims flattened to one lane axis) with
-    `init` shaped like one timestep. Time is padded with identity maps
-    (w=1, d=0) — the padded rows are processed first and leave the carry at
-    `init` — and the batch axis is padded to the lane width. The in-block op
-    order is exactly `_scan_reverse`'s, with an fp32 accumulator.
+    `init` shaped like one timestep. The kernel itself is float32 only: other
+    dtypes are widened before the call and the result narrowed after it, which
+    is the fp32 accumulator the module docstring promises and keeps every
+    block a whole (8, 128) float32 tile (a bfloat16 tile is 16 rows and its
+    rows are packed in pairs, so a per-row walk over it is not compiled).
+    Time is padded with identity maps (w=1, d=0) — the padded rows are
+    processed first and leave the carry at `init` — and the batch axis is
+    padded to the lane width; `block_t` is rounded up to whole tiles. The
+    in-block op order is exactly `_scan_reverse`'s.
+
+    `interpret=True` runs the Pallas interpreter and is something a test asks
+    for; no training path sets it.
     """
     orig_shape = delta_t.shape
+    out_dtype = delta_t.dtype
     t_len = orig_shape[0]
-    w2 = weight_t.reshape(t_len, -1)
-    d2 = delta_t.reshape(t_len, -1)
-    init2 = init.reshape(1, -1).astype(delta_t.dtype)
+    w2 = weight_t.reshape(t_len, -1).astype(jnp.float32)
+    d2 = delta_t.reshape(t_len, -1).astype(jnp.float32)
+    init2 = init.reshape(1, -1).astype(jnp.float32)
     b_len = d2.shape[1]
 
-    block_t = min(block_t, max(8, t_len))
+    block_t = -(-min(block_t, t_len) // _SUBLANES) * _SUBLANES
     w2 = _pad_tail(w2, 0, block_t, 1.0)  # identity maps keep acc = init
     d2 = _pad_tail(d2, 0, block_t, 0.0)
     w2 = _pad_tail(w2, 1, block_b, 1.0)
@@ -239,16 +253,17 @@ def pallas_linear_recurrence_reverse(
             pl.BlockSpec((1, block_b), lambda i, j: (0, i)),
         ],
         out_specs=pl.BlockSpec((block_t, block_b), lambda i, j, nt=n_t: (nt - 1 - j, i)),
-        out_shape=_out_struct((t_pad, b_pad), delta_t.dtype, w2, d2, init2),
+        out_shape=_out_struct((t_pad, b_pad), jnp.float32, w2, d2, init2),
         scratch_shapes=[pltpu.VMEM((1, block_b), jnp.float32)],
         # Both grid axes carry state through the scratch accumulator; neither
         # may be parallelized across cores.
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
+        name="linear_recurrence_reverse",
         interpret=interpret,
     )(w2, d2, init2)
-    return out[:t_len, :b_len].reshape(orig_shape)
+    return out[:t_len, :b_len].astype(out_dtype).reshape(orig_shape)
 
 
 # ---------------------------------------------------------------------------
@@ -268,9 +283,10 @@ def linear_recurrence_reverse(
     if impl == "pallas":
         if jax.default_backend() == "tpu":
             return pallas_linear_recurrence_reverse(weight_t, delta_t, init)
-        # Portable fallback: same values (the kernel's op order IS the scan's),
-        # and XLA's scan beats the Pallas interpreter off-TPU by orders of
-        # magnitude — the same posture as pallas_attention.best_attention.
+        # Off-TPU: same values (the kernel's op order IS the scan's), and
+        # XLA's scan beats the Pallas interpreter by orders of magnitude — the
+        # same posture as pallas_attention.best_attention. Whether a run took
+        # the kernel is read from its jaxpr (chip_smoke.py), not from here.
         return _scan_reverse(weight_t, delta_t, init)
     return _scan_reverse(weight_t, delta_t, init)
 

@@ -15,7 +15,6 @@ from stoix_tpu.parallel.mesh import (
     fetch_global,
     fetch_global_async,
     materialize,
-    shard_map,
     axis_size,
     create_mesh,
     data_sharding,
@@ -47,7 +46,6 @@ __all__ = [
     "fetch_global",
     "fetch_global_async",
     "materialize",
-    "shard_map",
     "axis_size",
     "create_mesh",
     "data_sharding",

@@ -25,32 +25,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(fn, mesh: Mesh, in_specs: Any, out_specs: Any, check_vma: bool = True):
-    """`jax.shard_map` across JAX versions.
-
-    Newer JAX exposes `jax.shard_map` with a `check_vma` validation toggle;
-    older releases ship it as `jax.experimental.shard_map.shard_map` where the
-    same toggle is spelled `check_rep`. Every stoix_tpu shard_map goes through
-    this seam so the whole stack runs on both.
-
-    Legacy caveat: old shard_map's autodiff TRANSPOSES a loss-level cross-shard
-    pmean/psum to an axis-size-scaled gradient (2x on a 2-shard axis,
-    regardless of check_rep). Differentiate per-shard and pmean the GRADS —
-    the pattern every stoix_tpu learner uses — which is exact on both APIs;
-    tests/test_tp.py::test_backward_matches_oracle covers the unsupported
-    pattern and is skipped on legacy JAX.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    return _legacy_shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
-
-
 def create_mesh(
     axes: Optional[Dict[str, int]] = None, devices: Optional[Sequence[jax.Device]] = None
 ) -> Mesh:

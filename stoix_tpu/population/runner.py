@@ -42,7 +42,6 @@ from stoix_tpu.evaluator import get_distribution_act_fn
 from stoix_tpu.observability import RunStats, get_logger
 from stoix_tpu.ops import running_statistics
 from stoix_tpu.parallel import is_coordinator, materialize
-from stoix_tpu.parallel.mesh import shard_map
 from stoix_tpu.population import hparams as hparams_lib
 from stoix_tpu.population import pbt as pbt_lib
 from stoix_tpu.systems import anakin
@@ -282,7 +281,7 @@ def population_setup(
             train_metrics=train_metrics,
         )
 
-    learn_sm = shard_map(
+    learn_sm = jax.shard_map(
         per_shard_learn,
         mesh=mesh,
         in_specs=(pop_specs,),

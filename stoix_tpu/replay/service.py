@@ -36,7 +36,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from stoix_tpu.observability import get_registry
-from stoix_tpu.parallel.mesh import shard_map
 from stoix_tpu.replay.core import ShardedSample, make_sharded_replay
 
 
@@ -125,33 +124,33 @@ class ShardedReplayService:
         # largest live allocation on a learner device, and the service owns
         # it exclusively (the previous state is never read again).
         self._add = jax.jit(
-            shard_map(
+            jax.shard_map(
                 per_shard_add, mesh=mesh, in_specs=(P(axis), P(axis)),
                 out_specs=P(axis),
             ),
             donate_argnums=(0,),
         )
         self._sample = jax.jit(
-            shard_map(
+            jax.shard_map(
                 per_shard_sample, mesh=mesh, in_specs=(P(axis), P()),
                 out_specs=P(axis),
             )
         )
         self._set_priorities = jax.jit(
-            shard_map(
+            jax.shard_map(
                 per_shard_set_priorities, mesh=mesh,
                 in_specs=(P(axis), P(axis), P(axis)), out_specs=P(axis),
             ),
             donate_argnums=(0,),
         )
         self._can_sample = jax.jit(
-            shard_map(
+            jax.shard_map(
                 per_shard_can_sample, mesh=mesh, in_specs=(P(axis),),
                 out_specs=P(),
             )
         )
         self._stats = jax.jit(
-            shard_map(
+            jax.shard_map(
                 per_shard_stats, mesh=mesh, in_specs=(P(axis),),
                 out_specs=(P(axis), P(axis)),
             )

@@ -250,7 +250,6 @@ def build_fingerprint_fn(
     import jax
     from jax.sharding import PartitionSpec
 
-    from stoix_tpu.parallel.mesh import shard_map
 
     groups = replicated_group_specs(template)
     if not groups:
@@ -277,7 +276,7 @@ def build_fingerprint_fn(
         return out
 
     program = jax.jit(
-        shard_map(
+        jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=(PartitionSpec(),),

@@ -99,7 +99,17 @@ def probe_backend(
     The parent never imports jax here and never blocks past
     `attempts * timeout_s + backoffs`: a wedged runtime wedges the CHILD,
     which the timeout kills. Raises BackendUnavailableError when every
-    attempt fails."""
+    attempt fails.
+
+    One process per chip: the child DOES touch the accelerator (it runs a
+    matmul on it), and an accelerator belongs to one process at a time. So
+    call this BEFORE the calling process's first backend call — a caller that
+    already holds the chip makes the child fail or hang — and rely on the
+    child having exited before this returns: `subprocess.run` waits for it,
+    and kills and reaps it on timeout. Every caller keeps that order
+    (bench.py main, systems/runner.py and both Sebulba systems probe before
+    `maybe_initialize_distributed`/mesh construction; launcher.py re-probes
+    between child incarnations)."""
     log = get_logger("stoix_tpu.resilience")
     counter = get_registry().counter(
         "stoix_tpu_preflight_probe_attempts_total",

@@ -498,6 +498,10 @@ class AsyncEvaluator:
             "stoix_tpu_sebulba_queue_depth",
             "Items currently buffered per Sebulba queue",
         )
+        # Evaluations that raised, in order (appended by the evaluator thread,
+        # read by wait_until_idle after the queue drained).
+        self._failures: list = []
+        self._failures_lock = threading.Lock()
         self.thread = threading.Thread(target=self._run, name="async-evaluator", daemon=True)
 
     def submit(self, params: Any, key: jax.Array, t: int) -> None:
@@ -528,10 +532,15 @@ class AsyncEvaluator:
                     metrics = self._evaluate(params, key)
                     self._on_result(metrics, params, t)
                 self.heartbeats.beat("evaluator")
-            except Exception:  # noqa: BLE001 — a lost eval window must not
-                # kill the thread silently nor wedge shutdown on a cleared
-                # _idle flag (mirrors rollout_thread's crash telemetry).
+            except Exception as exc:  # noqa: BLE001 — a lost eval window must
+                # not kill the thread silently nor wedge shutdown on a cleared
+                # _idle flag (mirrors rollout_thread's crash telemetry). The
+                # thread lives on; the RUN still fails — wait_until_idle
+                # raises for every recorded failure.
                 import traceback
+
+                with self._failures_lock:
+                    self._failures.append(exc)
 
                 get_registry().counter(
                     "stoix_tpu_sebulba_evaluator_errors_total",
@@ -550,8 +559,19 @@ class AsyncEvaluator:
         """Block until all submitted evaluations completed. A timeout RAISES
         (EvaluatorStallError with the evaluator's last-heartbeat age) instead
         of silently returning — shutdown must not proceed while evaluation
-        work is still dangling (it would be dropped unreported)."""
+        work is still dangling (it would be dropped unreported). So does a
+        FAILED evaluation: once idle, any request that raised on the evaluator
+        thread surfaces here as a ComponentFailure, so a run whose evaluations
+        died cannot return as if it had been evaluated."""
         if not self._idle.wait(timeout=timeout):
             raise EvaluatorStallError(
                 timeout, self.heartbeats.age("evaluator"), self._requests.qsize()
+            )
+        with self._failures_lock:
+            failures = list(self._failures)
+        if failures:
+            raise ComponentFailure(
+                "async-evaluator",
+                f"{len(failures)} evaluation request(s) raised; first",
+                cause=failures[0],
             )

@@ -39,6 +39,7 @@ from stoix_tpu.serve.engine import InferenceEngine
 from stoix_tpu.serve.errors import ServerClosedError, ServerOverloadError
 from stoix_tpu.serve.hotswap import ParameterWatcher
 from stoix_tpu.serve.telemetry import ServeTelemetry
+from stoix_tpu.utils import compilecache
 
 
 class ServeResult(NamedTuple):
@@ -116,6 +117,9 @@ class PolicyServer:
         i.e. the pre-MeshRoles placement). Pass `roles` to share one
         MeshRoles object across subsystems (e.g. a colocated train+serve
         deployment)."""
+        # Compile economy (docs/DESIGN.md §2.7): the persistent cache goes on
+        # before the policy is rebuilt and the buckets are warmed.
+        compilecache.configure(config)
         bundle = serve_checkpoint.load_policy(config)
         serve_cfg = config.arch.serve
         if roles is None:

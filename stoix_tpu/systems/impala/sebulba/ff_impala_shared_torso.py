@@ -19,7 +19,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from stoix_tpu.base_types import ActorCriticOptStates, ActorCriticParams, PPOTransition
 from stoix_tpu.ops import running_statistics
-from stoix_tpu.parallel.mesh import shard_map
 from stoix_tpu.systems.ppo.sebulba.ff_ppo import CoreLearnerState, run_experiment as _run
 from stoix_tpu.utils import config as config_lib
 
@@ -107,7 +106,7 @@ def get_shared_impala_learn_step(actor_apply, critic_apply, update_fns, config, 
         return CoreLearnerState(params, new_opts, state.key, obs_stats), metrics
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             per_shard,
             mesh=mesh,
             in_specs=(CoreLearnerState(P(), P(), P(), P()), P(None, "data")),

@@ -19,7 +19,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from stoix_tpu import envs
 from stoix_tpu.base_types import ExperimentOutput, OffPolicyLearnerState, Transition
 from stoix_tpu.buffers import make_item_buffer
-from stoix_tpu.parallel.mesh import shard_map
 from stoix_tpu.systems import anakin
 from stoix_tpu.utils.jax_utils import tree_merge_leading_dims
 
@@ -264,7 +263,7 @@ def wrap_learn_and_warmup(
         )
 
     warmup = jax.jit(
-        shard_map(
+        jax.shard_map(
             per_shard_warmup, mesh=mesh, in_specs=(state_specs,),
             # Same Anakin opt-out as systems/anakin.py: the in-shard
             # update-batch vmap axis' pmean fails check_vma's internal

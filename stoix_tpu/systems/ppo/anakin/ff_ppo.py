@@ -608,7 +608,6 @@ def grouped_learner_setup(
     import os
 
     from stoix_tpu.parallel import gossip as gossip_lib
-    from stoix_tpu.parallel.mesh import shard_map
     from stoix_tpu.systems import anakin
 
     gossip_lib.validate_grouped_config(config, mesh)
@@ -695,7 +694,7 @@ def grouped_learner_setup(
         out = learn_member(local)
         return jax.tree.map(lambda x: x[None], out)
 
-    learn_sm = shard_map(
+    learn_sm = jax.shard_map(
         per_shard_learn,
         mesh=mesh,
         in_specs=(grouped_specs,),

@@ -58,7 +58,6 @@ from stoix_tpu.ops import (
     truncated_generalized_advantage_estimation,
 )
 from stoix_tpu.parallel import MeshRoles, assemble_global_array
-from stoix_tpu.parallel.mesh import shard_map
 from stoix_tpu.resilience import (
     PreemptionHandler,
     faultinject,
@@ -68,7 +67,7 @@ from stoix_tpu.resilience import (
     preflight,
     supervisor_from_config,
 )
-from stoix_tpu.resilience.errors import EvaluatorStallError
+from stoix_tpu.resilience.errors import ComponentFailure, EvaluatorStallError
 from stoix_tpu.sebulba.core import (
     AsyncEvaluator,
     OffPolicyPipeline,
@@ -371,7 +370,7 @@ def get_learn_step(actor_apply, critic_apply, update_fns, config, mesh: Mesh):
         return CoreLearnerState(params, opt_states, key, obs_stats), metrics
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             per_shard,
             mesh=mesh,
             in_specs=(CoreLearnerState(P(), P(), P(), P()), P(None, "data")),
@@ -514,7 +513,7 @@ def get_impact_learn_step(
         return CoreLearnerState(params, opt_states, key, obs_stats), metrics
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             per_shard,
             mesh=mesh,
             in_specs=(CoreLearnerState(P(), P(), P(), P()), P(), P(None, "data")),
@@ -1227,15 +1226,16 @@ def run_experiment(
         failure_propagating = sys.exc_info()[0] is not None
         try:
             async_evaluator.wait_until_idle(timeout=120.0)
-        except EvaluatorStallError:
+        except (EvaluatorStallError, ComponentFailure) as exc:
             # Raising from a finally would REPLACE the failure that brought
             # us here (actor ComponentFailure, learner divergence); surface
-            # the stall as the primary error only on the clean-exit path.
+            # a stalled or failed evaluator as the primary error only on the
+            # clean-exit path.
             if not failure_propagating:
                 raise
             get_logger("stoix_tpu.sebulba").error(
-                "[shutdown] evaluator still busy while handling another "
-                "failure — dropping its in-flight work"
+                "[shutdown] evaluator did not finish cleanly while handling "
+                "another failure (%s) — dropping its work", exc,
             )
 
     if steady_start_time is not None and t_steps > steady_start_steps:

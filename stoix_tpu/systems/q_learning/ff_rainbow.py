@@ -20,7 +20,6 @@ from stoix_tpu.base_types import ExperimentOutput, OffPolicyLearnerState, Online
 from stoix_tpu.buffers import make_prioritised_trajectory_buffer
 from stoix_tpu.evaluator import get_distribution_act_fn
 from stoix_tpu.ops import categorical_l2_project
-from stoix_tpu.parallel.mesh import shard_map
 from stoix_tpu.systems import anakin, off_policy_core as core
 from stoix_tpu.systems.runner import AnakinSetup, run_anakin_experiment
 from stoix_tpu.utils import config as config_lib
@@ -239,7 +238,7 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
         )
 
     warmup = jax.jit(
-        shard_map(
+        jax.shard_map(
             per_shard_warmup, mesh=mesh, in_specs=(state_specs,),
             out_specs=state_specs, check_vma=False,
         )

@@ -54,7 +54,6 @@ from stoix_tpu.observability import (
     span,
 )
 from stoix_tpu.parallel import MeshRoles, assemble_global_array
-from stoix_tpu.parallel.mesh import shard_map
 from stoix_tpu.replay import ShardedReplayService, service_from_config
 from stoix_tpu.resilience import (
     PreemptionHandler,
@@ -62,7 +61,7 @@ from stoix_tpu.resilience import (
     guards,
     supervisor_from_config,
 )
-from stoix_tpu.resilience.errors import EvaluatorStallError
+from stoix_tpu.resilience.errors import ComponentFailure, EvaluatorStallError
 from stoix_tpu.sebulba.core import (
     AsyncEvaluator,
     OffPolicyPipeline,
@@ -177,7 +176,7 @@ def get_dqn_learn_step(
         return state, jax.tree.map(lambda x: x[None], rstate), metrics
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             per_shard,
             mesh=mesh,
             in_specs=(P(), P("data")),
@@ -643,12 +642,16 @@ def run_experiment(config: Any) -> float:
         failure_propagating = sys.exc_info()[0] is not None
         try:
             async_evaluator.wait_until_idle(timeout=120.0)
-        except EvaluatorStallError:
+        except (EvaluatorStallError, ComponentFailure) as exc:
+            # Raising from a finally would REPLACE the failure that brought
+            # us here (actor ComponentFailure, learner divergence); surface
+            # a stalled or failed evaluator as the primary error only on the
+            # clean-exit path.
             if not failure_propagating:
                 raise
             get_logger("stoix_tpu.sebulba").error(
-                "[shutdown] evaluator still busy while handling another "
-                "failure — dropping its in-flight work"
+                "[shutdown] evaluator did not finish cleanly while handling "
+                "another failure (%s) — dropping its work", exc,
             )
 
     final_items = ingested_items()

@@ -259,10 +259,10 @@ def run_anakin_experiment(
     # out-of-loop sites (faultinject stalls, watchdog) charge their seconds.
     ledger = goodput.GoodputLedger().start()
     goodput.set_active(ledger)
-    # Compile economy (docs/DESIGN.md §2.7): the persistent-cache knobs must
-    # land before the FIRST compile this process does (network init included),
-    # and the multistep scan-kernel default before the learner is traced —
-    # both are trace/compile-time statics, so the off defaults add zero work.
+    # Compile economy (docs/DESIGN.md §2.7): the persistent cache must be
+    # configured before the FIRST compile this process does (network init
+    # included), and the multistep scan-kernel default before the learner is
+    # traced — both are trace/compile-time statics.
     compilecache.configure(config)
     scan_kernels.configure_from_config(config)
     # Launch hardening (docs/DESIGN.md §2.4): probe the backend in a
@@ -498,8 +498,7 @@ def run_anakin_experiment(
     # set, the non-fused learner additionally round-trips the jax.export AOT
     # store (docs/DESIGN.md §2.7): a matching serialized artifact skips
     # trace+lower here, and a miss serializes this compile for peer hosts.
-    cc_settings = compilecache.settings_from_config(config)
-    export_dir = cc_settings["export_dir"] if cc_settings["enabled"] else None
+    export_dir = compilecache.settings_from_config(config)["export_dir"]
     cache_before = compilecache.cache_stats()
     aot_info = {"source": "compile", "export_path": None}
     t0 = time.perf_counter()

@@ -197,13 +197,13 @@ def read_host_leaves(store_dir: str, step: int) -> Dict[Tuple[str, ...], Any]:
     the devices recorded AT SAVE TIME, which need not exist on the restoring
     host — forcing numpy never touches device placement."""
     step_path = os.path.join(store_dir, str(step), "default")
-    if not os.path.isdir(step_path):  # older orbax layouts: no item subdir
-        step_path = os.path.join(store_dir, str(step))
     reader = ocp.Checkpointer(ocp.PyTreeCheckpointHandler())
     try:
-        raw_meta = reader.metadata(step_path)
+        # orbax returns a StepMetadata; the saved tree's per-leaf metadata is
+        # its `.item_metadata.tree`.
+        saved_tree = reader.metadata(step_path).item_metadata.tree
         restore_args = jax.tree.map(
-            lambda _m: ocp.RestoreArgs(restore_type=np.ndarray), raw_meta
+            lambda _m: ocp.RestoreArgs(restore_type=np.ndarray), saved_tree
         )
         raw = reader.restore(
             step_path, args=ocp.args.PyTreeRestore(restore_args=restore_args)

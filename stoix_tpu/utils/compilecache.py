@@ -1,20 +1,27 @@
 """Compile economy: persistent XLA compilation cache + `jax.export` AOT store.
 
-Two mechanisms, both wired through the `arch.compile_cache` config block
-(docs/DESIGN.md §2.7) and both off by default (zero work, bit-identical):
+1. **Persistent compilation cache — always on, placed from outside.**
+   `configure()` runs before the first compile of every entry point that
+   compiles (Anakin runner, Sebulba systems, population, serve, loop,
+   bench.py, chip_smoke.py). Where it lives is decided in exactly one place,
+   `cache_dir()`:
 
-1. **Persistent compilation cache.** `configure()` points
-   `jax_compilation_cache_dir` at a shared directory (with the
-   min-entry-size / min-compile-time admission knobs) BEFORE the first
-   compile, so every re-run — and every peer host of a multi-host fleet
-   launch sharing the directory — pays XLA's multi-minute learner compile
-   once instead of N times. Cache hits/misses are observable: jax's
-   `/jax/compilation_cache/*` monitoring events are folded into the PR 2
-   metrics registry as `stoix_tpu_compile_persistent_cache_events_total
-   {event=hit|miss}` and surfaced as first-class `cache_hits` bench payload
-   fields. A corrupted cache entry degrades to a recompile, never a crash
-   (`jax_raise_persistent_cache_errors` stays False;
-   tests/test_compilecache.py pins it).
+     * `JAX_COMPILATION_CACHE_DIR` set  → that directory. jax reads the
+       variable into `jax_compilation_cache_dir` itself; this module then
+       never writes that option.
+     * unset → `<checkout>/xla_cache`, one fixed absolute path derived from
+       this file's location (git-ignored). Never the working directory, a
+       temp name, a pid or the time: the path is part of what a second
+       process must agree on to hit.
+
+   The `arch.compile_cache` block only carries the two admission knobs
+   (min entry size / min compile time) and the export directory. Cache
+   hits/misses are observable: jax's `/jax/compilation_cache/*` monitoring
+   events are folded into the metrics registry as
+   `stoix_tpu_compile_persistent_cache_events_total{event=hit|miss}` and
+   surfaced by `cache_stats()`. A corrupted cache entry degrades to a
+   recompile, never a crash (`jax_raise_persistent_cache_errors` stays
+   False; tests/test_compilecache.py pins it).
 
 2. **AOT export of the top-level learn function.** `warmup_with_export`
    extends `utils/jax_utils.aot_warmup`: when `arch.compile_cache.export_dir`
@@ -23,12 +30,12 @@ Two mechanisms, both wired through the `arch.compile_cache` config block
    input avals / topology / jax version, else compiled once and serialized
    for peers. The deserialized path trades buffer donation for tracing
    economy (an `Exported.call` cannot donate its operands — documented in
-   §2.7), so it is opt-in and separate from the cache dir knob.
+   §2.7), so it is opt-in.
 
 Everything here is host-side setup code: nothing in this module is
-jit-reachable, and failures downgrade with a logged warning instead of
-killing a launch (an AOT store is an optimization, never a correctness
-dependency).
+jit-reachable. A missing or unloadable EXPORT artifact downgrades to a compile
+from source with a logged warning; a failed COMPILE is never swallowed
+(`aot_warmup` raises).
 """
 
 from __future__ import annotations
@@ -44,8 +51,7 @@ import jax.export as jax_export
 
 from stoix_tpu.observability import get_logger, get_registry
 
-# jax's monitoring event names for the persistent compilation cache
-# (stable across the 0.4.x line; unknown names simply never fire).
+# jax's monitoring event names for the persistent compilation cache.
 _EVENT_HITS = "/jax/compilation_cache/cache_hits"
 _EVENT_MISSES = "/jax/compilation_cache/cache_misses"
 
@@ -92,65 +98,78 @@ def cache_stats() -> Dict[str, int]:
     }
 
 
-def configure_cache(
-    cache_dir: str,
-    min_entry_size_bytes: int = 0,
-    min_compile_time_secs: float = 0.0,
-) -> None:
-    """Point jax's persistent compilation cache at `cache_dir` with the given
-    admission knobs, and start recording hit/miss metrics. Must run before
-    the first compile of interest; later compiles in this process all flow
-    through the cache."""
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-    jax.config.update(
-        "jax_persistent_cache_min_entry_size_bytes", int(min_entry_size_bytes)
-    )
-    jax.config.update(
-        "jax_persistent_cache_min_compile_time_secs", float(min_compile_time_secs)
-    )
-    # jax latches is-the-cache-used ONCE per process, at its first compile: a
-    # single jit executed before this point (an import-time helper, an env
-    # probe) would silently disable the cache for the whole run. Reset the
-    # latch so it re-evaluates under the directory we just configured.
-    from jax.experimental.compilation_cache import compilation_cache
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
-    compilation_cache.reset_cache()
-    install_cache_metrics_listener()
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    "xla_cache",
+)
+
+
+# (directory, min entry bytes, min compile secs) last applied by configure().
+_applied: Optional[Tuple[str, int, float]] = None
+
+
+def cache_dir() -> str:
+    """Where the persistent cache lives: the variable when set, else the one
+    fixed path inside the checkout."""
+    return os.environ.get(CACHE_DIR_ENV) or _CHECKOUT_CACHE_DIR
 
 
 def settings_from_config(config: Any) -> Dict[str, Any]:
     """The `arch.compile_cache` block as a plain dict with defaults applied
-    (the dict-style read keeps STX009 happy on configs that omit the block)."""
-    block = (config.arch.get("compile_cache") or {})
+    (the dict-style read keeps STX009 happy on configs that omit the block;
+    `config=None` — entry points without an arch config — is all defaults)."""
+    block = (config.arch.get("compile_cache") or {}) if config is not None else {}
+    min_compile = block.get("min_compile_time_secs")
     return {
-        "enabled": bool(block.get("enabled", False)),
-        "dir": block.get("dir") or os.path.join("checkpoints", "xla_cache"),
         "min_entry_size_bytes": int(block.get("min_entry_size_bytes", 0) or 0),
-        "min_compile_time_secs": float(block.get("min_compile_time_secs", 0.0) or 0.0),
+        # jax's own default admission: programs that took >= 1 s to compile.
+        "min_compile_time_secs": 1.0 if min_compile is None else float(min_compile),
         "export_dir": block.get("export_dir"),
     }
 
 
-def configure(config: Any) -> bool:
-    """Wire the persistent cache from `arch.compile_cache`; returns whether it
-    was enabled. Runs before any compile in both run entry points
-    (systems/runner.py and the Sebulba learner)."""
+def configure(config: Any = None) -> str:
+    """Turn jax's persistent compilation cache on at `cache_dir()` with the
+    config's admission knobs, start recording hit/miss metrics, and return the
+    directory. Must run before the first compile of interest; later compiles
+    in this process all flow through the cache."""
+    global _applied
     settings = settings_from_config(config)
-    if not settings["enabled"]:
-        return False
-    configure_cache(
-        settings["dir"],
-        min_entry_size_bytes=settings["min_entry_size_bytes"],
-        min_compile_time_secs=settings["min_compile_time_secs"],
+    directory = cache_dir()
+    applied = (
+        directory, settings["min_entry_size_bytes"], settings["min_compile_time_secs"]
     )
+    if applied == _applied:
+        # A process that runs several experiments (chip_smoke.py, bench.py
+        # --all, the tests) re-enters here with nothing to change.
+        return directory
+    os.makedirs(directory, exist_ok=True)
+    if not os.environ.get(CACHE_DIR_ENV):
+        jax.config.update("jax_compilation_cache_dir", directory)
+    jax.config.update(
+        "jax_persistent_cache_min_entry_size_bytes", settings["min_entry_size_bytes"]
+    )
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", settings["min_compile_time_secs"]
+    )
+    # jax latches is-the-cache-used ONCE per process, at its first compile: a
+    # single jit executed before this point (an import-time helper, an env
+    # probe) would silently disable the cache for the whole run. Reset the
+    # latch so it re-evaluates under the directory configured above.
+    from jax.experimental.compilation_cache import compilation_cache
+
+    compilation_cache.reset_cache()
+    install_cache_metrics_listener()
+    _applied = applied
     get_logger("stoix_tpu.compilecache").info(
         "[compilecache] persistent XLA cache at %s (min entry %d B, min "
         "compile %.1f s)",
-        settings["dir"], settings["min_entry_size_bytes"],
+        directory, settings["min_entry_size_bytes"],
         settings["min_compile_time_secs"],
     )
-    return True
+    return directory
 
 
 # ---------------------------------------------------------------------------

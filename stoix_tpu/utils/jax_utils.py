@@ -48,16 +48,21 @@ def aot_compile(fn: Any, *example_args: Any) -> Any:
 
 def aot_warmup(jit_fn: Any, *example_args: Any) -> Any:
     """AOT-compile an ALREADY-jitted callable for the given example arguments
-    and return the compiled executable; the jitted fn itself is returned when
-    AOT lowering is unsupported (non-jitted wrappers, exotic backends), in
-    which case compilation happens on the first call instead.
+    and return the compiled executable. A lowering or compile error RAISES
+    here, at the warmup site: returning the un-compiled function instead would
+    move the failure (a Mosaic refusal of a Pallas kernel, an HBM overflow) to
+    the first call inside the timed loop, where it reads as something else.
+
+    A plain Python wrapper with no `.lower` (a recording wrapper around the
+    jitted learner) has nothing to compile ahead of time and is returned as
+    is; the jit inside it compiles — and fails, if it fails — on its first
+    call. Wrappers that want the warmup forward `.lower` to their inner jit
+    (population/runner.py does).
 
     Donation declared on the jit (donate_argnums) is preserved by the compiled
     executable. The Anakin runner uses this to pay the learner's XLA compile
     BEFORE the timed host loop, so the first eval window's steps_per_second is
-    a real throughput number rather than compile time (the compile used to
-    pollute it, runner.py)."""
-    try:
-        return jit_fn.lower(*example_args).compile()
-    except Exception:  # noqa: BLE001 — any lowering failure degrades gracefully
+    a real throughput number rather than compile time."""
+    if not hasattr(jit_fn, "lower"):
         return jit_fn
+    return jit_fn.lower(*example_args).compile()

@@ -11,7 +11,7 @@ import numpy as np
 
 from stoix_tpu.networks.attention import TransformerTorso
 from stoix_tpu.ops.ring_attention import ring_attention
-from stoix_tpu.parallel import shard_map, create_mesh
+from stoix_tpu.parallel import create_mesh
 from jax.sharding import PartitionSpec as P
 
 
@@ -70,7 +70,7 @@ def test_ring_attention_plugs_in_and_matches_full():
     expected = full_torso.apply(params, x)
 
     sharded_apply = jax.jit(
-        shard_map(
+        jax.shard_map(
             apply_sharded,
             mesh=mesh,
             in_specs=(P(), P(None, "data")),

@@ -1,10 +1,9 @@
 """`bench.py --check` — the variance-aware regression gate's contract.
 
 Exit semantics for CI / fleet prologs: 0 = every compared metric within its
-variance band, 1 = regression / posture mismatch / failed workload, 2 = usage
-or file errors. The gate never imports jax (subprocess tests assert it stays
-fast enough for a prolog) and NEVER numerically compares a CPU-fallback
-payload against a device baseline.
+variance band, 1 = regression / failed workload, 2 = usage or file errors.
+The gate never imports jax (subprocess tests assert it stays fast enough for
+a prolog).
 """
 
 import importlib.util
@@ -28,7 +27,7 @@ def _bench():
 def _payload(metric="anakin_ppo_ant_env_steps_per_sec", median=10000.0, **over):
     return {
         "metric": metric, "value": median * 1.02, "median": median,
-        "rel_spread": 0.05, "fallback": False, **over,
+        "rel_spread": 0.05, **over,
     }
 
 
@@ -134,23 +133,6 @@ def test_improvement_never_fails():
     bench = _bench()
     code, verdicts = bench.check_payloads(
         [_payload()], [_payload(median=50000.0)]
-    )
-    assert code == 0, verdicts
-
-
-def test_fallback_vs_device_refused_both_directions():
-    bench = _bench()
-    for base_fb, cand_fb in [(False, True), (True, False)]:
-        code, verdicts = bench.check_payloads(
-            [_payload(fallback=base_fb)],
-            # Even a BETTER fallback number must be refused: it is not a
-            # measurement of the tracked hardware.
-            [_payload(median=99999.0, fallback=cand_fb)],
-        )
-        assert code == 1 and "posture mismatch" in verdicts[0]["reason"], verdicts
-    # Matching fallback posture (both CPU) compares normally.
-    code, verdicts = bench.check_payloads(
-        [_payload(fallback=True)], [_payload(median=9900.0, fallback=True)]
     )
     assert code == 0, verdicts
 
@@ -312,13 +294,13 @@ def test_multichip_record_converts_and_gates(tmp_path):
         {
             "metric": "multichip_dryrun_ok_d8", "value": 1.0, "median": 1.0,
             "rel_spread": 0.0, "unit": "dry-run success (1.0 = ok)",
-            "rc": 0, "fallback": False,
+            "rc": 0,
         }
     ]
     # ok vs ok: pass.
     code, verdicts = bench.check_payloads(baselines, baselines)
     assert code == 0, verdicts
-    # A broken dry run (the repo's own MULTICHIP_r01 shape: rc=124 timeout)
+    # A broken dry run (rc=124 timeout)
     # is a zero-median candidate -> loud failed-workload verdict.
     broken = bench._parse_payload_lines(
         json.dumps({"n_devices": 8, "rc": 124, "ok": False, "skipped": False})

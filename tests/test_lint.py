@@ -194,7 +194,7 @@ def test_stx006_factory_returned_learner_is_reachable():
     # in the returned learner must be found.
     rule = get_rule("STX006")
     source = (
-        "import jax\nfrom stoix_tpu.parallel.mesh import shard_map\n\n\n"
+        "import jax\nfrom jax import shard_map\n\n\n"
         "def get_learner_fn(config):\n"
         "    def learner_fn(state):\n"
         "        return state.loss.item()\n"
@@ -526,7 +526,7 @@ def test_stx011_partial_bound_args_drop_out_of_arity():
     source = (
         "from functools import partial\n"
         "from jax.sharding import PartitionSpec as P\n"
-        "from stoix_tpu.parallel.mesh import shard_map\n\n\n"
+        "from jax import shard_map\n\n\n"
         "def per_shard(cfg, batch):\n"
         "    return batch\n\n\n"
         "def build(mesh, cfg):\n"
@@ -543,7 +543,7 @@ def test_stx011_literal_axis_names_tuple_is_not_a_wildcard():
     rule = get_rule("STX011")
     source = (
         "from jax.sharding import PartitionSpec as P\n"
-        "from stoix_tpu.parallel.mesh import shard_map\n"
+        "from jax import shard_map\n"
         "from stoix_tpu.resilience import guards\n\n\n"
         "def per_shard(batch):\n"
         '    out, _ = guards.guard_update("skip", new=batch, old=batch,\n'
@@ -563,7 +563,7 @@ def test_stx011_variable_axis_name_suppresses_replication_check():
     rule = get_rule("STX011")
     source = (
         "import jax\nfrom jax.sharding import PartitionSpec as P\n"
-        "from stoix_tpu.parallel.mesh import shard_map\n\n\n"
+        "from jax import shard_map\n\n\n"
         "def make(axis):\n"
         "    def per_shard(batch):\n"
         "        return jax.lax.pmean(batch, axis_name=axis)\n\n"

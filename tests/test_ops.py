@@ -2,7 +2,6 @@
 
 import jax
 
-from stoix_tpu.parallel import shard_map
 import jax.numpy as jnp
 import numpy as np
 import scipy.stats
@@ -239,7 +238,7 @@ def test_running_statistics_psum_over_mesh(devices):
         return running_statistics.update(state, batch, axis_names=("data",))
 
     state = running_statistics.init_state(template)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_update,
         mesh=mesh,
         in_specs=(P(), P("data")),

@@ -108,7 +108,7 @@ def test_ring_attention_matches_full_attention(use_flash):
     from jax.sharding import PartitionSpec as P
 
     from stoix_tpu.ops.ring_attention import ring_attention
-    from stoix_tpu.parallel import shard_map, create_mesh
+    from stoix_tpu.parallel import create_mesh
 
     mesh = create_mesh({"data": -1})  # all 8 virtual CPU devices
     b, s, h, d = 1, 64, 2, 16
@@ -120,9 +120,10 @@ def test_ring_attention_matches_full_attention(use_flash):
     # workaround). The compiled Mosaic path on real TPU never interprets the
     # kernel body, so the check stays on everywhere else.
     ring = jax.jit(
-        shard_map(
+        jax.shard_map(
             partial(
-                ring_attention, axis_name="data", causal=True, use_flash=use_flash
+                ring_attention, axis_name="data", causal=True,
+                use_flash=use_flash, interpret=use_flash,
             ),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=not use_flash,
@@ -131,3 +132,82 @@ def test_ring_attention_matches_full_attention(use_flash):
     got = ring(q, k, v)
     want = full_attention(q, k, v, causal=True)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+# ---- gradients: the kernels' custom_vjp backward is plain JAX -----------------
+
+GRAD_TOL = 1e-4  # float32; forward kernel vs reference differ by reassociation
+
+
+def _weighted_loss(attend, w):
+    # A non-uniform cotangent so the backward sees more than ones.
+    return lambda q, k, v: jnp.sum(attend(q, k, v) * w)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_gradient_matches_full_attention(causal):
+    from functools import partial
+
+    q, k, v = _rand_qkv(jax.random.PRNGKey(6), 2, 100, 2, 32)
+    w = jax.random.normal(jax.random.PRNGKey(7), q.shape)
+    flash = partial(
+        flash_attention, causal=causal, block_q=64, block_k=64, interpret=True
+    )
+    got = jax.grad(_weighted_loss(flash, w), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(
+        _weighted_loss(partial(full_attention, causal=causal), w), argnums=(0, 1, 2)
+    )(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, atol=GRAD_TOL, rtol=GRAD_TOL)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_ring_flash_gradient_matches_full_attention(causal):
+    from functools import partial
+
+    from jax.sharding import PartitionSpec as P
+
+    from stoix_tpu.ops.ring_attention import ring_attention
+    from stoix_tpu.parallel import create_mesh
+
+    mesh = create_mesh({"data": -1})
+    q, k, v = _rand_qkv(jax.random.PRNGKey(8), 1, 64, 2, 16)
+    w = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+    spec = P(None, "data")
+    # check_vma=False: see test_ring_attention_matches_full_attention.
+    ring = jax.shard_map(
+        partial(
+            ring_attention, axis_name="data", causal=causal,
+            use_flash=True, interpret=True,
+        ),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+    )
+    got = jax.jit(jax.grad(_weighted_loss(ring, w), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.grad(
+        _weighted_loss(partial(full_attention, causal=causal), w), argnums=(0, 1, 2)
+    )(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, atol=GRAD_TOL, rtol=GRAD_TOL)
+
+
+def test_ring_never_interprets_by_itself():
+    # Interpretation is something a test asks for: with use_flash forced on and
+    # interpret left at its default, the traced program holds a pallas_call
+    # with interpret=False whatever the backend is.
+    from functools import partial
+
+    from jax.sharding import PartitionSpec as P
+
+    from stoix_tpu.ops.ring_attention import ring_attention
+    from stoix_tpu.parallel import create_mesh
+
+    mesh = create_mesh({"data": -1})
+    q, k, v = _rand_qkv(jax.random.PRNGKey(10), 1, 64, 2, 16)
+    spec = P(None, "data")
+    ring = jax.shard_map(
+        partial(ring_attention, axis_name="data", use_flash=True),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+    )
+    jaxpr = str(jax.make_jaxpr(ring)(q, k, v))
+    assert "pallas_call" in jaxpr
+    assert "interpret=False" in jaxpr and "interpret=True" not in jaxpr

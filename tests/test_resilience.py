@@ -491,6 +491,40 @@ def test_async_evaluator_stall_raises_named_error():
     lifetime.stop()
 
 
+def test_async_evaluator_failed_eval_fails_the_run():
+    # A raised evaluation leaves the thread alive (the next request is still
+    # served) and is counted, but it must not be survivable: once idle,
+    # wait_until_idle raises, so run_experiment cannot return as if evaluated.
+    from stoix_tpu.observability import get_registry
+    from stoix_tpu.sebulba.core import AsyncEvaluator, ThreadLifetime
+
+    counter = get_registry().counter(
+        "stoix_tpu_sebulba_evaluator_errors_total",
+        "Async evaluation requests that raised",
+    )
+    errors_before = counter.value()
+    lifetime = ThreadLifetime()
+    served = []
+
+    def flaky_eval(params, key):
+        if params["p"] == 0:
+            raise ValueError("eval blew up (unit test)")
+        return {"episode_return": np.zeros(1)}
+
+    evaluator = AsyncEvaluator(flaky_eval, lifetime, lambda m, p, t: served.append(t))
+    evaluator.thread.start()
+    evaluator.submit({"p": 0}, jax.random.PRNGKey(0), 0)
+    evaluator.submit({"p": 1}, jax.random.PRNGKey(0), 1)
+    with pytest.raises(ComponentFailure, match="async-evaluator") as excinfo:
+        evaluator.wait_until_idle(timeout=10.0)
+    assert isinstance(excinfo.value.__cause__, ValueError)
+    assert served == [1] and evaluator.thread.is_alive()
+    assert counter.value() == errors_before + 1
+    lifetime.stop()
+    evaluator.thread.join(timeout=10.0)
+    assert not evaluator.thread.is_alive()
+
+
 # ---------------------------------------------------------------------------
 # Pillar 5: launch hardening (preflight + watchdogs, DESIGN.md §2.4)
 # ---------------------------------------------------------------------------
@@ -612,7 +646,7 @@ def test_memory_gate_passes_and_estimates():
     assert estimate is not None and estimate["predicted_bytes"] >= 0
     # CPU exposes no bytes_limit: the gate logs and passes (returns estimate).
     assert preflight.check_device_memory(compiled, headroom=0.9) is not None
-    # Non-compiled callables (aot_warmup's graceful-degrade return) skip.
+    # Non-compiled callables (no memory_analysis to read) skip.
     assert preflight.estimate_compiled_memory(lambda x: x) is None
 
 

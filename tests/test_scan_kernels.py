@@ -78,8 +78,9 @@ def test_assoc_impl_matches_scan_bfloat16():
 def test_pallas_kernel_bitwise_matches_scan_float32():
     # The kernel proper, interpret mode (off-TPU the DISPATCH falls back to
     # scan; the kernel itself must still be right): block_t smaller than T
-    # exercises the cross-block carry, larger exercises time padding.
-    for seed, block_t in [(3, 4), (4, 8), (5, 64)]:
+    # exercises the cross-block carry, larger exercises time padding
+    # (block_t is rounded up to whole 8-row float32 tiles).
+    for seed, block_t in [(3, 8), (4, 16), (5, 64)]:
         w, d, init = _random_recurrence(seed, t_len=19, batch=3)
         got = sk.pallas_linear_recurrence_reverse(
             w, d, init, block_t=block_t, interpret=True

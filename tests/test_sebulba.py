@@ -3,7 +3,6 @@ multi-device split, plus the native C++ env pool."""
 
 import jax
 
-from stoix_tpu.parallel import shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -155,7 +154,7 @@ def test_impala_reward_normalization_is_shard_invariant(devices):
     for n_shards in (1, 2, 4):
         mesh = Mesh(np.asarray(jax.devices("cpu")[:n_shards]), ("data",))
         out = jax.jit(
-            shard_map(
+            jax.shard_map(
                 per_shard, mesh=mesh,
                 in_specs=(PPOTransition(*([P(None, "data")] * 9)),),
                 out_specs=P(None, "data"),
@@ -198,3 +197,43 @@ def test_param_server_places_once_per_device_and_reprime_reuses(devices):
     assert server.reprime(2)
     assert transfers() == before
     assert server.get_params(2, timeout=2.0) is got[0]
+
+
+def test_native_pool_is_built_from_source_by_content_hash(tmp_path, monkeypatch):
+    # The library that loads is named by the hash of the source that is read
+    # (not by an mtime, which a copy to another machine makes arbitrary); a
+    # changed source with no compiler is a loud error, never a stale pool.
+    import hashlib
+    import shutil
+
+    from stoix_tpu.envs import cvec
+
+    with open(cvec._SOURCE, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    assert cvec._ensure_built().endswith(f"libcvec-{digest}.so")
+
+    edited = tmp_path / "cvec.cpp"
+    shutil.copy(cvec._SOURCE, edited)
+    with open(edited, "a") as f:
+        f.write("\n// edited\n")
+    monkeypatch.setattr(cvec, "_SOURCE", str(edited))
+    monkeypatch.setattr(cvec, "_NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(shutil, "which", lambda name: None)
+    with pytest.raises(RuntimeError, match="needs g\\+\\+"):
+        cvec._ensure_built()
+    assert not list(tmp_path.glob("*.so"))
+
+
+def test_jax_env_twin_needs_the_cpu_backend_and_says_so(monkeypatch):
+    # Sebulba's pure-JAX env twin lives on the host CPU beside the
+    # accelerator; when the CPU backend is not in the process
+    # (JAX_PLATFORMS=tpu) the failure names the variable and the way out.
+    from stoix_tpu.envs import factory
+
+    def no_cpu_backend(backend=None):
+        raise RuntimeError(f"Unknown backend {backend}")
+
+    monkeypatch.setattr(factory.jax, "devices", no_cpu_backend)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS='tpu' excludes it"):
+        factory.JaxEnvFactory("CartPole-v1")

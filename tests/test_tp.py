@@ -4,7 +4,6 @@ block), forward and backward, on a 2D data x model mesh."""
 
 import jax
 
-from stoix_tpu.parallel import shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -32,7 +31,7 @@ def test_forward_matches_oracle():
     param_specs, data_spec = tp_specs()
 
     fwd = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda p, x: column_row_block(p, x, axis_name="model"),
             mesh=mesh,
             in_specs=(param_specs, data_spec),
@@ -44,11 +43,6 @@ def test_forward_matches_oracle():
     )
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="legacy shard_map AD transposes the loss-level pmean to an "
-    "axis-size-scaled gradient (parallel/mesh.py shard_map caveat)",
-)
 def test_backward_matches_oracle():
     mesh = _mesh(2, 2)
     params = init_column_row_params(jax.random.PRNGKey(2), 5, 8, 2, num_shards=2)
@@ -64,7 +58,7 @@ def test_backward_matches_oracle():
         return loss, jax.lax.pmean(grads, "data")
 
     loss, grads = jax.jit(
-        shard_map(
+        jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(param_specs, data_spec),
