@@ -1,0 +1,491 @@
+"""From a profiler trace to numbers. Every reduction is a pure function over a
+`Trace`, which is built either from a `.xplane.pb` (`read_xplane`) or from
+plain `Event` records (`Trace.from_events`), so synthetic events test the
+arithmetic and the small trace recorded on the chip (tests/benchmark/data)
+tests the reading.
+
+What a TPU trace holds (jax 0.9 / libtpu on a v5e, read by hand, PR 22): one
+plane a chip, "/device:TPU:<i>", with a line "XLA Modules" (one event for
+each execution of a jitted program, named "<module>(<program id>)") and a
+line "XLA Ops" (one event for each HLO op the core executed; a `while`,
+`conditional` or `call` op is an event that CONTAINS its body's events, so
+durations are never summed across ops — unions are taken, and a breakdown
+counts leaf ops only). An op event's name is its whole HLO text; its metadata
+carries `display_name` (the instruction's name), `hlo_category`, `program_id`,
+`flops` and `bytes_accessed`, but no framework path. The path — the jit and
+`named_scope`s around the op, e.g. ".../ppo_epoch/ppo_minibatch/dot_general" —
+is in the HLO proto of each program, which the trace carries on the plane
+"/host:metadata"; instruction name and program id join the two. Host threads
+are lines of the plane "/host:CPU"; a `jax.profiler.TraceAnnotation` appears
+there under its own name. All lines share one clock (picoseconds from the
+start of the profile).
+
+Definitions (the `on-chip-measurement` guide, section 4):
+
+* window: first op start to last op end over all the chips' op lines.
+* busy: on each chip, the union of its op intervals; `busy_s` is the mean
+  over chips. idle share = 1 - busy / window.
+* by program: sum of "XLA Modules" durations by module name, mean over chips.
+* by scope: the union of the intervals of the ops whose framework path names
+  the scope (a path component), optionally inside given program executions.
+* collective exposed: on each chip, the union of collective leaf-op intervals
+  minus the union of all other leaf-op intervals; mean over chips.
+"""
+
+from __future__ import annotations
+
+import re
+from typing import Any, Dict, Iterable, List, NamedTuple, Optional, Sequence, Tuple
+
+import numpy as np
+
+OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+METADATA_PLANE = "/host:metadata"
+DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+$")
+HOST_PLANE_PREFIX = "/host:CPU"
+COLLECTIVE = re.compile(
+    r"all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute|collective-broadcast"
+)
+CONTAINERS = ("while", "conditional", "call")
+
+Interval = Tuple[int, int]  # [start, end) in picoseconds
+
+
+class Event(NamedTuple):
+    """One trace event as a plain record (times in picoseconds). `stats` may
+    hold "tf_op" (the framework path), "hlo_category", "program"."""
+
+    plane: str
+    line: str
+    name: str
+    start_ps: int
+    dur_ps: int
+    stats: Dict[str, Any]
+
+
+class OpKind(NamedTuple):
+    """What is known of one HLO instruction, shared by all its executions."""
+
+    name: str  # the instruction's name, e.g. "fusion.123"
+    program: str  # "jit_learner_fn"
+    path: str  # framework path, "" if the trace names none
+    category: str  # hlo_category, "" if unknown
+
+    @property
+    def opcode(self) -> str:
+        return re.sub(r"[.][0-9]+$", "", self.name)
+
+    @property
+    def container(self) -> bool:
+        return self.opcode in CONTAINERS or self.category in CONTAINERS
+
+    @property
+    def collective(self) -> bool:
+        return bool(COLLECTIVE.search(self.name)) or bool(COLLECTIVE.search(self.category))
+
+
+class ChipOps(NamedTuple):
+    start: np.ndarray  # int64 ps
+    end: np.ndarray  # int64 ps
+    kind: np.ndarray  # int32 index into Trace.kinds
+
+
+class Trace(NamedTuple):
+    kinds: List[OpKind]
+    ops: Dict[str, ChipOps]  # device plane -> its op events
+    modules: Dict[str, List[Tuple[str, int, int]]]  # plane -> (program, start, end)
+    host: List[Event]  # host-plane events (annotations)
+
+    @staticmethod
+    def from_events(events: Sequence[Event]) -> "Trace":
+        kinds: List[OpKind] = []
+        index: Dict[OpKind, int] = {}
+        rows: Dict[str, List[Tuple[int, int, int]]] = {}
+        modules: Dict[str, List[Tuple[str, int, int]]] = {}
+        host: List[Event] = []
+        for e in events:
+            if e.plane.startswith(HOST_PLANE_PREFIX):
+                host.append(e)
+            elif not DEVICE_PLANE.match(e.plane):
+                continue
+            elif e.line == MODULES_LINE:
+                modules.setdefault(e.plane, []).append(
+                    (program_name(e.name), e.start_ps, e.start_ps + e.dur_ps)
+                )
+            elif e.line == OPS_LINE:
+                kind = OpKind(
+                    instruction_name(e.name), str(e.stats.get("program", "")),
+                    str(e.stats.get("tf_op", "")), str(e.stats.get("hlo_category", "")),
+                )
+                if kind not in index:
+                    index[kind] = len(kinds)
+                    kinds.append(kind)
+                rows.setdefault(e.plane, []).append((e.start_ps, e.start_ps + e.dur_ps, index[kind]))
+        ops = {
+            plane: ChipOps(
+                np.asarray([r[0] for r in data], np.int64),
+                np.asarray([r[1] for r in data], np.int64),
+                np.asarray([r[2] for r in data], np.int32),
+            )
+            for plane, data in rows.items()
+        }
+        for plane in modules:
+            ops.setdefault(plane, ChipOps(np.zeros(0, np.int64), np.zeros(0, np.int64), np.zeros(0, np.int32)))
+        return Trace(kinds, ops, modules, host)
+
+    @property
+    def planes(self) -> List[str]:
+        return sorted(self.ops)
+
+
+def program_name(event_name: str) -> str:
+    """"jit_learner_fn(1234567)" -> "jit_learner_fn"."""
+    return re.sub(r"\(\d+\)$", "", event_name)
+
+
+def instruction_name(event_name: str) -> str:
+    """"%fusion.5 = (bf16[512,256]{...}) fusion(...)" -> "fusion.5"."""
+    return event_name.split(" = ", 1)[0].lstrip("%").strip()
+
+
+# --------------------------------------------------------------------------
+# reading a .xplane.pb
+# --------------------------------------------------------------------------
+
+
+def _stat_value(stat: Any, stat_names: Dict[int, str]) -> Any:
+    """A stat's value; a string stored as a reference (`ref_value`: the id of
+    a stat-metadata entry whose name is the string) is looked up."""
+    for field in ("str_value", "uint64_value", "int64_value", "double_value"):
+        value = getattr(stat, field)
+        if value:
+            return value
+    if stat.ref_value:
+        return stat_names.get(stat.ref_value, "")
+    return ""
+
+
+def _framework_paths(space: Any) -> Dict[Tuple[str, str], str]:
+    """{(program id, instruction name): op_name} from the HLO protos on the
+    metadata plane."""
+    from benchmarks.harness import xplane_proto
+
+    paths: Dict[Tuple[str, str], str] = {}
+    for plane in space.planes:
+        if plane.name != METADATA_PLANE:
+            continue
+        for entry in plane.event_metadata:
+            match = re.search(r"\((\d+)\)$", entry.value.name)
+            if not match:
+                continue
+            for stat in entry.value.stats:
+                if not stat.bytes_value:
+                    continue
+                hlo = xplane_proto.parse("HloProto", stat.bytes_value)
+                for computation in hlo.hlo_module.computations:
+                    for instruction in computation.instructions:
+                        if instruction.metadata.op_name:
+                            paths[(match.group(1), instruction.name)] = instruction.metadata.op_name
+    return paths
+
+
+def read_xplane(path: str, host_names: Optional[Iterable[str]] = None) -> Trace:
+    """The device planes' op and module lines, and the host events named in
+    `host_names` (all of them if None: fine for a small trace)."""
+    from benchmarks.harness import xplane_proto
+
+    with open(path, "rb") as handle:
+        space = xplane_proto.parse("XSpace", handle.read())
+    paths = _framework_paths(space)
+    wanted = None if host_names is None else set(host_names)
+
+    kinds: List[OpKind] = []
+    ops: Dict[str, ChipOps] = {}
+    modules: Dict[str, List[Tuple[str, int, int]]] = {}
+    host: List[Event] = []
+    for plane in space.planes:
+        names = {entry.key: entry.value for entry in plane.event_metadata}
+        if plane.name.startswith(HOST_PLANE_PREFIX):
+            keep = {
+                key for key, meta in names.items() if wanted is None or meta.name in wanted
+            }
+            for line in plane.lines:
+                base = line.timestamp_ns * 1000
+                for ev in line.events:
+                    if ev.metadata_id in keep:
+                        host.append(Event(
+                            plane.name, line.name, names[ev.metadata_id].name,
+                            base + ev.offset_ps, ev.duration_ps, {},
+                        ))
+            continue
+        if not DEVICE_PLANE.match(plane.name):
+            continue
+        stat_names = {entry.key: entry.value.name for entry in plane.stat_metadata}
+        for line in plane.lines:
+            base = line.timestamp_ns * 1000
+            if line.name == MODULES_LINE:
+                modules[plane.name] = [
+                    (program_name(names[ev.metadata_id].name), base + ev.offset_ps,
+                     base + ev.offset_ps + ev.duration_ps)
+                    for ev in line.events
+                ]
+            elif line.name == OPS_LINE:
+                kind_of: Dict[int, int] = {}
+                n = len(line.events)
+                start = np.empty(n, np.int64)
+                dur = np.empty(n, np.int64)
+                kind = np.empty(n, np.int32)
+                for i, ev in enumerate(line.events):
+                    meta_id = ev.metadata_id
+                    k = kind_of.get(meta_id)
+                    if k is None:
+                        meta = names[meta_id]
+                        stats = {
+                            stat_names.get(s.metadata_id, ""): _stat_value(s, stat_names)
+                            for s in meta.stats
+                        }
+                        program_id = str(stats.get("program_id", ""))
+                        name = meta.display_name or instruction_name(meta.name)
+                        k = kind_of[meta_id] = len(kinds)
+                        kinds.append(OpKind(
+                            name, program_id, paths.get((program_id, name), ""),
+                            str(stats.get("hlo_category", "")),
+                        ))
+                    start[i], dur[i], kind[i] = ev.offset_ps, ev.duration_ps, k
+                ops[plane.name] = ChipOps(start + base, start + base + dur, kind)
+        ops.setdefault(plane.name, ChipOps(np.zeros(0, np.int64), np.zeros(0, np.int64), np.zeros(0, np.int32)))
+    # Program ids become program names where a module event names them.
+    id_to_name = {}
+    for plane in space.planes:
+        if DEVICE_PLANE.match(plane.name):
+            for entry in plane.event_metadata:
+                match = re.match(r"^(.*)\((\d+)\)$", entry.value.name)
+                if match and " " not in match.group(1):
+                    id_to_name[match.group(2)] = match.group(1)
+    kinds = [k._replace(program=id_to_name.get(k.program, k.program)) for k in kinds]
+    return Trace(kinds, ops, modules, host)
+
+
+# --------------------------------------------------------------------------
+# interval arithmetic
+# --------------------------------------------------------------------------
+
+
+def merge(intervals: Iterable[Interval]) -> List[Interval]:
+    merged: List[Interval] = []
+    for start, end in sorted(i for i in intervals if i[1] > i[0]):
+        if merged and start <= merged[-1][1]:
+            merged[-1] = (merged[-1][0], max(merged[-1][1], end))
+        else:
+            merged.append((start, end))
+    return merged
+
+
+def merged_arrays(start: np.ndarray, end: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """`merge` over millions of intervals: (starts, ends) of the union."""
+    keep = end > start
+    start, end = start[keep], end[keep]
+    if start.size == 0:
+        return start, end
+    order = np.argsort(start, kind="stable")
+    start, end = start[order], np.maximum.accumulate(end[order])
+    opens = np.concatenate(([True], start[1:] > end[:-1]))
+    closes = np.concatenate((opens[1:], [True]))
+    return start[opens], end[closes]
+
+
+def covered(starts: np.ndarray, ends: np.ndarray, at: np.ndarray) -> np.ndarray:
+    """Length of the merged union (starts, ends) that lies before each `at`."""
+    if starts.size == 0:
+        return np.zeros(at.shape, np.int64)
+    before = np.concatenate(([0], np.cumsum(ends - starts)))
+    k = np.searchsorted(starts, at, side="right")  # segments starting at or before
+    inside = np.where(k > 0, np.minimum(at, ends[np.maximum(k - 1, 0)]) - starts[np.maximum(k - 1, 0)], 0)
+    return before[np.maximum(k - 1, 0)] * (k > 0) + np.maximum(inside, 0)
+
+
+def overlap(a: Tuple[np.ndarray, np.ndarray], b: Tuple[np.ndarray, np.ndarray]) -> int:
+    """Length of union(a) ∩ union(b), both merged."""
+    if a[0].size == 0 or b[0].size == 0:
+        return 0
+    return int(np.sum(covered(b[0], b[1], a[1]) - covered(b[0], b[1], a[0])))
+
+
+# --------------------------------------------------------------------------
+# reductions (seconds; means over the chips in the trace)
+# --------------------------------------------------------------------------
+
+_PS = 1e-12
+
+
+def _mean(values: Sequence[float]) -> float:
+    return sum(values) / len(values) if values else 0.0
+
+
+def _kind_mask(trace: Trace, chip: ChipOps, predicate) -> np.ndarray:
+    flags = np.fromiter((bool(predicate(k)) for k in trace.kinds), bool, len(trace.kinds))
+    return flags[chip.kind] if chip.kind.size else np.zeros(0, bool)
+
+
+def window_of(trace: Trace) -> Optional[Interval]:
+    chips = [c for c in trace.ops.values() if c.start.size]
+    if not chips:
+        return None
+    return (int(min(c.start.min() for c in chips)), int(max(c.end.max() for c in chips)))
+
+
+def busy_and_window(trace: Trace) -> Optional[Dict[str, float]]:
+    window = window_of(trace)
+    if window is None:
+        return None
+    busy = []
+    for plane in trace.planes:
+        s, e = merged_arrays(trace.ops[plane].start, trace.ops[plane].end)
+        busy.append(float(np.sum(e - s)))
+    return {
+        "busy_s": _mean(busy) * _PS,
+        "window_s": (window[1] - window[0]) * _PS,
+        "chips": len(busy),
+    }
+
+
+def seconds_by_program(trace: Trace) -> Dict[str, float]:
+    sums: Dict[str, float] = {}
+    for plane in trace.planes:
+        for program, start, end in trace.modules.get(plane, []):
+            sums[program] = sums.get(program, 0.0) + (end - start)
+    return {k: v * _PS / max(1, len(trace.planes)) for k, v in sums.items()}
+
+
+def program_seconds(trace: Trace, patterns: Sequence[str]) -> Optional[float]:
+    """Device seconds (mean over chips) in the programs whose name contains
+    one of `patterns`; None if no module event matched."""
+    hits = [v for k, v in seconds_by_program(trace).items() if any(p in k for p in patterns)]
+    return sum(hits) if hits else None
+
+
+def program_windows(
+    trace: Trace, patterns: Sequence[str], whole_only: bool = False
+) -> Dict[str, List[Interval]]:
+    """Per chip, the intervals in which a matching program ran. With
+    `whole_only`, an execution that touches an edge of the traced window is
+    left out: the profiler may have seen only part of it."""
+    window = window_of(trace)
+    out: Dict[str, List[Interval]] = {}
+    for plane in trace.planes:
+        chip = trace.ops[plane]
+        first = int(chip.start.min()) if chip.start.size else 0
+        last = int(chip.end.max()) if chip.end.size else 0
+        for program, start, end in trace.modules.get(plane, []):
+            if not any(p in program for p in patterns):
+                continue
+            if whole_only and window is not None and (start <= first or end >= last):
+                continue
+            out.setdefault(plane, []).append((start, end))
+    return out
+
+
+def has_paths(trace: Trace) -> bool:
+    return any(k.path for k in trace.kinds)
+
+
+def scope_seconds(
+    trace: Trace, scope: str, within: Optional[Dict[str, List[Interval]]] = None
+) -> Optional[float]:
+    """Device seconds (mean over chips) covered by ops whose framework path
+    has `scope` as a component, optionally only the part inside
+    `within[plane]`. None if the trace names no framework path at all."""
+    if not has_paths(trace):
+        return None
+    sums = []
+    for plane in trace.planes:
+        chip = trace.ops[plane]
+        mask = _kind_mask(trace, chip, lambda k: scope in k.path.split("/"))
+        union = merged_arrays(chip.start[mask], chip.end[mask])
+        if within is None:
+            sums.append(float(np.sum(union[1] - union[0])))
+        else:
+            inside = merge(within.get(plane, []))
+            bounds = (
+                np.asarray([s for s, _ in inside], np.int64),
+                np.asarray([e for _, e in inside], np.int64),
+            )
+            sums.append(float(overlap(union, bounds)))
+    return _mean(sums) * _PS
+
+
+def collective_stats(trace: Trace) -> Optional[Dict[str, float]]:
+    """Exposed collective seconds, seconds in collectives, and the number of
+    collective calls (an async -start/-done pair counts once), means over
+    chips. None with no device ops."""
+    if window_of(trace) is None:
+        return None
+    exposed, calls, in_collectives = [], [], []
+    for plane in trace.planes:
+        chip = trace.ops[plane]
+        leaf = _kind_mask(trace, chip, lambda k: not k.container)
+        coll = _kind_mask(trace, chip, lambda k: k.collective and not k.container)
+        done = _kind_mask(trace, chip, lambda k: k.collective and k.opcode.endswith("-done"))
+        coll_union = merged_arrays(chip.start[coll], chip.end[coll])
+        rest_union = merged_arrays(chip.start[leaf & ~coll], chip.end[leaf & ~coll])
+        length = float(np.sum(coll_union[1] - coll_union[0]))
+        in_collectives.append(length)
+        exposed.append(length - overlap(coll_union, rest_union))
+        calls.append(float(np.sum(coll & ~done)))
+    return {
+        "exposed_s": _mean(exposed) * _PS,
+        "collective_s": _mean(in_collectives) * _PS,
+        "calls": _mean(calls),
+    }
+
+
+def op_label(kind: OpKind) -> str:
+    """A name a reader can place: the program, the last named scopes of the
+    op's framework path, and the HLO op's name without its number."""
+    parts = [p for p in kind.path.split("/") if p and not p.startswith(("jit(", "pjit"))]
+    where = "/".join(parts[-3:])
+    label = f"{where} [{kind.opcode}]" if where else kind.opcode
+    return f"{kind.program}: {label}" if kind.program else label
+
+
+def top_device_ops(trace: Trace, n: int = 10) -> List[List[Any]]:
+    """[[label, seconds], ...]: the n groups of LEAF ops that took most
+    device time (mean over chips), grouped by `op_label`."""
+    labels = [op_label(k) for k in trace.kinds]
+    sums: Dict[str, float] = {}
+    for plane in trace.planes:
+        chip = trace.ops[plane]
+        if not chip.kind.size:
+            continue
+        leaf = _kind_mask(trace, chip, lambda k: not k.container)
+        per_kind = np.bincount(
+            chip.kind[leaf], weights=(chip.end - chip.start)[leaf].astype(np.float64),
+            minlength=len(trace.kinds),
+        )
+        for index in np.nonzero(per_kind)[0]:
+            sums[labels[index]] = sums.get(labels[index], 0.0) + float(per_kind[index])
+    ranked = sorted(sums.items(), key=lambda kv: -kv[1])[:n]
+    return [[k, v * _PS / max(1, len(trace.planes))] for k, v in ranked]
+
+
+def longest_idle_gaps(trace: Trace, annotations: Sequence[str], n: int = 10) -> List[List[Any]]:
+    """[[what the host was doing, seconds], ...] for the n longest gaps on
+    the first chip's op line: the innermost of the named host `annotations`
+    open when the gap began, else "unattributed"."""
+    if window_of(trace) is None:
+        return []
+    chip = trace.ops[trace.planes[0]]
+    starts, ends = merged_arrays(chip.start, chip.end)
+    if starts.size < 2:
+        return []
+    gap_start, gap_len = ends[:-1], starts[1:] - ends[:-1]
+    order = np.argsort(-gap_len, kind="stable")[:n]
+    named = [h for h in trace.host if h.name in set(annotations)]
+    out = []
+    for i in order:
+        at = int(gap_start[i])
+        open_now = [h for h in named if h.start_ps <= at < h.start_ps + h.dur_ps]
+        label = min(open_now, key=lambda h: h.dur_ps).name if open_now else "unattributed"
+        out.append([label, float(gap_len[i]) * _PS])
+    return out

@@ -1,0 +1,16 @@
+"""Learner program (`systems/ppo/*/ff_ppo.py`): the share of the traced
+window in which the learner's XLA program ran on the device (mean over
+chips). Found by the configuration's `programs.learn` name patterns."""
+
+from benchmarks.harness import trace_reduce
+
+
+def read(ctx):
+    if ctx.trace_data is None:
+        return None
+    patterns = ctx.cell.config.get("programs", {}).get("learn")
+    busy = trace_reduce.busy_and_window(ctx.trace_data)
+    if not patterns or busy is None:
+        return None
+    seconds = trace_reduce.program_seconds(ctx.trace_data, patterns)
+    return None if seconds is None else 100.0 * seconds / busy["window_s"]

@@ -1,0 +1,9 @@
+"""Puts the checkout's root on sys.path so `benchmarks.harness` imports."""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
