@@ -971,8 +971,9 @@ def _phase_breakdown_probe(
     default_yaml: str, setup_module: str, env_overrides: list, smoke: bool, n_devices: int
 ) -> tuple:
     """Run ONE tiny experiment through the pipelined Anakin runner to capture
-    the per-phase host-loop breakdown (compile_s/learn_s/eval_s/fetch_s/
-    ckpt_s). The headline SPS stays the timed learn-loop measurement; this
+    the per-phase host-loop breakdown (compile_s and every phase of the
+    runner's clock: learn_s/snapshot_s/eval_s/fetch_dispatch_s/fetch_s/log_s/
+    host_s/ckpt_s, forwarded as the runner reports them). The headline SPS stays the timed learn-loop measurement; this
     probe is what surfaces where host time goes per eval window. The probe
     runs with telemetry ENABLED (stoix_tpu/observability), so the payload
     also carries the telemetry self-check: span count, registry series

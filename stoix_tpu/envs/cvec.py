@@ -101,6 +101,10 @@ def _load_lib() -> ctypes.CDLL:
 class CVecPool:
     """Stateful Sebulba env backed by the native pool: numpy in, TimeStep out."""
 
+    # step() reads the action on the host: a Sebulba actor copies it there
+    # before it times the pool (systems/ppo/sebulba/ff_ppo.py).
+    takes_host_actions = True
+
     def __init__(self, task: str, num_envs: int, seed: int, max_steps: int = 500):
         self._lib = _load_lib()
         self._handle = self._lib.cvec_create(task.encode(), num_envs, max_steps, seed)

@@ -36,6 +36,8 @@ from stoix_tpu.envs.types import Observation, TimeStep
 class EnvPoolAdapter:
     """Wrap a constructed envpool env (gymnasium API, gym_reset_return_info)."""
 
+    takes_host_actions = True  # step() reads the action on the host (see CVecPool)
+
     def __init__(self, env: Any, has_lives: Optional[bool] = None):
         self._env = env
         obs, _ = env.reset()

@@ -20,6 +20,8 @@ from stoix_tpu.envs.types import Observation, TimeStep
 
 
 class VecGymToStoix:
+    takes_host_actions = True  # step() reads the action on the host (see CVecPool)
+
     def __init__(self, envs: Any):
         self._envs = envs
         self._n = envs.num_envs

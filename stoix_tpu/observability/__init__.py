@@ -2,10 +2,12 @@
 
 Three pillars, all zero-dependency and off by default:
 
-  * **Tracing** (trace.py / trace_export.py): host-side `span()` context
-    managers — thread-aware, monotonic-clock — exported as Chrome-trace/
-    Perfetto JSON so host threads load alongside the `jax.profiler` device
-    trace. `annotate()` tags jitted code at epoch/minibatch boundaries.
+  * **Tracing** (trace.py / trace_export.py): `span()` is the one host
+    primitive — always a `jax.profiler.TraceAnnotation`, so any profiler
+    session carries the host threads on the device trace's clock; optionally
+    a phase-clock feed; recorded for the Chrome-trace/Perfetto JSON export
+    (its own epoch) when telemetry is on. `annotate()` tags jitted code with
+    a scope name from `SCOPES`.
   * **Metrics** (registry.py / exporters.py): process-wide counters, gauges,
     and histograms with labels, snapshot-on-demand, Prometheus text
     exposition + JSONL sinks. `RunStats` is the dict-compatible per-run view
@@ -15,11 +17,12 @@ Three pillars, all zero-dependency and off by default:
     heartbeats and a stall detector that names the starved component.
 
 `configure(cfg.logger.telemetry)` is the single switch — called by
-StoixLogger on construction. Disabled (the default), spans are shared no-op
-context managers, no poller thread starts, and no files are written: behavior
-is bit-identical to a build without telemetry (tests/test_observability.py
-pins this) and PR 1's pipelined-loop no-host-sync guarantees are untouched —
-every instrument here is host-memory only.
+StoixLogger on construction. Disabled (the default), spans are bare
+TraceAnnotations that record nothing, no poller thread starts, and no files
+are written: behavior is bit-identical to a build without telemetry
+(tests/test_observability.py pins this) and PR 1's pipelined-loop
+no-host-sync guarantees are untouched — every instrument here is host-memory
+only.
 """
 
 from __future__ import annotations
@@ -75,10 +78,11 @@ from stoix_tpu.observability.registry import (  # noqa: F401
     get_registry,
 )
 from stoix_tpu.observability.trace import (  # noqa: F401
+    HOST_SPANS,
+    SCOPES,
+    SetupClock,
     annotate,
-    device_annotation,
     get_recorder,
-    instant,
     is_enabled,
     set_enabled,
     span,

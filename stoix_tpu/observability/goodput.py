@@ -55,12 +55,19 @@ PHASES = (
 # Anakin runner phase-clock names (stoix_tpu_runner_phase_seconds_total
 # labels) -> taxonomy. learn_s is dispatch cost in the pipelined loop; the
 # device execution it overlaps lands in the compute residual either way.
+# fetch_s is the blocked materialize wait alone; the other host phases
+# (snapshot/fetch dispatch, logging, per-window bookkeeping) were part of the
+# compute residual before the clock covered them, and stay there.
 RUNNER_PHASE_MAP = {
     "compile_s": "compile",
     "learn_s": "compute",
     "gossip_s": "gossip",
+    "snapshot_s": "compute",
     "eval_s": "eval",
+    "fetch_dispatch_s": "compute",
     "fetch_s": "fetch_wait",
+    "log_s": "compute",
+    "host_s": "compute",
     "ckpt_s": "checkpoint",
 }
 

@@ -134,6 +134,11 @@ class Histogram(_Instrument):
             else:
                 series.bucket_counts[-1] += 1
 
+    def record(self, labels: Optional[Dict[str, str]], seconds: float) -> None:
+        """The `span(..., clock=histogram, phase=labels)` sink: the span's
+        seconds observed into the labelled series."""
+        self.observe(seconds, labels)
+
     def summary(self, labels: Optional[Dict[str, str]] = None) -> Dict[str, float]:
         with self._lock:
             series = self._series.get(_label_key(labels))

@@ -1,54 +1,111 @@
-"""Host-side span tracing: thread-aware, monotonic-clock, Chrome-trace ready.
+"""Host spans and jitted-code scopes: ONE primitive each, one clock.
 
-`span("learn_dispatch")` records one complete event (Chrome trace `"ph": "X"`)
-into a process-wide buffer when tracing is enabled; when disabled (the
-default) it returns a shared no-op context manager — one boolean check, no
-allocation — so hot loops can keep their spans unconditionally.
+`span(name)` is the only way a host phase is marked. It always opens a
+`jax.profiler.TraceAnnotation(name)` (name only, so the event keeps exactly
+that name in the XSpace): every profiler session — the benchmark's traced
+run, an operator's `STOIX_TPU_PROFILE_DIR=<dir>` — carries every host span,
+each on its own thread's line, on the clock the device ops are on. That
+profiler trace is where host spans and device ops line up. With no session
+open a TraceAnnotation is a few hundred nanoseconds (PERF.md §6, PR 23).
 
-Timestamps come from `time.perf_counter_ns()` against a per-recorder epoch
-(monotonic: wall-clock steps cannot reorder events), recorded in microseconds
-— the Chrome trace-event unit — so the exported file (trace_export.py) lines
-up with the `jax.profiler` device trace when both are loaded in Perfetto.
+Besides, a span can
+  * feed a phase counter: `span(name, clock=c, phase=p)` calls
+    `c.record(p, seconds)` when it closes (the Anakin runner's phase clock,
+    a Sebulba `TimingTracker`, a labelled wait `Histogram`, `SetupClock`),
+    so no call site keeps a `perf_counter()` pair beside its span;
+  * be recorded, when `logger.telemetry.enabled`, as one complete event
+    (Chrome trace `"ph": "X"`) in the process-wide `TraceRecorder`, which
+    trace_export.py writes as Perfetto-loadable JSON. Those timestamps are
+    `time.perf_counter_ns()` against the recorder's OWN epoch, in
+    microseconds: that file shows the host threads against each other, not
+    against the device (it shares no epoch with the profiler trace).
 
-For code under `jax.jit`, use `annotate(name)` — a `jax.named_scope` — at
-epoch/minibatch boundaries: it tags XLA ops so the device trace carries the
-same taxonomy, and costs nothing at runtime.
+For code under `jax.jit`, use `annotate(name)` — a `jax.named_scope`, trace-
+time metadata only — with a name from `SCOPES`: it tags the XLA ops, so the
+device trace can be cut by scope. `SCOPES` and `HOST_SPANS` are the name
+tables the systems import and the benchmark's readers and tests read.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
+
+# Scopes inside the jitted programs (path components of the ops' framework
+# path in the device trace). One table for both architectures.
+SCOPES = {
+    "rollout": "rollout",  # one env-step scan body (Anakin `_env_step`)
+    "rollout_policy": "rollout_policy",  # actor+critic apply, sampling (also Sebulba `act_fn`)
+    "rollout_env": "rollout_env",  # `env.step`
+    "gae": "gae",  # bootstrap values and the `ops/multistep` call
+    "update_epoch": "ppo_epoch",  # one epoch: shuffle + minibatch scan
+    "update_minibatch": "ppo_minibatch",  # one SGD step
+    "minibatch_shuffle": "minibatch_shuffle",  # permutation + `take` over the trajectory
+}
+
+# Host spans that recur in steady state, by the thread that opens them. A
+# trace reduction attributes a device-idle gap to the innermost of these open
+# when the gap began (`host_annotations` of a benchmark config).
+HOST_SPANS = {
+    "anakin": (
+        "learn_dispatch", "gossip_dispatch", "snapshot_dispatch", "eval_dispatch",
+        "fetch_dispatch", "fetch_materialize", "window_bookkeeping", "log", "ckpt_save",
+    ),
+    "sebulba_actor": (
+        "actor_rollout", "actor_inference", "actor_env_step", "actor_prepare_data",
+        "pipeline_put", "param_get",
+    ),
+    "sebulba_learner": (
+        "learner_rollout_wait", "pipeline_get", "learner_assemble", "learner_update",
+        "param_push", "learner_log",
+    ),
+    "sebulba_evaluator": ("async_eval",),
+}
+
+_trace_annotation: Any = None
 
 
-class _NoopSpan:
-    __slots__ = ()
+def _annotation(name: str) -> Any:
+    """`jax.profiler.TraceAnnotation(name)`; jax is imported on first use so
+    that importing the telemetry package stays free of it."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        import jax
 
-    def __enter__(self) -> None:
-        return None
-
-    def __exit__(self, *exc: Any) -> None:
-        return None
-
-
-_NOOP = _NoopSpan()
+        _trace_annotation = jax.profiler.TraceAnnotation
+    return _trace_annotation(name)
 
 
 class _Span:
-    __slots__ = ("_recorder", "_name", "_args", "_start")
+    """A TraceAnnotation that also times itself, for a phase clock and/or a
+    recorder."""
 
-    def __init__(self, recorder: "TraceRecorder", name: str, args: Dict[str, Any]):
+    __slots__ = ("_recorder", "_name", "_args", "_clock", "_phase", "_annotation", "_start")
+
+    def __init__(
+        self, recorder: Optional["TraceRecorder"], name: str, args: Dict[str, Any],
+        clock: Any, phase: Any,
+    ):
         self._recorder = recorder
         self._name = name
         self._args = args
+        self._clock = clock
+        self._phase = phase
 
     def __enter__(self) -> "_Span":
+        self._annotation = _annotation(self._name)
+        self._annotation.__enter__()
         self._start = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc: Any) -> None:
-        self._recorder._record(self._name, self._start, time.perf_counter_ns(), self._args)
+        end = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
+        if self._clock is not None:
+            self._clock.record(self._phase, (end - self._start) / 1e9)
+        if self._recorder is not None:
+            self._recorder._record(self._name, self._start, end, self._args)
 
 
 class TraceRecorder:
@@ -67,10 +124,10 @@ class TraceRecorder:
         self.dropped = 0
         self.enabled = False
 
-    def span(self, name: str, **args: Any):
-        if not self.enabled:
-            return _NOOP
-        return _Span(self, name, args)
+    def span(self, name: str, clock: Any = None, phase: Any = None, **args: Any):
+        if not self.enabled and clock is None:
+            return _annotation(name)
+        return _Span(self if self.enabled else None, name, args, clock, phase)
 
     def _record(self, name: str, start_ns: int, end_ns: int, args: Dict[str, Any]) -> None:
         thread = threading.current_thread()
@@ -90,13 +147,6 @@ class TraceRecorder:
                     "args": {k: _jsonable(v) for k, v in args.items()},
                 }
             )
-
-    def instant(self, name: str, **args: Any) -> None:
-        """Zero-duration marker (exported as a Chrome instant event)."""
-        if not self.enabled:
-            return
-        now = time.perf_counter_ns()
-        self._record(name, now, now, args)
 
     def events(self) -> List[Dict[str, Any]]:
         with self._lock:
@@ -131,14 +181,11 @@ def get_recorder() -> TraceRecorder:
     return _RECORDER
 
 
-def span(name: str, **args: Any):
-    """Context manager timing one host-side phase. No-op unless tracing is
-    enabled (observability.configure / set_enabled)."""
-    return _RECORDER.span(name, **args)
-
-
-def instant(name: str, **args: Any) -> None:
-    _RECORDER.instant(name, **args)
+def span(name: str, clock: Any = None, phase: Any = None, **args: Any):
+    """Context manager marking one host-side phase: a TraceAnnotation always;
+    `clock.record(phase, seconds)` on close when a clock is given; a recorded
+    event (with `args`) when telemetry is enabled (observability.configure)."""
+    return _RECORDER.span(name, clock, phase, **args)
 
 
 def set_enabled(enabled: bool) -> None:
@@ -150,21 +197,39 @@ def is_enabled() -> bool:
 
 
 def annotate(name: str):
-    """Taxonomy tag for code under jit: a `jax.named_scope`. Trace-time only
-    — zero runtime cost — and surfaces the span name in the XLA/Perfetto
-    device trace next to the host spans recorded here."""
+    """Taxonomy tag for code under jit: a `jax.named_scope` (context manager
+    and decorator). Trace-time only — the compiled program is the same — and
+    the name becomes a component of the ops' path in the device trace. Under
+    `vmap`/`grad` JAX wraps the outermost component (`vmap(gae)`)."""
     import jax
 
     return jax.named_scope(name)
 
 
-def device_annotation(name: str, **kwargs: Any):
-    """Host-thread annotation for the `jax.profiler` device trace (TraceMe):
-    wraps dispatch sites so the device timeline names them too. Falls back to
-    a no-op when the profiler is unavailable."""
-    import jax
+class SetupClock:
+    """`span(..., clock=SetupClock(), phase=...)` sink for the once-a-run
+    set-up phases of a `run_experiment`: seconds per phase, published as the
+    gauge `stoix_tpu_setup_phase_seconds{phase=...}` as each phase closes."""
 
-    try:
-        return jax.profiler.TraceAnnotation(name, **kwargs)
-    except Exception:  # noqa: BLE001 — profiling must never kill a run
-        return _NOOP
+    PHASES = (
+        "env_build", "network_init", "learner_setup", "evaluator_setup",
+        "aot_warmup", "first_tick",
+    )
+
+    def __init__(self) -> None:
+        from stoix_tpu.observability.registry import get_registry
+
+        self._gauge = get_registry().gauge(
+            "stoix_tpu_setup_phase_seconds",
+            "Wall seconds of each set-up phase of the most recent run",
+        )
+        self._seconds: Dict[str, float] = {}
+        for phase in self.PHASES:  # a fresh run does not show the last one's
+            self._gauge.set(0.0, {"phase": phase})
+
+    def record(self, phase: str, seconds: float) -> None:
+        self._seconds[phase] = self._seconds.get(phase, 0.0) + seconds
+        self._gauge.set(self._seconds[phase], {"phase": phase})
+
+    def seconds(self) -> Dict[str, float]:
+        return dict(self._seconds)

@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import jax
@@ -49,6 +48,7 @@ from stoix_tpu.observability import (
     span,
 )
 from stoix_tpu.resilience.errors import ComponentFailure, EvaluatorStallError
+from stoix_tpu.utils.timing import TimingTracker
 
 
 def _replace_nowait(q: "queue.Queue", item: Any) -> None:
@@ -124,14 +124,13 @@ class OnPolicyPipeline:
 
     def send_rollout(self, actor_id: int, payload: Any, timeout: Optional[float] = None) -> None:
         labels = {"queue": "rollout", "actor": str(actor_id)}
-        start = time.perf_counter()
         try:
-            with span("pipeline_put", actor=actor_id):
+            # The span observes its seconds into the put-wait histogram when
+            # it closes, a queue.Full timeout included: the worst-case
+            # backpressure sample is the one this histogram exists to capture.
+            with span("pipeline_put", clock=self._put_wait, phase=labels, actor=actor_id):
                 self._queues[actor_id].put(payload, timeout=timeout)
         finally:
-            # finally: a queue.Full timeout is the worst-case backpressure
-            # sample — the one this histogram exists to capture.
-            self._put_wait.observe(time.perf_counter() - start, labels)
             self._depth.set(self._queues[actor_id].qsize(), labels)
         self.heartbeats.beat(f"actor-{actor_id}")
 
@@ -149,13 +148,12 @@ class OnPolicyPipeline:
             if failure is not None:
                 raise failure
             labels = {"queue": "rollout", "actor": str(actor_id)}
-            start = time.perf_counter()
             try:
-                with span("pipeline_get", actor=actor_id):
+                # Feeds the get-wait histogram on close (a starved timeout's
+                # wait too, like the put side's queue.Full).
+                with span("pipeline_get", clock=self._get_wait, phase=labels,
+                          actor=actor_id):
                     payload = q.get(timeout=timeout)
-                    if isinstance(payload, ComponentFailure):
-                        raise payload
-                    payloads.append(payload)
             except queue.Empty:
                 raise ActorStarvationError(
                     actor_id,
@@ -163,7 +161,9 @@ class OnPolicyPipeline:
                     detector.diagnose(waiting_on=f"actor-{actor_id}"),
                     self.heartbeats.age(f"actor-{actor_id}"),
                 ) from None
-            self._get_wait.observe(time.perf_counter() - start, labels)
+            if isinstance(payload, ComponentFailure):
+                raise payload
+            payloads.append(payload)
             self._depth.set(q.qsize(), labels)
         self.heartbeats.beat("learner")
         return payloads
@@ -203,6 +203,7 @@ class OffPolicyPipeline:
         self._queue: queue.Queue = queue.Queue(maxsize=max(1, num_actors * depth_per_actor))
         self.heartbeats = HeartbeatBoard()
         self._depth, self._put_wait, self._get_wait = _queue_instruments()
+        self._poll_timer = TimingTracker(maxlen=1)  # the newest poll's seconds
         self._failures: Dict[int, ComponentFailure] = {}
         self._failure_lock = threading.Lock()
         self._fleet = fleet
@@ -233,14 +234,13 @@ class OffPolicyPipeline:
 
     def push(self, actor_id: int, payload: Any, timeout: Optional[float] = None) -> None:
         labels = {"queue": "transitions", "actor": str(actor_id)}
-        start = time.perf_counter()
         try:
-            with span("offpolicy_push", actor=actor_id):
+            # As in OnPolicyPipeline.send_rollout: the span feeds the put-wait
+            # histogram on close, a queue.Full timeout included.
+            with span("offpolicy_push", clock=self._put_wait, phase=labels,
+                      actor=actor_id):
                 self._queue.put((actor_id, payload), timeout=timeout)
         finally:
-            # finally: a queue.Full timeout is the worst-case backpressure
-            # sample — the one this histogram exists to capture.
-            self._put_wait.observe(time.perf_counter() - start, labels)
             self._depth.set(self._queue.qsize(), labels)
         self.heartbeats.beat(f"actor-{actor_id}")
 
@@ -252,8 +252,7 @@ class OffPolicyPipeline:
         self._check_failures()
         labels = {"queue": "transitions", "actor": "learner"}
         items: List[Any] = []
-        start = time.perf_counter()
-        with span("offpolicy_poll"):
+        with span("offpolicy_poll", clock=self._poll_timer, phase="poll"):
             while len(items) < max_items:
                 try:
                     got = self._queue.get(timeout=timeout if not items else 0.0)
@@ -263,7 +262,9 @@ class OffPolicyPipeline:
                     raise got
                 items.append(got)
         if items:
-            self._get_wait.observe(time.perf_counter() - start, labels)
+            # Only a poll that got something is a wait sample: the learner's
+            # empty timeout-0 polls between updates are not.
+            self._get_wait.observe(self._poll_timer.latest("poll"), labels)
             self._depth.set(self._queue.qsize(), labels)
             self.heartbeats.beat("learner")
         return items
@@ -360,6 +361,12 @@ class ParameterServer:
             "Host-side device_put time per param placement (once per DEVICE "
             "per version, not per actor; NOT queue blocking)",
         )
+        self._policy_lag = get_registry().histogram(
+            "stoix_tpu_sebulba_policy_lag_updates",
+            "Learner's newest param version minus the version a consumed "
+            "rollout was collected with (updates of staleness; on-policy path)",
+            buckets=(0, 1, 2, 3, 4, 6, 8, 16),
+        )
 
     @property
     def num_actors(self) -> int:
@@ -369,11 +376,9 @@ class ParameterServer:
         """device_put once per device; later actors on the device reuse it."""
         local = placed.get(device)
         if local is None:
-            start = time.perf_counter()
-            local = jax.device_put(params, device)
-            self._transfer.observe(
-                time.perf_counter() - start, {"queue": "params", "device": str(device)}
-            )
+            with span("param_transfer", clock=self._transfer,
+                      phase={"queue": "params", "device": str(device)}):
+                local = jax.device_put(params, device)
             placed[device] = local
         return local
 
@@ -382,6 +387,15 @@ class ParameterServer:
         """Monotone count of completed/started distribute_params calls — the
         learner's CURRENT policy version (0 before the first push)."""
         return self._version
+
+    def observe_policy_lag(self, behavior_version: int) -> int:
+        """The learner calls this for every rollout it consumes: its newest
+        version minus the version the actor acted with, into the histogram
+        `stoix_tpu_sebulba_policy_lag_updates`. 0 = trained on at the version
+        it was collected with; the skip-fetch pipelining makes 1 the norm."""
+        lag = self._version - int(behavior_version)
+        self._policy_lag.observe(lag)
+        return lag
 
     def distribute_params(self, params: Any) -> None:
         self._version += 1
@@ -395,14 +409,13 @@ class ParameterServer:
                 # slow push must be attributable to the right cause (large
                 # params vs an actor not draining its queue).
                 local = self._place(params, device, placed)
-                start = time.perf_counter()
-                # Keep only the freshest params: drop a stale entry if present.
-                try:
-                    q.get_nowait()
-                except queue.Empty:
-                    pass
-                q.put(VersionedParams(version, local))
-                self._put_wait.observe(time.perf_counter() - start, labels)
+                with span("param_put", clock=self._put_wait, phase=labels):
+                    # Keep only the freshest params: drop a stale entry if present.
+                    try:
+                        q.get_nowait()
+                    except queue.Empty:
+                        pass
+                    q.put(VersionedParams(version, local))
                 self._depth.set(q.qsize(), labels)
                 self._pushes.inc(labels={"actor": str(actor_id)})
         self._placed_entry = (params, placed, version)
@@ -451,10 +464,8 @@ class ParameterServer:
         under: (version, params), or None (shutdown sentinel). IMPACT actors
         use this to tag trajectories with their behavior-policy version."""
         labels = {"queue": "params", "actor": str(actor_id)}
-        start = time.perf_counter()
-        with span("param_get", actor=actor_id):
+        with span("param_get", clock=self._get_wait, phase=labels, actor=actor_id):
             entry = self._queues[actor_id].get(timeout=timeout)
-        self._get_wait.observe(time.perf_counter() - start, labels)
         self._depth.set(self._queues[actor_id].qsize(), labels)
         if isinstance(entry, ComponentFailure):
             raise entry

@@ -43,7 +43,7 @@ from stoix_tpu.base_types import (
     PPOTransition,
 )
 from stoix_tpu.evaluator import get_distribution_act_fn
-from stoix_tpu.observability import annotate, get_logger
+from stoix_tpu.observability import SCOPES, annotate, get_logger, span
 from stoix_tpu.ops import (
     losses,
     running_statistics,
@@ -134,6 +134,7 @@ def get_learner_fn(
             return observation
         return running_statistics.normalize_observation(observation, obs_stats)
 
+    @annotate(SCOPES["rollout"])
     def _env_step(learner_state: PPOLearnerState, _: Any):
         params, opt_states, key = (
             learner_state.params, learner_state.opt_states, learner_state.key,
@@ -142,13 +143,15 @@ def get_learner_fn(
         obs_stats = learner_state.obs_stats
         key, policy_key = jax.random.split(key)
 
-        observation = _maybe_normalize(last_timestep.observation, obs_stats)
-        actor_policy = actor_apply(params.actor_params, observation)
-        value = critic_apply(params.critic_params, observation)
-        action = actor_policy.sample(seed=policy_key)
-        log_prob = actor_policy.log_prob(action)
+        with annotate(SCOPES["rollout_policy"]):
+            observation = _maybe_normalize(last_timestep.observation, obs_stats)
+            actor_policy = actor_apply(params.actor_params, observation)
+            value = critic_apply(params.critic_params, observation)
+            action = actor_policy.sample(seed=policy_key)
+            log_prob = actor_policy.log_prob(action)
 
-        env_state, timestep = env.step(env_state, action)
+        with annotate(SCOPES["rollout_env"]):
+            env_state, timestep = env.step(env_state, action)
 
         done = timestep.discount == 0.0
         truncated = jnp.logical_and(timestep.last(), timestep.discount != 0.0)
@@ -214,7 +217,7 @@ def get_learner_fn(
         )
         return actor_total + critic_total, (loss_actor, entropy, value_loss)
 
-    @annotate("ppo_minibatch")
+    @annotate(SCOPES["update_minibatch"])
     def _update_minibatch(train_state: Tuple, batch_info: Tuple):
         params, opt_states, behavior_actor_params, kl_beta = train_state
         traj_batch, advantages, targets = batch_info
@@ -313,7 +316,7 @@ def get_learner_fn(
             kl_beta,
         ), loss_info
 
-    @annotate("ppo_epoch")
+    @annotate(SCOPES["update_epoch"])
     def _update_epoch(update_state: Tuple, _: Any):
         (
             params, opt_states, behavior_actor_params, kl_beta,
@@ -323,9 +326,10 @@ def get_learner_fn(
 
         # Flatten [T, E] -> [T*E] and shuffle across both time and envs.
         batch_size = advantages.shape[0] * advantages.shape[1]
-        permutation = jax.random.permutation(shuffle_key, batch_size)
-        flat = tree_merge_leading_dims((traj_batch, advantages, targets), 2)
-        shuffled = jax.tree.map(lambda x: jnp.take(x, permutation, axis=0), flat)
+        with annotate(SCOPES["minibatch_shuffle"]):
+            permutation = jax.random.permutation(shuffle_key, batch_size)
+            flat = tree_merge_leading_dims((traj_batch, advantages, targets), 2)
+            shuffled = jax.tree.map(lambda x: jnp.take(x, permutation, axis=0), flat)
         minibatches = jax.tree.map(
             lambda x: x.reshape(
                 (int(config.system.num_minibatches), -1) + x.shape[1:]
@@ -371,20 +375,23 @@ def get_learner_fn(
                 std_max_value=5e4,
             )
 
-        # ONE batched critic apply for all bootstrap values [T, E].
-        v_t = critic_apply(params.critic_params, traj_batch.next_obs)
+        with annotate(SCOPES["gae"]):
+            # ONE batched critic apply for all bootstrap values [T, E].
+            v_t = critic_apply(params.critic_params, traj_batch.next_obs)
 
-        d_t = gamma * (1.0 - traj_batch.done.astype(jnp.float32))
-        advantages, targets = truncated_generalized_advantage_estimation(
-            traj_batch.reward * reward_scale,
-            d_t,
-            gae_lambda,
-            v_tm1=traj_batch.value,
-            v_t=v_t,
-            truncation_t=traj_batch.truncated.astype(jnp.float32),
-            standardize_advantages=bool(config.system.get("standardize_advantages", True)),
-            impl=multistep_impl,
-        )
+            d_t = gamma * (1.0 - traj_batch.done.astype(jnp.float32))
+            advantages, targets = truncated_generalized_advantage_estimation(
+                traj_batch.reward * reward_scale,
+                d_t,
+                gae_lambda,
+                v_tm1=traj_batch.value,
+                v_t=v_t,
+                truncation_t=traj_batch.truncated.astype(jnp.float32),
+                standardize_advantages=bool(
+                    config.system.get("standardize_advantages", True)
+                ),
+                impl=multistep_impl,
+            )
 
         # Behavior params (the rollout's) stay FIXED across all epochs: KL
         # penalties anchor to them, matching the reference's
@@ -512,11 +519,14 @@ def learner_setup(
     )
 
     key, actor_key, critic_key, env_key = jax.random.split(keys, 4)
-    dummy_obs = jax.tree.map(lambda x: x[None], env.observation_value())
-    actor_params = actor_network.init(actor_key, dummy_obs)
-    critic_params = critic_network.init(critic_key, dummy_obs)
-    actor_opt_state = actor_optim.init(actor_params)
-    critic_opt_state = critic_optim.init(critic_params)
+    # The runner times this whole function as set-up's `learner_setup`; the
+    # span marks the network-init part of it in a profiler trace.
+    with span("network_init"):
+        dummy_obs = jax.tree.map(lambda x: x[None], env.observation_value())
+        actor_params = actor_network.init(actor_key, dummy_obs)
+        critic_params = critic_network.init(critic_key, dummy_obs)
+        actor_opt_state = actor_optim.init(actor_params)
+        critic_opt_state = critic_optim.init(critic_params)
 
     apply_fns = (actor_network.apply, critic_network.apply)
     update_fns = (actor_optim.update, critic_optim.update)

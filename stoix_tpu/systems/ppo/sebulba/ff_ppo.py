@@ -25,6 +25,8 @@ guards the gradient step against non-finite losses/grads.
 
 from __future__ import annotations
 
+import contextlib
+import os
 import queue
 import sys
 import threading
@@ -41,7 +43,9 @@ from stoix_tpu.base_types import ActorCriticOptStates, ActorCriticParams, PPOTra
 from stoix_tpu.envs.factory import make_factory
 from stoix_tpu.evaluator import get_distribution_act_fn, get_ff_evaluator_fn
 from stoix_tpu.observability import (
+    SCOPES,
     RunStats,
+    SetupClock,
     annotate,
     flightrec,
     get_health_monitor,
@@ -78,7 +82,7 @@ from stoix_tpu.sebulba.core import (
 from stoix_tpu.utils import compilecache
 from stoix_tpu.utils import config as config_lib
 from stoix_tpu.utils.logger import LogEvent, StoixLogger
-from stoix_tpu.utils.timing import TimingTracker
+from stoix_tpu.utils.timing import StepAccumulator, TimingTracker
 from stoix_tpu.utils.training import make_learning_rate
 
 # Throughput stats of the most recent run_experiment call in this process
@@ -277,17 +281,20 @@ def get_learn_step(actor_apply, critic_apply, update_fns, config, mesh: Mesh):
                 obs_stats, raw_obs.agent_view, axis_names=("data",),
                 std_min_value=5e-4, std_max_value=5e4,
             )
-        v_t = critic_apply(state.params.critic_params, traj.next_obs)
-        d_t = gamma * (1.0 - traj.done.astype(jnp.float32))
-        advantages, targets = truncated_generalized_advantage_estimation(
-            traj.reward, d_t, float(config.system.gae_lambda),
-            v_tm1=traj.value, v_t=v_t,
-            truncation_t=traj.truncated.astype(jnp.float32),
-            standardize_advantages=bool(config.system.get("standardize_advantages", True)),
-            impl=str(config.system.get("multistep_impl", "scan")),
-        )
+        with annotate(SCOPES["gae"]):
+            v_t = critic_apply(state.params.critic_params, traj.next_obs)
+            d_t = gamma * (1.0 - traj.done.astype(jnp.float32))
+            advantages, targets = truncated_generalized_advantage_estimation(
+                traj.reward, d_t, float(config.system.gae_lambda),
+                v_tm1=traj.value, v_t=v_t,
+                truncation_t=traj.truncated.astype(jnp.float32),
+                standardize_advantages=bool(
+                    config.system.get("standardize_advantages", True)
+                ),
+                impl=str(config.system.get("multistep_impl", "scan")),
+            )
 
-        @annotate("ppo_minibatch")
+        @annotate(SCOPES["update_minibatch"])
         def _minibatch(carry, batch):
             params, opt_states = carry
             mb_traj, mb_adv, mb_tgt = batch
@@ -341,16 +348,17 @@ def get_learn_step(actor_apply, critic_apply, update_fns, config, mesh: Mesh):
                 **guard_metrics,
             }
 
-        @annotate("ppo_epoch")
+        @annotate(SCOPES["update_epoch"])
         def _epoch(carry, _):
             params, opt_states, key = carry
             key, shuffle_key = jax.random.split(key)
             batch_size = advantages.shape[0] * advantages.shape[1]
-            perm = jax.random.permutation(shuffle_key, batch_size)
-            flat = jax.tree.map(
-                lambda x: x.reshape((-1,) + x.shape[2:]), (traj, advantages, targets)
-            )
-            shuffled = jax.tree.map(lambda x: jnp.take(x, perm, axis=0), flat)
+            with annotate(SCOPES["minibatch_shuffle"]):
+                perm = jax.random.permutation(shuffle_key, batch_size)
+                flat = jax.tree.map(
+                    lambda x: x.reshape((-1,) + x.shape[2:]), (traj, advantages, targets)
+                )
+                shuffled = jax.tree.map(lambda x: jnp.take(x, perm, axis=0), flat)
             minibatches = jax.tree.map(
                 lambda x: x.reshape(
                     (int(config.system.num_minibatches), -1) + x.shape[1:]
@@ -418,15 +426,18 @@ def get_impact_learn_step(
                 obs_stats, raw_obs.agent_view, axis_names=("data",),
                 std_min_value=5e-4, std_max_value=5e4,
             )
-        v_t = critic_apply(state.params.critic_params, traj.next_obs)
-        d_t = gamma * (1.0 - traj.done.astype(jnp.float32))
-        advantages, targets = truncated_generalized_advantage_estimation(
-            traj.reward, d_t, float(config.system.gae_lambda),
-            v_tm1=traj.value, v_t=v_t,
-            truncation_t=traj.truncated.astype(jnp.float32),
-            standardize_advantages=bool(config.system.get("standardize_advantages", True)),
-            impl=str(config.system.get("multistep_impl", "scan")),
-        )
+        with annotate(SCOPES["gae"]):
+            v_t = critic_apply(state.params.critic_params, traj.next_obs)
+            d_t = gamma * (1.0 - traj.done.astype(jnp.float32))
+            advantages, targets = truncated_generalized_advantage_estimation(
+                traj.reward, d_t, float(config.system.gae_lambda),
+                v_tm1=traj.value, v_t=v_t,
+                truncation_t=traj.truncated.astype(jnp.float32),
+                standardize_advantages=bool(
+                    config.system.get("standardize_advantages", True)
+                ),
+                impl=str(config.system.get("multistep_impl", "scan")),
+            )
 
         @annotate("impact_minibatch")
         def _minibatch(carry, batch):
@@ -489,11 +500,12 @@ def get_impact_learn_step(
             params, opt_states, key = carry
             key, shuffle_key = jax.random.split(key)
             batch_size = advantages.shape[0] * advantages.shape[1]
-            perm = jax.random.permutation(shuffle_key, batch_size)
-            flat = jax.tree.map(
-                lambda x: x.reshape((-1,) + x.shape[2:]), (traj, advantages, targets)
-            )
-            shuffled = jax.tree.map(lambda x: jnp.take(x, perm, axis=0), flat)
+            with annotate(SCOPES["minibatch_shuffle"]):
+                perm = jax.random.permutation(shuffle_key, batch_size)
+                flat = jax.tree.map(
+                    lambda x: x.reshape((-1,) + x.shape[2:]), (traj, advantages, targets)
+                )
+                shuffled = jax.tree.map(lambda x: jnp.take(x, perm, axis=0), flat)
             minibatches = jax.tree.map(
                 lambda x: x.reshape(
                     (int(config.system.num_minibatches), -1) + x.shape[1:]
@@ -521,6 +533,24 @@ def get_impact_learn_step(
             check_vma=True,
         )
     )
+
+
+def get_act_fn(actor_apply, critic_apply, normalize_obs: bool):
+    """The actors' per-step inference program (`jit_act_fn` in a device
+    trace), all of it under the `rollout_policy` scope."""
+
+    @jax.jit
+    @annotate(SCOPES["rollout_policy"])
+    def act_fn(bundle, observation, key):
+        params, obs_stats = bundle
+        if normalize_obs:
+            observation = running_statistics.normalize_observation(observation, obs_stats)
+        dist = actor_apply(params.actor_params, observation)
+        value = critic_apply(params.critic_params, observation)
+        action = dist.sample(seed=key)
+        return action, dist.log_prob(action), value
+
+    return act_fn
 
 
 def rollout_thread(
@@ -574,22 +604,19 @@ def _rollout_body(
 ):
     envs = env_factory(envs_per_actor)
     timestep = envs.reset(seed=seed)
+    # A host pool (C++/EnvPool/Gymnasium) reads the action on the host; a
+    # pure-JAX twin takes the device array as it is.
+    host_pool = bool(getattr(envs, "takes_host_actions", False))
 
     normalize_obs = bool(config.system.get("normalize_observations", False))
-    # IMPACT path (docs/DESIGN.md §2.12): fetch params WITH their version and
-    # tag every pushed trajectory with it — the learner computes per-batch
-    # staleness (its current version minus this behavior version).
+    # Every pushed trajectory is tagged with the version of the params that
+    # collected it: the learner gauges policy lag from it (its newest version
+    # minus this one), and on the IMPACT path (docs/DESIGN.md §2.12)
+    # computes per-batch staleness.
     impact_on = impact_settings_from_config(config) is not None
 
-    @jax.jit
-    def act_fn(bundle, observation, key):
-        params, obs_stats = bundle
-        if normalize_obs:
-            observation = running_statistics.normalize_observation(observation, obs_stats)
-        dist = actor_apply(params.actor_params, observation)
-        value = critic_apply(params.critic_params, observation)
-        action = dist.sample(seed=key)
-        return action, dist.log_prob(action), value
+    act_fn = get_act_fn(actor_apply, critic_apply, normalize_obs)
+    step_seconds = StepAccumulator()
 
     with jax.default_device(actor_device):
         key = jax.random.PRNGKey(seed)
@@ -615,17 +642,24 @@ def _rollout_body(
                         break
                     behavior_version, params = fetched
             traj: List[PPOTransition] = []
-            with span("actor_rollout", actor=actor_id, idx=rollout_idx), timer.time("rollout"):
+            with span("actor_rollout", clock=timer, phase="rollout",
+                      actor=actor_id, idx=rollout_idx):
                 for _ in range(rollout_length):
-                    key, act_key = jax.random.split(key)
-                    with timer.time("inference"):
+                    with span("actor_inference", clock=step_seconds, phase="inference"):
+                        key, act_key = jax.random.split(key)
                         # Envs may live on a different device (e.g. CPU for
                         # C++/EnvPool backends); stage observations onto the
                         # actor device for inference.
                         obs_local = jax.device_put(timestep.observation, actor_device)
                         action, log_prob, value = act_fn(params, obs_local, act_key)
-                    with timer.time("env_step"):
-                        next_timestep = envs.step(action)
+                        # `inference` ends when the action is where the env
+                        # reads it. For a host pool that is the host: the
+                        # device-to-host copy its step() would make, made
+                        # here (moved, not added), so `env_step` times the
+                        # pool alone and not the wait for the device.
+                        env_action = np.asarray(action) if host_pool else action
+                    with span("actor_env_step", clock=step_seconds, phase="env_step"):
+                        next_timestep = envs.step(env_action)
                     traj.append(
                         PPOTransition(
                             done=next_timestep.discount == 0.0,
@@ -642,8 +676,12 @@ def _rollout_body(
                         )
                     )
                     timestep = next_timestep
+            # Mean seconds a step over this rollout, into the rolling means
+            # logged as actor<i>_inference_time / actor<i>_env_step_time.
+            step_seconds.flush(timer, rollout_length)
 
-            with span("actor_prepare_data", actor=actor_id), timer.time("prepare_data"):
+            with span("actor_prepare_data", clock=timer, phase="prepare_data",
+                      actor=actor_id):
                 # Stack [T, E] then split the env axis across learner devices
                 # as single-device shards for global-array assembly.
                 stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *traj)
@@ -657,10 +695,11 @@ def _rollout_body(
                 )
             with timer.time("queue_put"):
                 try:
+                    tagged = (behavior_version, payload)
                     if impact_on:
-                        pipeline.push(actor_id, (behavior_version, payload), timeout=60.0)
+                        pipeline.push(actor_id, tagged, timeout=60.0)
                     else:
-                        pipeline.send_rollout(actor_id, payload, timeout=60.0)
+                        pipeline.send_rollout(actor_id, tagged, timeout=60.0)
                 except queue.Full:
                     if lifetime.should_stop():
                         break
@@ -693,6 +732,9 @@ def run_experiment(
     # before the learner is traced.
     compilecache.configure(config)
     scan_kernels.configure_from_config(config)
+    # Set-up phases -> stoix_tpu_setup_phase_seconds{phase}, as in the
+    # Anakin runner (host memory only).
+    setup_phases = SetupClock()
     # Launch hardening (docs/DESIGN.md §2.4, arch.preflight): subprocess
     # backend probe + config cross-validation before any device work — the
     # actor/learner device-id split below is exactly the class of config this
@@ -734,81 +776,85 @@ def run_experiment(
     config.arch.num_updates_per_eval = max(1, int(config.arch.num_updates) // num_evaluation)
     config.logger.system_name = config.system.system_name
 
-    env_factory = make_factory(config)
-    probe_envs = env_factory(1)
-    num_actions = probe_envs.num_actions
-    config.system.action_dim = num_actions
-    dummy_obs = jax.tree.map(
-        lambda x: np.asarray(x)[None], probe_envs.observation_space().generate_value()
-        if hasattr(probe_envs.observation_space(), "generate_value")
-        else probe_envs.reset(seed=0).observation,
-    )
+    with span("env_build", clock=setup_phases, phase="env_build"):
+        # The C++ pool's first build (g++, once a checkout) is in here.
+        env_factory = make_factory(config)
+        probe_envs = env_factory(1)
+        num_actions = probe_envs.num_actions
+        config.system.action_dim = num_actions
+        dummy_obs = jax.tree.map(
+            lambda x: np.asarray(x)[None], probe_envs.observation_space().generate_value()
+            if hasattr(probe_envs.observation_space(), "generate_value")
+            else probe_envs.reset(seed=0).observation,
+        )
 
     build = networks_builder or (
         lambda cfg, n, obs: _build_networks(cfg, n, obs, env=probe_envs)
     )
-    actor, critic = build(config, num_actions, dummy_obs)
-    key = jax.random.PRNGKey(int(config.arch.seed))
-    key, a_key, c_key = jax.random.split(key, 3)
-    obs0 = jax.tree.map(lambda x: jnp.asarray(x), probe_envs.reset(seed=0).observation)
-    actor_params = actor.init(a_key, obs0)
-    critic_params = critic.init(c_key, obs0)
+    with span("network_init", clock=setup_phases, phase="network_init"):
+        actor, critic = build(config, num_actions, dummy_obs)
+        key = jax.random.PRNGKey(int(config.arch.seed))
+        key, a_key, c_key = jax.random.split(key, 3)
+        obs0 = jax.tree.map(lambda x: jnp.asarray(x), probe_envs.reset(seed=0).observation)
+        actor_params = actor.init(a_key, obs0)
+        critic_params = critic.init(c_key, obs0)
 
-    actor_optim = optax.chain(
-        optax.clip_by_global_norm(float(config.system.max_grad_norm)),
-        optax.adam(make_learning_rate(float(config.system.actor_lr), config,
-                                      int(config.system.epochs),
-                                      int(config.system.num_minibatches)), eps=1e-5),
-    )
-    critic_optim = optax.chain(
-        optax.clip_by_global_norm(float(config.system.max_grad_norm)),
-        optax.adam(make_learning_rate(float(config.system.critic_lr), config,
-                                      int(config.system.epochs),
-                                      int(config.system.num_minibatches)), eps=1e-5),
-    )
-    params = ActorCriticParams(actor_params, critic_params)
-    opt_states = ActorCriticOptStates(
-        actor_optim.init(actor_params), critic_optim.init(critic_params)
-    )
-    key, learn_key = jax.random.split(key)
-    obs0_single = jax.tree.map(lambda x: jnp.asarray(x)[0], obs0.agent_view)
-    obs_stats = running_statistics.init_state(obs0_single)
-    learner_state = jax.device_put(
-        CoreLearnerState(params, opt_states, learn_key, obs_stats),
-        NamedSharding(learner_mesh, P()),
-    )
-
-    # IMPACT stale-trajectory reuse (docs/DESIGN.md §2.12): None (the
-    # default) constructs the UNCHANGED on-policy objects below — same
-    # OnPolicyPipeline, same get_learn_step trace.
-    impact = impact_settings_from_config(config)
-    if impact is not None and learn_step_builder is not None:
-        raise ValueError(
-            "system.impact.enabled is incompatible with a custom "
-            "learn_step_builder: the IMPACT update takes (state, "
-            "target_params, batch), not (state, batch)"
+    with span("learner_setup", clock=setup_phases, phase="learner_setup"):
+        actor_optim = optax.chain(
+            optax.clip_by_global_norm(float(config.system.max_grad_norm)),
+            optax.adam(make_learning_rate(float(config.system.actor_lr), config,
+                                          int(config.system.epochs),
+                                          int(config.system.num_minibatches)), eps=1e-5),
         )
-    if impact is not None:
-        learn_step = get_impact_learn_step(
-            actor.apply, critic.apply, (actor_optim.update, critic_optim.update),
-            config, learner_mesh, rho_clip=impact.rho_clip,
+        critic_optim = optax.chain(
+            optax.clip_by_global_norm(float(config.system.max_grad_norm)),
+            optax.adam(make_learning_rate(float(config.system.critic_lr), config,
+                                          int(config.system.epochs),
+                                          int(config.system.num_minibatches)), eps=1e-5),
         )
-    else:
-        builder = learn_step_builder or get_learn_step
-        learn_step = builder(
-            actor.apply, critic.apply, (actor_optim.update, critic_optim.update),
-            config, learner_mesh,
+        params = ActorCriticParams(actor_params, critic_params)
+        opt_states = ActorCriticOptStates(
+            actor_optim.init(actor_params), critic_optim.init(critic_params)
+        )
+        key, learn_key = jax.random.split(key)
+        obs0_single = jax.tree.map(lambda x: jnp.asarray(x)[0], obs0.agent_view)
+        obs_stats = running_statistics.init_state(obs0_single)
+        learner_state = jax.device_put(
+            CoreLearnerState(params, opt_states, learn_key, obs_stats),
+            NamedSharding(learner_mesh, P()),
         )
 
-    # State-integrity sentinel (docs/DESIGN.md §2.9, arch.integrity): Sebulba
-    # has no coalesced fetch to piggyback fingerprints on, so the learner
-    # loop checks the replicated learner state synchronously at each eval
-    # boundary (the vector is [num_learner_devices] uint32 — tiny). Off (the
-    # default) = None = unchanged loop.
-    sentinel = integrity.sentinel_from_config(config)
-    if sentinel is not None:
-        sentinel.bind(learner_mesh, learner_state)
-        sentinel.install_excepthook()
+        # IMPACT stale-trajectory reuse (docs/DESIGN.md §2.12): None (the
+        # default) constructs the UNCHANGED on-policy objects below — same
+        # OnPolicyPipeline, same get_learn_step trace.
+        impact = impact_settings_from_config(config)
+        if impact is not None and learn_step_builder is not None:
+            raise ValueError(
+                "system.impact.enabled is incompatible with a custom "
+                "learn_step_builder: the IMPACT update takes (state, "
+                "target_params, batch), not (state, batch)"
+            )
+        if impact is not None:
+            learn_step = get_impact_learn_step(
+                actor.apply, critic.apply, (actor_optim.update, critic_optim.update),
+                config, learner_mesh, rho_clip=impact.rho_clip,
+            )
+        else:
+            builder = learn_step_builder or get_learn_step
+            learn_step = builder(
+                actor.apply, critic.apply, (actor_optim.update, critic_optim.update),
+                config, learner_mesh,
+            )
+
+        # State-integrity sentinel (docs/DESIGN.md §2.9, arch.integrity): Sebulba
+        # has no coalesced fetch to piggyback fingerprints on, so the learner
+        # loop checks the replicated learner state synchronously at each eval
+        # boundary (the vector is [num_learner_devices] uint32 — tiny). Off (the
+        # default) = None = unchanged loop.
+        sentinel = integrity.sentinel_from_config(config)
+        if sentinel is not None:
+            sentinel.bind(learner_mesh, learner_state)
+            sentinel.install_excepthook()
 
     normalize_obs = bool(config.system.get("normalize_observations", False))
 
@@ -837,19 +883,22 @@ def run_experiment(
     )
     suite = getattr(config.env, "env_name", None)
     has_jax_twin = scenario in ENV_REGISTRY or suite in suites.SUITE_MAKERS
-    if has_jax_twin:
-        # Genuine construction errors must surface — only the known
-        # no-JAX-twin case (EnvPool/Gymnasium task ids) falls back.
-        eval_env = RecordEpisodeMetrics(
-            make_single(scenario, suite=suite, **dict(config.env.get("kwargs", {}) or {}))
-        )
-        eval_fn = get_ff_evaluator_fn(
-            eval_env, get_distribution_act_fn(config, eval_apply), config, eval_mesh
-        )
-    else:
-        eval_fn = get_stateful_evaluator_fn(
-            env_factory, get_distribution_act_fn(config, eval_apply), config
-        )
+    with span("evaluator_setup", clock=setup_phases, phase="evaluator_setup"):
+        if has_jax_twin:
+            # Genuine construction errors must surface — only the known
+            # no-JAX-twin case (EnvPool/Gymnasium task ids) falls back.
+            eval_env = RecordEpisodeMetrics(
+                make_single(
+                    scenario, suite=suite, **dict(config.env.get("kwargs", {}) or {})
+                )
+            )
+            eval_fn = get_ff_evaluator_fn(
+                eval_env, get_distribution_act_fn(config, eval_apply), config, eval_mesh
+            )
+        else:
+            eval_fn = get_stateful_evaluator_fn(
+                env_factory, get_distribution_act_fn(config, eval_apply), config
+            )
 
     logger = StoixLogger(config)
     # Ops plane (docs/DESIGN.md §2.13): StoixLogger's configure() just reset
@@ -910,6 +959,11 @@ def run_experiment(
         logger.log(metrics, t, len(eval_results), LogEvent.EVAL)
         eval_results.append(float(jnp.mean(metrics["episode_return"])))
 
+    # Set-up's last phase: from the first thread started to the first
+    # completed learner update (the actors' first rollouts and every first
+    # compile — act_fn, the learn step — are in it).
+    first_tick = contextlib.ExitStack()
+    first_tick.enter_context(span("first_tick", clock=setup_phases, phase="first_tick"))
     async_evaluator = AsyncEvaluator(
         eval_fn, lifetime, on_eval_result, heartbeats=pipeline.heartbeats
     )
@@ -1017,23 +1071,44 @@ def run_experiment(
     run_start_time = time.perf_counter()  # whole-run FPS denominator (incl.
     # first-rollout compile — the number a fleet scheduler actually gets)
     fleet_window_started = time.perf_counter()
+    # STOIX_TPU_PROFILE_DIR=<dir>: a jax.profiler trace around ONE
+    # steady-state learner update, as the Anakin runner wraps one eval
+    # window: the update that follows the SECOND eval block (the first
+    # block's evaluation compiles), opened just before that block is logged
+    # and its evaluation submitted, so the evaluator's `async_eval` is whole
+    # inside it. Every thread's spans are TraceAnnotations, so the trace
+    # holds the actor, learner and evaluator threads on separate host lines
+    # and the device ops, all on one clock.
+    profile_dir = os.environ.get("STOIX_TPU_PROFILE_DIR")
+    profile_update, profiling = -1, False
+    if profile_dir:
+        profile_update = min(
+            2 * int(config.arch.num_updates_per_eval), int(config.arch.num_updates) - 1
+        )
     try:
         for update_idx in range(int(config.arch.num_updates)):
             fresh = True
             if impact_ingest is None:
-                with timer.time("rollout_get"):
-                    payloads = pipeline.collect_rollouts()
+                with span("learner_rollout_wait", clock=timer, phase="rollout_get",
+                          update=update_idx):
+                    tagged = pipeline.collect_rollouts()
                 ledger.note(
                     goodput.SEBULBA_PHASE_MAP["rollout_get"],
                     timer.latest("rollout_get"),
                 )
-                with span("learner_assemble", update=update_idx), timer.time("assemble"):
-                    batch = _assemble_batch(payloads)
+                with span("learner_assemble", clock=timer, phase="assemble",
+                          update=update_idx):
+                    # Policy lag of every rollout consumed: the learner's
+                    # newest version minus the one the actor acted with.
+                    for behavior_version, _ in tagged:
+                        param_server.observe_policy_lag(behavior_version)
+                    batch = _assemble_batch([payload for _, payload in tagged])
                 ledger.note(
                     goodput.SEBULBA_PHASE_MAP["assemble"], timer.latest("assemble")
                 )
             else:
-                with span("impact_next_batch", update=update_idx), timer.time("rollout_get"):
+                with span("impact_next_batch", clock=timer, phase="rollout_get",
+                          update=update_idx):
                     got = impact_ingest.next_batch(
                         _assemble_batch, param_server.version
                     )
@@ -1055,7 +1130,7 @@ def run_experiment(
                     impact_stats["max_staleness_seen"], staleness
                 )
 
-            with span("learner_update", update=update_idx), timer.time("learn"):
+            with span("learner_update", clock=timer, phase="learn", update=update_idx):
                 if impact_ingest is None:
                     learner_state, train_metrics = learn_step(learner_state, batch)
                 else:
@@ -1067,6 +1142,20 @@ def run_experiment(
             param_server.distribute_params(
                 (learner_state.params, learner_state.obs_stats)
             )
+            if update_idx == 0:
+                first_tick.close()
+            if profiling:
+                profiling = False
+                try:
+                    jax.profiler.stop_trace()
+                except Exception:  # noqa: BLE001 — profiling must never kill a run
+                    pass
+            elif update_idx + 1 == profile_update:
+                try:
+                    jax.profiler.start_trace(profile_dir)
+                    profiling = True
+                except Exception:  # noqa: BLE001
+                    pass
             if impact_ingest is not None:
                 if impact_stats["updates"] % impact.target_update_interval == 0:
                     target_params = learner_state.params
@@ -1099,39 +1188,42 @@ def run_experiment(
                     )
 
             if (update_idx + 1) % int(config.arch.num_updates_per_eval) == 0:
-                # Drain actor metrics and log.
-                ep_returns, timings = [], {}
-                while not metrics_sink.empty():
-                    m = metrics_sink.get_nowait()
-                    em = m["episode_metrics"]
-                    mask = em["is_terminal_step"].reshape(-1)
-                    if mask.any():
-                        ep_returns.extend(em["episode_return"].reshape(-1)[mask].tolist())
-                    timings.update(m["timings"])
-                if ep_returns:
-                    logger.log({"episode_return": np.asarray(ep_returns)}, t_steps,
-                               update_idx, LogEvent.ACT)
-                logger.log(jax.tree.map(lambda x: jnp.mean(x), train_metrics),
-                           t_steps, update_idx, LogEvent.TRAIN)
-                logger.log(
-                    {
-                        **timings,
-                        **timer.all_means(prefix="learner_"),
-                        **timer.all_percentiles(prefix="learner_"),
-                    },
-                    t_steps, update_idx, LogEvent.MISC,
-                )
-                key, ek = jax.random.split(key)
-                if normalize_obs:
-                    eval_payload = (
-                        learner_state.params.actor_params, learner_state.obs_stats
+                with span("learner_log", update=update_idx):
+                    # Drain actor metrics and log.
+                    ep_returns, timings = [], {}
+                    while not metrics_sink.empty():
+                        m = metrics_sink.get_nowait()
+                        em = m["episode_metrics"]
+                        mask = em["is_terminal_step"].reshape(-1)
+                        if mask.any():
+                            ep_returns.extend(
+                                em["episode_return"].reshape(-1)[mask].tolist()
+                            )
+                        timings.update(m["timings"])
+                    if ep_returns:
+                        logger.log({"episode_return": np.asarray(ep_returns)}, t_steps,
+                                   update_idx, LogEvent.ACT)
+                    logger.log(jax.tree.map(lambda x: jnp.mean(x), train_metrics),
+                               t_steps, update_idx, LogEvent.TRAIN)
+                    logger.log(
+                        {
+                            **timings,
+                            **timer.all_means(prefix="learner_"),
+                            **timer.all_percentiles(prefix="learner_"),
+                        },
+                        t_steps, update_idx, LogEvent.MISC,
                     )
-                else:
-                    eval_payload = learner_state.params.actor_params
-                eval_params = jax.device_put(
-                    jax.tree.map(np.asarray, eval_payload), evaluator_device
-                )
-                async_evaluator.submit(eval_params, ek, t_steps)
+                    key, ek = jax.random.split(key)
+                    if normalize_obs:
+                        eval_payload = (
+                            learner_state.params.actor_params, learner_state.obs_stats
+                        )
+                    else:
+                        eval_payload = learner_state.params.actor_params
+                    eval_params = jax.device_put(
+                        jax.tree.map(np.asarray, eval_payload), evaluator_device
+                    )
+                    async_evaluator.submit(eval_params, ek, t_steps)
                 if steady_start_time is None:
                     # Steady-state SPS window opens once compile/warmup has
                     # been paid (end of the first eval block).
@@ -1199,6 +1291,7 @@ def run_experiment(
             raise fleet_coord.partition_error from None
         raise
     finally:
+        first_tick.close()  # a run that never completed an update
         preempt.uninstall()
         goodput.set_active(None)
         monitor.unregister("sebulba-pipeline")
@@ -1266,6 +1359,9 @@ def run_experiment(
     # work concurrent with actor rollouts, teardown joins) to compute per the
     # pipelined-residual rule, so the fractions sum to 1.
     LAST_RUN_STATS["goodput"] = ledger.finalize()
+    LAST_RUN_STATS["setup_phases"] = {
+        k: round(v, 6) for k, v in setup_phases.seconds().items()
+    }
     # None when disabled (the pin tests/test_impact.py asserts): the default
     # config must report the untouched on-policy path, not a zeroed dict.
     LAST_RUN_STATS["impact"] = None if impact is None else {

@@ -42,19 +42,27 @@ invariants that make it legal:
 program — classic Anakin, one XLA launch per window; RNN/stateful evaluators
 fall back to the snapshot-overlap path automatically.
 
-Observability (stoix_tpu/observability, docs/DESIGN.md §2.2): per-phase
-host-side wall time (learn_s/eval_s/fetch_s/ckpt_s + compile_s) accumulates
-into the process-wide metrics registry
-(`stoix_tpu_runner_phase_seconds_total{phase=...}`) and is mirrored into the
-dict-compatible `LAST_RUN_STATS["phase_breakdown"]` view at run end (bench.py
-forwards it). With `logger.telemetry.enabled=true` every dispatcher phase
-also records a host span (learn_dispatch / snapshot_dispatch / eval_dispatch
-/ fetch_dispatch / fetch_materialize / log / ckpt_save), exported as
-Perfetto-loadable JSON next to the `jax.profiler` device trace that
-STOIX_TPU_PROFILE_DIR=<dir> wraps around one steady-state eval window. In the
-pipelined loop the phases are HOST attribution: device time spent in
-learn/eval surfaces as fetch_s (the materialize wait), while learn_s/eval_s
-shrink to dispatch cost.
+Observability (stoix_tpu/observability, docs/DESIGN.md §2.2): every
+statement of the main thread between two window completions runs inside a
+`span` that feeds the phase clock — learn_dispatch (learn_s), gossip_dispatch
+(gossip_s), snapshot_dispatch (snapshot_s), eval_dispatch (eval_s),
+fetch_dispatch (fetch_dispatch_s), fetch_materialize (fetch_s: the blocked
+wait alone), log (log_s), ckpt_save (ckpt_s) and window_bookkeeping (host_s:
+what is left — best-params tracking, integrity and fleet checks, the loop's
+own tests) — so the phases sum to the loop's wall
+(`LAST_RUN_STATS["loop_wall_s"]`; tests/test_runner_pipeline.py holds them
+to 95% of it). The seconds accumulate in the process-wide metrics registry
+(`stoix_tpu_runner_phase_seconds_total{phase=...}`) and are mirrored into
+`LAST_RUN_STATS["phase_breakdown"]` at run end (bench.py forwards it).
+Every span is a `jax.profiler.TraceAnnotation`, so the device trace that
+STOIX_TPU_PROFILE_DIR=<dir> wraps around one steady-state eval window (or
+any other profiler session) shows them on the device ops' clock; with
+`logger.telemetry.enabled=true` they are also recorded for the Perfetto JSON
+export. In the pipelined loop the phases are HOST attribution: device time
+spent in learn/eval surfaces as fetch_s (the materialize wait), while
+learn_s/eval_s shrink to dispatch cost. Set-up (env build, learner_setup,
+evaluator set-up, AOT warm-up, and from there to the first completed
+window) goes to `stoix_tpu_setup_phase_seconds{phase=...}` the same way.
 
 Resilience (stoix_tpu/resilience, docs/DESIGN.md §2.3): SIGTERM/SIGINT
 request a graceful stop at the next window boundary — the loop drains the
@@ -110,7 +118,7 @@ from stoix_tpu.evaluator import evaluator_setup, get_rnn_evaluator_fn
 from stoix_tpu.observability import (
     HeartbeatBoard,
     RunStats,
-    device_annotation,
+    SetupClock,
     flightrec,
     get_health_monitor,
     get_logger,
@@ -147,14 +155,18 @@ from stoix_tpu.utils.logger import LogEvent, StoixLogger
 from stoix_tpu.utils.timestep_checker import check_total_timesteps
 
 # Stats of the most recent run_anakin_experiment call (this process):
-# phase_breakdown {compile_s, learn_s, eval_s, fetch_s, ckpt_s},
+# phase_breakdown {compile_s, learn_s, snapshot_s, eval_s, fetch_dispatch_s,
+# fetch_s, log_s, host_s, ckpt_s [, gossip_s]}, loop_wall_s,
 # steady_state_sps, pipelined, fused_eval. bench.py reads this. The values
 # are published to the process-wide metrics registry during the run
 # (stoix_tpu_runner_* series — the source of truth) and refreshed into this
 # dict-compatible view at run end.
 LAST_RUN_STATS = RunStats()
 
-_PHASE_NAMES = ("compile_s", "learn_s", "gossip_s", "eval_s", "fetch_s", "ckpt_s")
+_PHASE_NAMES = (
+    "compile_s", "learn_s", "gossip_s", "snapshot_s", "eval_s", "fetch_dispatch_s",
+    "fetch_s", "log_s", "host_s", "ckpt_s",
+)
 
 
 class _PhaseClock:
@@ -172,14 +184,15 @@ class _PhaseClock:
         }
         self._touched: set = set()
 
-    def add(self, name: str, seconds: float) -> None:
+    def record(self, name: str, seconds: float) -> None:
+        """The `span(..., clock=phases, phase=name)` sink."""
         self._touched.add(name)
         self._counter.inc(seconds, {"phase": name})
 
     def breakdown(self) -> dict:
         # gossip_s appears only in runs that actually dispatched a gossip step;
-        # lockstep runs keep the original five-key schema bench.py and the
-        # observability contract tests pin.
+        # lockstep runs keep the schema bench.py and the observability
+        # contract tests pin.
         return {
             name: self._counter.value({"phase": name}) - self._base[name]
             for name in _PHASE_NAMES
@@ -265,6 +278,9 @@ def run_anakin_experiment(
     # traced — both are trace/compile-time statics.
     compilecache.configure(config)
     scan_kernels.configure_from_config(config)
+    # Set-up phases -> stoix_tpu_setup_phase_seconds{phase}: what the wait
+    # before the first window is made of (host memory only).
+    setup_phases = SetupClock()
     # Launch hardening (docs/DESIGN.md §2.4): probe the backend in a
     # SUBPROCESS and cross-validate the config BEFORE this process commits to
     # device work — a wedged PJRT runtime or a bad shape aborts here with a
@@ -307,11 +323,15 @@ def run_anakin_experiment(
     config = check_total_timesteps(config, int(mesh.shape["data"]))
     config.logger.system_name = config.system.system_name
 
-    env, eval_env = envs.make(config)
+    with span("env_build", clock=setup_phases, phase="env_build"):
+        env, eval_env = envs.make(config)
 
     key = jax.random.PRNGKey(int(config.arch.seed))
     key, setup_key = jax.random.split(key)
-    setup = setup_fn(env, config, mesh, setup_key)
+    # Network init and the learner's build are both the system's own
+    # learner_setup; the systems mark `network_init` inside it.
+    with span("learner_setup", clock=setup_phases, phase="learner_setup"):
+        setup = setup_fn(env, config, mesh, setup_key)
     learner_state = setup.learner_state
 
     if warmup_fn is not None:
@@ -365,7 +385,10 @@ def run_anakin_experiment(
             )
 
     make_evaluators = evaluator_setup_fn or evaluator_setup
-    evaluator, absolute_evaluator = make_evaluators(eval_env, setup.eval_act_fn, config, mesh)
+    with span("evaluator_setup", clock=setup_phases, phase="evaluator_setup"):
+        evaluator, absolute_evaluator = make_evaluators(
+            eval_env, setup.eval_act_fn, config, mesh
+        )
     logger = StoixLogger(config)
     checkpointer = checkpointer_from_config(config, config.system.system_name)
 
@@ -501,8 +524,7 @@ def run_anakin_experiment(
     export_dir = compilecache.settings_from_config(config)["export_dir"]
     cache_before = compilecache.cache_stats()
     aot_info = {"source": "compile", "export_path": None}
-    t0 = time.perf_counter()
-    with span("aot_warmup", fused=fused):
+    with span("aot_warmup", clock=phases, phase="compile_s", fused=fused):
         with _maybe_watchdog(pf, "first_compile", pf.compile_deadline_s):
             faultinject.maybe_slow_compile()
             if fused:
@@ -522,9 +544,13 @@ def run_anakin_experiment(
                 gossip_step = aot_warmup(
                     gossip_step, learner_state, jnp.asarray(0, jnp.int32)
                 )
-    compile_s = time.perf_counter() - t0
-    phases.add("compile_s", compile_s)
+    compile_s = phases.breakdown()["compile_s"]  # this run's: that one span
+    setup_phases.record("aot_warmup", compile_s)
     compile_counter.inc(compile_s)
+    # From here to the first completed window: the snapshot and evaluator
+    # programs' compiles, the first dispatches and the first window itself.
+    first_tick = contextlib.ExitStack()
+    first_tick.enter_context(span("first_tick", clock=setup_phases, phase="first_tick"))
     # Per-entry compile observability (docs/DESIGN.md §2.7): which program
     # paid how much compile, and whether the persistent cache absorbed it.
     cache_after = compilecache.cache_stats()
@@ -563,41 +589,35 @@ def run_anakin_experiment(
         """Enqueue one full eval window on the device stream; never blocks on
         device results (post-compile, each call is dispatch cost only)."""
         nonlocal learner_state, key, last_save_t, gossip_rounds
-        key, eval_key = jax.random.split(key)
-        ts = time.perf_counter()
-        # device_annotation: names this dispatch in the jax.profiler device
-        # trace (STOIX_TPU_PROFILE_DIR) so host spans and TraceMe rows share
-        # the taxonomy; a TraceMe is nanoseconds when no profiler is active.
-        with span("learn_dispatch", window=eval_idx, fused=fused), \
-                device_annotation("learn_dispatch"):
+        with span("learn_dispatch", clock=phases, phase="learn_s",
+                  window=eval_idx, fused=fused):
+            key, eval_key = jax.random.split(key)
             if fused:
                 output, eval_metrics = fused_step(learner_state, eval_key)
             else:
                 output = learn(learner_state)
-        phases.add("learn_s", time.perf_counter() - ts)
-        learner_state = output.learner_state
+            learner_state = output.learner_state
         if gossip_step is not None and (eval_idx + 1) % gossip_interval == 0:
             # Mix BEFORE the snapshot below: eval, best-params tracking, and
             # checkpoints all observe the POST-gossip parameters. The round
             # index seeds random_peer's edge draw deterministically, and the
             # step donates the learn output it consumes (nothing else reads
             # the pre-gossip state).
-            ts = time.perf_counter()
-            with span("gossip_dispatch", window=eval_idx), \
-                    device_annotation("gossip_dispatch"):
+            with span("gossip_dispatch", clock=phases, phase="gossip_s",
+                      window=eval_idx):
                 learner_state = gossip_step(
                     learner_state, jnp.asarray(eval_idx, jnp.int32)
                 )
-            phases.add("gossip_s", time.perf_counter() - ts)
-            gossip_rounds += 1
-            gossip_counter.inc()
-        t = start_step + (eval_idx + 1) * steps_per_eval
+                gossip_rounds += 1
+                gossip_counter.inc()
 
         # On-device snapshots, enqueued BEFORE the next learn dispatch ever
         # happens: donation of learner_state stays legal while eval/best/ckpt
         # consumers read the copies at their leisure. The full-state copy is
         # only taken for windows orbax's save policy will actually accept.
-        with span("snapshot_dispatch", window=eval_idx):
+        with span("snapshot_dispatch", clock=phases, phase="snapshot_s",
+                  window=eval_idx):
+            t = start_step + (eval_idx + 1) * steps_per_eval
             snapshot = _tree_copy(setup.eval_params_fn(learner_state))
             take_ckpt = (
                 checkpointer is not None
@@ -617,15 +637,13 @@ def run_anakin_experiment(
                 )
 
         if not fused:
-            ts = time.perf_counter()
-            with span("eval_dispatch", window=eval_idx):
+            with span("eval_dispatch", clock=phases, phase="eval_s", window=eval_idx):
                 eval_metrics = evaluator(snapshot, eval_key)
-            phases.add("eval_s", time.perf_counter() - ts)
 
         # ONE coalesced collective fetch for the whole window (episode, train,
         # and eval metrics ride a single pytree -> a single host-sync point).
-        ts = time.perf_counter()
-        with span("fetch_dispatch", window=eval_idx):
+        with span("fetch_dispatch", clock=phases, phase="fetch_dispatch_s",
+                  window=eval_idx):
             tree = {
                 "episode": dict(output.episode_metrics),
                 "train": dict(output.train_metrics),
@@ -647,100 +665,109 @@ def run_anakin_experiment(
                 # zero collectives to the window.
                 tree["integrity"] = sentinel.fingerprints(output.learner_state)
             metrics = fetch_global_async(tree, mesh)
-        phases.add("fetch_s", time.perf_counter() - ts)
-        return _Window(eval_idx, t, snapshot, ckpt_state, metrics)
+            window = _Window(eval_idx, t, snapshot, ckpt_state, metrics)
+        return window
 
     def process_window(window: _Window) -> None:
         """Host half: materialize the window's metrics, log, track best
         params, and hand the checkpoint snapshot to orbax (async, no wait)."""
         nonlocal best_params, best_return, final_return, window_done_at, last_save_t
         nonlocal agreed_stop
-        ts = time.perf_counter()
-        with span("fetch_materialize", window=window.eval_idx):
+        with span("fetch_materialize", clock=phases, phase="fetch_s",
+                  window=window.eval_idx):
             fetched = materialize(window.metrics)
-        phases.add("fetch_s", time.perf_counter() - ts)
+        if window.eval_idx == 0:
+            first_tick.close()  # set-up's last phase ends with the first window
 
-        now = time.perf_counter()
-        wall = now - window_done_at
-        window_done_at = now
-        window_walls.append(wall)
+        with span("window_bookkeeping", clock=phases, phase="host_s",
+                  window=window.eval_idx):
+            now = time.perf_counter()
+            wall = now - window_done_at
+            window_done_at = now
+            window_walls.append(wall)
 
-        if sentinel is not None:
-            # Integrity verdict FIRST — before this window's checkpoint
-            # snapshot is handed to orbax AND before confirm_candidate
-            # promotes this window's state to the fleet rescue snapshot: a
-            # corrupt state must never be persisted by EITHER path (a
-            # concurrent partition would otherwise rescue-save exactly the
-            # corruption being proven; window N-1's verified state stays the
-            # candidate). The fingerprint vector is replicated data, so every
-            # host computes the SAME verdict at the SAME window — the
-            # corruption flag on the fleet byte is observability, not the
-            # agreement mechanism.
-            integrity_payload = fetched.pop("integrity")
-            corruption = sentinel.verify(integrity_payload, window.eval_idx, window.t)
-            if corruption is not None:
-                # Last ring entry before the rc-88 path unwinds: the dumped
-                # flight record ends with the verdict itself.
-                recorder.record(
-                    "integrity_verdict",
-                    window=window.eval_idx,
-                    step=window.t,
-                    detail=str(corruption),
-                )
-                if fleet_coord is not None:
-                    fleet_coord.request_stop(fleet.FLAG_CORRUPT, note=str(corruption))
-                raise corruption
-            if window.eval_idx == 0:
-                # Window 0's fingerprint IS fingerprint(learn(probe_input))
-                # — the determinism probe's reference, recorded for free.
-                sentinel.record_probe_reference(integrity_payload)
+            if sentinel is not None:
+                # Integrity verdict FIRST — before this window's checkpoint
+                # snapshot is handed to orbax AND before confirm_candidate
+                # promotes this window's state to the fleet rescue snapshot: a
+                # corrupt state must never be persisted by EITHER path (a
+                # concurrent partition would otherwise rescue-save exactly the
+                # corruption being proven; window N-1's verified state stays
+                # the candidate). The fingerprint vector is replicated data,
+                # so every host computes the SAME verdict at the SAME window —
+                # the corruption flag on the fleet byte is observability, not
+                # the agreement mechanism.
+                integrity_payload = fetched.pop("integrity")
+                corruption = sentinel.verify(integrity_payload, window.eval_idx, window.t)
+                if corruption is not None:
+                    # Last ring entry before the rc-88 path unwinds: the dumped
+                    # flight record ends with the verdict itself.
+                    recorder.record(
+                        "integrity_verdict",
+                        window=window.eval_idx,
+                        step=window.t,
+                        detail=str(corruption),
+                    )
+                    if fleet_coord is not None:
+                        fleet_coord.request_stop(fleet.FLAG_CORRUPT, note=str(corruption))
+                    raise corruption
+                if window.eval_idx == 0:
+                    # Window 0's fingerprint IS fingerprint(learn(probe_input))
+                    # — the determinism probe's reference, recorded for free.
+                    sentinel.record_probe_reference(integrity_payload)
 
-        if fleet_coord is not None:
-            # This window's metrics are on the host, so (stream ordering) its
-            # learn completed — and the sentinel (above) vouched for its
-            # state: promote the rescue candidate, decode the fleet-wide
-            # flags + straggler wall-times, and record this window's wall for
-            # the next dispatch's payload.
-            fleet_coord.confirm_candidate(window.t)
-            payload = fetched.pop("fleet")
-            decision = fleet_coord.decide_from_fetch(payload, mesh)
-            if decision.stop and agreed_stop is None:
-                agreed_stop = decision
-            fleet_coord.skew_from_fetch(payload, mesh, window.eval_idx)
-            fleet_coord.note_window_wall(wall)
+            if fleet_coord is not None:
+                # This window's metrics are on the host, so (stream ordering)
+                # its learn completed — and the sentinel (above) vouched for
+                # its state: promote the rescue candidate, decode the
+                # fleet-wide flags + straggler wall-times, and record this
+                # window's wall for the next dispatch's payload.
+                fleet_coord.confirm_candidate(window.t)
+                payload = fetched.pop("fleet")
+                decision = fleet_coord.decide_from_fetch(payload, mesh)
+                if decision.stop and agreed_stop is None:
+                    agreed_stop = decision
+                fleet_coord.skew_from_fetch(payload, mesh, window.eval_idx)
+                fleet_coord.note_window_wall(wall)
 
-        episode_metrics = envs.get_final_step_metrics(fetched["episode"])
-        train_metrics = fetched["train"]
-        eval_metrics = fetched["eval"]
-        # Divergence guard, host half: fold this window's skipped-update flags
-        # into the registry counter; update_guard=halt raises DivergenceError
-        # here, naming the step and the offending metric.
-        guards.publish_guard_metrics(guard_mode, train_metrics, window.t)
-        sps = steps_per_eval / wall
-        get_registry().gauge(
-            "stoix_tpu_runner_steps_per_second",
-            "Env-steps/sec over the most recent eval window",
-        ).set(sps)
-        # Ops plane: /statusz freshness + one flight-recorder ring entry per
-        # completed window (the last N of these are what an rc-86/87/88 dump
-        # hands the post-mortem).
-        status.update(
-            {"window": window.eval_idx, "step": window.t,
-             "steps_per_second": round(sps, 3)}
-        )
-        recorder.record(
-            "window",
-            window=window.eval_idx,
-            step=window.t,
-            wall_s=round(wall, 6),
-            steps_per_second=round(sps, 3),
-            phases={k: round(v, 6) for k, v in phases.breakdown().items()},
-            fleet=fleet_coord is not None,
-            fleet_stop=agreed_stop.describe() if agreed_stop is not None else None,
-            integrity=sentinel is not None,
-        )
+            episode_metrics = envs.get_final_step_metrics(fetched["episode"])
+            train_metrics = fetched["train"]
+            eval_metrics = fetched["eval"]
+            # Divergence guard, host half: fold this window's skipped-update
+            # flags into the registry counter; update_guard=halt raises
+            # DivergenceError here, naming the step and the offending metric.
+            guards.publish_guard_metrics(guard_mode, train_metrics, window.t)
+            sps = steps_per_eval / wall
+            get_registry().gauge(
+                "stoix_tpu_runner_steps_per_second",
+                "Env-steps/sec over the most recent eval window",
+            ).set(sps)
+            # Ops plane: /statusz freshness + one flight-recorder ring entry
+            # per completed window (the last N of these are what an
+            # rc-86/87/88 dump hands the post-mortem).
+            status.update(
+                {"window": window.eval_idx, "step": window.t,
+                 "steps_per_second": round(sps, 3)}
+            )
+            recorder.record(
+                "window",
+                window=window.eval_idx,
+                step=window.t,
+                wall_s=round(wall, 6),
+                steps_per_second=round(sps, 3),
+                phases={k: round(v, 6) for k, v in phases.breakdown().items()},
+                fleet=fleet_coord is not None,
+                fleet_stop=agreed_stop.describe() if agreed_stop is not None else None,
+                integrity=sentinel is not None,
+            )
+            mean_return = float(eval_metrics["episode_return"].mean())
+            final_return = mean_return
+            if mean_return >= float(best_return):
+                best_return = mean_return
+                best_params = window.snapshot  # already a donation-safe copy
+
         if is_coordinator():
-            with span("log", window=window.eval_idx):
+            with span("log", clock=phases, phase="log_s", window=window.eval_idx):
                 logger.log(
                     {**episode_metrics, "steps_per_second": sps},
                     window.t, window.eval_idx, LogEvent.ACT,
@@ -751,18 +778,11 @@ def run_anakin_experiment(
                 )
                 logger.log(eval_metrics, window.t, window.eval_idx, LogEvent.EVAL)
 
-        mean_return = float(eval_metrics["episode_return"].mean())
-        final_return = mean_return
-        if mean_return >= float(best_return):
-            best_return = mean_return
-            best_params = window.snapshot  # already a donation-safe copy
-
         if checkpointer is not None:
             # Orbax saves sharded globals collectively: ALL processes call
             # save. The snapshot is not donated to anything, so the async save
             # needs no wait() here — serialization overlaps the next window.
-            ts = time.perf_counter()
-            with span("ckpt_save", window=window.eval_idx):
+            with span("ckpt_save", clock=phases, phase="ckpt_s", window=window.eval_idx):
                 if window.ckpt_state is not None:
                     checkpointer.save(window.t, window.ckpt_state, mean_return)
                 elif not snapshot_ckpt and checkpointer.should_save(window.t):
@@ -774,13 +794,13 @@ def run_anakin_experiment(
                     checkpointer.save(window.t, learner_state, mean_return)
                     checkpointer.wait()
                     last_save_t = window.t
-            phases.add("ckpt_s", time.perf_counter() - ts)
 
         if window.eval_idx == profile_window:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:  # noqa: BLE001 — profiling must never kill a run
-                pass
+            with span("profile_stop", clock=phases, phase="host_s"):
+                try:
+                    jax.profiler.stop_trace()
+                except Exception:  # noqa: BLE001 — profiling must never kill a run
+                    pass
 
     # Graceful preemption: SIGTERM/SIGINT set a flag; the loop observes it at
     # the next window boundary, drains the one-window-deep dispatcher, writes
@@ -796,30 +816,35 @@ def run_anakin_experiment(
         # Determinism-probe input: a donation-safe copy of the state going
         # into window 0 (every replay runs learn on a fresh copy of it).
         sentinel.capture_probe_input(_tree_copy(learner_state))
+    loop_started = time.perf_counter()
     try:
         for eval_idx in range(num_evaluation):
             # One beat per window top: an injected host_stall (next line) or
             # a wedged dispatch stops the beats and /healthz goes 503 once
-            # the age crosses the stale threshold.
+            # the age crosses the stale threshold. (An injected stall is the
+            # goodput ledger's `stall`, not a phase of the loop.)
             loop_beats.beat("window")
             faultinject.maybe_host_stall(eval_idx)
-            # Chaos: `bitflip:N` rebuilds the replicated state with ONE
-            # mantissa bit flipped in one device's copy going INTO window N
-            # — the silent-corruption class only the sentinel can see.
-            learner_state = faultinject.maybe_bitflip(learner_state, eval_idx)
-            if sentinel is not None and sentinel.should_probe(eval_idx):
-                probe_err = sentinel.run_probe(setup.learn, _tree_copy)
-                if probe_err is not None:
-                    if fleet_coord is not None:
-                        fleet_coord.request_stop(
-                            fleet.FLAG_CORRUPT, note=str(probe_err)
-                        )
-                    raise probe_err
             if eval_idx == profile_window:
+                # Before the window's first span opens, so the session holds
+                # every one of them whole.
                 try:
                     jax.profiler.start_trace(profile_dir)
                 except Exception:  # noqa: BLE001
                     profile_window = -1
+            with span("window_bookkeeping", clock=phases, phase="host_s", window=eval_idx):
+                # Chaos: `bitflip:N` rebuilds the replicated state with ONE
+                # mantissa bit flipped in one device's copy going INTO window
+                # N — the silent-corruption class only the sentinel can see.
+                learner_state = faultinject.maybe_bitflip(learner_state, eval_idx)
+                if sentinel is not None and sentinel.should_probe(eval_idx):
+                    probe_err = sentinel.run_probe(setup.learn, _tree_copy)
+                    if probe_err is not None:
+                        if fleet_coord is not None:
+                            fleet_coord.request_stop(
+                                fleet.FLAG_CORRUPT, note=str(probe_err)
+                            )
+                        raise probe_err
             if eval_idx == 0 and pf.enabled:
                 # First-window execution watchdog (docs/DESIGN.md §2.4): force
                 # this window's metrics to the host under a deadline, so a
@@ -829,7 +854,8 @@ def run_anakin_experiment(
                 # program sequence (and hence the trajectory) is unchanged.
                 with _maybe_watchdog(pf, "first_window", pf.first_window_deadline_s):
                     window = dispatch_window(eval_idx)
-                    jax.block_until_ready(window.metrics)
+                    with span("first_window_wait", clock=phases, phase="fetch_s"):
+                        jax.block_until_ready(window.metrics)
             else:
                 window = dispatch_window(eval_idx)
             dispatched_t = window.t
@@ -842,45 +868,48 @@ def run_anakin_experiment(
                 pending = window
             else:
                 process_window(window)
-            # Chaos: `shrink:N`/`grow:N` vacate for a different topology
-            # (docs/DESIGN.md §2.14). AFTER process_window so the newest
-            # CONFIRMED rescue candidate exists — the resize exit's emergency
-            # snapshot is what the relaunch restores digest-identically.
-            resize_action = faultinject.maybe_resize(eval_idx)
-            if resize_action is not None:
-                elastic.resize_exit(
-                    resize_action,
-                    config=config,
-                    window_idx=eval_idx,
-                    step=dispatched_t,
-                    fleet_coord=fleet_coord,
-                )
-            if fleet_coord is None:
-                if preempt.stop_requested():
-                    preempted = True
-                    break
-            else:
-                # Fleet mode: a host-local stop request is never acted on
-                # alone — it becomes this host's flag on the NEXT window's
-                # fetch, and every host breaks together once the combined
-                # decision (identical everywhere, it is a pure function of
-                # the same replicated flag vector) comes back. A partition
-                # verdict from the monitor thread surfaces here as the typed
-                # error instead of a hung collective.
-                fleet_coord.check_partition()
-                if preempt.stop_requested():
-                    fleet_coord.request_stop(
-                        fleet.FLAG_PREEMPT,
-                        note=f"{preempt.signal_name} at window {eval_idx}",
+            with span("window_bookkeeping", clock=phases, phase="host_s", window=eval_idx):
+                # Chaos: `shrink:N`/`grow:N` vacate for a different topology
+                # (docs/DESIGN.md §2.14). AFTER process_window so the newest
+                # CONFIRMED rescue candidate exists — the resize exit's
+                # emergency snapshot is what the relaunch restores
+                # digest-identically.
+                resize_action = faultinject.maybe_resize(eval_idx)
+                if resize_action is not None:
+                    elastic.resize_exit(
+                        resize_action,
+                        config=config,
+                        window_idx=eval_idx,
+                        step=dispatched_t,
+                        fleet_coord=fleet_coord,
                     )
-                if agreed_stop is not None:
-                    preempted = True
-                    break
+                if fleet_coord is None:
+                    if preempt.stop_requested():
+                        preempted = True
+                        break
+                else:
+                    # Fleet mode: a host-local stop request is never acted on
+                    # alone — it becomes this host's flag on the NEXT window's
+                    # fetch, and every host breaks together once the combined
+                    # decision (identical everywhere, it is a pure function of
+                    # the same replicated flag vector) comes back. A partition
+                    # verdict from the monitor thread surfaces here as the
+                    # typed error instead of a hung collective.
+                    fleet_coord.check_partition()
+                    if preempt.stop_requested():
+                        fleet_coord.request_stop(
+                            fleet.FLAG_PREEMPT,
+                            note=f"{preempt.signal_name} at window {eval_idx}",
+                        )
+                    if agreed_stop is not None:
+                        preempted = True
+                        break
         # Drain the dispatcher: the final (or preemption-interrupted) window's
         # host half — metrics, logging, and its pending checkpoint snapshot.
         if pending is not None:
             process_window(pending)
             pending = None
+        loop_wall_s = time.perf_counter() - loop_started
 
         if fleet_coord is not None and not preempted:
             # Final-boundary agreement: a SIGTERM that landed during the last
@@ -956,6 +985,7 @@ def run_anakin_experiment(
             raise fleet_coord.partition_error from None
         raise
     finally:
+        first_tick.close()  # a run that never completed a window
         preempt.uninstall()
         goodput.set_active(None)
         monitor.unregister("anakin-host-loop")
@@ -996,6 +1026,8 @@ def run_anakin_experiment(
     LAST_RUN_STATS.update(
         {
             "phase_breakdown": {k: round(v, 6) for k, v in phases.breakdown().items()},
+            "loop_wall_s": round(loop_wall_s, 6),
+            "setup_phases": {k: round(v, 6) for k, v in setup_phases.seconds().items()},
             "goodput": goodput_report,
             "steady_state_sps": steady,
             "pipelined": pipelined,

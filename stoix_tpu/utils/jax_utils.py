@@ -29,23 +29,6 @@ def select_pytree(pred: jax.Array, on_true: Any, on_false: Any) -> Any:
     return jax.tree.map(lambda a, b: jnp.where(pred, a, b), on_true, on_false)
 
 
-def aot_compile(fn: Any, *example_args: Any) -> Any:
-    """Ahead-of-time trace/lower/compile with a FLOPs estimate printed
-    (reference jax_utils.py:68-115)."""
-    lowered = jax.jit(fn).lower(*example_args)
-    compiled = lowered.compile()
-    try:
-        cost = compiled.cost_analysis()
-        flops = cost.get("flops") if isinstance(cost, dict) else cost[0].get("flops")
-        if flops:
-            from stoix_tpu.observability import get_logger
-
-            get_logger("stoix_tpu.aot").info("[aot] estimated FLOPs/call: %.3e", flops)
-    except Exception:  # noqa: STX003 — FLOPs estimate is best-effort telemetry
-        pass
-    return compiled
-
-
 def aot_warmup(jit_fn: Any, *example_args: Any) -> Any:
     """AOT-compile an ALREADY-jitted callable for the given example arguments
     and return the compiled executable. A lowering or compile error RAISES

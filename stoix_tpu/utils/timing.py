@@ -25,8 +25,9 @@ class TimingTracker:
             )
 
     def record(self, name: str, seconds: float) -> None:
-        """Record an externally measured duration (the serving path measures
-        request latency at completion time, not around a with-block)."""
+        """Record an externally measured duration: the serving path measures
+        request latency at completion time, and `span(..., clock=tracker,
+        phase=name)` (observability/trace.py) feeds its seconds in here."""
         self._times.setdefault(name, deque(maxlen=self._maxlen)).append(float(seconds))
 
     def mean(self, name: str) -> float:
@@ -68,3 +69,24 @@ class TimingTracker:
             for stat, value in self.percentiles(name).items():
                 out[f"{prefix}{name}_{stat}"] = value
         return out
+
+
+class StepAccumulator:
+    """`span(..., clock=..., phase=...)` sink for phases that recur many times
+    inside one unit of work (the Sebulba actor's per-step `inference` and
+    `env_step` inside a rollout): sums each phase's seconds, and `flush`
+    records the mean per step into a TimingTracker — so the tracker's
+    rolling window is ten ROLLOUTS, like the `rollout` timer beside it, and
+    one step that waited behind another program does not vanish from (or
+    swamp) a ten-step window."""
+
+    def __init__(self) -> None:
+        self._sums: Dict[str, float] = {}
+
+    def record(self, name: str, seconds: float) -> None:
+        self._sums[name] = self._sums.get(name, 0.0) + seconds
+
+    def flush(self, tracker: TimingTracker, steps: int) -> None:
+        for name, total in self._sums.items():
+            tracker.record(name, total / max(1, steps))
+        self._sums.clear()

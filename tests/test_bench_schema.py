@@ -15,7 +15,10 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-PHASE_KEYS = ("compile_s", "learn_s", "eval_s", "fetch_s", "ckpt_s")
+PHASE_KEYS = (
+    "compile_s", "learn_s", "snapshot_s", "eval_s", "fetch_dispatch_s", "fetch_s",
+    "log_s", "host_s", "ckpt_s",
+)
 
 GOODPUT_KEYS = ("wall_s", "fraction", "stall_s", "recovery_s", "fractions")
 GOODPUT_PHASES = {

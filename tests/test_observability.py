@@ -13,6 +13,7 @@ import re
 import threading
 
 import numpy as np
+import pytest
 
 from stoix_tpu import observability as obs
 from stoix_tpu.observability.registry import MetricsRegistry
@@ -331,7 +332,8 @@ def test_telemetry_off_keeps_last_run_stats_contract_and_records_nothing(tmp_pat
     # LAST_RUN_STATS keeps the PR 1 schema bench.py and tests read.
     stats = runner.LAST_RUN_STATS
     assert set(stats["phase_breakdown"]) == {
-        "compile_s", "learn_s", "eval_s", "fetch_s", "ckpt_s"
+        "compile_s", "learn_s", "snapshot_s", "eval_s", "fetch_dispatch_s", "fetch_s",
+        "log_s", "host_s", "ckpt_s",
     }
     assert all(v >= 0.0 for v in stats["phase_breakdown"].values())
     assert stats["phase_breakdown"]["compile_s"] > 0.0
@@ -366,6 +368,119 @@ def test_telemetry_on_writes_valid_trace_and_prometheus(tmp_path):
     )
     # The sink's close() turned tracing back off for the next run.
     assert obs.is_enabled() is False
+
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: counts what a span opens."""
+
+    opened: list = []
+
+    def __init__(self, name, **kwargs):
+        assert not kwargs, "a span's TraceAnnotation carries the name only"
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.opened.append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.opened.append("/" + self.name)
+
+
+class _Clock:
+    def __init__(self):
+        self.records = []
+
+    def record(self, phase, seconds):
+        self.records.append((phase, seconds))
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_span_always_opens_a_trace_annotation(monkeypatch, enabled):
+    """The one host primitive: with telemetry off a span is still a
+    TraceAnnotation under exactly its name (so any profiler session carries
+    it) and records nothing; with telemetry on it is recorded too."""
+    from stoix_tpu.observability import trace
+
+    monkeypatch.setattr(trace, "_trace_annotation", _FakeAnnotation)
+    monkeypatch.setattr(_FakeAnnotation, "opened", [])
+    obs.shutdown()
+    obs.get_recorder().clear()
+    obs.set_enabled(enabled)
+    try:
+        with obs.span("learn_dispatch", window=3):
+            pass
+    finally:
+        obs.set_enabled(False)
+    assert _FakeAnnotation.opened == ["learn_dispatch", "/learn_dispatch"]
+    events = obs.get_recorder().events()
+    assert [e["name"] for e in events] == (["learn_dispatch"] if enabled else [])
+    if enabled:
+        assert events[0]["args"] == {"window": 3}
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_span_feeds_its_phase_clock_whether_or_not_it_records(monkeypatch, enabled):
+    from stoix_tpu.observability import trace
+
+    monkeypatch.setattr(trace, "_trace_annotation", _FakeAnnotation)
+    monkeypatch.setattr(_FakeAnnotation, "opened", [])
+    obs.get_recorder().clear()
+    obs.set_enabled(enabled)
+    clock = _Clock()
+    try:
+        with obs.span("fetch_materialize", clock=clock, phase="fetch_s"):
+            pass
+        with pytest.raises(KeyError):
+            with obs.span("log", clock=clock, phase="log_s"):
+                raise KeyError("a span closes, and feeds its clock, on an error too")
+    finally:
+        obs.set_enabled(False)
+    assert [phase for phase, _ in clock.records] == ["fetch_s", "log_s"]
+    assert all(seconds >= 0.0 for _, seconds in clock.records)
+    assert _FakeAnnotation.opened == [
+        "fetch_materialize", "/fetch_materialize", "log", "/log"
+    ]
+    assert obs.get_recorder().event_count() == (2 if enabled else 0)
+
+
+def test_span_sinks_histogram_timing_tracker_and_setup_clock():
+    """What a span can feed: a labelled wait histogram (phase = its labels),
+    a TimingTracker (phase = the timer's name), the set-up gauge."""
+    from stoix_tpu.utils.timing import StepAccumulator
+
+    registry = MetricsRegistry()
+    hist = registry.histogram("stoix_tpu_test_wait_seconds")
+    with obs.span("pipeline_get", clock=hist, phase={"queue": "rollout"}):
+        pass
+    assert hist.summary({"queue": "rollout"})["count"] == 1
+    tracker = TimingTracker()
+    with obs.span("learner_update", clock=tracker, phase="learn"):
+        pass
+    assert tracker.latest("learn") >= 0.0 and "learn_time" in tracker.all_means()
+    # Per-step phases of one rollout become ONE sample, the mean a step.
+    steps = StepAccumulator()
+    for seconds in (0.25, 0.75):
+        steps.record("inference", seconds)
+    steps.flush(tracker, steps=2)
+    assert tracker.latest("inference") == 0.5
+    steps.flush(tracker, steps=2)  # flushed: nothing left to record
+    assert tracker.mean("inference") == 0.5
+    setup = obs.SetupClock()
+    with obs.span("env_build", clock=setup, phase="env_build"):
+        pass
+    gauge = obs.get_registry().gauge("stoix_tpu_setup_phase_seconds")
+    assert gauge.value({"phase": "env_build"}) == setup.seconds()["env_build"]
+    assert gauge.value({"phase": "first_tick"}) == 0.0  # a fresh run starts at 0
+
+
+def test_removed_tracing_entry_points_are_gone():
+    """`instant` and the public `device_annotation` had no caller: a host
+    phase is a `span`, and nothing else."""
+    assert not hasattr(obs, "instant") and not hasattr(obs, "device_annotation")
+    assert set(obs.SCOPES) >= {
+        "rollout", "rollout_policy", "rollout_env", "gae", "minibatch_shuffle"
+    }
 
 
 def test_describe_masks_non_finite():

@@ -125,16 +125,67 @@ def test_async_checkpoint_saves_from_snapshot(devices, tmp_path, monkeypatch):
     assert saved, f"no checkpoint files under {ckpt_dir}"
 
 
-def test_runner_reports_phase_breakdown(devices):
+RUNNER_PHASES = (
+    "compile_s", "learn_s", "snapshot_s", "eval_s", "fetch_dispatch_s", "fetch_s",
+    "log_s", "host_s", "ckpt_s",
+)
+
+
+@pytest.fixture(scope="module")
+def pipelined_run_stats(devices):
+    """LAST_RUN_STATS of one tiny pipelined ff_ppo run (six windows)."""
     from stoix_tpu.systems import runner
 
+    _run_recorded(["arch.num_updates=12", "arch.num_evaluation=6"])
+    return dict(runner.LAST_RUN_STATS)
+
+
+@pytest.mark.parametrize("phase", RUNNER_PHASES)
+def test_runner_reports_phase_breakdown(pipelined_run_stats, phase):
+    phases = pipelined_run_stats["phase_breakdown"]
+    assert set(phases) == set(RUNNER_PHASES)  # gossip_s only in a gossip run
+    assert isinstance(phases[phase], float) and phases[phase] >= 0.0, phases
+    assert pipelined_run_stats["steady_state_sps"] > 0.0
+    assert pipelined_run_stats["pipelined"] is True
+
+
+def test_runner_phases_cover_the_loop_wall(pipelined_run_stats):
+    """The clock covers the wall: every statement of the main thread between
+    two window completions runs inside a span with a phase, so the phases
+    of the loop (all but the AOT compile, which is before it) sum to at
+    least 95% of the loop's wall — and cannot exceed it, being disjoint."""
+    phases = pipelined_run_stats["phase_breakdown"]
+    in_loop = sum(v for k, v in phases.items() if k != "compile_s")
+    wall = pipelined_run_stats["loop_wall_s"]
+    assert 0.95 * wall <= in_loop <= wall * 1.001, (in_loop, wall, phases)
+
+
+def test_fetch_materialize_is_its_own_phase(devices, monkeypatch):
+    """`fetch_s` is the blocked wait for a window's metrics alone: the
+    seconds the fetch_materialize spans fed the clock, and nothing of the
+    fetch's dispatch (fetch_dispatch_s)."""
+    from stoix_tpu.systems import runner
+
+    fed = {}
+    record = runner._PhaseClock.record
+
+    def recording(self, name, seconds):
+        fed.setdefault(name, []).append(seconds)
+        return record(self, name, seconds)
+
+    monkeypatch.setattr(runner._PhaseClock, "record", recording)
     _run_recorded([])
-    stats = runner.LAST_RUN_STATS
-    phases = stats["phase_breakdown"]
-    for phase in ("compile_s", "learn_s", "eval_s", "fetch_s", "ckpt_s"):
-        assert isinstance(phases[phase], float) and phases[phase] >= 0.0, phases
-    assert stats["steady_state_sps"] > 0.0
-    assert stats["pipelined"] is True
+    phases = runner.LAST_RUN_STATS["phase_breakdown"]
+    assert len(fed["fetch_s"]) == 3 and len(fed["fetch_dispatch_s"]) == 3  # 3 windows
+    assert phases["fetch_s"] == pytest.approx(sum(fed["fetch_s"]), abs=1e-5)
+    assert phases["fetch_dispatch_s"] == pytest.approx(sum(fed["fetch_dispatch_s"]), abs=1e-5)
+    # Set-up is split the same way, once a run.
+    setup = runner.LAST_RUN_STATS["setup_phases"]
+    assert set(setup) == {
+        "env_build", "learner_setup", "evaluator_setup", "aot_warmup", "first_tick"
+    }
+    assert setup["aot_warmup"] == pytest.approx(phases["compile_s"], abs=1e-5)
+    assert setup["first_tick"] > 0.0
 
 
 @pytest.mark.slow
