@@ -11,6 +11,7 @@ internal use and tests.
 from stoix_tpu.ops import (
     distributions,
     losses,
+    minibatch,
     multistep,
     pallas_attention,
     ring_attention,
@@ -20,6 +21,7 @@ from stoix_tpu.ops import (
 )
 from stoix_tpu.ops.distributions import Distribution, EpsilonGreedy
 from stoix_tpu.ops.losses import categorical_l2_project
+from stoix_tpu.ops.minibatch import shuffled_minibatch_epoch
 from stoix_tpu.ops.multistep import (
     batch_discounted_returns,
     batch_general_off_policy_returns_from_q_and_v,
@@ -61,6 +63,7 @@ __all__ = [
     # submodules
     "distributions",
     "losses",
+    "minibatch",
     "multistep",
     "pallas_attention",
     "ring_attention",
@@ -105,6 +108,8 @@ __all__ = [
     "twohot",
     # losses commonly imported by name (distributional projection)
     "categorical_l2_project",
+    # the PPO learners' shuffled epoch (pack once, one gather a minibatch)
+    "shuffled_minibatch_epoch",
     # distributions commonly referenced by name
     "Distribution",
     "EpsilonGreedy",

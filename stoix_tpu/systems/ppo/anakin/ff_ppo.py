@@ -47,12 +47,13 @@ from stoix_tpu.observability import SCOPES, annotate, get_logger, span
 from stoix_tpu.ops import (
     losses,
     running_statistics,
+    shuffled_minibatch_epoch,
     truncated_generalized_advantage_estimation,
 )
 from stoix_tpu.parallel import is_coordinator
 from stoix_tpu.resilience import guards
 from stoix_tpu.utils import config as config_lib
-from stoix_tpu.utils.jax_utils import count_parameters, tree_merge_leading_dims
+from stoix_tpu.utils.jax_utils import count_parameters
 from stoix_tpu.systems.runner import AnakinSetup, run_anakin_experiment
 from stoix_tpu.utils.training import make_learning_rate
 
@@ -316,36 +317,6 @@ def get_learner_fn(
             kl_beta,
         ), loss_info
 
-    @annotate(SCOPES["update_epoch"])
-    def _update_epoch(update_state: Tuple, _: Any):
-        (
-            params, opt_states, behavior_actor_params, kl_beta,
-            traj_batch, advantages, targets, key,
-        ) = update_state
-        key, shuffle_key = jax.random.split(key)
-
-        # Flatten [T, E] -> [T*E] and shuffle across both time and envs.
-        batch_size = advantages.shape[0] * advantages.shape[1]
-        with annotate(SCOPES["minibatch_shuffle"]):
-            permutation = jax.random.permutation(shuffle_key, batch_size)
-            flat = tree_merge_leading_dims((traj_batch, advantages, targets), 2)
-            shuffled = jax.tree.map(lambda x: jnp.take(x, permutation, axis=0), flat)
-        minibatches = jax.tree.map(
-            lambda x: x.reshape(
-                (int(config.system.num_minibatches), -1) + x.shape[1:]
-            ),
-            shuffled,
-        )
-        (params, opt_states, behavior_actor_params, kl_beta), loss_info = jax.lax.scan(
-            _update_minibatch,
-            (params, opt_states, behavior_actor_params, kl_beta),
-            minibatches,
-        )
-        return (
-            params, opt_states, behavior_actor_params, kl_beta,
-            traj_batch, advantages, targets, key,
-        ), loss_info
-
     def _update_step(learner_state: PPOLearnerState, _: Any):
         learner_state, traj_batch = jax.lax.scan(
             _env_step, learner_state, None, int(config.system.rollout_length)
@@ -396,15 +367,27 @@ def get_learner_fn(
         # Behavior params (the rollout's) stay FIXED across all epochs: KL
         # penalties anchor to them, matching the reference's
         # behaviour_actor_params capture (reference ff_ppo_penalty.py:128).
-        update_state = (
-            params, opt_states, params.actor_params, kl_beta,
-            traj_batch, advantages, targets, key,
+        train_state = (params, opt_states, params.actor_params, kl_beta)
+        # Shuffle across both time and envs: [T, E] -> T*E samples, packed
+        # once here, permuted anew every epoch (ops/minibatch.py).
+        minibatch_epoch = shuffled_minibatch_epoch(
+            _update_minibatch,
+            train_state,
+            (traj_batch, advantages, targets),
+            config.system.num_minibatches,
         )
-        update_state, loss_info = jax.lax.scan(
-            _update_epoch, update_state, None, int(config.system.epochs)
+
+        @annotate(SCOPES["update_epoch"])
+        def _update_epoch(update_state: Tuple, _: Any):
+            train_state, key = update_state
+            key, shuffle_key = jax.random.split(key)
+            train_state, loss_info = minibatch_epoch(train_state, shuffle_key)
+            return (train_state, key), loss_info
+
+        (train_state, key), loss_info = jax.lax.scan(
+            _update_epoch, (train_state, key), None, int(config.system.epochs)
         )
-        params, opt_states, behavior_actor_params, kl_beta = update_state[:4]
-        key = update_state[7]
+        params, opt_states, behavior_actor_params, kl_beta = train_state
 
         if adaptive_kl:
             # Adaptive-KL PPO (Schulman et al. 2017 §4): after the full

@@ -59,6 +59,7 @@ from stoix_tpu.ops import (
     losses,
     running_statistics,
     scan_kernels,
+    shuffled_minibatch_epoch,
     truncated_generalized_advantage_estimation,
 )
 from stoix_tpu.parallel import MeshRoles, assemble_global_array
@@ -348,26 +349,18 @@ def get_learn_step(actor_apply, critic_apply, update_fns, config, mesh: Mesh):
                 **guard_metrics,
             }
 
+        minibatch_epoch = shuffled_minibatch_epoch(
+            _minibatch,
+            (state.params, state.opt_states),
+            (traj, advantages, targets),
+            config.system.num_minibatches,
+        )
+
         @annotate(SCOPES["update_epoch"])
         def _epoch(carry, _):
             params, opt_states, key = carry
             key, shuffle_key = jax.random.split(key)
-            batch_size = advantages.shape[0] * advantages.shape[1]
-            with annotate(SCOPES["minibatch_shuffle"]):
-                perm = jax.random.permutation(shuffle_key, batch_size)
-                flat = jax.tree.map(
-                    lambda x: x.reshape((-1,) + x.shape[2:]), (traj, advantages, targets)
-                )
-                shuffled = jax.tree.map(lambda x: jnp.take(x, perm, axis=0), flat)
-            minibatches = jax.tree.map(
-                lambda x: x.reshape(
-                    (int(config.system.num_minibatches), -1) + x.shape[1:]
-                ),
-                shuffled,
-            )
-            (params, opt_states), metrics = jax.lax.scan(
-                _minibatch, (params, opt_states), minibatches
-            )
+            (params, opt_states), metrics = minibatch_epoch((params, opt_states), shuffle_key)
             return (params, opt_states, key), metrics
 
         (params, opt_states, key), metrics = jax.lax.scan(
@@ -495,26 +488,18 @@ def get_impact_learn_step(
                 **guard_metrics,
             }
 
+        minibatch_epoch = shuffled_minibatch_epoch(
+            _minibatch,
+            (state.params, state.opt_states),
+            (traj, advantages, targets),
+            config.system.num_minibatches,
+        )
+
         @annotate("impact_epoch")
         def _epoch(carry, _):
             params, opt_states, key = carry
             key, shuffle_key = jax.random.split(key)
-            batch_size = advantages.shape[0] * advantages.shape[1]
-            with annotate(SCOPES["minibatch_shuffle"]):
-                perm = jax.random.permutation(shuffle_key, batch_size)
-                flat = jax.tree.map(
-                    lambda x: x.reshape((-1,) + x.shape[2:]), (traj, advantages, targets)
-                )
-                shuffled = jax.tree.map(lambda x: jnp.take(x, perm, axis=0), flat)
-            minibatches = jax.tree.map(
-                lambda x: x.reshape(
-                    (int(config.system.num_minibatches), -1) + x.shape[1:]
-                ),
-                shuffled,
-            )
-            (params, opt_states), metrics = jax.lax.scan(
-                _minibatch, (params, opt_states), minibatches
-            )
+            (params, opt_states), metrics = minibatch_epoch((params, opt_states), shuffle_key)
             return (params, opt_states, key), metrics
 
         (params, opt_states, key), metrics = jax.lax.scan(
