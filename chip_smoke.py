@@ -14,6 +14,9 @@ cut to a few updates and weights made from the config's seed. Legs:
                  forward and gradient; ring attention over all the chips.
   trans_ppo      Anakin transformer PPO (identity_game): the flash-attention
                  kernel in the learner's forward pass, gradient steps taken.
+  lm_ppo         Anakin PPO with the OLMoE token policy at a tiny preset
+                 (token_task): grouped matmuls over sorted experts, the KV
+                 cache in rollout and evaluator, flash attention in the update.
   ppo_pallas_gae Anakin ff_ppo with system.multistep_impl=pallas: the
                  recurrence kernel inside the learner.
   sebulba        Sebulba ff_ppo on the native C++ CartPole pool, 512 envs,
@@ -296,6 +299,28 @@ def leg_trans_ppo(n: int) -> Dict[str, Any]:
         "stoix_tpu.systems.ppo.anakin.ff_trans_ppo",
         "default/anakin/default_ff_trans_ppo.yaml",
         ["env=identity_game", f"arch.total_num_envs={64 * n}", "arch.num_updates=4"],
+        expect_kernel=True,
+    )
+
+
+def leg_lm_ppo(n: int) -> Dict[str, Any]:
+    """The token policy at the tiny preset, data-parallel over the chips: the
+    grouped-matmul kernels of `jax.lax.ragged_dot` in decode and update, the
+    KV cache through rollout and evaluator, and (T = 128, the flash kernel's
+    block) the Pallas attention in the teacher-forced pass."""
+    tiny = [
+        "hidden_size=128", "num_heads=4", "head_dim=32", "num_experts=8",
+        "experts_per_token=2", "expert_width=64",
+    ]
+    return _anakin_leg(
+        n,
+        "stoix_tpu.systems.ppo.anakin.ff_lm_ppo",
+        "default/anakin/default_ff_lm_ppo.yaml",
+        [f"network.actor_network.{o}" for o in tiny] + [
+            "env.kwargs.vocab_size=512", "env.kwargs.length=128", "system.rollout_length=128",
+            f"arch.total_num_envs={16 * n}", "system.num_minibatches=4", "arch.num_updates=4",
+            "arch.evaluation_greedy=True",
+        ],
         expect_kernel=True,
     )
 
@@ -595,6 +620,7 @@ def leg_kernels(n: int) -> Dict[str, Any]:
 LEGS: List[Tuple[str, Callable[[int], Dict[str, Any]]]] = [
     ("kernels", leg_kernels),
     ("trans_ppo", leg_trans_ppo),
+    ("lm_ppo", leg_lm_ppo),
     ("ppo_pallas_gae", leg_ppo_pallas_gae),
     ("sebulba", leg_sebulba),
     ("anakin_ant", leg_anakin_ant),

@@ -19,6 +19,7 @@ from stoix_tpu.envs import (
     locomotion,
     minatar,
     snake,
+    token_task,
 )
 from stoix_tpu.envs.core import Environment
 from stoix_tpu.envs.wrappers import (
@@ -50,6 +51,7 @@ ENV_REGISTRY: Dict[str, Callable[..., Environment]] = {
     "DoorKey-v0": doorkey.DoorKey,
     "IdentityGame": debug.IdentityGame,
     "SequenceGame": debug.SequenceGame,
+    "TokenTask": token_task.TokenTask,
 }
 
 

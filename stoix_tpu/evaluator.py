@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from stoix_tpu.envs.core import Environment
+from stoix_tpu.envs.types import _bcast
 
 # act_fn(params, observation, key) -> action  (single unbatched observation)
 ActFn = Callable[[Any, Any, jax.Array], jax.Array]
@@ -234,49 +235,63 @@ def get_stateful_evaluator_fn(env_factory: Any, act_fn: ActFn, config: Any):
 
 def get_rnn_evaluator_fn(
     eval_env: Environment,
-    rnn_act_fn: Callable[..., Tuple[Any, jax.Array]],
+    act: Callable[..., Tuple[Any, jax.Array]],
     config: Any,
     mesh: Mesh,
-    init_hstate_fn: Callable[[], Any],
+    init_carry: Callable[[int], Any],
     eval_multiplier: int = 1,
 ):
-    """Recurrent evaluator: carries the hidden state through the episode
-    (reference evaluator.py:209-344). rnn_act_fn(params, hstate, obs, done, key)
-    -> (hstate, action)."""
+    """Evaluator for a policy that carries state through the episode
+    (reference evaluator.py:209-344): an RNN's hidden vector, a context
+    window, a KV cache — any pytree the system's core declares.
+
+    The core acts for a shard's E episodes together: `act(params, carry,
+    obs [E, ...], done [E], keys [E, 2]) -> (carry, action [E])` and
+    `init_carry(E)` — which is what a policy whose layers work on the whole
+    batch (a sort of all tokens by expert) needs. A core written for ONE
+    episode is vmapped by its caller (`per_episode_evaluator_setup`).
+
+    Episodes run until the longest ends. A finished episode's env state,
+    timestep and key are frozen; its carry is not (nothing reads it again),
+    so a large carry costs no select a step."""
 
     n_shards = int(mesh.shape["data"])
     episodes_global = int(config.arch.num_eval_episodes) * eval_multiplier
     if episodes_global % n_shards != 0:
         episodes_global = ((episodes_global // n_shards) + 1) * n_shards
+    per_shard = episodes_global // n_shards
 
     reset_fn = _make_eval_reset_fn(eval_env, config)
 
-    def eval_one_episode(params: Any, key: jax.Array, idx: jax.Array) -> Dict[str, jax.Array]:
-        reset_key, act_key = jax.random.split(key)
-        env_state, timestep = reset_fn(reset_key, idx)
-        hstate = init_hstate_fn()
+    def _shard_eval(params: Any, keys: jax.Array, idxs: jax.Array) -> Dict[str, jax.Array]:
+        split = jax.vmap(jax.random.split)(keys)  # [E, 2, 2]
+        env_state, timestep = jax.vmap(reset_fn)(split[:, 0], idxs)
 
         def cond(carry) -> jax.Array:
-            return ~carry[1].last()
+            return jnp.any(~carry[0][1].last())
 
         def body(carry):
-            env_state, timestep, hstate, key = carry
-            key, act_key = jax.random.split(key)
-            hstate, action = rnn_act_fn(
-                params, hstate, timestep.observation, timestep.last(), act_key
+            (env_state, timestep, key), hstate = carry
+            split = jax.vmap(jax.random.split)(key)
+            hstate, action = act(
+                params, hstate, timestep.observation, timestep.last(), split[:, 1]
             )
-            env_state, timestep = eval_env.step(env_state, action)
-            return (env_state, timestep, hstate, key)
+            stepped = jax.vmap(eval_env.step)(env_state, action)
+            running = ~timestep.last()
+            frozen = jax.tree.map(
+                lambda new, old: jnp.where(_bcast(running, new), new, old),
+                (*stepped, split[:, 0]), (env_state, timestep, key),
+            )
+            return frozen, hstate
 
-        final = jax.lax.while_loop(cond, body, (env_state, timestep, hstate, act_key))
+        final, _ = jax.lax.while_loop(
+            cond, body, ((env_state, timestep, split[:, 1]), init_carry(per_shard))
+        )
         metrics = final[1].extras["episode_metrics"]
         return {
             "episode_return": metrics["episode_return"],
             "episode_length": metrics["episode_length"],
         }
-
-    def _shard_eval(params: Any, keys: jax.Array, idxs: jax.Array) -> Dict[str, jax.Array]:
-        return jax.vmap(eval_one_episode, in_axes=(None, 0, 0))(params, keys, idxs)
 
     sharded = jax.jit(
         jax.shard_map(
@@ -290,6 +305,32 @@ def get_rnn_evaluator_fn(
         return sharded(params, keys, jnp.arange(episodes_global))
 
     return evaluator
+
+
+def carry_evaluator_setup(init_carry: Callable[[int], Any]):
+    """The `evaluator_setup_fn` of a system whose policy carries state:
+    (evaluator, absolute-metric evaluator) over `get_rnn_evaluator_fn`."""
+
+    def setup(eval_env: Environment, act_fn: Any, config: Any, mesh: Mesh) -> Tuple[Any, Any]:
+        make = lambda multiplier: get_rnn_evaluator_fn(
+            eval_env, act_fn, config, mesh, init_carry, eval_multiplier=multiplier
+        )
+        return make(1), make(int(config.arch.get("absolute_metric_multiplier", 10)))
+
+    return setup
+
+
+def per_episode_evaluator_setup(init_one: Callable[[], Any]):
+    """`carry_evaluator_setup` for a core written for ONE episode —
+    `act_fn(params, carry, obs, done, key) -> (carry, action)`, `init_one()`
+    one episode's carry (an RNN cell, a context window): both are vmapped over
+    the shard's episodes here, at the call site."""
+    batched = carry_evaluator_setup(lambda n: jax.vmap(lambda _: init_one())(jnp.arange(n)))
+
+    def setup(eval_env: Environment, act_fn: Any, config: Any, mesh: Mesh) -> Tuple[Any, Any]:
+        return batched(eval_env, jax.vmap(act_fn, in_axes=(None, 0, 0, 0, 0)), config, mesh)
+
+    return setup
 
 
 def evaluator_setup(

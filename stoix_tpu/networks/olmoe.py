@@ -1,0 +1,359 @@
+"""The OLMoE decoder block as a token policy: sparse experts, a KV cache for
+acting, a teacher-forced pass for the update — two entry points over ONE set
+of parameters.
+
+Published forward (`model_type` `olmoe`; stoix_tpu/reference/olmoe.py writes
+it out plainly and is what the tests and the benchmark compare this file
+with): pre-norm residual block `h = x + Attn(RMSNorm(x))`, `y = h +
+MoE(RMSNorm(h))`; q and k are RMS-normalised over the whole projection
+before the split into heads; RoPE in the rotate-half convention; the router
+is a float32 softmax over ALL experts, then top-k, weights not renormalised;
+experts are SwiGLUs; final RMSNorm and an untied `lm_head`.
+
+  * `OlmoeLM.forward(tokens [B, T])` — teacher-forced: attention through
+    `ops.best_attention` (the Pallas flash kernel on TPU).
+  * `OlmoeLM.step(cache, token [B])` — one decode step through the cache.
+    `KVCache.length [B]` is each sequence's next position; entries at or
+    beyond it are never attended, so `reset_cache` (length := 0 where done)
+    is the whole reset and costs nothing.
+
+No token is ever dropped and there is no capacity factor: the (token, slot)
+pairs are sorted by expert and the three expert matmuls run as grouped
+matmuls over the ragged groups (`jax.lax.ragged_dot`; XLA:TPU lowers it to a
+native grouped-matmul kernel whose FLOPs are exactly the routed rows').
+The router's matmul and softmax run in float32 at `precision=HIGHEST` so
+that expert choice does not depend on the MXU's bfloat16 pass.
+
+Parameters, by name (the reference reads them by these names):
+  embed [V, D]; layer_<i>/{input_norm [D], wq wk wv wo [D, D], q_norm k_norm
+  [D], post_attn_norm [D], router [D, E], gate up [E, D, F], down [E, F, D]};
+  final_norm [D]; lm_head [D, V].
+Initialisation is normal(0.02) as the family does (an orthogonal QR of a
+50,304 x 2,048 matrix is minutes of set-up); norms start at one.
+
+`ValueHead` is a Dense [D -> 1] on the final-norm hidden state of the same
+trunk: the critic side of `ActorCriticParams` holds only it.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, NamedTuple, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from stoix_tpu.observability import SCOPES, annotate
+from stoix_tpu.ops.pallas_attention import best_attention
+
+# Keys beyond this many cache positions are read in blocks of this size: a
+# decode step at position p reads ceil((p + 1) / block) blocks, not the whole
+# cache.
+_CACHE_BLOCK = 128
+
+
+class KVCache(NamedTuple):
+    # Position-major, so that a prefix of positions is one contiguous slab
+    # (a decode step reads cache[:prefix] without a copy).
+    k: Tuple[jax.Array, ...]  # a layer: [S, B, heads, head_dim] float32
+    v: Tuple[jax.Array, ...]
+    length: jax.Array  # [B] int32: positions filled = the next token's position
+
+
+def init_cache(num_layers: int, batch: int, max_len: int, heads: int, head_dim: int) -> KVCache:
+    zeros = lambda: tuple(
+        jnp.zeros((max_len, batch, heads, head_dim), jnp.float32) for _ in range(num_layers)
+    )
+    return KVCache(zeros(), zeros(), jnp.zeros((batch,), jnp.int32))
+
+
+def reset_cache(cache: KVCache, done: jax.Array) -> KVCache:
+    """Start a new sequence where `done`: nothing beyond `length` is read."""
+    return cache._replace(length=jnp.where(done, 0, cache.length))
+
+
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * weight
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """x [..., heads, head_dim] rotated at `positions` [...] (rotate-half)."""
+    head_dim = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    freqs = positions[..., None].astype(jnp.float32) * inv_freq
+    emb = jnp.concatenate([freqs, freqs], axis=-1)[..., None, :]  # broadcast over heads
+    half = head_dim // 2
+    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return x * jnp.cos(emb) + rotated * jnp.sin(emb)
+
+
+def route(x: jax.Array, router: jax.Array, top_k: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Float32 softmax over all experts, then top-k, not renormalised:
+    (probs [N, E], weights [N, k], index [N, k])."""
+    logits = jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, index = jax.lax.top_k(probs, top_k)
+    return probs, weights, index
+
+
+@jax.custom_vjp
+def _dispatch(x: jax.Array, order: jax.Array, back: jax.Array) -> jax.Array:
+    """Row p of the result is token `order[p] // k`'s row of x [N, D] (k =
+    len(order) / N). `back` is `order`'s inverse, which makes the transpose a
+    gather and a sum over slots instead of a scatter-add of N*k rows."""
+    return jnp.take(x, order // (order.shape[0] // x.shape[0]), axis=0)
+
+
+def _dispatch_fwd(x, order, back):
+    return _dispatch(x, order, back), (back, x.shape[0])
+
+
+def _dispatch_bwd(residuals, g):
+    back, tokens = residuals
+    return jnp.take(g, back, axis=0).reshape(tokens, -1, g.shape[-1]).sum(axis=1), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _permute(x: jax.Array, perm: jax.Array, inverse: jax.Array) -> jax.Array:
+    """x[perm] whose transpose is g[inverse]: a gather both ways."""
+    return jnp.take(x, perm, axis=0)
+
+
+def _permute_fwd(x, perm, inverse):
+    return jnp.take(x, perm, axis=0), inverse
+
+
+def _permute_bwd(inverse, g):
+    return jnp.take(g, inverse, axis=0), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def moe(
+    x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array, down: jax.Array, top_k: int
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """x [N, D] -> (y [N, D], stats). Every (token, slot) pair is computed:
+    `stats["expert_count"]` sums to N * top_k."""
+    tokens, num_experts = x.shape[0], router.shape[-1]
+    with annotate(SCOPES["moe_router"]):
+        probs, weights, index = route(x, router, top_k)
+    with annotate(SCOPES["moe_dispatch"]):
+        flat = index.reshape(-1)  # pair p = token p // k, slot p % k
+        order = jnp.argsort(flat, stable=True)  # pairs grouped by expert
+        back = jnp.argsort(order)  # where each pair's row went
+        rows = _dispatch(x, order, back)  # [N*k, D]
+        experts = jnp.arange(num_experts, dtype=flat.dtype)
+        counts = jnp.sum(flat[:, None] == experts[None, :], axis=0, dtype=jnp.int32)
+    with annotate(SCOPES["moe_experts"]):
+        hidden = jax.nn.silu(jax.lax.ragged_dot(rows, gate, counts)) * jax.lax.ragged_dot(
+            rows, up, counts
+        )
+        routed = jax.lax.ragged_dot(hidden, down, counts)  # [N*k, D]
+    with annotate(SCOPES["moe_dispatch"]):
+        pairs = _permute(routed, back, order).reshape(tokens, top_k, -1)
+        out = jnp.sum(pairs * weights[..., None].astype(pairs.dtype), axis=1)
+    stats = {
+        "expert_index": index,
+        "expert_count": counts,
+        "router_prob_sum": jnp.sum(probs, axis=0),
+        "router_entropy_sum": -jnp.sum(probs * jnp.log(jnp.maximum(probs, 1e-30))),
+    }
+    return out, stats
+
+
+class OlmoeLayer(nn.Module):
+    hidden_size: int
+    num_heads: int
+    head_dim: int
+    num_experts: int
+    experts_per_token: int
+    expert_width: int
+    rope_theta: float
+    rms_eps: float
+
+    def setup(self) -> None:
+        init = nn.initializers.normal(0.02)
+        ones = nn.initializers.ones
+        d, e, f = self.hidden_size, self.num_experts, self.expert_width
+        proj = self.num_heads * self.head_dim
+        self.input_norm = self.param("input_norm", ones, (d,))
+        self.wq = self.param("wq", init, (d, proj))
+        self.wk = self.param("wk", init, (d, proj))
+        self.wv = self.param("wv", init, (d, proj))
+        self.wo = self.param("wo", init, (proj, d))
+        self.q_norm = self.param("q_norm", ones, (proj,))
+        self.k_norm = self.param("k_norm", ones, (proj,))
+        self.post_attn_norm = self.param("post_attn_norm", ones, (d,))
+        self.router = self.param("router", init, (d, e))
+        self.gate = self.param("gate", init, (e, d, f))
+        self.up = self.param("up", init, (e, d, f))
+        self.down = self.param("down", init, (e, f, d))
+
+    def _qkv(self, x: jax.Array, positions: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """x [..., D], positions [...] -> q, k, v [..., heads, head_dim]; q
+        and k normalised over the whole projection, then rotated."""
+        heads = lambda t: t.reshape(t.shape[:-1] + (self.num_heads, self.head_dim))
+        q = heads(rms_norm(x @ self.wq, self.q_norm, self.rms_eps))
+        k = heads(rms_norm(x @ self.wk, self.k_norm, self.rms_eps))
+        rotate = lambda t: rope(t, positions, self.rope_theta)
+        return rotate(q), rotate(k), heads(x @ self.wv)
+
+    def _moe(self, h: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        flat = rms_norm(h, self.post_attn_norm, self.rms_eps).reshape(-1, self.hidden_size)
+        with annotate(SCOPES["moe"]):
+            routed, stats = moe(
+                flat, self.router, self.gate, self.up, self.down, self.experts_per_token
+            )
+        return h + routed.reshape(h.shape), stats
+
+    def forward(self, x: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """Teacher-forced: x [B, T, D], positions 0..T-1."""
+        batch, length, _ = x.shape
+        with annotate(SCOPES["attention"]):
+            positions = jnp.broadcast_to(jnp.arange(length), (batch, length))
+            q, k, v = self._qkv(rms_norm(x, self.input_norm, self.rms_eps), positions)
+            attended = best_attention(q, k, v, causal=True)  # [B, T, heads, head_dim]
+            attended = attended.reshape(batch, length, -1)
+            h = x + attended @ self.wo
+        return self._moe(h)
+
+    def step(
+        self, x: jax.Array, cache_k: jax.Array, cache_v: jax.Array, length: jax.Array
+    ) -> Tuple[jax.Array, jax.Array, jax.Array, Dict[str, jax.Array]]:
+        """One token a sequence: x [B, D], this layer's cache [S, B, heads,
+        head_dim], `length` [B] the token's position."""
+        batch = x.shape[0]
+        with annotate(SCOPES["attention"]):
+            q, k, v = self._qkv(rms_norm(x, self.input_norm, self.rms_eps), length)
+            at = (length, jnp.arange(batch))
+            cache_k = cache_k.at[at].set(k)
+            cache_v = cache_v.at[at].set(v)
+            attended = _attend_cache(q, cache_k, cache_v, length)
+            h = x + attended.reshape(batch, -1) @ self.wo
+        y, stats = self._moe(h)
+        return y, cache_k, cache_v, stats
+
+
+def _attend_cache(
+    q: jax.Array, cache_k: jax.Array, cache_v: jax.Array, length: jax.Array
+) -> jax.Array:
+    """softmax(q k^T / sqrt(head_dim)) v over cache positions <= `length`
+    ([B], the position just written). Only the leading blocks that hold a
+    live position are read."""
+    max_len, head_dim = cache_k.shape[0], q.shape[-1]
+    scale = 1.0 / jnp.sqrt(jnp.float32(head_dim))
+
+    def over(prefix: int):
+        def attend(q, cache_k, cache_v, length):
+            # One query a sequence: a matrix-vector product a head, bound by
+            # reading the cache. Written as multiply-and-reduce so that XLA
+            # streams the float32 cache once, instead of first writing a
+            # bfloat16 copy of it in the MXU's layout for a dot.
+            keys, values = cache_k[:prefix], cache_v[:prefix]
+            scores = jnp.sum(q[None] * keys, axis=-1) * scale  # [prefix, B, heads]
+            live = jnp.arange(prefix)[:, None, None] <= length[None, :, None]
+            scores = jnp.where(live, scores, jnp.finfo(jnp.float32).min)
+            weights = jax.nn.softmax(scores, axis=0)
+            return jnp.sum(weights[..., None] * values, axis=0)  # [B, heads, head_dim]
+
+        return attend
+
+    prefixes = list(range(_CACHE_BLOCK, max_len, _CACHE_BLOCK)) + [max_len]
+    if len(prefixes) == 1:
+        return over(max_len)(q, cache_k, cache_v, length)
+    blocks = jnp.max(length) // _CACHE_BLOCK  # index of the last live block
+    return jax.lax.switch(
+        jnp.minimum(blocks, len(prefixes) - 1), [over(p) for p in prefixes],
+        q, cache_k, cache_v, length,
+    )
+
+
+class OlmoeLM(nn.Module):
+    """Embedding, `num_layers` OLMoE blocks, final norm, untied head. Both
+    entry points return (logits [.., V] un-normalised, hidden [.., D] after
+    the final norm, stats with a leading layer axis)."""
+
+    vocab_size: int
+    hidden_size: int
+    num_heads: int
+    head_dim: int
+    num_experts: int
+    experts_per_token: int
+    expert_width: int
+    num_layers: int = 1
+    rope_theta: float = 10000.0
+    rms_eps: float = 1e-5
+
+    def setup(self) -> None:
+        init = nn.initializers.normal(0.02)
+        self.embed = self.param("embed", init, (self.vocab_size, self.hidden_size))
+        self.layers = [
+            OlmoeLayer(
+                self.hidden_size, self.num_heads, self.head_dim, self.num_experts,
+                self.experts_per_token, self.expert_width, self.rope_theta, self.rms_eps,
+                name=f"layer_{i}",
+            )
+            for i in range(self.num_layers)
+        ]
+        self.final_norm = self.param("final_norm", nn.initializers.ones, (self.hidden_size,))
+        self.lm_head = self.param("lm_head", init, (self.hidden_size, self.vocab_size))
+
+    def _head(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        with annotate(SCOPES["lm_head"]):
+            hidden = rms_norm(x, self.final_norm, self.rms_eps)
+            return hidden @ self.lm_head, hidden
+
+    def forward(self, tokens: jax.Array) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+        x = jnp.take(self.embed, tokens, axis=0)
+        stats = []
+        for layer in self.layers:
+            x, layer_stats = layer.forward(x)
+            stats.append(layer_stats)
+        logits, hidden = self._head(x)
+        return logits, hidden, _stack(stats)
+
+    def step(
+        self, cache: KVCache, token: jax.Array
+    ) -> Tuple[jax.Array, jax.Array, KVCache, Dict[str, jax.Array]]:
+        x = jnp.take(self.embed, token, axis=0)
+        keys, values, stats = [], [], []
+        for i, layer in enumerate(self.layers):
+            x, cache_k, cache_v, layer_stats = layer.step(x, cache.k[i], cache.v[i], cache.length)
+            keys.append(cache_k)
+            values.append(cache_v)
+            stats.append(layer_stats)
+        logits, hidden = self._head(x)
+        cache = KVCache(tuple(keys), tuple(values), cache.length + 1)
+        return logits, hidden, cache, _stack(stats)
+
+
+def _stack(stats: list) -> Dict[str, jax.Array]:
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *stats)
+
+
+class ValueHead(nn.Module):
+    """Scalar value on the trunk's final-norm hidden state."""
+
+    @nn.compact
+    def __call__(self, hidden: jax.Array) -> jax.Array:
+        kernel = self.param("kernel", nn.initializers.normal(0.02), (hidden.shape[-1], 1))
+        bias = self.param("bias", nn.initializers.zeros, (1,))
+        return (hidden @ kernel)[..., 0] + bias[0]
+
+
+def load_balancing_loss(stats: Dict[str, Any], num_tokens: int) -> jax.Array:
+    """The HF `load_balancing_loss_func`: num_experts * sum_e (share of the
+    (token, slot) pairs of all layers routed to e, summed over slots) * (mean
+    router probability of e). `stats` leaves carry a leading layer axis."""
+    layers, num_experts = stats["expert_count"].shape
+    total = layers * num_tokens
+    routed_share = jnp.sum(stats["expert_count"], axis=0).astype(jnp.float32) / total
+    mean_prob = jnp.sum(stats["router_prob_sum"], axis=0) / total
+    return num_experts * jnp.sum(routed_share * mean_prob)

@@ -78,6 +78,7 @@ from stoix_tpu.observability.registry import (  # noqa: F401
     get_registry,
 )
 from stoix_tpu.observability.trace import (  # noqa: F401
+    BLOCK_SCOPES,
     HOST_SPANS,
     SCOPES,
     SetupClock,

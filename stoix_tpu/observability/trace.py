@@ -42,7 +42,19 @@ SCOPES = {
     "update_epoch": "ppo_epoch",  # one epoch: shuffle + minibatch scan
     "update_minibatch": "ppo_minibatch",  # one SGD step
     "minibatch_shuffle": "minibatch_shuffle",  # permutation + `take` over the trajectory
+    # The token policy's block (networks/olmoe.py), under `rollout` (cached
+    # decode) and under `ppo_epoch` (teacher-forced update) alike.
+    "attention": "attention",  # input norm, q/k/v/o projections, RoPE, cache write, softmax
+    "moe": "moe",  # the sparse-expert layer: the three scopes below
+    "moe_router": "moe_router",  # router matmul, float32 softmax, top-k
+    "moe_dispatch": "moe_dispatch",  # sort by expert, gather, un-permute, weighted combine
+    "moe_experts": "moe_experts",  # the three grouped matmuls and the SwiGLU between
+    "lm_head": "lm_head",  # final norm, head matmul, log-prob / entropy / sampling over the vocabulary
 }
+
+# The scopes of the token policy's block: only the systems built on
+# networks/olmoe.py carry them.
+BLOCK_SCOPES = ("attention", "moe", "moe_router", "moe_dispatch", "moe_experts", "lm_head")
 
 # Host spans that recur in steady state, by the thread that opens them. A
 # trace reduction attributes a device-idle gap to the innermost of these open

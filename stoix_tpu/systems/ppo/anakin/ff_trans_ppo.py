@@ -326,7 +326,7 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
 
     # Evaluator: the context window plays the RNN evaluator's hidden-state
     # role — carried across eval steps, cleared on done (rnn_act_fn
-    # signature, runner wires get_rnn_evaluator_fn via evaluator_setup_fn).
+    # signature, one episode at a time: run_experiment wires the carry evaluator).
     def window_act_fn(p, ctx_state, observation, done, act_key):
         flat = observation.agent_view.reshape(-1)[None]  # [1, F]
         ctx_state = jnp.where(jnp.asarray(done), 0.0, ctx_state)
@@ -345,19 +345,14 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
 
 
 def run_experiment(config: Any) -> float:
-    from stoix_tpu.evaluator import get_rnn_evaluator_fn
+    from stoix_tpu.evaluator import per_episode_evaluator_setup
 
     window = int(config.system.get("window_length", 16))
 
     def evaluator_setup(eval_env, act_fn, cfg, mesh):
         feat = int(eval_env.observation_value().agent_view.reshape(-1).shape[0])
-        init_h = lambda: jnp.zeros((1, window, feat))
-        evaluator = get_rnn_evaluator_fn(eval_env, act_fn, cfg, mesh, init_h)
-        absolute = get_rnn_evaluator_fn(
-            eval_env, act_fn, cfg, mesh, init_h,
-            eval_multiplier=int(cfg.arch.get("absolute_metric_multiplier", 10)),
-        )
-        return evaluator, absolute
+        setup = per_episode_evaluator_setup(lambda: jnp.zeros((1, window, feat)))
+        return setup(eval_env, act_fn, cfg, mesh)
 
     return run_anakin_experiment(config, learner_setup, evaluator_setup_fn=evaluator_setup)
 

@@ -114,7 +114,7 @@ import jax
 import jax.numpy as jnp
 
 from stoix_tpu import envs
-from stoix_tpu.evaluator import evaluator_setup, get_rnn_evaluator_fn
+from stoix_tpu.evaluator import evaluator_setup, per_episode_evaluator_setup
 from stoix_tpu.observability import (
     HeartbeatBoard,
     RunStats,
@@ -228,7 +228,7 @@ class _Window(NamedTuple):
 
     eval_idx: int
     t: int  # global env-step count at window end
-    snapshot: Any  # on-device copy of eval params (donation-safe)
+    snapshot: Any  # on-device copy of eval params (donation-safe); None when nothing keeps them
     ckpt_state: Any  # on-device copy of the full learner state, or None
     metrics: Any  # ONE coalesced device tree: episode/train/eval metrics
 
@@ -569,7 +569,13 @@ def run_anakin_experiment(
             fused_step if fused else learn, headroom=pf.hbm_headroom
         )
 
-    best_params = _tree_copy(setup.eval_params_fn(learner_state))
+    # Only the absolute-metric evaluation at the run's end reads the best
+    # parameters. Without it no snapshot is kept or even taken: the evaluator
+    # is dispatched on the live parameters BEFORE the next (donating) learn
+    # dispatch, so the device stream orders its reads first, and nothing on
+    # the host reads them afterwards. A 2.5 GB policy then costs no copy.
+    track_best = bool(config.arch.get("absolute_metric", True))
+    best_params = _tree_copy(setup.eval_params_fn(learner_state)) if track_best else None
     best_return = -jnp.inf
     final_return = 0.0
 
@@ -618,7 +624,8 @@ def run_anakin_experiment(
         with span("snapshot_dispatch", clock=phases, phase="snapshot_s",
                   window=eval_idx):
             t = start_step + (eval_idx + 1) * steps_per_eval
-            snapshot = _tree_copy(setup.eval_params_fn(learner_state))
+            eval_params = setup.eval_params_fn(learner_state)
+            snapshot = _tree_copy(eval_params) if track_best else None
             take_ckpt = (
                 checkpointer is not None
                 and snapshot_ckpt
@@ -638,7 +645,7 @@ def run_anakin_experiment(
 
         if not fused:
             with span("eval_dispatch", clock=phases, phase="eval_s", window=eval_idx):
-                eval_metrics = evaluator(snapshot, eval_key)
+                eval_metrics = evaluator(snapshot if track_best else eval_params, eval_key)
 
         # ONE coalesced collective fetch for the whole window (episode, train,
         # and eval metrics ride a single pytree -> a single host-sync point).
@@ -762,7 +769,7 @@ def run_anakin_experiment(
             )
             mean_return = float(eval_metrics["episode_return"].mean())
             final_return = mean_return
-            if mean_return >= float(best_return):
+            if track_best and mean_return >= float(best_return):
                 best_return = mean_return
                 best_params = window.snapshot  # already a donation-safe copy
 
@@ -1075,13 +1082,7 @@ def run_rnn_anakin_experiment(config: Any, setup_fn: SetupFn) -> float:
     hidden_size = int(config.network.get("rnn_hidden_size", 128))
     cell_type = str(config.network.get("rnn_cell_type", "gru"))
 
-    def rnn_evaluator_setup(eval_env, act_fn, cfg, mesh):
-        init_h = lambda: ScannedRNN.initialize_carry(cell_type, hidden_size, (1,))
-        evaluator = get_rnn_evaluator_fn(eval_env, act_fn, cfg, mesh, init_h)
-        absolute = get_rnn_evaluator_fn(
-            eval_env, act_fn, cfg, mesh, init_h,
-            eval_multiplier=int(cfg.arch.get("absolute_metric_multiplier", 10)),
-        )
-        return evaluator, absolute
-
+    rnn_evaluator_setup = per_episode_evaluator_setup(
+        lambda: ScannedRNN.initialize_carry(cell_type, hidden_size, (1,))
+    )
     return run_anakin_experiment(config, setup_fn, evaluator_setup_fn=rnn_evaluator_setup)
