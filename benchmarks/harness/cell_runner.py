@@ -11,12 +11,22 @@ from __future__ import annotations
 import math
 import os
 import shutil
+import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from benchmarks.harness import clock as clock_lib
 from benchmarks.harness import loader, observe
 from benchmarks.harness.trace_capture import TraceWindow
+
+
+# A traced run's sessions are over by this many seconds after the process
+# began: the 360 s the benchmark's contract gives a run, less 30 s for the
+# program's own stop, the reference's check after the run and the exit (8 s
+# in the Ant cells; the token cell's 100 s replay follows sessions that cost
+# 25 s each, three of which are over by 170 s: PERF.md section 2). A further
+# session opens only if what the last one cost, as measured, still fits.
+SESSIONS_END_BY_S = 330.0
 
 
 class DeviceMismatch(RuntimeError):
@@ -37,10 +47,15 @@ class RunContext:
         # How the run is ended when the interval is over: the program's own
         # graceful stop. A driver whose system stops otherwise sets its own.
         self.stop: Callable[[], None] = observe.request_graceful_stop
+        # A traced run reports no rate: once its interval is over it goes on
+        # while this holds (the tracer needs a further session).
+        self.hold_stop: Callable[[], bool] = lambda: False
+        self._stop_due = False
+        self._stop_lock = threading.Lock()
         self.clock = clock_lib.IntervalClock(
             seconds,
             warmup_ticks=int(cell.spec.get("warmup_ticks", 1)),
-            on_deadline=lambda: self.stop(),
+            on_deadline=self._interval_over,
             ready=self._ready,
             process_start=process_start,
         )
@@ -65,6 +80,18 @@ class RunContext:
 
     def _ready(self) -> bool:
         return all(check() for check in self.ready_checks)
+
+    def _interval_over(self) -> None:
+        self._stop_due = True
+        self.release_stop()
+
+    def release_stop(self) -> None:
+        """Ends the run if its interval is over and nothing holds the stop
+        back: called at the deadline (the timer's thread) and at each tick."""
+        with self._stop_lock:
+            if self._stop_due and not self.hold_stop():
+                self._stop_due = False
+                self.stop()
 
     def overrides(self) -> List[str]:
         return self.cell.overrides + [
@@ -112,23 +139,33 @@ def run_cell(
 
     tracer: Optional[TraceWindow] = None
     if trace:
+        from benchmarks.harness import trace_reduce
+
         base = scratch_dir or os.path.join(cell.root, "bench_out", "trace")
         directory = os.path.join(base, f"{cell.name}-seed{seed}")
         shutil.rmtree(directory, ignore_errors=True)
         os.makedirs(directory, exist_ok=True)
+
+        def judge(path: str) -> Tuple[Any, Dict[str, Any]]:
+            data = trace_reduce.read_xplane(path, host_names=cell.config.get("host_annotations", []))
+            return data, trace_reduce.describe(data, cell.config.get("programs", {}).get("learn"))
+
         tracer = TraceWindow(
-            directory, int(cell.spec.get("trace_start_tick", 2)), int(cell.spec.get("trace_ticks", 2))
+            directory, int(cell.spec.get("trace_start_tick", 2)), int(cell.spec.get("trace_ticks", 2)),
+            judge, seconds_left=lambda: SESSIONS_END_BY_S - (time.perf_counter() - process_start),
         )
+        ctx.hold_stop = lambda: tracer.busy
 
     def on_tick(index: int, tick: clock_lib.Tick) -> None:
         if ctx.clock.start_index is None:
             return
         # Registry marks at whole ticks of the interval, up to the one the
-        # traced window opens at; then the trace.
-        if ctx.clock.in_interval(tick) and not (tracer and (tracer.running or tracer.done)):
+        # first traced session opens at; then the trace.
+        if ctx.clock.in_interval(tick) and not (tracer and (tracer.running or tracer.records)):
             ctx.registry_marks.append((index, tick.time, observe.flat_registry()))
         if tracer is not None:
             tracer.on_tick(index - ctx.clock.start_index)
+            ctx.release_stop()
 
     ctx.clock.on_tick(on_tick)
 
@@ -157,8 +194,8 @@ def run_cell(
         return build_result(ctx, tracer, run_end)
     finally:
         if tracer is not None:
-            # A trace is hundreds of MB: once reduced, it is removed.
-            shutil.rmtree(os.path.dirname(tracer.path), ignore_errors=True)
+            # A trace is hundreds of MB: its directory goes with the run.
+            shutil.rmtree(tracer.directory, ignore_errors=True)
 
 
 def build_result(ctx: RunContext, tracer: Optional[TraceWindow], run_end: float) -> Dict[str, Any]:
@@ -219,21 +256,41 @@ def build_result(ctx: RunContext, tracer: Optional[TraceWindow], run_end: float)
 
     metrics: Dict[str, Dict[str, Any]] = {}
     device = dict(ctx.device)
-    breakdown = None
+    breakdown = trace_report = None
     if ctx.trace:
         from benchmarks.harness import trace_reduce
 
-        path = tracer.xplane() if tracer is not None else None
-        if path is None:
+        # What happened to the trace goes on the result line and, for the
+        # reader of stderr, into `health`.
+        trace_report = tracer.report() if tracer is not None else {"sessions": 0}
+        detail["health"] = {**ctx.health, "trace": trace_report}
+        if tracer is None or tracer.chosen is None:
             problems.append("the traced run wrote no .xplane.pb")
         else:
-            ctx.trace_data = trace_reduce.read_xplane(
-                path, host_names=cell.config.get("host_annotations", [])
+            ctx.trace_data, used = tracer.chosen
+            sessions = (
+                f"{len(tracer.records)} session(s) of {tracer.ticks} ticks, unreadable seconds "
+                f"{[round(r.get('unreadable_s', 0.0), 4) for r in tracer.records]}"
             )
+            if used.get("chips_with_whole_execution") == 0:
+                problems.append(
+                    f"no whole learner execution could be read: {sessions}, the per-layer "
+                    "metrics that need one are left out"
+                )
+            if not used.get("window_sound"):
+                # The readers of the window's shares return nothing then
+                # (trace_reduce.sound_window): none is printed as if right.
+                problems.append(
+                    "the profiler damaged the traced window: lost_inside_s "
+                    f"{used.get('lost_inside_s', 0.0):.4f} of {used.get('raw_window_s', 0.0):.4f} s, "
+                    f"whole learner executions on the chip with fewest {used.get('whole_learner_executions')} "
+                    f"({sessions}), the window's shares are left out"
+                )
             busy = trace_reduce.busy_and_window(ctx.trace_data)
             if busy is None or busy["busy_s"] <= 0.0:
                 problems.append("no operation ran on the device in the traced window")
             else:
+                # Readable seconds and the busy seconds inside them.
                 device["busy_s"] = busy["busy_s"]
                 device["window_s"] = busy["window_s"]
             breakdown = {
@@ -262,6 +319,12 @@ def build_result(ctx: RunContext, tracer: Optional[TraceWindow], run_end: float)
     }
     if breakdown is not None:
         result["breakdown"] = breakdown
+    if trace_report is not None:
+        result["trace"] = trace_report
+    # Every number the reference compared, beside its limit: last on the line.
+    result["compared"] = {
+        name: {"value": error, "limit": limit} for name, (error, limit) in ctx.errors.items()
+    }
     result["problems"] = problems
     result["detail"] = detail
     return result

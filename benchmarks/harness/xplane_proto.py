@@ -21,6 +21,7 @@ _SCHEMA = {
         ("lines", 3, "XLine", _REPEATED),
         ("event_metadata", 4, "EventMetadataEntry", _REPEATED),
         ("stat_metadata", 5, "StatMetadataEntry", _REPEATED),
+        ("stats", 6, "XStat", _REPEATED),
     ],
     "EventMetadataEntry": [("key", 1, _INT64, _OPTIONAL), ("value", 2, "XEventMetadata", _OPTIONAL)],
     "StatMetadataEntry": [("key", 1, _INT64, _OPTIONAL), ("value", 2, "XStatMetadata", _OPTIONAL)],

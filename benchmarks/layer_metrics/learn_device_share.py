@@ -1,6 +1,8 @@
 """Learner program (`systems/ppo/*/ff_ppo.py`): the share of the traced
 window in which the learner's XLA program ran on the device (mean over
-chips). Found by the configuration's `programs.learn` name patterns."""
+chips). Found by the configuration's `programs.learn` name patterns. A share
+of the window: left out where the profiler damaged the window
+(`trace_reduce.sound_window`)."""
 
 from benchmarks.harness import trace_reduce
 
@@ -9,7 +11,7 @@ def read(ctx):
     if ctx.trace_data is None:
         return None
     patterns = ctx.cell.config.get("programs", {}).get("learn")
-    busy = trace_reduce.busy_and_window(ctx.trace_data)
+    busy = trace_reduce.sound_window(ctx.trace_data, patterns)
     if not patterns or busy is None:
         return None
     seconds = trace_reduce.program_seconds(ctx.trace_data, patterns)

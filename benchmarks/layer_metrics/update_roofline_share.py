@@ -15,7 +15,7 @@ def read(ctx):
     patterns = config.get("programs", {}).get("learn")
     scope = config.get("scopes", {}).get("update")
     cost = ctx.shapes.get("update_cost")
-    if ctx.trace_data is None or not (patterns and scope and cost):
+    if ctx.trace_data is None or not ctx.trace_data.planes or not (patterns and scope and cost):
         return None
     windows = trace_reduce.program_windows(ctx.trace_data, patterns, whole_only=True)
     # Mean over chips, like the scoped seconds below: the chips' traces need

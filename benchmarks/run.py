@@ -8,9 +8,11 @@ configuration and traffic into the system's config and calls the system
 module's own `run_experiment` (the path `main()` takes); set-up ends with the
 cell's warm-up window, the measured interval is `--seconds` long, and the run
 is ended through the program's own graceful stop. The last line of stdout is
-one JSON object: correct, attempted, failed, metrics, device (and breakdown
-with `--trace 1`). `--trace 0` gives the end-to-end metrics, `--trace 1` the
-per-layer ones. What the run saw besides goes to stderr.
+one JSON object: correct, attempted, failed, metrics, device (with `--trace 1`
+also breakdown and trace, what happened to the profiler sessions), and last
+compared, each number the reference compared beside its limit. `--trace 0`
+gives the end-to-end metrics, `--trace 1` the per-layer ones. What the run
+saw besides goes to stderr.
 
 There is no CPU mode. Without a TPU, or with another number of chips than
 the cell's, it exits 2 and prints no result line.
@@ -28,7 +30,7 @@ import os  # noqa: E402
 import sys  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-RESULT_KEYS = ("correct", "attempted", "failed", "metrics", "device", "breakdown")
+RESULT_KEYS = ("correct", "attempted", "failed", "metrics", "device", "breakdown", "trace", "compared")
 
 
 def main() -> int:

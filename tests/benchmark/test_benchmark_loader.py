@@ -184,3 +184,100 @@ def test_old_cells_are_untouched_by_the_additions(grown):
 def test_unknown_names_say_what_is_known():
     with pytest.raises(KeyError, match="known: "):
         loader.load_cell("no_such_cell")
+
+
+# --------------------------------------------------------------------------
+# A traced run whose profiler loses a program boundary (PR 27): the stub
+# system, faked sessions, and the synthetic traces of _loop_trace.py
+# --------------------------------------------------------------------------
+
+
+@pytest.fixture()
+def scripted_traces(monkeypatch):
+    """`script` lists what each session's trace holds: (windows, lost)."""
+    from _loop_trace import loop_trace
+    from benchmarks.harness import trace_capture, trace_reduce
+
+    script, made = [], []
+
+    def stop(session, path):
+        with open(path, "w") as handle:
+            handle.write(str(session))
+
+    def read_xplane(path, host_names=None):
+        with open(path) as handle:
+            return loop_trace(*script[int(handle.read())])
+
+    monkeypatch.setattr(trace_capture, "start", lambda: made.append(len(made)) or made[-1])
+    monkeypatch.setattr(trace_capture, "stop", stop)
+    monkeypatch.setattr(trace_reduce, "read_xplane", read_xplane)
+    return script
+
+
+def _traced_stub_run(root, tmp_path):
+    """The stub cell's files name no number of sessions, as no cell's do:
+    the cap is trace_capture's own."""
+    cell = loader.load_cell("dummy_cell", root)
+    programs = {"learn": ["learner_fn"], "eval": ["_shard_eval"]}
+    spec = {**cell.spec, "trace_start_tick": 2, "trace_ticks": 2}
+    config = {**cell.config, "programs": programs, "scopes": {"update": "ppo_epoch"}}
+    cell = cell._replace(config=config, spec=spec)
+    return cell_runner.run_cell(
+        cell, 1, 0.1, True, time.perf_counter(), require_platform="cpu", scratch_dir=str(tmp_path / "t"),
+    )
+
+
+WINDOW_SHARES = {"eval_device_share", "learn_device_share", "device_idle_share"}
+
+
+@pytest.mark.parametrize("script,made,used,whole,sound", [
+    ([(2, None)], 1, 0, 1, True),  # nothing lost: one session, as before
+    ([(2, "first"), (2, None)], 2, 1, 1, True),  # the one whole execution lost: a second session
+    ([(2, "last"), (2, None)], 1, 0, 1, True),  # lost at the very end: a shorter session, sound
+    ([(2, "first"), (2, "first"), (2, "last")], 3, 2, 1, True),  # the third time lucky
+    ([(2, "first"), (3, "first"), (2, "first")], 3, 1, 1, False),  # none clean: the one with a whole execution
+    ([(2, "first"), (2, "first"), (2, "first"), (2, None)], 3, 0, 0, False),  # three and no more: nothing whole
+])
+def test_a_traced_run_survives_a_lost_boundary(grown, one_cpu_device, scripted_traces, tmp_path,
+                                               script, made, used, whole, sound):
+    root, _ = grown
+    scripted_traces.extend(script)
+    result = _traced_stub_run(root, tmp_path)
+    report = result["trace"]
+    assert (report["sessions"], report["used"], report["whole_learner_executions"]) == (made, used, whole)
+    assert (report["sound"], report["window_sound"], report["chips_traced"]) == (sound, sound, 1)
+    assert result["detail"]["health"]["trace"] == report  # the same on stderr
+    assert len(report["candidates"]) == made
+    assert report["raw_window_s"] == pytest.approx((1100 * script[used][0]) * 1e-12)
+    assert 0.0 < result["device"]["busy_s"] <= result["device"]["window_s"]
+    names = WINDOW_SHARES | {"update_share", "dummy_metric"}
+    if sound:
+        assert result["correct"], result["problems"]
+        assert names <= set(result["metrics"])
+        assert result["metrics"]["update_share"]["value"] == pytest.approx(30.0)
+    else:
+        # A damaged window is never read as if whole: its shares are left out
+        # and the run says so; what whole executions give is kept.
+        unreadable = [0.0] * made
+        sessions = f"{made} session(s) of 2 ticks, unreadable seconds {unreadable}"
+        assert result["problems"] == ([] if whole else [
+            f"no whole learner execution could be read: {sessions}, the per-layer metrics that need one are left out"
+        ]) + [
+            f"the profiler damaged the traced window: lost_inside_s 0.0000 of 0.0000 s, whole learner "
+            f"executions on the chip with fewest {whole} ({sessions}), the window's shares are left out"
+        ]
+        assert not WINDOW_SHARES & set(result["metrics"]) and "dummy_metric" in result["metrics"]
+        assert ("update_share" in result["metrics"]) == bool(whole)
+        if whole:
+            assert result["metrics"]["update_share"]["value"] == pytest.approx(30.0)
+    if script[used][1] is None:
+        assert result["metrics"]["eval_device_share"]["value"] == pytest.approx(100.0 * 200 / 2200)
+        assert report["unreadable_s"] == 0.0 and report["unnamed_op_events"] == 0
+    else:
+        assert result["breakdown"]["device_ops"][0][0].startswith("unreadable")
+        assert not any("region" in label for label, _ in result["breakdown"]["device_ops"])
+    # The stop was held back until the last session had closed: sessions of
+    # two 20 ms ticks with a tick between, from tick 2, in a 0.1 s interval.
+    if made > 1:
+        assert result["detail"]["exit_after_interval_s"] > 0.02 * (3 * made + 1) - 0.1 - 0.02
+    assert not os.listdir(tmp_path / "t")

@@ -241,3 +241,99 @@ def test_each_threads_spans_lie_on_its_own_host_line():
     actor = {"actor_rollout", "actor_inference", "actor_env_step", "pipeline_put"}
     learner = {"learner_rollout_wait", "learner_update"}
     assert sorted(lines.values(), key=sorted) == sorted([actor, learner], key=sorted), lines
+
+
+# --------------------------------------------------------------------------
+# The readers on a trace the profiler damaged (tests/benchmark/_loop_trace.py)
+# --------------------------------------------------------------------------
+
+from _loop_trace import D0 as L0, D1 as L1, loop_trace  # noqa: E402
+
+WINDOW_SHARES = ["eval_device_share", "learn_device_share", "device_idle_share"]
+SCOPE_SHARES = ["update_share", "rollout_share", "rollout_env_share", "gae_share", "shuffle_share"]
+
+
+def loop_ctx(*args, cell=CELLS["anakin"], **kwargs):
+    ctx = trace_ctx(loop_trace(*args, **kwargs), cell)
+    ctx.shapes = {"update_cost": {"flops": 1.0e3, "bytes": 1.0}, "updates_per_tick": 1}
+    ctx.device = {"kind": "TPU v5 lite"}
+    return ctx
+
+
+@pytest.mark.parametrize("name", WINDOW_SHARES + SCOPE_SHARES + ["update_roofline_share"])
+@pytest.mark.parametrize("windows,lost,planes", [
+    (3, "first", (L0,)), (3, "first", (L0, L1)), (3, "last", (L0,)), (2, "first", (L0,)), (2, "last", (L0,)),
+])
+def test_readers_read_a_damaged_twin_as_they_read_the_whole_trace(name, windows, lost, planes):
+    """What the driver reads of a run whose profiler lost a boundary. The
+    learner's shares are the undamaged trace's wherever a whole execution is
+    left. The window's shares: with the last boundary lost, those of a
+    session that ended with the evaluator (310 ps earlier); with a hole
+    INSIDE the window they are left out, never printed as if right (the
+    arithmetic under them still gives the whole trace's shares on this
+    synthetic loop, test_benchmark_trace_reduce.py; on the chip a hole took
+    one evaluation of two and halved `eval_device_share`, PR 27)."""
+    read = reader(name)
+    whole, twin = read(loop_ctx(windows, None, planes)), read(loop_ctx(windows, lost, planes))
+    assert whole is not None and 0.0 < whole <= 100.0
+    if name in WINDOW_SHARES:
+        if lost == "first":
+            assert twin is None
+        else:
+            window = 700 + windows * 1100 - 1000 - 10
+            expected = {
+                "eval_device_share": (100 * windows - 10) / window,
+                "learn_device_share": (1000 * windows - 300) / window,
+                "device_idle_share": (60 * windows - 10) / window,
+            }[name]
+            assert twin == pytest.approx(100.0 * expected, rel=1e-12)
+    elif windows == 2 and lost == "first":
+        assert twin is None  # the one whole execution is the one that was lost
+    else:
+        assert twin == pytest.approx(whole, rel=1e-12)
+
+
+@pytest.mark.parametrize("name", WINDOW_SHARES + SCOPE_SHARES + ["update_roofline_share"])
+def test_readers_on_four_chips_of_which_one_lost_its_first_boundary(name):
+    """One chip of four damaged (the fast program's usual four-chip session,
+    my chip runs, PR 27): every chip stays in the trace; the learner's shares
+    are read on the three chips that hold a whole execution and are the whole
+    trace's; the window's shares are left out."""
+    planes = tuple(f"/device:TPU:{i}" for i in range(4))
+    read = reader(name)
+    whole = read(loop_ctx(2, None, planes))
+    twin = read(loop_ctx(2, "first", planes, damaged_planes=planes[2:3]))
+    assert whole is not None
+    if name in WINDOW_SHARES:
+        assert twin is None
+    else:
+        assert twin == pytest.approx(whole, rel=1e-12)
+
+
+def test_the_four_chip_readers_count_collectives_over_readable_time():
+    """`collective_calls_per_update` on a twin whose every chip lost the first
+    boundary: the all-reduce of the lost execution is unnamed and is not
+    counted, and neither is its time. `collective_exposed_share` is a share
+    of the window: left out with a hole inside it, and with the last boundary
+    lost that of the shorter session."""
+    from _loop_trace import loop_events
+
+    def with_all_reduce(lost):
+        events = []
+        for e in loop_events(3, lost, (L0, L1)):
+            # The SGD fusion of every named learner execution becomes an all-reduce.
+            if e.line == tr.OPS_LINE and "ppo_minibatch" in e.stats.get("tf_op", ""):
+                e = e._replace(name="%all-reduce.6 = f32[8]{0} thing()")
+            events.append(e)
+        ctx = trace_ctx(tr.Trace.from_events(events), CELLS["anakin4"])
+        ctx.shapes = {"updates_per_tick": 1}
+        return ctx
+
+    calls = reader("collective_calls_per_update", CELLS["anakin4"])
+    whole = calls(with_all_reduce(None))
+    assert whole is not None and calls(with_all_reduce("first")) == pytest.approx(whole, rel=1e-12)
+    exposed = reader("collective_exposed_share", CELLS["anakin4"])
+    assert exposed(with_all_reduce(None)) == pytest.approx(100.0 * 600 / 3300, rel=1e-12)
+    assert exposed(with_all_reduce("first")) is None
+    # Last boundary lost: the learner's head held no all-reduce, the window is 310 ps shorter.
+    assert exposed(with_all_reduce("last")) == pytest.approx(100.0 * 600 / (3300 - 310), rel=1e-12)
