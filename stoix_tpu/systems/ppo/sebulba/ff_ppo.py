@@ -80,6 +80,7 @@ from stoix_tpu.sebulba.core import (
     ParameterServer,
     ThreadLifetime,
 )
+from stoix_tpu.sebulba.rollout_storage import RolloutStorage, host_copy
 from stoix_tpu.utils import compilecache
 from stoix_tpu.utils import config as config_lib
 from stoix_tpu.utils.logger import LogEvent, StoixLogger
@@ -602,6 +603,7 @@ def _rollout_body(
 
     act_fn = get_act_fn(actor_apply, critic_apply, normalize_obs)
     step_seconds = StepAccumulator()
+    storage = RolloutStorage(rollout_length, learner_devices)
 
     with jax.default_device(actor_device):
         key = jax.random.PRNGKey(seed)
@@ -626,7 +628,6 @@ def _rollout_body(
                     if fetched is None:
                         break
                     behavior_version, params = fetched
-            traj: List[PPOTransition] = []
             with span("actor_rollout", clock=timer, phase="rollout",
                       actor=actor_id, idx=rollout_idx):
                 for _ in range(rollout_length):
@@ -645,12 +646,13 @@ def _rollout_body(
                         env_action = np.asarray(action) if host_pool else action
                     with span("actor_env_step", clock=step_seconds, phase="env_step"):
                         next_timestep = envs.step(env_action)
-                    traj.append(
+                    # Row t of the rollout, outside both spans. The operators
+                    # keep what a host pool returns on the host (numpy) and a
+                    # JAX twin's arrays on their device.
+                    storage.add(
                         PPOTransition(
                             done=next_timestep.discount == 0.0,
-                            truncated=jnp.logical_and(
-                                next_timestep.last(), next_timestep.discount != 0.0
-                            ),
+                            truncated=next_timestep.last() & (next_timestep.discount != 0.0),
                             action=action,
                             value=value,
                             reward=next_timestep.reward,
@@ -667,17 +669,10 @@ def _rollout_body(
 
             with span("actor_prepare_data", clock=timer, phase="prepare_data",
                       actor=actor_id):
-                # Stack [T, E] then split the env axis across learner devices
-                # as single-device shards for global-array assembly.
-                stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *traj)
-                n_learners = len(learner_devices)
-                payload = jax.tree.map(
-                    lambda x: [
-                        jax.device_put(s, d)
-                        for s, d in zip(jnp.split(x, n_learners, axis=1), learner_devices)
-                    ],
-                    stacked,
-                )
+                # Per leaf, the learner devices' [T, E/n] slices of the env
+                # axis, as single-device shards for global-array assembly:
+                # one transfer a host leaf, one program for the device leaves.
+                payload, stored = storage.finish()
             with timer.time("queue_put"):
                 try:
                     tagged = (behavior_version, payload)
@@ -691,7 +686,7 @@ def _rollout_body(
                     raise
             metrics_sink.put(
                 {
-                    "episode_metrics": jax.tree.map(np.asarray, stacked.info),
+                    "episode_metrics": host_copy(stored.info),
                     "timings": {
                         **timer.all_means(prefix=f"actor{actor_id}_"),
                         **timer.all_percentiles(prefix=f"actor{actor_id}_"),
