@@ -376,6 +376,7 @@ def leg_anakin_ant_large(n: int) -> Dict[str, Any]:
 
 def leg_sebulba(n: int) -> Dict[str, Any]:
     from stoix_tpu.parallel import mesh as mesh_lib
+    from stoix_tpu.sebulba import sources as sebulba_sources
     from stoix_tpu.systems.ppo.sebulba import ff_ppo as sebulba_ppo
     from stoix_tpu.utils import config as config_lib
 
@@ -403,9 +404,9 @@ def leg_sebulba(n: int) -> Dict[str, Any]:
     observed: Dict[str, Any] = {"assembled": []}
 
     def observing_builder(*args: Any, **kwargs: Any) -> Callable:
-        # `learn_step_builder` is run_experiment's own seam (ff_impala uses
-        # it): build the stock PPO update, and note where the state and the
-        # trajectory batch live the first time it is stepped.
+        # `learn_step_builder` is run_experiment's own seam: build the stock
+        # PPO update, and note where the state and the trajectory batch live
+        # the first time it is stepped.
         inner = sebulba_ppo.get_learn_step(*args, **kwargs)
 
         def learn_step(state: Any, batch: Any) -> Any:
@@ -416,8 +417,8 @@ def leg_sebulba(n: int) -> Dict[str, Any]:
 
         return learn_step
 
-    # The trajectory hand-off primitive, observed where the Sebulba learner
-    # calls it (several learner devices only).
+    # The trajectory hand-off primitive, observed where the Sebulba learner's
+    # batch source calls it (several learner devices only).
     assemble = mesh_lib.assemble_global_array
 
     @functools.wraps(assemble)
@@ -428,14 +429,14 @@ def leg_sebulba(n: int) -> Dict[str, Any]:
 
     errors_before = _counter_total("stoix_tpu_sebulba_evaluator_errors_total")
     crashes_before = _counter_total("stoix_tpu_sebulba_actor_crashes_total")
-    sebulba_ppo.assemble_global_array = observing_assemble
+    sebulba_sources.assemble_global_array = observing_assemble
     try:
         with _tee_train_metrics(observed):
             final_return = sebulba_ppo.run_experiment(
                 config, learn_step_builder=observing_builder
             )
     finally:
-        sebulba_ppo.assemble_global_array = assemble
+        sebulba_sources.assemble_global_array = assemble
 
     stats = sebulba_ppo.LAST_RUN_STATS
     _require(math.isfinite(final_return), f"final return not finite: {final_return}")

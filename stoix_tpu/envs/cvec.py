@@ -102,7 +102,7 @@ class CVecPool:
     """Stateful Sebulba env backed by the native pool: numpy in, TimeStep out."""
 
     # step() reads the action on the host: a Sebulba actor copies it there
-    # before it times the pool (systems/ppo/sebulba/ff_ppo.py).
+    # before it times the pool (sebulba/runner.py::_rollout_body).
     takes_host_actions = True
 
     def __init__(self, task: str, num_envs: int, seed: int, max_steps: int = 500):

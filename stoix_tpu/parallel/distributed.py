@@ -1,7 +1,8 @@
 """Multi-host initialisation and host-side coordination.
 
-The reference explicitly does not support multi-host (reference
-sebulba/ff_ppo.py:808-810 asserts local == global devices; README.md:57).
+The reference explicitly does not support multi-host (the reference's
+stoix/systems/ppo/sebulba/ff_ppo.py:808-810 asserts local == global devices;
+its README.md:57).
 Here multi-host is first-class: call `maybe_initialize_distributed()` before
 any JAX computation; the global mesh then spans all processes and collectives
 ride ICI within a slice / DCN across slices automatically via shardings.

@@ -87,8 +87,9 @@ def assemble_global_array(
     array_axis: int = 0,
 ) -> jax.Array:
     """Build one global array from per-device shards without host concat —
-    the Sebulba trajectory hand-off primitive (replaces the reference's
-    `jax.device_put_sharded`, sebulba/ff_ppo.py:263; see SURVEY.md §7.1.3).
+    the Sebulba trajectory hand-off primitive, called by the batch sources of
+    `sebulba/sources.py` (replaces the reference's `jax.device_put_sharded`,
+    reference sebulba/ff_ppo.py:263; see SURVEY.md §7.1.3).
 
     `array_axis` names the array dimension the shards tile (and the mesh
     axis shards): 0 for leading-axis items (the replay service's transition

@@ -6,8 +6,8 @@ bookkeeping:
   * Anakin (`systems/runner.py`) built a global mesh straight from
     `arch.mesh` and implicitly ran every role (act / learn / evaluate) on
     every device;
-  * Sebulba (`systems/ppo/sebulba/ff_ppo.py`, `systems/q_learning/sebulba/
-    ff_dqn.py`) indexed `jax.devices()` with `arch.actor.device_ids` /
+  * Sebulba (two host loops then; `sebulba/runner.py` now) indexed
+    `jax.devices()` with `arch.actor.device_ids` /
     `arch.learner.device_ids` / `arch.evaluator_device_id` and hand-rolled
     the learner mesh;
   * serve (`serve/server.py`) silently used whatever jax's default device

@@ -5,7 +5,7 @@ whole run's lifetime; a WEDGED actor (alive but silent) hung the learner
 until the 180 s collect timeout. The supervisor owns the actor threads
 instead:
 
-  * a crash is reported by the dying thread (rollout_thread); the supervisor
+  * a crash is reported by the dying thread (sebulba/runner.py); the supervisor
     respawns a replacement — fresh thread, fresh env instance (the thread
     factory re-invokes the env factory), re-fetched params (the param queue
     is re-primed with the latest distributed params so the replacement never

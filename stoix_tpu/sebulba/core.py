@@ -330,10 +330,9 @@ class ParameterServer:
     reason). `reprime` reuses the version's placed copy the same way.
 
     Versioning: every distribute_params bumps a monotone version counter;
-    queue entries are VersionedParams. `get_params` strips the version
-    (back-compat contract for the on-policy path); `get_params_versioned`
-    returns (version, params) for actors that must report which policy
-    collected a trajectory (IMPACT, arXiv:1912.00167)."""
+    queue entries are VersionedParams, and `get_params_versioned` returns
+    (version, params) so that an actor can report which policy collected a
+    trajectory (policy lag; IMPACT, arXiv:1912.00167)."""
 
     def __init__(
         self,
@@ -443,34 +442,28 @@ class ParameterServer:
         return True
 
     def fail(self, failure: ComponentFailure, actor_id: int) -> None:
-        """Poison one actor's param queue: an actor blocked in get_params
+        """Poison one actor's param queue: an actor blocked in its param get
         raises `failure` instead of waiting on params that will never come.
         The supervisor uses this for the failed actor itself — a wedge
-        blocked in get_params dies with a typed error instead of lingering
+        blocked there dies with a typed error instead of lingering
         forever. (Orderly teardown of HEALTHY actors stays shutdown()'s
         None-sentinel job.)"""
         _replace_nowait(self._queues[actor_id], failure)
 
-    def get_params(self, actor_id: int, timeout: Optional[float] = None) -> Any:
-        """Returns fresh params, or None (shutdown sentinel); raises a
-        ComponentFailure poison-pill if the learner failed unrecoverably."""
-        got = self.get_params_versioned(actor_id, timeout=timeout)
-        return None if got is None else got.params
-
     def get_params_versioned(
         self, actor_id: int, timeout: Optional[float] = None
     ) -> Optional[VersionedParams]:
-        """Like get_params, but keeps the version the entry was distributed
-        under: (version, params), or None (shutdown sentinel). IMPACT actors
-        use this to tag trajectories with their behavior-policy version."""
+        """Fresh params with the version they were distributed under,
+        (version, params), or None (shutdown sentinel); raises a
+        ComponentFailure poison-pill if the learner failed unrecoverably.
+        Actors tag their trajectories with the version (policy lag, IMPACT
+        staleness)."""
         labels = {"queue": "params", "actor": str(actor_id)}
         with span("param_get", clock=self._get_wait, phase=labels, actor=actor_id):
             entry = self._queues[actor_id].get(timeout=timeout)
         self._depth.set(self._queues[actor_id].qsize(), labels)
         if isinstance(entry, ComponentFailure):
             raise entry
-        if entry is None:
-            return None
         return entry
 
     def shutdown(self) -> None:
@@ -545,7 +538,7 @@ class AsyncEvaluator:
                 self.heartbeats.beat("evaluator")
             except Exception as exc:  # noqa: BLE001 — a lost eval window must
                 # not kill the thread silently nor wedge shutdown on a cleared
-                # _idle flag (mirrors rollout_thread's crash telemetry). The
+                # _idle flag (mirrors the actor thread's crash telemetry). The
                 # thread lives on; the RUN still fails — wait_until_idle
                 # raises for every recorded failure.
                 import traceback

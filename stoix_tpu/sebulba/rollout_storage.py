@@ -20,6 +20,10 @@ Which path a leaf takes is read from its type at every step; nothing is
 configured. The values are those of `jnp.stack` over the per-step leaves
 followed by `jnp.split(..., n, axis=1)` and a `device_put` a slice, bit for
 bit, dtypes canonicalised as `jnp.asarray` would.
+
+A reader that takes items and not trajectories (the replay service) gets them
+from `FlatRolloutStorage`: every leaf as `[T*E, ...]`, cut along that axis into
+`[T*E/n, ...]`, bit for bit the stack / reshape / `jnp.split(..., n, axis=0)`.
 """
 
 from __future__ import annotations
@@ -33,17 +37,20 @@ import jax.numpy as jnp
 import numpy as np
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _stack_and_cut(n: int, rows: List[List[jax.Array]]) -> List[List[jax.Array]]:
+def _cut(xp: Any, stacked: Any, n: int, flat: bool) -> List[Any]:
+    """A `[T, E, ...]` leaf as `n` equal slices: along the env axis, or, `flat`,
+    as `[T*E, ...]` along that one (with one learner device the array IS the
+    slice). `xp` is numpy for host rows and jax.numpy inside the program."""
+    if flat:
+        stacked = stacked.reshape((-1,) + stacked.shape[2:])
+    return xp.split(stacked, n, axis=0 if flat else 1) if n > 1 else [stacked]
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _stack_and_cut(n: int, flat: bool, rows: List[List[jax.Array]]) -> List[List[jax.Array]]:
     """`rows[leaf][t]` -> `[leaf][device]`: every leaf's steps stacked to
-    `[T, E, ...]` and cut along the env axis into `n` equal slices (with one
-    learner device the stacked array IS the slice)."""
-
-    def stack_and_cut(steps: List[jax.Array]) -> List[jax.Array]:
-        stacked = jnp.stack(steps)
-        return jnp.split(stacked, n, axis=1) if n > 1 else [stacked]
-
-    return [stack_and_cut(steps) for steps in rows]
+    `[T, E, ...]` and cut into the `n` learner devices' slices."""
+    return [_cut(jnp, jnp.stack(steps), n, flat) for steps in rows]
 
 
 def _send(rows: np.ndarray, device: jax.Device) -> jax.Array:
@@ -55,22 +62,11 @@ def _send(rows: np.ndarray, device: jax.Device) -> jax.Array:
     return jax.device_put(rows, device)
 
 
-def host_copy(stored: Any) -> Any:
-    """A copy on the host of (a subtree of) the second tree `finish` returns:
-    of the host rows themselves — a copy, because their set is written again —
-    or, for a device leaf, of the learner devices' slices."""
-
-    def to_host(leaf: Any) -> np.ndarray:
-        if isinstance(leaf, np.ndarray):
-            return leaf.copy()
-        return np.concatenate([np.asarray(s) for s in leaf], axis=1)
-
-    return jax.tree.map(to_host, stored, is_leaf=lambda x: isinstance(x, list))
-
-
 class RolloutStorage:
     """`add` a transition a step, `finish` after `rollout_length` of them.
     Owned and called by one actor thread."""
+
+    _flat = False  # slices are `[T, E/n, ...]`, cut along the env axis
 
     def __init__(self, rollout_length: int, learner_devices: Sequence[jax.Device]) -> None:
         self._length = int(rollout_length)
@@ -117,6 +113,7 @@ class RolloutStorage:
         is the list of the learner devices' `[T, E/n, ...]` arrays; a `stored`
         leaf is the host rows `[T, E, ...]` (a view of a set that is written
         again: see `host_copy`) or, for a device leaf, that same list."""
+        flat = self._flat
         n = len(self._devices)
         host = self._host[self._set]
         uneven = [i for i, steps in self._steps.items() if len(steps) != self._length or i in host]
@@ -132,11 +129,11 @@ class RolloutStorage:
             by_device.setdefault(steps[0].device, []).append(i)
         stacked: Dict[int, List[jax.Array]] = {}
         for indices in by_device.values():
-            cut = _stack_and_cut(n, [self._steps[i] for i in indices])
+            cut = _stack_and_cut(n, flat, [self._steps[i] for i in indices])
             for i, slices in zip(indices, cut):
                 stacked[i] = [jax.device_put(s, d) for s, d in zip(slices, self._devices)]
         sent = {
-            i: [_send(s, d) for s, d in zip(np.split(rows, n, axis=1), self._devices)]
+            i: [_send(s, d) for s, d in zip(_cut(np, rows, n, flat), self._devices)]
             for i, rows in host.items()
         }
         self._in_flight[self._set].extend(
@@ -152,3 +149,21 @@ class RolloutStorage:
             )
 
         return as_transition({**stacked, **sent}), as_transition({**stacked, **host})
+
+    def host_copy(self, stored: Any) -> Any:
+        """A copy on the host of (a subtree of) the second tree `finish`
+        returns: of the host rows themselves — a copy, because their set is
+        written again — or, for a device leaf, of the learner devices' slices."""
+
+        def to_host(leaf: Any) -> np.ndarray:
+            if isinstance(leaf, np.ndarray):
+                return leaf.copy()
+            return np.concatenate([np.asarray(s) for s in leaf], axis=0 if self._flat else 1)
+
+        return jax.tree.map(to_host, stored, is_leaf=lambda x: isinstance(x, list))
+
+
+class FlatRolloutStorage(RolloutStorage):
+    """The same rollout for a reader of items: slices are `[T*E/n, ...]`."""
+
+    _flat = True

@@ -128,7 +128,7 @@ def shardmap_learner(
             # JAX's varying-manual-axes machinery (collectives over vmap axes
             # nested in shard_map are outside what the validator models).
             # The Sebulba learners have no in-shard vmap axis and run with
-            # check_vma=True (systems/ppo/sebulba/ff_ppo.py); carry-leaf
+            # check_vma=True (sebulba/actor_critic.py::shard_learn_step); carry-leaf
             # varying-ness was fixed where real (wrappers._ensure_truncation).
             check_vma=False,
         ),

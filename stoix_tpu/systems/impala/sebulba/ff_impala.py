@@ -4,12 +4,15 @@ Off-policy actor-critic with V-trace corrections (Espeholt et al. 2018): the
 actor threads' stored log-probs are the behavior policy; the learner computes
 V-trace value targets and policy-gradient advantages
 (stoix_tpu.ops.multistep.vtrace_td_error_and_advantage, replacing the
-reference's rlax vmap at :426-439) in one pass per rollout. Shares the Sebulba
-scaffolding (threads/pipeline/param-server/async-eval) with sebulba ff_ppo.
+reference's rlax vmap at :426-439) in one pass per rollout. The host loop is
+the Sebulba runner's (stoix_tpu/sebulba/runner.py); learner state, networks
+and the actors' `act_fn` are the shared actor-critic ones
+(stoix_tpu/sebulba/actor_critic.py).
 """
 
 from __future__ import annotations
 
+import sys
 from typing import Any
 
 import jax
@@ -19,9 +22,18 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from stoix_tpu.base_types import ActorCriticOptStates, ActorCriticParams, PPOTransition
 from stoix_tpu.observability import annotate
-from stoix_tpu.ops import running_statistics, vtrace_td_error_and_advantage
+from stoix_tpu.ops import vtrace_td_error_and_advantage
 from stoix_tpu.resilience import guards
-from stoix_tpu.systems.ppo.sebulba.ff_ppo import CoreLearnerState, run_experiment as _run
+from stoix_tpu.sebulba import runner
+from stoix_tpu.sebulba.actor_critic import (
+    CoreLearnerState,
+    actor_critic_system,
+    build_networks,
+    normalize_trajectory,
+    shard_learn_step,
+)
+from stoix_tpu.sebulba.runner import LAST_RUN_STATS  # noqa: F401 — read through this module
+from stoix_tpu.sebulba.sources import OnPolicySource
 from stoix_tpu.utils import config as config_lib
 
 
@@ -110,21 +122,7 @@ def get_impala_learn_step(actor_apply, critic_apply, update_fns, config, mesh: M
     impala_loss = build_impala_loss(actor_apply, critic_apply, config)
 
     def per_shard(state: CoreLearnerState, traj: PPOTransition):
-        # Match the actor path: observations the behavior policy consumed were
-        # normalized with these (pre-update) statistics; fold the raw batch in
-        # afterwards so the stats keep advancing.
-        obs_stats = state.obs_stats
-        if normalize_obs:
-            raw_obs = traj.obs
-            traj = traj._replace(
-                obs=running_statistics.normalize_observation(traj.obs, obs_stats),
-                next_obs=running_statistics.normalize_observation(traj.next_obs, obs_stats),
-            )
-            obs_stats = running_statistics.update(
-                obs_stats, raw_obs.agent_view, axis_names=("data",),
-                std_min_value=5e-4, std_max_value=5e4,
-            )
-
+        traj, obs_stats = normalize_trajectory(traj, state.obs_stats, normalize_obs)
         traj = maybe_normalize_rewards(traj, config)
 
         def loss_fn(params: ActorCriticParams, mb: PPOTransition):
@@ -171,27 +169,19 @@ def get_impala_learn_step(actor_apply, critic_apply, update_fns, config, mesh: M
         }
         return CoreLearnerState(params, opt_states, state.key, obs_stats), metrics
 
-    return jax.jit(
-        jax.shard_map(
-            per_shard,
-            mesh=mesh,
-            in_specs=(CoreLearnerState(P(), P(), P(), P()), P(None, "data")),
-            out_specs=(CoreLearnerState(P(), P(), P(), P()), P()),
-            # No in-shard vmap axis here, so the varying-manual-axes
-            # validator runs (Anakin's pmean-over-vmap-axis limitation
-            # does not apply — see systems/anakin.py).
-            check_vma=True,
-        )
-    )
+    return shard_learn_step(per_shard, mesh, P(None, "data"))
 
 
 def run_experiment(config: Any) -> float:
-    return _run(config, learn_step_builder=get_impala_learn_step)
+    def networks(config: Any, probe_envs: Any):
+        return build_networks(config, probe_envs.num_actions, None, env=probe_envs)
+
+    return runner.run_experiment(
+        config, actor_critic_system(networks, get_impala_learn_step, OnPolicySource)
+    )
 
 
 def main() -> float:
-    import sys
-
     config = config_lib.compose(
         config_lib.default_config_dir(),
         "default/sebulba/default_ff_impala.yaml",

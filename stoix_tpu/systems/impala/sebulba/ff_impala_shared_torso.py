@@ -9,6 +9,7 @@ once through the actor optimizer (the critic optimizer sees an empty tree).
 
 from __future__ import annotations
 
+import sys
 from typing import Any
 
 import flax.linen as nn
@@ -18,8 +19,15 @@ import optax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from stoix_tpu.base_types import ActorCriticOptStates, ActorCriticParams, PPOTransition
-from stoix_tpu.ops import running_statistics
-from stoix_tpu.systems.ppo.sebulba.ff_ppo import CoreLearnerState, run_experiment as _run
+from stoix_tpu.sebulba import runner
+from stoix_tpu.sebulba.actor_critic import (
+    CoreLearnerState,
+    actor_critic_system,
+    normalize_trajectory,
+    shard_learn_step,
+)
+from stoix_tpu.sebulba.runner import LAST_RUN_STATS  # noqa: F401 — read through this module
+from stoix_tpu.sebulba.sources import OnPolicySource
 from stoix_tpu.utils import config as config_lib
 
 
@@ -66,21 +74,7 @@ def get_shared_impala_learn_step(actor_apply, critic_apply, update_fns, config, 
     impala_loss = build_impala_loss(actor_apply, critic_apply, config)
 
     def per_shard(state: CoreLearnerState, traj: PPOTransition):
-        # Match the actor path: observations the behavior policy consumed were
-        # normalized with these (pre-update) statistics; fold the raw batch in
-        # afterwards so the stats keep advancing.
-        obs_stats = state.obs_stats
-        if normalize_obs:
-            raw_obs = traj.obs
-            traj = traj._replace(
-                obs=running_statistics.normalize_observation(traj.obs, obs_stats),
-                next_obs=running_statistics.normalize_observation(traj.next_obs, obs_stats),
-            )
-            obs_stats = running_statistics.update(
-                obs_stats, raw_obs.agent_view, axis_names=("data",),
-                std_min_value=5e-4, std_max_value=5e4,
-            )
-
+        traj, obs_stats = normalize_trajectory(traj, state.obs_stats, normalize_obs)
         traj = maybe_normalize_rewards(traj, config)
 
         def loss_fn(shared_params, mb: PPOTransition):
@@ -105,31 +99,19 @@ def get_shared_impala_learn_step(actor_apply, critic_apply, update_fns, config, 
         new_opts = ActorCriticOptStates(a_opt, state.opt_states.critic_opt_state)
         return CoreLearnerState(params, new_opts, state.key, obs_stats), metrics
 
-    return jax.jit(
-        jax.shard_map(
-            per_shard,
-            mesh=mesh,
-            in_specs=(CoreLearnerState(P(), P(), P(), P()), P(None, "data")),
-            out_specs=(CoreLearnerState(P(), P(), P(), P()), P()),
-            # No in-shard vmap axis here, so the varying-manual-axes
-            # validator runs (Anakin's pmean-over-vmap-axis limitation
-            # does not apply — see systems/anakin.py).
-            check_vma=True,
-        )
-    )
+    return shard_learn_step(per_shard, mesh, P(None, "data"))
 
 
 def run_experiment(config: Any) -> float:
-    return _run(
-        config,
-        learn_step_builder=get_shared_impala_learn_step,
-        networks_builder=build_shared_networks,
+    def networks(config: Any, probe_envs: Any):
+        return build_shared_networks(config, probe_envs.num_actions, None)
+
+    return runner.run_experiment(
+        config, actor_critic_system(networks, get_shared_impala_learn_step, OnPolicySource)
     )
 
 
 def main() -> float:
-    import sys
-
     config = config_lib.compose(
         config_lib.default_config_dir(),
         "default/sebulba/default_ff_impala_shared_torso.yaml",

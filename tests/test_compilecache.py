@@ -2,9 +2,8 @@
 
 The core acceptance is cross-PROCESS: two cold subprocesses run the same tiny
 jitted program against one cache dir named by JAX_COMPILATION_CACHE_DIR on
-CPU — the second must record persistent-cache hits and spend less wall time
-compiling, and a corrupted cache entry must degrade to a recompile, never a
-crash. Where the cache lives is decided in one place: the variable when set
+CPU — the second must record persistent-cache hits and no miss, and a
+corrupted cache entry must degrade to a recompile, never a crash. Where the cache lives is decided in one place: the variable when set
 (and then the program never writes `jax_compilation_cache_dir` itself), else
 one fixed path inside the checkout.
 """
@@ -87,15 +86,16 @@ def test_persistent_cache_roundtrip_across_cold_processes(tmp_path):
     assert first["hits"] == 0 and first["misses"] >= 1, first
     entries = [p for p in os.listdir(cache_dir) if p.endswith("-cache")]
     assert entries, "first run wrote no cache entries"
-    # With the variable set the entries land there and nowhere else.
-    assert _checkout_cache_listing() == checkout_before
+    # With the variable set the entries land there and nowhere else: none of
+    # them is new in the checkout's own cache (which the suite's other
+    # workers may be writing to meanwhile).
+    assert not set(entries) & (set(_checkout_cache_listing()) - set(checkout_before))
 
+    # Every entry the first process wrote is found again: hits, and nothing
+    # compiled anew. (Not a comparison of the two processes' compile seconds:
+    # under the suite's workers that is a comparison of their neighbours.)
     second = _run_child(cache_dir)
-    assert second["hits"] >= 1, second
-    assert second["compile_s"] < first["compile_s"], (
-        f"cache hit did not reduce compile seconds: "
-        f"{first['compile_s']:.3f}s -> {second['compile_s']:.3f}s"
-    )
+    assert second["hits"] >= 1 and second["misses"] == 0, second
 
     # Corruption degrades to a recompile (jax_raise_persistent_cache_errors
     # stays False), not a crash: garbage every entry and run again.

@@ -107,8 +107,8 @@ def test_param_server_versions_are_monotone_and_back_compat(devices):
     got = server.get_params_versioned(0, timeout=2.0)
     assert isinstance(got, VersionedParams)
     assert got.version == 1
-    # Back-compat contract: get_params strips the version.
-    assert server.get_params(1, timeout=2.0)["w"].shape == (2,)
+    # Both actors on the device are fed the same version.
+    assert server.get_params_versioned(1, timeout=2.0).params["w"].shape == (2,)
 
     server.distribute_params({"w": jnp.zeros((2,), jnp.float32)})
     assert server.version == 2
@@ -190,7 +190,7 @@ class _ScriptedPipe:
 
 
 def _settings(**over):
-    from stoix_tpu.systems.ppo.sebulba.ff_ppo import ImpactSettings
+    from stoix_tpu.sebulba.sources import ImpactSettings
 
     base = dict(
         target_update_interval=1, rho_clip=2.0, max_staleness=3, max_reuse=2,
@@ -205,7 +205,7 @@ def _assemble(payloads):
 
 
 def test_impact_ingest_reuses_stale_when_fresh_is_late():
-    from stoix_tpu.systems.ppo.sebulba.ff_ppo import ImpactIngest
+    from stoix_tpu.sebulba.sources import ImpactIngest
 
     pipe = _ScriptedPipe(
         [
@@ -238,7 +238,7 @@ def test_impact_ingest_reuses_stale_when_fresh_is_late():
 
 
 def test_impact_ingest_drops_overstale_buffered_batches():
-    from stoix_tpu.systems.ppo.sebulba.ff_ppo import ImpactIngest
+    from stoix_tpu.sebulba.sources import ImpactIngest
 
     dropped = get_registry().counter("stoix_tpu_impact_dropped_batches_total")
     before = dropped.value()
@@ -265,7 +265,7 @@ def test_impact_ingest_mixed_actor_payloads_form_full_set():
     """Any `need` payloads tile to the full batch shape — two payloads from
     the SAME healthy actor are a valid fresh set (this is what keeps the
     learner fed while another actor is wedged)."""
-    from stoix_tpu.systems.ppo.sebulba.ff_ppo import ImpactIngest
+    from stoix_tpu.sebulba.sources import ImpactIngest
 
     pipe = _ScriptedPipe([[(1, (2, "b0")), (1, (3, "b1"))]])
     ingest = ImpactIngest(pipe, need=2, settings=_settings())

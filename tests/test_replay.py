@@ -281,22 +281,70 @@ def _sebulba_config(extra):
     )
 
 
-def test_sebulba_dqn_trains_and_actor_crash_never_deadlocks(devices, monkeypatch):
-    """ONE end-to-end drive covering both acceptance criteria: ff_dqn trains
+@pytest.fixture(scope="module")
+def sebulba_dqn_run(devices):
+    """ONE end-to-end drive of Sebulba ff_dqn with an actor crash injected
+    mid-run: its return, LAST_RUN_STATS and the MISC events it logged."""
+    from stoix_tpu.systems.q_learning.sebulba import ff_dqn
+    from stoix_tpu.utils.logger import LogEvent, StoixLogger
+
+    misc = {}
+    original = StoixLogger.log
+
+    def log(self, metrics, t, t_eval, event):
+        if event == LogEvent.MISC:
+            misc.update(metrics)
+        return original(self, metrics, t, t_eval, event)
+
+    patch = pytest.MonkeyPatch()
+    patch.setenv("STOIX_TPU_FAULT", "actor_crash:2")
+    patch.setattr(StoixLogger, "log", log)
+    try:
+        ret = ff_dqn.run_experiment(_sebulba_config([]))
+    finally:
+        patch.undo()
+    return {"return": ret, "stats": dict(ff_dqn.LAST_RUN_STATS), "misc": misc}
+
+
+def test_sebulba_dqn_trains_and_actor_crash_never_deadlocks(sebulba_dqn_run):
+    """Both acceptance criteria on the one drive: ff_dqn trains
     through the OffPolicyPipeline + sharded replay service (replay ledger
     populated), AND an injected actor crash mid-run is supervised-restarted
     while the SAMPLING learner keeps going — no lockstep collect to
     deadlock on."""
-    from stoix_tpu.systems.q_learning.sebulba import ff_dqn
-
-    monkeypatch.setenv("STOIX_TPU_FAULT", "actor_crash:2")
-    ret = ff_dqn.run_experiment(_sebulba_config([]))
-    assert np.isfinite(ret)
-    stats = dict(ff_dqn.LAST_RUN_STATS)
+    assert np.isfinite(sebulba_dqn_run["return"])
+    stats = sebulba_dqn_run["stats"]
     assert stats["replay"]["added_items"] > 0
     assert stats["replay"]["sampled_items"] > 0
     assert stats["replay"]["sampled_bytes_crossed"] > 0
     assert stats["resilience"]["actor_restarts"] >= 1
+
+
+@pytest.mark.parametrize(
+    "what", ["setup_phases", "actor_step_timers", "prepare_data", "learner_timers", "stats"]
+)
+def test_sebulba_dqn_reports_what_the_one_runner_reports(sebulba_dqn_run, what):
+    """The DQN system runs the Sebulba runner's loop (sebulba/runner.py), so
+    it reports what PPO does: the set-up phases, the span-fed per-step actor
+    timers, the hand-off's percentiles, and the whole-run stats."""
+    stats, misc = sebulba_dqn_run["stats"], sebulba_dqn_run["misc"]
+    if what == "setup_phases":
+        assert set(stats["setup_phases"]) == {
+            "env_build", "network_init", "learner_setup", "evaluator_setup", "first_tick"
+        }
+        assert all(seconds > 0.0 for seconds in stats["setup_phases"].values())
+    elif what == "actor_step_timers":
+        step = misc["actor0_rollout_time"] / 8
+        split = misc["actor0_inference_time"] + misc["actor0_env_step_time"]
+        assert 0.0 < split <= step * 1.001, (split, step)
+    elif what == "prepare_data":
+        assert misc["actor0_prepare_data_p50"] > 0.0 and "actor0_queue_put_time" in misc
+    elif what == "learner_timers":
+        assert {"learner_ingest_time", "learner_learn_time", "learner_learn_p50"} <= set(misc)
+    else:
+        assert stats["resilience"]["fleet"] is False and stats["integrity"]["enabled"] is False
+        assert stats["total_env_steps"] == stats["replay"]["added_items"] > 0
+        assert stats["fps"] > 0.0 and "impact" not in stats
 
 
 @pytest.mark.slow

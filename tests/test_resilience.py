@@ -462,12 +462,12 @@ def test_pipeline_poison_pill_and_param_server_units():
     server = ParameterServer(jax.devices("cpu")[:1], 1)
     assert server.reprime(0) is False  # nothing distributed yet
     server.distribute_params({"w": np.ones(2)})
-    assert server.get_params(0, timeout=1.0)["w"].shape == (2,)
+    assert server.get_params_versioned(0, timeout=1.0).params["w"].shape == (2,)
     assert server.reprime(0) is True  # replacement actor gets latest params
-    assert server.get_params(0, timeout=1.0)["w"].shape == (2,)
+    assert server.get_params_versioned(0, timeout=1.0).params["w"].shape == (2,)
     server.fail(ComponentFailure("actor-0", "wedged (unit test)"), actor_id=0)
     with pytest.raises(ComponentFailure, match="actor-0"):
-        server.get_params(0, timeout=1.0)
+        server.get_params_versioned(0, timeout=1.0)
 
 
 def test_async_evaluator_stall_raises_named_error():

@@ -6,6 +6,7 @@ pure-JAX env twin, for one and two learner devices; the two sets of host
 rows never alias a payload in flight; nothing compiles after the second
 rollout."""
 
+import functools
 import queue
 
 import jax
@@ -16,7 +17,9 @@ import pytest
 from stoix_tpu.base_types import ActorCriticParams, PPOTransition
 from stoix_tpu.envs.factory import make_factory
 from stoix_tpu.envs.types import Observation, TimeStep
-from stoix_tpu.sebulba.core import OnPolicyPipeline, ParameterServer, ThreadLifetime
+from stoix_tpu.sebulba import actor_critic, runner
+from stoix_tpu.sebulba.core import ParameterServer, ThreadLifetime
+from stoix_tpu.sebulba.sources import OnPolicySource, SourceContext
 from stoix_tpu.systems.ppo.sebulba import ff_ppo
 from stoix_tpu.utils import config as config_lib
 from stoix_tpu.utils.timing import TimingTracker
@@ -113,7 +116,7 @@ class _EveryRolloutParams:
     def __init__(self, bundle):
         self._bundle, self.version = bundle, 0
 
-    def get_params_versioned(self, actor_id):
+    def get_params_versioned(self, actor_id, timeout=None):
         self.version += 1
         return self.version, self._bundle
 
@@ -190,29 +193,34 @@ def _run_actor(backend, n_learners, rollouts, pipeline=None):
         recorded.append(_Recording(env, lifetime, rollouts))
         return recorded[-1]
 
-    if pipeline is None:
-        pipeline = OnPolicyPipeline(num_actors=1)
+    mesh = jax.sharding.Mesh(np.asarray(learner_devices), ("data",))
+    source = OnPolicySource(
+        SourceContext(1, learner_devices, mesh, None, None, None, ENVS * LENGTH)
+    )
+    if pipeline is not None:
+        source.pipeline = pipeline
     bundle = jax.device_put((params, None), actor_device)
     if rollouts == 1:
         params_source = ParameterServer([actor_device], 1)
         params_source.distribute_params((params, None))
     else:
         params_source = _EveryRolloutParams(bundle)
-    mesh = jax.sharding.Mesh(np.asarray(learner_devices), ("data",))
-    ff_ppo._rollout_body(
-        0, actor_device, factory, actor.apply, critic.apply, config, pipeline, params_source,
-        learner_devices, mesh, lifetime, SEED, sink, ENVS, LENGTH, timer,
+    make_act_fn = functools.partial(ff_ppo.get_act_fn, actor.apply, critic.apply, False)
+    runner._rollout_body(
+        0, actor_device, factory, make_act_fn, actor_critic.transition, source, params_source,
+        learner_devices, lifetime, SEED, sink, ENVS, LENGTH, timer,
     )
     expected = _parent_payloads(
         recorded[0].timesteps, actor, critic, bundle, actor_device, learner_devices
     )
     return {
-        "pipeline": pipeline, "sink": sink, "expected": expected,
+        "pipeline": source.pipeline, "sink": sink, "expected": expected,
         "learner_devices": learner_devices, "timesteps": recorded[0].timesteps,
     }
 
 
-def _assert_same_payload(payload, expected, learner_devices):
+def _assert_same_payload(payload, expected, learner_devices, lead=None):
+    lead = lead or (LENGTH, ENVS // len(learner_devices))
     is_shards = lambda x: isinstance(x, list)  # noqa: E731
     got_leaves, got_def = jax.tree.flatten(payload, is_leaf=is_shards)
     want_leaves, want_def = jax.tree.flatten(expected, is_leaf=is_shards)
@@ -225,7 +233,7 @@ def _assert_same_payload(payload, expected, learner_devices):
         for g, w, device in zip(got, want, learner_devices):
             assert isinstance(g, jax.Array) and g.devices() == w.devices() == {device}, path
             assert (g.shape, g.dtype) == (w.shape, w.dtype), (path, g.shape, g.dtype)
-            assert g.shape[:2] == (LENGTH, ENVS // len(learner_devices)), path
+            assert g.shape[:len(lead)] == lead, path
             assert np.asarray(g).tobytes() == np.asarray(w).tobytes(), path
 
 
@@ -331,3 +339,96 @@ def test_storage_refuses_a_short_rollout_and_a_leaf_that_changes_sides(devices):
     storage.add({"a": jnp.zeros(4, jnp.float32)})
     with pytest.raises(ValueError, match="at some steps"):
         storage.finish()
+
+
+def _run_dqn_actor(backend, n_learners):
+    """One rollout of a Sebulba DQN actor through `_rollout_body` and its
+    `ReplaySource`, beside what the parent's actor built from the same env
+    outputs: the per-step list stacked, `[T, E]` flattened to `[T*E]`, split
+    along that axis and `device_put` a slice (the parent's three lines, kept
+    here as the reference)."""
+    from stoix_tpu.base_types import Transition
+    from stoix_tpu.sebulba.sources import ReplaySource
+    from stoix_tpu.systems.q_learning.q_family import build_q_network
+    from stoix_tpu.systems.q_learning.sebulba import ff_dqn
+
+    devices = jax.devices()
+    actor_device, learner_devices = devices[3], devices[1:1 + n_learners]
+    config = config_lib.compose(
+        config_lib.default_config_dir(), "default/sebulba/default_ff_dqn.yaml",
+        ["env=cartpole", f"env.backend={backend}", "logger.use_console=False"],
+    )
+    pool = make_factory(config)(1)
+    q_network = build_q_network(config, pool.num_actions)
+    obs0 = jax.tree.map(jnp.asarray, pool.reset(seed=0).observation)
+    params = q_network.init(jax.random.PRNGKey(0), obs0)
+    make_act_fn = functools.partial(ff_dqn.get_act_fn, q_network.apply, 0.3)
+    lifetime, sink, recorded = ThreadLifetime(), queue.Queue(), []
+
+    def factory(num_envs):
+        env = _StubPool(num_envs, SEED) if backend == "cvec" else make_factory(config)(num_envs)
+        recorded.append(_Recording(env, lifetime, 1))
+        return recorded[-1]
+
+    class _Service:
+        def stats(self):
+            return {}
+
+    mesh = jax.sharding.Mesh(np.asarray(learner_devices), ("data",))
+    source = ReplaySource(
+        SourceContext(1, learner_devices, mesh, None, None, None, ENVS * LENGTH),
+        service=_Service(), epochs=1, param_sync_interval=1,
+    )
+    server = ParameterServer([actor_device], 1)
+    server.distribute_params(params)
+    runner._rollout_body(
+        0, actor_device, factory, make_act_fn, ff_dqn.transition, source, server,
+        learner_devices, lifetime, SEED, sink, ENVS, LENGTH, TimingTracker(),
+    )
+
+    act_fn, traj = make_act_fn(), []
+    placed = jax.device_put(params, actor_device)
+    with jax.default_device(actor_device):
+        key = jax.random.PRNGKey(SEED)
+        timesteps = recorded[0].timesteps
+        for timestep, next_timestep in zip(timesteps[:-1], timesteps[1:]):
+            key, act_key = jax.random.split(key)
+            obs_local = jax.device_put(timestep.observation, actor_device)
+            (action,) = act_fn(placed, obs_local, act_key)
+            traj.append(Transition(
+                obs=obs_local, action=action, reward=next_timestep.reward,
+                done=next_timestep.discount == 0.0, next_obs=next_timestep.extras["next_obs"],
+                info={},
+            ))
+        stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *traj)
+        flat = jax.tree.map(lambda x: x.reshape((-1,) + x.shape[2:]), stacked)
+        expected = jax.tree.map(
+            lambda x: [
+                jax.device_put(s, d)
+                for s, d in zip(jnp.split(x, n_learners, axis=0), learner_devices)
+            ],
+            flat,
+        )
+    return source.pipeline, expected, learner_devices, sink, timesteps[1:]
+
+
+@pytest.mark.parametrize(
+    "backend,n_learners", [("cvec", 1), ("cvec", 2), ("jax", 1), ("jax", 2)],
+    ids=lambda p: {"cvec": "host_pool", "jax": "jax_twin"}.get(p, f"{p}_learner"),
+)
+def test_dqn_payload_is_the_parents_stack_reshape_split_device_put(devices, backend, n_learners):
+    pipeline, expected, learner_devices, sink, steps = _run_dqn_actor(backend, n_learners)
+    (actor_id, payload), = pipeline.poll(timeout=5.0)
+    assert actor_id == 0 and payload.info == {}
+    _assert_same_payload(
+        payload, expected, learner_devices, lead=(LENGTH * ENVS // n_learners,)
+    )
+    # Episode metrics go to the sink, whole and in the env's order, and the
+    # span-fed per-step timers with them.
+    message = sink.get_nowait()
+    for name, got in message["episode_metrics"].items():
+        want = np.stack([np.asarray(s.extras["episode_metrics"][name]) for s in steps])
+        assert got.dtype == want.dtype and np.array_equal(got.reshape(-1), want.reshape(-1)), name
+    assert {"actor0_inference_time", "actor0_env_step_time", "actor0_prepare_data_time"} <= set(
+        message["timings"]
+    )

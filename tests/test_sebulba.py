@@ -185,7 +185,7 @@ def test_param_server_places_once_per_device_and_reprime_reuses(devices):
     server.distribute_params({"w": jnp.ones((4,), jnp.float32)})
     assert transfers() - before == 2, "one device_put per device, not per actor"
 
-    got = [server.get_params(actor_id, timeout=2.0) for actor_id in range(6)]
+    got = [server.get_params_versioned(actor_id, timeout=2.0).params for actor_id in range(6)]
     # Actors 0-2 share dev_a and must hold the SAME placed copy (identity,
     # not equality); likewise 3-5 on dev_b.
     assert got[0] is got[1] is got[2]
@@ -196,7 +196,7 @@ def test_param_server_places_once_per_device_and_reprime_reuses(devices):
     before = transfers()
     assert server.reprime(2)
     assert transfers() == before
-    assert server.get_params(2, timeout=2.0) is got[0]
+    assert server.get_params_versioned(2, timeout=2.0).params is got[0]
 
 
 def test_native_pool_is_built_from_source_by_content_hash(tmp_path, monkeypatch):

@@ -175,9 +175,12 @@ def test_actor_env_step_timer_is_given_host_actions(devices):
     """`inference` ends once the action is on the host and `env_step` times
     the pool alone: a stub pool sees numpy, never a jax Array — and the
     payload it sends is tagged with the params version it acted with."""
+    import functools
+
     from stoix_tpu.envs.factory import make_factory
-    from stoix_tpu.sebulba.core import OnPolicyPipeline, ParameterServer, ThreadLifetime
-    from stoix_tpu.systems.ppo.sebulba import ff_ppo
+    from stoix_tpu.sebulba import actor_critic, runner
+    from stoix_tpu.sebulba.core import ParameterServer, ThreadLifetime
+    from stoix_tpu.sebulba.sources import OnPolicySource, SourceContext
     from stoix_tpu.utils.timing import TimingTracker
 
     config = _sebulba_config()
@@ -192,14 +195,16 @@ def test_actor_env_step_timer_is_given_host_actions(devices):
         pools.append(_AssertingPool(factory(num_envs), lifetime, steps=8))
         return pools[-1]
 
-    pipeline = OnPolicyPipeline(num_actors=1)
+    mesh = jax.sharding.Mesh(np.asarray([device]), ("data",))
+    source = OnPolicySource(SourceContext(1, [device], mesh, None, None, None, 64))
+    pipeline = source.pipeline
     server = ParameterServer([device], 1)
     server.distribute_params((params, None))
     timer, sink = TimingTracker(), __import__("queue").Queue()
-    mesh = jax.sharding.Mesh(np.asarray([device]), ("data",))
-    ff_ppo._rollout_body(
-        0, device, stub_factory, actor.apply, critic.apply, config, pipeline, server,
-        [device], mesh, lifetime, 7, sink, 8, 8, timer,
+    runner._rollout_body(
+        0, device, stub_factory,
+        functools.partial(actor_critic.get_act_fn, actor.apply, critic.apply, False),
+        actor_critic.transition, source, server, [device], lifetime, 7, sink, 8, 8, timer,
     )
     assert len(pools[0].seen) == 8 and set(pools[0].seen) == {np.ndarray}
     (version, payload), = pipeline.collect_rollouts(timeout=5.0)
