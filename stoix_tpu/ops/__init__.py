@@ -19,7 +19,7 @@ from stoix_tpu.ops import (
     scan_kernels,
     value_transforms,
 )
-from stoix_tpu.ops.distributions import Distribution, EpsilonGreedy
+from stoix_tpu.ops.distributions import Distribution, EpsilonGreedy, pick_along_last
 from stoix_tpu.ops.losses import categorical_l2_project
 from stoix_tpu.ops.minibatch import shuffled_minibatch_epoch
 from stoix_tpu.ops.multistep import (
@@ -113,4 +113,6 @@ __all__ = [
     # distributions commonly referenced by name
     "Distribution",
     "EpsilonGreedy",
+    # x[..., index] along the last axis: by select where it is narrow
+    "pick_along_last",
 ]

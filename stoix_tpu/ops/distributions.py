@@ -22,6 +22,47 @@ import jax.numpy as jnp
 
 _HALF_LOG_2PI = 0.5 * math.log(2.0 * math.pi)
 
+# Widest last axis that `pick_along_last` picks from by select; above it, by
+# gather. From the chip (TPU v5e, PR 30, PERF.md §6): forward + gradient of one
+# pick from normalised logits, gather against select, in ns a pick:
+#   W        2     18    128    512   2,048   50,304
+#   gather  11.2   15.5   19.4   29.0   73.7   1,603
+#   select   0.3    0.7    5.3   14.4   57.6   1,415
+# and of the pick alone 11.0 / 18.3 / 14.5 / 16.0 / 25.4 / 302 against
+# 0.2 / 0.3 / 1.7 / 6.3 / 25.1 / 612: alone the forms cross at about 2,048,
+# so the constant is the widest width measured at which the select still wins
+# by half in both settings.
+PICK_SELECT_MAX_WIDTH = 512
+
+
+def pick_along_last(x: jax.Array, index: jax.Array) -> jax.Array:
+    """`x[..., index]`: the entry of the last axis of `x` [..., W] that `index`
+    [...] names, i.e. `take_along_axis(x, index[..., None], -1)[..., 0]`.
+
+    `index` must lie in `[0, W)`: every caller passes an action that the same
+    head sampled or a buffer stored. (Outside it `take_along_axis` wraps a
+    negative index and fills NaN; the select gives 0. Neither is emulated.)
+
+    The form is chosen by the static width W. XLA:TPU runs a gather of single
+    elements as a serial loop, 11 ns a pick and more, whatever W is; up to
+    `PICK_SELECT_MAX_WIDTH` the pick is therefore a masked sum over the row,
+    elementwise work that fuses into its neighbours and shows in a device trace
+    under `pick_select`. `where`, not a product with a one-hot: a masked logit
+    holds `finfo.min`, and an unpicked NaN or inf must not reach the sum. The
+    sum has one non-zero term, so value and gradient are the gather's and its
+    scatter's bit for bit (but a picked -0.0 comes back +0.0). Above the
+    threshold the gather stays exactly as it was: on its own a wide row costs
+    more to read than a pick costs to loop over.
+    """
+    index = jnp.asarray(index, jnp.int32)
+    if x.shape[-1] > PICK_SELECT_MAX_WIDTH:
+        return jnp.take_along_axis(x, index[..., None], axis=-1)[..., 0]
+    if index.ndim != x.ndim - 1:
+        raise ValueError(f"index of shape {index.shape} picks from no x of shape {x.shape}")
+    with jax.named_scope("pick_select"):
+        iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+        return jnp.sum(jnp.where(iota == index[..., None], x, 0), axis=-1)
+
 
 class Distribution:
     """Minimal distribution interface."""
@@ -74,8 +115,7 @@ class Categorical(Distribution):
         return jax.random.categorical(seed, self.logits, axis=-1)
 
     def log_prob(self, value: jax.Array) -> jax.Array:
-        value = jnp.asarray(value, jnp.int32)
-        return jnp.take_along_axis(self.logits, value[..., None], axis=-1)[..., 0]
+        return pick_along_last(self.logits, value)
 
     def entropy(self) -> jax.Array:
         p = self.probs

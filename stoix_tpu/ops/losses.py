@@ -17,6 +17,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from stoix_tpu.ops.distributions import pick_along_last
+
 Array = jax.Array
 
 
@@ -129,7 +131,7 @@ def q_learning(
 ) -> Array:
     """One-step Q-learning: target r + γ max_a Q(s', a)."""
     target = r_t + d_t * jnp.max(q_t, axis=-1)
-    qa_tm1 = jnp.take_along_axis(q_tm1, a_tm1[..., None], axis=-1)[..., 0]
+    qa_tm1 = pick_along_last(q_tm1, a_tm1)
     td = jax.lax.stop_gradient(target) - qa_tm1
     return jnp.mean(huber_loss(td, huber_delta) if use_huber else 0.5 * td**2)
 
@@ -146,8 +148,8 @@ def double_q_learning(
 ) -> Array:
     """Double Q-learning: online net selects, target net evaluates."""
     best_a = jnp.argmax(q_t_selector, axis=-1)
-    target = r_t + d_t * jnp.take_along_axis(q_t_value, best_a[..., None], axis=-1)[..., 0]
-    qa_tm1 = jnp.take_along_axis(q_tm1, a_tm1[..., None], axis=-1)[..., 0]
+    target = r_t + d_t * pick_along_last(q_t_value, best_a)
+    qa_tm1 = pick_along_last(q_tm1, a_tm1)
     td = jax.lax.stop_gradient(target) - qa_tm1
     return jnp.mean(huber_loss(td, huber_delta) if use_huber else 0.5 * td**2)
 
@@ -180,11 +182,11 @@ def munchausen_q_learning(
 
     # Munchausen bonus: alpha * tau * log pi(a_tm1 | s_tm1), clipped.
     log_pi_tm1 = jax.nn.log_softmax(q_tm1_target / tau, axis=-1)
-    red_term = jnp.take_along_axis(log_pi_tm1, a_tm1[..., None], axis=-1)[..., 0]
+    red_term = pick_along_last(log_pi_tm1, a_tm1)
     munchausen = munchausen_coefficient * tau * jnp.clip(red_term, clip_value_min, 0.0)
 
     target = r_t + munchausen + d_t * soft_v_t
-    qa_tm1 = jnp.take_along_axis(q_tm1, a_tm1[..., None], axis=-1)[..., 0]
+    qa_tm1 = pick_along_last(q_tm1, a_tm1)
     td = jax.lax.stop_gradient(target) - qa_tm1
     return jnp.mean(0.5 * td**2)
 

@@ -13,6 +13,8 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from stoix_tpu.ops.distributions import pick_along_last
+
 Array = jax.Array
 
 
@@ -60,12 +62,12 @@ def transformed_n_step_q_learning_td(
     """
     from stoix_tpu.ops.multistep import n_step_bootstrapped_returns
 
-    v_t = tx_pair.apply_inv(jnp.take_along_axis(target_q_t, a_t[:, None], axis=-1)[:, 0])
+    v_t = tx_pair.apply_inv(pick_along_last(target_q_t, a_t))
     targets = n_step_bootstrapped_returns(
         r_t[None], discount_t[None], v_t[1:][None], n=n, batch_major=True
     )[0]
     targets = tx_pair.apply(targets)
-    qa_tm1 = jnp.take_along_axis(q_tm1, a_tm1[:, None], axis=-1)[:, 0]
+    qa_tm1 = pick_along_last(q_tm1, a_tm1)
     return jax.lax.stop_gradient(targets) - qa_tm1[:-1]
 
 

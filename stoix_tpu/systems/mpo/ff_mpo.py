@@ -94,7 +94,7 @@ def get_learner_fn(env, networks, update_fns, buffer, config, continuous: bool):
         if continuous:
             return q_network.apply(q_params, obs, action)
         q_all = q_network.apply(q_params, obs, 0.0).preferences
-        return jnp.take_along_axis(q_all, action[..., None], axis=-1)[..., 0]
+        return dists.pick_along_last(q_all, action)
 
     def _critic_loss_fn(q_online, params: MPOParams, seq, key):
         # Retrace targets over the sampled sequences [B, L].

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from stoix_tpu.base_types import Transition
+from stoix_tpu.ops import pick_along_last
 from stoix_tpu.systems.q_learning.q_family import run_q_experiment
 from stoix_tpu.utils import config as config_lib
 
@@ -18,7 +19,7 @@ def dqn_reg_loss(online_params: Any, target_params: Any, batch: Transition, q_ap
     q_tm1 = q_apply(online_params, batch.obs, 0.0).preferences
     q_t = q_apply(target_params, batch.next_obs, 0.0).preferences
     d_t = float(config.system.gamma) * (1.0 - batch.done.astype(jnp.float32))
-    qa_tm1 = jnp.take_along_axis(q_tm1, batch.action[..., None], axis=-1)[..., 0]
+    qa_tm1 = pick_along_last(q_tm1, batch.action)
     target = jax.lax.stop_gradient(batch.reward + d_t * jnp.max(q_t, axis=-1))
     td = target - qa_tm1
     reg = float(config.system.get("regularizer_coeff", 0.1))

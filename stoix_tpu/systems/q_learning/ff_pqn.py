@@ -16,7 +16,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from stoix_tpu import envs
 from stoix_tpu.base_types import ExperimentOutput, OnPolicyLearnerState
 from stoix_tpu.evaluator import get_distribution_act_fn
-from stoix_tpu.ops import q_lambda
+from stoix_tpu.ops import pick_along_last, q_lambda
 from stoix_tpu.systems import anakin
 from stoix_tpu.systems.q_learning.q_family import build_q_network
 from stoix_tpu.systems.runner import AnakinSetup, run_anakin_experiment
@@ -133,7 +133,7 @@ def get_learner_fn(env, q_apply, q_update, config):
 
                 def loss_fn(p):
                     q = q_apply(p, obs, 0.0).preferences
-                    qa = jnp.take_along_axis(q, action[..., None], axis=-1)[..., 0]
+                    qa = pick_along_last(q, action)
                     loss = 0.5 * jnp.mean((qa - target) ** 2)
                     return loss, {"q_loss": loss, "mean_q": jnp.mean(q)}
 

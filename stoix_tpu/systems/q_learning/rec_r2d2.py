@@ -21,7 +21,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from stoix_tpu import envs
 from stoix_tpu.base_types import ExperimentOutput, OnlineAndTarget, RNNOffPolicyLearnerState
 from stoix_tpu.buffers import make_prioritised_trajectory_buffer
-from stoix_tpu.ops import SIGNED_HYPERBOLIC_PAIR, n_step_bootstrapped_returns
+from stoix_tpu.ops import SIGNED_HYPERBOLIC_PAIR, n_step_bootstrapped_returns, pick_along_last
 from stoix_tpu.systems import anakin, off_policy_core as core
 from stoix_tpu.systems.off_policy_core import pmean_grads
 from stoix_tpu.systems.runner import AnakinSetup
@@ -98,9 +98,7 @@ def get_learner_fn(env, q_network, q_update, buffer, config, cell_type, hidden_s
 
         # Transformed double n-step targets (selector = online argmax).
         selector = jnp.argmax(q_online, axis=-1)
-        v_raw = tx.apply_inv(
-            jnp.take_along_axis(q_target, selector[..., None], axis=-1)[..., 0]
-        )
+        v_raw = tx.apply_inv(pick_along_last(q_target, selector))
         targets = n_step_bootstrapped_returns(
             reward[:-1].swapaxes(0, 1),
             (gamma * discount[:-1]).swapaxes(0, 1),
@@ -109,7 +107,7 @@ def get_learner_fn(env, q_network, q_update, buffer, config, cell_type, hidden_s
         ).swapaxes(0, 1)
         targets = tx.apply(targets)
 
-        qa = jnp.take_along_axis(q_online, action[..., None], axis=-1)[..., 0][:-1]
+        qa = pick_along_last(q_online, action)[:-1]
         td = jax.lax.stop_gradient(targets) - qa  # [L'-1, B]
 
         # Sequence priorities: eta * max|td| + (1-eta) * mean|td|.

@@ -30,6 +30,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from stoix_tpu.base_types import OnlineAndTarget, Transition
+from stoix_tpu.ops import pick_along_last
 from stoix_tpu.replay import ShardedReplayService, service_from_config
 from stoix_tpu.resilience import guards
 from stoix_tpu.sebulba import runner
@@ -101,9 +102,7 @@ def get_dqn_learn_step(
                 q_t = q_apply(state.params.target, batch.next_obs, 0.0).preferences
                 d_t = gamma * (1.0 - batch.done.astype(jnp.float32))
                 target = batch.reward + d_t * jnp.max(q_t, axis=-1)
-                qa = jnp.take_along_axis(
-                    q_tm1, batch.action.astype(jnp.int32)[:, None], axis=-1
-                )[:, 0]
+                qa = pick_along_last(q_tm1, batch.action)
                 td = jax.lax.stop_gradient(target) - qa
                 loss = 0.5 * jnp.mean(w * jnp.square(td))
                 return loss, (td, jnp.mean(q_tm1))

@@ -1,9 +1,12 @@
 """Tests for distributions, losses, value transforms, running statistics."""
 
+import re
+
 import jax
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 import scipy.stats
 
 from stoix_tpu.ops import distributions as dists
@@ -267,3 +270,204 @@ def test_c51_loss_accepts_head_shaped_atoms():
         logits, atoms, jnp.zeros((B, A)),
     )
     assert np.isfinite(float(loss))
+
+
+# ---- pick_along_last: x[..., index] by select where the last axis is narrow --
+
+
+def _gather_pick(x, index):
+    """What `Categorical.log_prob` and the Q losses did before the helper."""
+    return jnp.take_along_axis(x, index[..., None], axis=-1)[..., 0]
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+def _pick_inputs(width, lead, masked, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed + width), 4)
+    raw = 3.0 * jax.random.normal(ks[0], lead + (width,))
+    if masked:  # at least one legal action a row; the index is drawn among them
+        mask = jax.random.bernoulli(ks[1], 0.5, lead + (width,)).at[..., 0].set(True)
+        index = jax.random.categorical(ks[2], jnp.where(mask, 0.0, -jnp.inf), axis=-1)
+    else:
+        mask = None
+        index = jax.random.randint(ks[2], lead, 0, width)
+    return raw, mask, index.astype(jnp.int32), jax.random.normal(ks[3], lead)
+
+
+def _weighted_pick(pick, raw, mask, index, weight):
+    """The pick as the losses meet it: from normalised, possibly masked logits,
+    weighted, so the gradient reaches the raw logits through the pick."""
+    if mask is not None:
+        raw = jnp.where(mask, raw, jnp.finfo(raw.dtype).min)
+    logits = raw - jax.nn.logsumexp(raw, axis=-1, keepdims=True)
+    picked = pick(logits, index)
+    return jnp.sum(weight * picked), picked
+
+
+@pytest.mark.parametrize("transform", ["jit", "vmap"])
+@pytest.mark.parametrize("masked", [False, True], ids=["unmasked", "masked"])
+@pytest.mark.parametrize("lead", [(7,), (3, 5)], ids=["B", "TB"])
+@pytest.mark.parametrize("width", [2, 3, 18, 128, 129, 512, 513, 1000])
+def test_pick_along_last_is_the_gather_bit_for_bit(width, lead, masked, transform):
+    """Value and gradient, either side of `PICK_SELECT_MAX_WIDTH`."""
+    if transform == "vmap":
+        lead = (4,) + lead
+    raw, mask, index, weight = _pick_inputs(width, lead, masked)
+    assert (width <= dists.PICK_SELECT_MAX_WIDTH) == (width <= 512)
+
+    def run(pick):
+        fn = jax.value_and_grad(
+            lambda r, m, i, w: _weighted_pick(pick, r, m, i, w), has_aux=True
+        )
+        if transform == "vmap":
+            fn = jax.vmap(fn, in_axes=(0, None if mask is None else 0, 0, 0))
+        return jax.jit(fn)(raw, mask, index, weight)
+
+    ((_, got), got_grad), ((_, want), want_grad) = run(dists.pick_along_last), run(_gather_pick)
+    assert got.shape == lead and got_grad.shape == lead + (width,)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    np.testing.assert_array_equal(_bits(got_grad), _bits(want_grad))
+    if masked:  # no illegal action's finfo.min reached a picked value
+        assert np.all(np.asarray(got) > -1e4)
+
+
+def test_pick_along_last_ignores_what_it_does_not_pick_and_checks_the_rank():
+    x = jnp.array([[jnp.nan, 1.5, -jnp.inf], [jnp.inf, jnp.nan, -0.0]])
+    np.testing.assert_array_equal(dists.pick_along_last(x, jnp.array([1, 2])), [1.5, 0.0])
+    with pytest.raises(ValueError, match="picks from no x"):
+        dists.pick_along_last(jnp.zeros((4, 3, 2)), jnp.zeros((3,), jnp.int32))
+
+
+def _log_prob_case(name):
+    key = jax.random.PRNGKey(5)
+    if name == "multi_discrete":
+        dist = dists.MultiDiscrete(jax.random.normal(key, (6, 5)), (2, 3))
+        value = dist.sample(seed=key)
+        want = sum(_gather_pick(d.logits, value[..., i]) for i, d in enumerate(dist.dists))
+        return dist, value, want
+    width = 600 if name == "categorical_wide" else 6
+    prefs = jax.random.normal(key, (4, 6, width))
+    mask = jax.random.bernoulli(key, 0.6, prefs.shape).at[..., 1].set(True)
+    dist = {
+        "categorical": lambda: dists.Categorical(prefs),
+        "categorical_wide": lambda: dists.Categorical(prefs),
+        "categorical_masked": lambda: dists.Categorical(prefs, mask=mask),
+        "epsilon_greedy": lambda: dists.EpsilonGreedy(prefs, 0.1, mask=mask),
+        "greedy": lambda: dists.Greedy(prefs),
+    }[name]()
+    value = dist.sample(seed=key)
+    return dist, value, _gather_pick(dist.logits, value)
+
+
+@pytest.mark.parametrize("name", [
+    "categorical", "categorical_wide", "categorical_masked", "multi_discrete",
+    "epsilon_greedy", "greedy",
+])
+def test_log_prob_is_the_old_expression(name):
+    dist, value, want = _log_prob_case(name)
+    got = dist.log_prob(value)
+    assert got.shape == want.shape
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+def _ops_under(hlo_text, opcode, scope):
+    return [
+        line for line in hlo_text.splitlines()
+        if re.search(rf"= [^=]*\b{opcode}\(", line)
+        and re.search(rf'op_name="[^"]*\b{scope}\b', line)
+    ]
+
+
+@pytest.fixture(scope="module")
+def discrete_programs(devices):
+    """Compiled text of the Sebulba PPO learn step and `act_fn` on CartPole
+    (two actions), and of the token policy's loss either side of the threshold."""
+    import optax
+
+    from stoix_tpu.base_types import (
+        ActorCriticOptStates,
+        ActorCriticParams,
+        PPOTransition,
+    )
+    from stoix_tpu.envs.factory import make_factory
+    from stoix_tpu.networks import olmoe
+    from stoix_tpu.parallel import MeshRoles
+    from stoix_tpu.systems.ppo.anakin import ff_lm_ppo
+    from stoix_tpu.systems.ppo.sebulba import ff_ppo
+    from stoix_tpu.utils import config as config_lib
+
+    config = config_lib.compose(
+        config_lib.default_config_dir(), "default/sebulba/default_ff_ppo.yaml",
+        ["env=cartpole", "env.backend=cvec", "arch.total_num_envs=16",
+         "arch.actor.device_ids=[0]", "arch.learner.device_ids=[0]",
+         "arch.evaluator_device_id=0", "arch.total_timesteps=~", "arch.num_updates=2",
+         "arch.num_evaluation=1", "system.rollout_length=8", "system.epochs=2",
+         "system.num_minibatches=2"],
+    )
+    mesh = MeshRoles.from_config(config).learn_mesh()
+    pool = make_factory(config)(1)
+    assert pool.num_actions == 2
+    config.system.action_dim = pool.num_actions
+    actor, critic = ff_ppo._build_networks(config, pool.num_actions, None, env=pool)
+    obs0 = jax.tree.map(jnp.asarray, pool.reset(seed=0).observation)
+    params = ActorCriticParams(actor.init(KEY, obs0), critic.init(KEY, obs0))
+    optim = optax.adam(1e-3)
+    state = ff_ppo.CoreLearnerState(
+        params,
+        ActorCriticOptStates(optim.init(params.actor_params), optim.init(params.critic_params)),
+        KEY,
+        running_statistics.init_state(obs0.agent_view[0]),
+    )
+    obs = jax.tree.map(lambda x: jnp.zeros((8, 16) + x.shape[1:], x.dtype), obs0)
+    zeros = jnp.zeros((8, 16))
+    traj = PPOTransition(
+        done=zeros.astype(bool), truncated=zeros.astype(bool), action=zeros.astype(jnp.int32),
+        value=zeros, reward=zeros, log_prob=zeros, obs=obs, next_obs=obs, info={},
+    )
+    learn = ff_ppo.get_learn_step(
+        actor.apply, critic.apply, (optim.update, optim.update), config, mesh
+    )
+    programs = {"learn": learn.lower(state, traj).compile().as_text()}
+    act_fn = ff_ppo.get_act_fn(actor.apply, critic.apply, False)
+    programs["act_fn"] = act_fn.lower((params, state.obs_stats), obs0, KEY).compile().as_text()
+
+    for vocab in (97, 600):
+        lm = olmoe.OlmoeLM(
+            vocab_size=vocab, hidden_size=32, num_heads=2, head_dim=16, num_experts=4,
+            experts_per_token=2, expert_width=16, num_layers=1,
+        )
+        head = olmoe.ValueHead()
+        tokens = jnp.zeros((2, 8), jnp.int32)
+        lm_params = ActorCriticParams(
+            lm.init(KEY, tokens, method="forward"), head.init(KEY, jnp.zeros((2, 8, 32)))
+        )
+        nets = ff_lm_ppo.network_functions(lm, head, 8)
+        batch = {
+            "token": tokens, "action": tokens,
+            **{k: jnp.zeros(tokens.shape) for k in ("log_prob", "value", "advantage", "target")},
+        }
+        loss = lambda p: ff_lm_ppo.lm_ppo_loss(
+            nets, p, batch, clip_eps=0.2, ent_coef=0.01, vf_coef=0.5, aux_coef=0.01
+        )
+        lowered = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(lm_params)
+        programs[f"lm_loss_{vocab}"] = lowered.compile().as_text()
+    return programs
+
+
+@pytest.mark.parametrize("program,scope", [
+    ("learn", "ppo_minibatch"), ("act_fn", "rollout_policy"), ("lm_loss_97", "lm_head"),
+])
+def test_narrow_action_axis_is_picked_without_a_gather(discrete_programs, program, scope):
+    hlo = discrete_programs[program]
+    assert not _ops_under(hlo, "gather", scope), _ops_under(hlo, "gather", scope)
+    assert not _ops_under(hlo, "scatter", scope)
+    assert re.search(rf'op_name="[^"]*\b{scope}\b[^"]*\bpick_select\b', hlo)
+
+
+def test_wide_vocabulary_keeps_its_one_gather(discrete_programs):
+    hlo = discrete_programs["lm_loss_600"]
+    gathers = _ops_under(hlo, "gather", "lm_head")
+    assert len(gathers) == 1 and "jit(take_along_axis)/gather" in gathers[0], gathers
+    assert "pick_select" not in hlo
