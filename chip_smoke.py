@@ -17,6 +17,10 @@ cut to a few updates and weights made from the config's seed. Legs:
   lm_ppo         Anakin PPO with the OLMoE token policy at a tiny preset
                  (token_task): grouped matmuls over sorted experts, the KV
                  cache in rollout and evaluator, flash attention in the update.
+  sdar_ppo       Anakin PPO with the SDAR block-diffusion token policy at a
+                 tiny preset (block_token_task): the held-experts loop of
+                 grouped matmuls, block steps through the GQA cache in rollout
+                 and evaluator, the [clean ; noisy copies] update.
   ppo_pallas_gae Anakin ff_ppo with system.multistep_impl=pallas: the
                  recurrence kernel inside the learner.
   sebulba        Sebulba ff_ppo on the native C++ CartPole pool, 512 envs,
@@ -325,6 +329,29 @@ def leg_lm_ppo(n: int) -> Dict[str, Any]:
     )
 
 
+def leg_sdar_ppo(n: int) -> Dict[str, Any]:
+    """The block-diffusion token policy at a tiny preset, data-parallel over
+    the chips: `jax.lax.ragged_dot` over the held experts inside the chunk
+    loop (forward and its hand-written backward), denoise and commit passes
+    through the grouped-query cache, the teacher-forced pass under the block
+    mask with its layers rematerialised."""
+    tiny = [
+        "hidden_size=128", "num_heads=4", "num_kv_heads=2", "head_dim=32", "num_experts=16",
+        "experts_held=4", "experts_per_token=4", "expert_width=64", "num_layers=2",
+    ]
+    return _anakin_leg(
+        n,
+        "stoix_tpu.systems.ppo.anakin.ff_sdar_ppo",
+        "default/anakin/default_ff_sdar_ppo.yaml",
+        [f"network.actor_network.{o}" for o in tiny] + [
+            "env.kwargs.vocab_size=512", "env.kwargs.length=128", "system.rollout_length=64",
+            f"arch.total_num_envs={16 * n}", "system.num_minibatches=4", "arch.num_updates=4",
+            "arch.evaluation_greedy=True",
+        ],
+        expect_kernel=False,
+    )
+
+
 def leg_ppo_pallas_gae(n: int) -> Dict[str, Any]:
     return _anakin_leg(
         n,
@@ -622,6 +649,7 @@ LEGS: List[Tuple[str, Callable[[int], Dict[str, Any]]]] = [
     ("kernels", leg_kernels),
     ("trans_ppo", leg_trans_ppo),
     ("lm_ppo", leg_lm_ppo),
+    ("sdar_ppo", leg_sdar_ppo),
     ("ppo_pallas_gae", leg_ppo_pallas_gae),
     ("sebulba", leg_sebulba),
     ("anakin_ant", leg_anakin_ant),

@@ -11,6 +11,7 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, Optional, Tuple
 
 from stoix_tpu.envs import (
+    block_token_task,
     breakout_pixel,
     classic,
     debug,
@@ -52,6 +53,7 @@ ENV_REGISTRY: Dict[str, Callable[..., Environment]] = {
     "IdentityGame": debug.IdentityGame,
     "SequenceGame": debug.SequenceGame,
     "TokenTask": token_task.TokenTask,
+    "BlockTokenTask": block_token_task.BlockTokenTask,
 }
 
 

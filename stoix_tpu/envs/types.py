@@ -136,14 +136,18 @@ def get_final_step_metrics(metrics: Dict[str, jax.Array]) -> Dict[str, jax.Array
     Given a dict with "episode_return", "episode_length", "is_terminal_step"
     (each shaped [...]), returns values gathered where is_terminal_step is True,
     as 1-D host-side arrays. Used by the host logging loop (reference
-    ff_ppo.py:624-629 via stoa's helper).
+    ff_ppo.py:624-629 via stoa's helper). A leaf with further axes behind
+    those of is_terminal_step (a record of a block of tokens a step) keeps
+    them: [episodes, ...].
     """
     import numpy as np
 
-    is_final = np.asarray(metrics["is_terminal_step"]).reshape(-1)
+    terminal = np.asarray(metrics["is_terminal_step"])
+    is_final = terminal.reshape(-1)
     out: Dict[str, jax.Array] = {}
     for k, v in metrics.items():
         if k == "is_terminal_step":
             continue
-        out[k] = np.asarray(v).reshape(-1)[is_final]
+        v = np.asarray(v)
+        out[k] = v.reshape((is_final.size,) + v.shape[terminal.ndim:])[is_final]
     return out

@@ -37,7 +37,8 @@ trunk: the critic side of `ActorCriticParams` holds only it.
 
 from __future__ import annotations
 
-from typing import Any, Dict, NamedTuple, Tuple
+import functools
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -88,14 +89,19 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return x * jnp.cos(emb) + rotated * jnp.sin(emb)
 
 
-def route(x: jax.Array, router: jax.Array, top_k: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Float32 softmax over all experts, then top-k, not renormalised:
-    (probs [N, E], weights [N, k], index [N, k])."""
+def route(
+    x: jax.Array, router: jax.Array, top_k: int, renormalise: bool = False
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Float32 softmax over all experts, then top-k: (probs [N, E], weights
+    [N, k], index [N, k]). The top-k weights are the probabilities themselves
+    (OLMoE) or, with `renormalise` (`norm_topk_prob`), divided by their sum."""
     logits = jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
     )
     probs = jax.nn.softmax(logits, axis=-1)
     weights, index = jax.lax.top_k(probs, top_k)
+    if renormalise:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return probs, weights, index
 
 
@@ -137,13 +143,23 @@ _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
 def moe(
-    x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array, down: jax.Array, top_k: int
+    x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array, down: jax.Array, top_k: int,
+    held: Optional[Tuple[int, int]] = None, renormalise: bool = False,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """x [N, D] -> (y [N, D], stats). Every (token, slot) pair is computed:
-    `stats["expert_count"]` sums to N * top_k."""
+    `stats["expert_count"]` sums to N * top_k.
+
+    `held` = (offset, count) says that `gate`, `up`, `down` hold only the
+    experts [offset, offset + count) of the router's E (one expert-parallel
+    rank's share): the router still runs over all E and the stats stay over
+    all E, but y is the part of the layer's result that the held experts
+    give, and only the pairs routed to them are gathered and multiplied
+    (`_moe_held`). The default holds them all, on the path above."""
     tokens, num_experts = x.shape[0], router.shape[-1]
     with annotate(SCOPES["moe_router"]):
-        probs, weights, index = route(x, router, top_k)
+        probs, weights, index = route(x, router, top_k, renormalise)
+    if held is not None:
+        return _moe_held(x, probs, weights, index, gate, up, down, held)
     with annotate(SCOPES["moe_dispatch"]):
         flat = index.reshape(-1)  # pair p = token p // k, slot p % k
         order = jnp.argsort(flat, stable=True)  # pairs grouped by expert
@@ -159,6 +175,156 @@ def moe(
     with annotate(SCOPES["moe_dispatch"]):
         pairs = _permute(routed, back, order).reshape(tokens, top_k, -1)
         out = jnp.sum(pairs * weights[..., None].astype(pairs.dtype), axis=1)
+    stats = {
+        "expert_index": index,
+        "expert_count": counts,
+        "router_prob_sum": jnp.sum(probs, axis=0),
+        "router_entropy_sum": -jnp.sum(probs * jnp.log(jnp.maximum(probs, 1e-30))),
+    }
+    return out, stats
+
+
+class _HeldRows(NamedTuple):
+    """Rows [lo, lo + rows) of the held pairs in expert-sorted order."""
+
+    token: jax.Array  # [rows] the token whose row of x each holds
+    pair: jax.Array  # [rows] its (token, slot) pair, flat
+    valid: jax.Array  # [rows] bool: the row holds a held pair
+    sizes: jax.Array  # [held] rows of each held expert inside the chunk
+
+
+def _held_rows(
+    order: jax.Array, ends: jax.Array, lo: jax.Array, rows: int, top_k: int
+) -> _HeldRows:
+    pair = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+    bounds = jnp.clip(ends, lo, lo + rows) - lo
+    valid = lo + jnp.arange(rows, dtype=jnp.int32) < ends[-1]
+    sizes = jnp.diff(bounds, prepend=0).astype(jnp.int32)
+    return _HeldRows(pair // top_k, pair, valid, sizes)
+
+
+def _held_swiglu(
+    gathered: jax.Array, gate: jax.Array, up: jax.Array, down: jax.Array, sizes: jax.Array
+) -> jax.Array:
+    with annotate(SCOPES["moe_experts"]):
+        hidden = jax.nn.silu(jax.lax.ragged_dot(gathered, gate, sizes)) * jax.lax.ragged_dot(
+            gathered, up, sizes
+        )
+        return jax.lax.ragged_dot(hidden, down, sizes)
+
+
+def _chunks(ends: jax.Array, rows: int) -> jax.Array:
+    return (ends[-1] + rows - 1) // rows  # chunks that hold a held pair
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def _held_experts(x, weights, gate, up, down, order, slot, ends, rows):
+    """The held experts' part of the layer: x [N, D], weights [N, k] ->
+    [N, D]. `order[r]` is the pair in row r of the held pairs sorted by
+    expert, `slot[t, j]` the row of pair (t, j) (past the held total for an
+    absent pair), `ends` the cumulative held counts. A loop over chunks of
+    `rows` rows, as many turns as the held pairs fill: the cost follows the
+    pairs that landed here and nothing is dropped however the router skews.
+    A chunk's rows are gathered from their tokens and added back to them by
+    one scatter-add (a third of the time of a gather a slot, PERF.md §6,
+    PR 31). A loop of data-dependent length has no reverse of its own: the
+    backward pass below is the same loop, each chunk recomputed (nothing but
+    the inputs is kept)."""
+    top_k, flat_weights = weights.shape[1], weights.reshape(-1)
+
+    def body(carry):
+        i, out = carry
+        lo = i * rows
+        with annotate(SCOPES["moe_dispatch"]):
+            at = _held_rows(order, ends, lo, rows, top_k)
+            gathered = jnp.take(x, at.token, axis=0)  # [rows, D]
+        routed = _held_swiglu(gathered, gate, up, down, at.sizes)
+        with annotate(SCOPES["moe_dispatch"]):
+            # Rows past the last group belong to no expert: whatever the
+            # kernel left there is not a result.
+            weighted = jnp.where(
+                at.valid[:, None], routed * jnp.take(flat_weights, at.pair)[:, None], 0.0
+            )
+            out = out.at[at.token].add(weighted)
+        return i + 1, out
+
+    chunks = _chunks(ends, rows)
+    _, out = jax.lax.while_loop(lambda c: c[0] < chunks, body, (jnp.int32(0), jnp.zeros_like(x)))
+    return out
+
+
+def _held_experts_fwd(x, weights, gate, up, down, order, slot, ends, rows):
+    out = _held_experts(x, weights, gate, up, down, order, slot, ends, rows)
+    return out, (x, weights, gate, up, down, order, slot, ends)
+
+
+def _held_experts_bwd(rows, residuals, g):
+    x, weights, gate, up, down, order, slot, ends = residuals
+    top_k, flat_weights = weights.shape[1], weights.reshape(-1)
+
+    def body(carry):
+        i, (dx, dweights, dgate, dup, ddown) = carry
+        lo = i * rows
+        with annotate(SCOPES["moe_dispatch"]):
+            at = _held_rows(order, ends, lo, rows, top_k)
+            gathered = jnp.take(x, at.token, axis=0)
+            g_rows = jnp.where(at.valid[:, None], jnp.take(g, at.token, axis=0), 0.0)
+        routed, vjp = jax.vjp(
+            lambda *operands: _held_swiglu(*operands, at.sizes), gathered, gate, up, down
+        )
+        with annotate(SCOPES["moe_dispatch"]):
+            d_weight_rows = jnp.sum(jnp.where(at.valid[:, None], routed, 0.0) * g_rows, axis=-1)
+            d_routed = g_rows * jnp.take(flat_weights, at.pair)[:, None]
+        d_gathered, d_gate, d_up, d_down = vjp(d_routed)
+        with annotate(SCOPES["moe_dispatch"]):
+            dx = dx.at[at.token].add(jnp.where(at.valid[:, None], d_gathered, 0.0))
+            rows_of = slot - lo  # [N, k]: each pair's own weight gradient
+            here = (rows_of >= 0) & (rows_of < rows) & (slot < ends[-1])
+            dweights = dweights + jnp.where(
+                here, jnp.take(d_weight_rows, jnp.clip(rows_of, 0, rows - 1)), 0.0
+            )
+        return i + 1, (dx, dweights, dgate + d_gate, dup + d_up, ddown + d_down)
+
+    chunks = _chunks(ends, rows)
+    zeros = tuple(jnp.zeros_like(f) for f in (x, weights, gate, up, down))
+    _, grads = jax.lax.while_loop(lambda c: c[0] < chunks, body, (jnp.int32(0), zeros))
+    return (*grads, None, None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+# A chunk of the held pairs' loop is this many times the pairs that uniform
+# routing would land on the held experts: one turn in the common case.
+_HELD_CHUNK_ROOM = 1.25
+# A large chunk is a whole number of the grouped-matmul kernel's row tiles:
+# 30,800 rows take eight times as long as 31,232 (PERF.md §6, PR 31).
+_HELD_CHUNK_TILE = 512
+
+
+def _moe_held(
+    x: jax.Array, probs: jax.Array, weights: jax.Array, index: jax.Array,
+    gate: jax.Array, up: jax.Array, down: jax.Array, held: Tuple[int, int],
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The held experts' part of the layer's result. Of the N * k routed
+    pairs only the integer keys are ranked; rows of x are gathered, and
+    multiplied, for the pairs that land on [offset, offset + count) alone."""
+    offset, count = held
+    tokens, top_k = index.shape
+    num_experts = probs.shape[-1]
+    with annotate(SCOPES["moe_dispatch"]):
+        local = index.reshape(-1) - offset
+        key = jnp.where((local >= 0) & (local < count), local, count)  # absent pairs last
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)  # held pairs grouped by expert
+        slot = jnp.argsort(order).astype(jnp.int32).reshape(tokens, top_k)
+        experts = jnp.arange(num_experts, dtype=index.dtype)
+        counts = jnp.sum(index.reshape(-1)[:, None] == experts[None, :], axis=0, dtype=jnp.int32)
+        ends = jnp.cumsum(jax.lax.dynamic_slice_in_dim(counts, offset, count))
+    expected = tokens * top_k * count / num_experts
+    tile = _HELD_CHUNK_TILE if expected >= 8 * _HELD_CHUNK_TILE else 8
+    rows = min(tokens * top_k, -(-int(_HELD_CHUNK_ROOM * expected) // tile) * tile)
+    # The last chunk may reach past the pairs: a slice that does is moved, not cut.
+    order = jnp.concatenate([order, jnp.zeros((rows,), jnp.int32)])
+    out = _held_experts(x, weights.astype(x.dtype), gate, up, down, order, slot, ends, rows)
     stats = {
         "expert_index": index,
         "expert_count": counts,

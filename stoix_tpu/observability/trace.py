@@ -50,11 +50,20 @@ SCOPES = {
     "moe_dispatch": "moe_dispatch",  # sort by expert, gather, un-permute, weighted combine
     "moe_experts": "moe_experts",  # the three grouped matmuls and the SwiGLU between
     "lm_head": "lm_head",  # final norm, head matmul, log-prob / entropy / sampling over the vocabulary
+    # Generation by diffusion over blocks (systems/ppo/anakin/ff_sdar_ppo.py,
+    # networks/sdar.py): the two kinds of pass under `rollout` (and in the
+    # evaluator), and the score/softmax/value products inside `attention`.
+    "denoise": "denoise",  # the policy's pass over a block: nothing is written to the cache
+    "block_commit": "block_commit",  # the finished block's pass that writes its keys and values
+    "attention_scores": "attention_scores",  # q k^T, the masked softmax, p v (both entry points)
 }
 
 # The scopes of the token policy's block: only the systems built on
 # networks/olmoe.py carry them.
 BLOCK_SCOPES = ("attention", "moe", "moe_router", "moe_dispatch", "moe_experts", "lm_head")
+# What generation by diffusion over blocks adds to them: only the system
+# built on networks/sdar.py carries these.
+DIFFUSION_SCOPES = ("denoise", "block_commit", "attention_scores")
 
 # Host spans that recur in steady state, by the thread that opens them. A
 # trace reduction attributes a device-idle gap to the innermost of these open
