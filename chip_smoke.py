@@ -20,7 +20,9 @@ cut to a few updates and weights made from the config's seed. Legs:
   sdar_ppo       Anakin PPO with the SDAR block-diffusion token policy at a
                  tiny preset (block_token_task): the held-experts loop of
                  grouped matmuls, block steps through the GQA cache in rollout
-                 and evaluator, the [clean ; noisy copies] update.
+                 and evaluator, the [clean ; noisy copies] update with its
+                 attention in the block-mask kernels (forward and backward),
+                 then `trunk_copies` against the plain masked products.
   ppo_pallas_gae Anakin ff_ppo with system.multistep_impl=pallas: the
                  recurrence kernel inside the learner.
   sebulba        Sebulba ff_ppo on the native C++ CartPole pool, 512 envs,
@@ -334,12 +336,15 @@ def leg_sdar_ppo(n: int) -> Dict[str, Any]:
     the chips: `jax.lax.ragged_dot` over the held experts inside the chunk
     loop (forward and its hand-written backward), denoise and commit passes
     through the grouped-query cache, the teacher-forced pass under the block
-    mask with its layers rematerialised."""
+    mask with its layers rematerialised — its attention through the Pallas
+    kernels, forward and backward (heads of 128: whole lanes), which the
+    learner the runner compiled has to hold; then `trunk_copies` and its
+    gradient as the chip runs them against the plain masked products."""
     tiny = [
-        "hidden_size=128", "num_heads=4", "num_kv_heads=2", "head_dim=32", "num_experts=16",
+        "hidden_size=128", "num_heads=4", "num_kv_heads=2", "head_dim=128", "num_experts=16",
         "experts_held=4", "experts_per_token=4", "expert_width=64", "num_layers=2",
     ]
-    return _anakin_leg(
+    facts = _anakin_leg(
         n,
         "stoix_tpu.systems.ppo.anakin.ff_sdar_ppo",
         "default/anakin/default_ff_sdar_ppo.yaml",
@@ -348,8 +353,65 @@ def leg_sdar_ppo(n: int) -> Dict[str, Any]:
             f"arch.total_num_envs={16 * n}", "system.num_minibatches=4", "arch.num_updates=4",
             "arch.evaluation_greedy=True",
         ],
-        expect_kernel=False,
+        expect_kernel=True,
     )
+    facts["trunk_copies_kernel_vs_plain_rms"] = _sdar_trunk_copies_against_plain()
+    return facts
+
+
+def _sdar_trunk_copies_against_plain() -> Dict[str, float]:
+    import jax
+    import jax.numpy as jnp
+
+    from stoix_tpu.networks import sdar
+    from stoix_tpu.systems.runner import LAST_RUN_STATS
+
+    attention = LAST_RUN_STATS.get("update_attention", {})
+    _require(attention.get("kernel") == 1, f"the run's update took the plain attention: {attention}")
+    model = sdar.SdarLM(
+        vocab_size=512, hidden_size=128, num_heads=4, num_kv_heads=2, head_dim=128,
+        num_experts=16, experts_held=4, experts_per_token=4, expert_width=64, block_length=4,
+    )
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    # (weights scaled up so that attention and the norms matter to the result)
+    params = jax.tree.map(lambda w: w * 8.0 if w.ndim > 1 else w, model.init(keys[0]))
+    clean = jax.random.randint(keys[1], (4, 4 + 256), 0, 511)
+    noisy = jax.random.randint(keys[2], (4, 2, 256), 0, 512)
+
+    def hidden_and_gradient():
+        def loss(params):
+            hidden, _ = model.trunk_copies(params, clean, noisy)
+            return jnp.sum(jnp.sin(hidden)), hidden
+
+        (_, hidden), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+        layer = grads["params"]["layer_0"]
+        return {"hidden": hidden, **{f"d_{name}": layer[name] for name in ("wq", "wk", "wv")}}
+
+    _require(
+        _has_pallas_call(model.trunk_copies, params, clean, noisy),
+        "sdar trunk_copies: no pallas_call traced",
+    )
+    got = hidden_and_gradient()
+    chosen = sdar.SdarLM.copies_attention
+    sdar.SdarLM.copies_attention = lambda self, *a: {**chosen(self, *a), "kernel": 0}
+    try:
+        _require(
+            not _has_pallas_call(model.trunk_copies, params, clean, noisy),
+            "sdar trunk_copies: the plain path holds a pallas_call",
+        )
+        want = hidden_and_gradient()
+    finally:
+        sdar.SdarLM.copies_attention = chosen
+    errors = {}
+    for name in got:
+        _require(bool(jnp.all(jnp.isfinite(got[name]))), f"sdar trunk_copies {name}: non-finite")
+        rms = float(jnp.sqrt(jnp.mean((got[name] - want[name]) ** 2) / jnp.mean(want[name] ** 2)))
+        errors[name] = float(f"{rms:.3e}")
+        # Two roundings of one float32 computation at the MXU's default
+        # precision (and a few tokens routed otherwise): a wrong mask, head
+        # group or gradient reads of order 1.
+        _require(rms <= TOL_GRAD, f"sdar trunk_copies {name}: relative rms {rms:.3e} > {TOL_GRAD}")
+    return errors
 
 
 def leg_ppo_pallas_gae(n: int) -> Dict[str, Any]:

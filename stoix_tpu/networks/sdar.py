@@ -46,7 +46,10 @@ Initialisation is normal(0.02); norms start at one.
 Not a flax module: the entry points are plain functions of the parameter
 tree, so that a layer of the teacher-forced pass can be rematerialised
 (`jax.checkpoint`) — the update keeps one layer's activations at a time,
-and of the others their input and their attention's result.
+and of the others their input and their attention's result. On a TPU that
+attention is one Pallas kernel forward and one backward over the block mask's
+tiles (`ops/pallas_attention.py::block_mask_attention`); elsewhere the plain
+masked products of `_attend_copies`.
 """
 
 from __future__ import annotations
@@ -60,6 +63,11 @@ from jax.ad_checkpoint import checkpoint_name
 
 from stoix_tpu.networks.olmoe import _stack, moe, rms_norm, rope
 from stoix_tpu.observability import SCOPES, annotate
+from stoix_tpu.ops.pallas_attention import (
+    BLOCK_MASK_RESIDUALS,
+    block_mask_attention,
+    block_mask_layout,
+)
 
 # A block step at block b reads the leading cache blocks of this many
 # positions that hold a committed position, not the whole cache.
@@ -260,7 +268,9 @@ class SdarLM:
         block's keys in its own copy. The response blocks are taken in
         `_KEY_GROUPS` runs, all copies of a run together: a run's queries are
         multiplied with the clean keys up to its last block only, a little
-        over half of the rows' whole width in all."""
+        over half of the rows' whole width in all. The plain statement of the
+        mask: what every backend but a TPU runs (`copies_attention`), and what
+        the kernel that a TPU runs instead is tested against."""
         size = self.block_length
         scale = 1.0 / jnp.sqrt(jnp.float32(self.head_dim))
         q = self._grouped(q)
@@ -301,23 +311,49 @@ class SdarLM:
         outs.append(jnp.concatenate(run_outs, axis=1).reshape((-1,) + q.shape[1:]))
         return jnp.concatenate(outs).reshape(q.shape[0], -1)
 
+    def copies_attention(self, clean: int, copies: int) -> Dict[str, int]:
+        """How `trunk_copies` multiplies its scores here, by what it can see
+        (the backend and the shapes): `kernel` 1 for the Pallas kernel over
+        the block mask's tiles (a TPU, heads of whole lanes), 0 for the plain
+        products of `_attend_copies`; the tiles of 128 x 128 (query, key)
+        positions that hold an allowed pair, which are all the kernel visits,
+        and all tiles."""
+        layout = block_mask_layout(self.block_length, clean, copies)
+        kernel = jax.default_backend() == "tpu" and self.head_dim % 128 == 0
+        return {
+            "kernel": int(kernel), "tiles_visited": layout.tiles_visited,
+            "tiles_total": layout.tiles_total,
+        }
+
     def _layer_copies(
         self, layer: Dict[str, jax.Array], x: jax.Array, positions: jax.Array, clean: int
     ):
+        """One layer over `[clean ; noisy copies]`. The scores go through
+        `ops/pallas_attention.py::block_mask_attention` where
+        `copies_attention` says so: nothing of [queries, keys] reaches HBM,
+        forward or backward, and what the backward kernel reads of the forward
+        (`BLOCK_MASK_RESIDUALS`: the result and each row's log-sum-exp) is
+        kept by name. Elsewhere a few sequences' score matrices at a time
+        (`_ATTENTION_CHUNK`), recomputed in the backward pass, and the result
+        kept as `"attended"`. Either way the layer's backward pass
+        (`trunk_copies`) multiplies no scores forward a second time."""
         copies = (x.shape[1] - clean) // (clean - self.block_length)
         with annotate(SCOPES["attention"]):
             q, k, v = self._qkv(layer, x, jnp.broadcast_to(positions, x.shape[:2]))
+            kernel = self.copies_attention(clean, copies)["kernel"]
             with annotate(SCOPES["attention_scores"]):
-                # A few sequences' score matrices at a time, recomputed in the
-                # backward pass.
-                attend = jax.checkpoint(
-                    lambda qkv: self._attend_copies(*qkv, clean=clean, copies=copies)
-                )
-                chunk = min(_ATTENTION_CHUNK, x.shape[0])
-                attended = jax.lax.map(attend, (q, k, v), batch_size=chunk)
-            # Kept for the layer's backward pass (`trunk_copies`): its
-            # recomputation then multiplies no scores a second time.
-            attended = checkpoint_name(attended, "attended")
+                if kernel:
+                    attended = block_mask_attention(
+                        q, k, v, block_length=self.block_length, clean=clean, copies=copies
+                    )
+                else:
+                    attend = jax.checkpoint(
+                        lambda qkv: self._attend_copies(*qkv, clean=clean, copies=copies)
+                    )
+                    chunk = min(_ATTENTION_CHUNK, x.shape[0])
+                    attended = jax.lax.map(attend, (q, k, v), batch_size=chunk)
+            if not kernel:  # the kernel names its own result, in the layout it reads
+                attended = checkpoint_name(attended, BLOCK_MASK_RESIDUALS[0])
             h = x + attended @ layer["wo"]
         return self._moe(layer, h)
 
@@ -329,7 +365,9 @@ class SdarLM:
         passes of its blocks) -> (final-norm hidden of the noisy copies [n, S,
         R, D], stats over all n * (B + R + S * R) positions, layer axis
         first). Each layer is rematerialised in the backward pass, from its
-        input and its attention's result."""
+        input and what its attention's backward pass reads of the forward
+        (`BLOCK_MASK_RESIDUALS`: the result and, where the kernel ran, each
+        row's log-sum-exp)."""
         tree = params["params"]
         batch, length = clean.shape
         copies, response = noisy.shape[1], noisy.shape[2]
@@ -338,7 +376,7 @@ class SdarLM:
         positions = jnp.concatenate([own, jnp.tile(own[length - response:], copies)])
         x = jnp.take(tree["embed"], tokens, axis=0)
         stats = []
-        keep_attended = jax.checkpoint_policies.save_only_these_names("attended")
+        keep_attended = jax.checkpoint_policies.save_only_these_names(*BLOCK_MASK_RESIDUALS)
         for i in range(self.num_layers):
             run = lambda layer, x: self._layer_copies(layer, x, positions, length)
             x, layer_stats = jax.checkpoint(run, policy=keep_attended)(tree[f"layer_{i}"], x)

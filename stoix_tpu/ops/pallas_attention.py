@@ -16,16 +16,19 @@ Layout notes (see /opt/skills/guides/pallas_guide.md):
   - sequence padding to the block size is masked with statically-known
     lengths; causal masking uses 2-D `broadcasted_iota` (TPU needs ≥2-D iota).
 
-What is compiled where. Both forward kernels (`flash_attention`,
-`flash_attention_chunk`) are compiled by Mosaic on TPU (`interpret=False`,
-the default) and checked there against `full_attention` by `chip_smoke.py`.
-The BACKWARD of both is plain JAX: each has a `jax.custom_vjp` whose backward
-recomputes the reference (`full_attention` / `_block_attend`) and
+What is compiled where. Every kernel here is compiled by Mosaic on TPU
+(`interpret=False`, the default) and checked there against its plain-JAX
+reference by `chip_smoke.py`. The BACKWARD of `flash_attention` and
+`flash_attention_chunk` is plain JAX: each has a `jax.custom_vjp` whose
+backward recomputes the reference (`full_attention` / `_block_attend`) and
 differentiates that — exact, but it materializes the [S, S] scores the
-forward avoids (a Pallas backward is a later optimisation). Without the
-`custom_vjp`, reverse-mode through a `pallas_call` whose body reads
-`pl.program_id` fails in JAX's generic pallas_call JVP rule, so the learner
-could not take a gradient step on the chip.
+forward avoids. Without the `custom_vjp`, reverse-mode through a
+`pallas_call` whose body reads `pl.program_id` fails in JAX's generic
+pallas_call JVP rule, so the learner could not take a gradient step on the
+chip. `block_mask_attention` (the block-diffusion update's attention, at the
+end of this file) is the first kernel here with a Pallas backward: one kernel
+forward, one backward (dq, dk and dv from the saved output and log-sum-exp),
+nothing of [queries, keys] in HBM either way.
 
 `flash_attention` is a drop-in for `full_attention` ([B, S, H, D] in/out) and
 is the default `attention_fn` for the transformer torso on TPU; on other
@@ -38,11 +41,16 @@ path selects it.
 from __future__ import annotations
 
 import functools
+from typing import List, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from stoix_tpu.observability import SCOPES, annotate
 from stoix_tpu.ops.ring_attention import _block_attend, full_attention
 
 _NEG_INF = float("-inf")
@@ -407,3 +415,466 @@ def flash_attention_chunk(
     return _chunk(
         q, k, v, q_positions, k_positions, causal, block_q, block_k, interpret
     )
+
+
+# --------------------------------------------------------------------------- #
+# Block-diffusion attention: `[clean ; noisy copies]` under the block mask
+# --------------------------------------------------------------------------- #
+
+# A masked score. Finite, so that a row with nothing allowed in the tiles seen
+# so far holds garbage, not NaN, and the first allowed score (alpha = exp(this
+# - a real maximum) = 0) wipes it.
+_MASKED_SCORE = -0.7 * float(np.finfo(np.float32).max)
+# The backward kernel holds a sequence's keys, values, dk and dv and some ten
+# [heads * tile, 2 tiles] temporaries: over the 16 MiB a kernel gets unasked,
+# well inside a v5e's 128.
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+class BlockMaskLayout(NamedTuple):
+    """The block mask of `[clean ; S noisy copies]` as the kernels walk it:
+    static numpy, a function of (block length, clean length, copies, tile).
+
+    The sequence is cut into tiles where it lies, the last one filled up with
+    padding: nothing is moved to line a copy up with a tile (a copy of q or of
+    the result costs more than the tiles that lining up saves). `mask`
+    [padded, padded] says which query sees which key; a padded position sees
+    and is seen by the padding of its own tile alone, so no row is empty and
+    no real query sees padding. `plan` holds, query tile by query tile, the
+    key tiles with an allowed pair in them as chunks of one or two tiles:
+    pairs of neighbouring tiles all of whose pairs are allowed (for the
+    tile's real queries), single such tiles, pairs and singles of the others,
+    each with the number of its pattern in `bias` ([patterns, tile, tile]: 0
+    where allowed, `_MASKED_SCORE` elsewhere); `stride` numbers a query tile,
+    the four counts first and the four lists at `offsets`."""
+
+    tile: int
+    clean: int
+    response: int
+    copies: int
+    mask: np.ndarray
+    plan: np.ndarray
+    stride: int
+    offsets: Tuple[int, int, int, int]
+    bias: np.ndarray
+    tiles_visited: int
+
+    @property
+    def positions(self) -> int:
+        return self.clean + self.copies * self.response
+
+    @property
+    def tiles(self) -> int:
+        return -(-self.positions // self.tile)
+
+    @property
+    def padded(self) -> int:
+        return self.tiles * self.tile
+
+    @property
+    def tiles_total(self) -> int:
+        return self.tiles**2
+
+
+@functools.lru_cache(maxsize=None)
+def block_mask_layout(block_length: int, clean: int, copies: int, tile: int = 128) -> BlockMaskLayout:
+    """`clean` = block_length + response length (the prompt block first)."""
+    response = clean - block_length
+    if response <= 0 or response % block_length:
+        raise ValueError(f"blocks of {block_length} do not tile a response of {response}")
+    positions = clean + copies * response
+    tiles = -(-positions // tile)
+    at = np.arange(tiles * tile)
+    real = at < positions
+    copy = np.where(at < clean, 0, 1 + (at - clean) // response)
+    # A noisy copy starts at block 1: the prompt is block 0 of the clean copy alone.
+    block = np.where(copy == 0, at // block_length, (at - clean) % response // block_length + 1)
+    own = np.where(real, copy * (2 + response // block_length) + block, -1 - at // tile)
+    clean_block = np.where(real & (copy == 0), block, np.iinfo(np.int32).max)
+    mask = (clean_block[None, :] < np.where(real, block, 0)[:, None]) | (own[None, :] == own[:, None])
+
+    by_tile = mask.reshape(tiles, tile, tiles, tile).transpose(0, 2, 1, 3)  # [q tile, k tile, q, k]
+    real_rows = real.reshape(tiles, tile)
+    patterns: List[np.ndarray] = []
+    rows, visited = [], 0
+    for i in range(tiles):
+        full, part = [], []
+        for j in range(tiles):
+            seen = by_tile[i, j]
+            if seen[real_rows[i]].all():  # what a padded query sees is dropped
+                full.append(j)
+            elif seen.any():
+                found = [n for n, pattern in enumerate(patterns) if np.array_equal(pattern, seen)]
+                if not found:
+                    patterns.append(seen)
+                part.append((j, found[0] if found else len(patterns) - 1))
+        visited += len(full) + len(part)
+        full2, full1 = [], []
+        while full:
+            if len(full) > 1 and full[1] == full[0] + 1:
+                full2.append(full[0])
+                full = full[2:]
+            else:
+                full1.append(full[0])
+                full = full[1:]
+        part2 = [part[j] + part[j + 1] for j in range(0, len(part) - 1, 2)]
+        rows.append((full2, full1, part2, part[len(part) - len(part) % 2:]))
+    widths = (1, 1, 4, 2)  # numbers an entry: tiles, and patterns beside the masked ones
+    sizes = [max(1, max(len(row[kind]) for row in rows)) for kind in range(4)]
+    offsets = np.cumsum([4] + [size * width for size, width in zip(sizes, widths)])
+    plan = np.zeros((tiles, offsets[-1]), np.int32)
+    for i, row in enumerate(rows):
+        for kind in range(4):
+            flat = np.asarray(row[kind], np.int32).reshape(-1)
+            plan[i, kind] = len(row[kind])
+            plan[i, offsets[kind]:offsets[kind] + flat.size] = flat
+    patterns = patterns or [np.ones((tile, tile), bool)]  # (a mask of whole tiles has none)
+    bias = np.where(np.stack(patterns), 0.0, _MASKED_SCORE).astype(np.float32)
+    return BlockMaskLayout(
+        tile, clean, response, copies, mask, plan.reshape(-1), int(offsets[-1]),
+        tuple(int(o) for o in offsets[:4]), bias, visited,
+    )
+
+
+def _to_col(row):
+    """[1, n] -> [n, 1] with a select and a sum: exact, and no relayout."""
+    n = row.shape[1]
+    eye = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) == jax.lax.broadcasted_iota(
+        jnp.int32, (n, n), 1
+    )
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _stack_heads(ref, group: int, head_dim: int, real_rows):
+    """A tile of a group's query heads -> [group * tile, head_dim]: the heads
+    one after another, so that one product with a key tile serves them all.
+    `ref` is [tile, group * head_dim] (heads side by side, as the output
+    projection reads and writes them) or [tile, group, head_dim] (a head a
+    sublane, as the rotation writes q and reads dq: read so, no copy of q
+    stands between the two). Rows past the sequence's end (`real_rows`
+    [tile, 1] false: the last tile's, which hold whatever was there) become
+    zeros."""
+    head = (lambda r: ref[:, r, :]) if len(ref.shape) == 3 else (
+        lambda r: ref[:, r * head_dim:(r + 1) * head_dim]
+    )
+    return jnp.concatenate([jnp.where(real_rows, head(r), 0.0) for r in range(group)], axis=0)
+
+
+def _real_rows(tile: int, positions: int):
+    at = pl.program_id(2) * tile + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+    return at < positions
+
+
+def _biased(scores, bias, group: int):
+    """A pattern's bias [tile, keys] on the scores of a group's heads [group *
+    tile, keys]."""
+    if bias is None:
+        return scores
+    rows, keys = scores.shape
+    return (scores.reshape(group, rows // group, keys) + bias[None]).reshape(rows, keys)
+
+
+def _walk_chunks(plan_ref, layout: BlockMaskLayout, fold) -> None:
+    """`fold(key tiles, their patterns or None)` over the chunks of this
+    program's query tile, as `layout.plan` lists them: tuples of one or two
+    scalars."""
+    base = pl.program_id(2) * layout.stride
+
+    def full_pair(j, _):
+        a = plan_ref[base + layout.offsets[0] + j]
+        fold((a, a + 1), None)
+        return 0
+
+    def full_single(j, _):
+        fold((plan_ref[base + layout.offsets[1] + j],), None)
+        return 0
+
+    def masked_pair(j, _):
+        a, bias_a, b, bias_b = (plan_ref[base + layout.offsets[2] + 4 * j + n] for n in range(4))
+        fold((a, b), (bias_a, bias_b))
+        return 0
+
+    def masked_single(j, _):
+        entry = base + layout.offsets[3] + 2 * j
+        fold((plan_ref[entry],), (plan_ref[entry + 1],))
+        return 0
+
+    counts = layout.plan.reshape(layout.tiles, -1)[:, :4].max(axis=0)
+    for kind, body in enumerate((full_pair, full_single, masked_pair, masked_single)):
+        if counts[kind]:  # (a kind no query tile has is not traced: two tiles may not exist)
+            jax.lax.fori_loop(0, plan_ref[base + kind], body, 0)
+
+
+def _key_rows(ref, tiles, tile: int):
+    """The rows of the key tiles `tiles` of a [padded, head_dim] block."""
+    rows = [ref[pl.ds(pl.multiple_of(t * tile, tile), tile), :] for t in tiles]
+    return rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
+
+
+def _patterns(ref, patterns, axis: int):
+    """The bias of a chunk's tiles, side by side along the keys' axis."""
+    if patterns is None:
+        return None
+    return jnp.concatenate([ref[n] for n in patterns], axis=axis) if len(patterns) > 1 else ref[patterns[0]]
+
+
+def _block_mask_fwd_kernel(
+    plan_ref, q_ref, k_ref, vt_ref, bias_ref, o_ref, lse_ref, q_scr, m_scr, l_scr, acc_scr,
+    *, layout: BlockMaskLayout, group: int,
+):
+    """One query tile of one key/value head's query heads, the sequence's keys
+    and values staying in VMEM. Scores are [keys, queries]: the soft-max's
+    sums run down the sublanes and its statistics are rows, a sixteenth of
+    what columns take. Two walks over the tile's chunks: the first finds each
+    row's maximum and sum (and from them the log-sum-exp the backward kernel
+    reads), the second multiplies p = exp(score - maximum) / sum with the
+    values — the NORMALISED weights, and the exp taken where the plain path
+    and the rollout's cache attention take it, so that the three hand the MXU
+    the same numbers to round: exp(score - log-sum-exp) is the same weight to
+    float32's rounding but another float, and one in a hundred then rounds
+    the other way."""
+    tile, head_dim = layout.tile, k_ref.shape[1]
+    scale = head_dim**-0.5
+    q_scr[...] = _stack_heads(q_ref, group, head_dim, _real_rows(tile, layout.positions))
+    m_scr[...] = jnp.full_like(m_scr, _MASKED_SCORE)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def scores(tiles, patterns):
+        # Scaled after the product, as the plain path does it: the operands
+        # the MXU rounds are then the same numbers.
+        found = _dot(_key_rows(k_ref, tiles, tile), q_scr[...], _NT) * scale  # [keys, queries]
+        bias = _patterns(bias_ref, patterns, 0)
+        return found if bias is None else found + bias
+
+    def statistics(tiles, patterns):
+        found = scores(tiles, patterns)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(found, axis=0, keepdims=True))
+        l_scr[...] = jnp.exp(m_prev - m_new) * l_scr[...] + jnp.sum(
+            jnp.exp(found - m_new), axis=0, keepdims=True
+        )
+        m_scr[...] = m_new
+
+    _walk_chunks(plan_ref, layout, statistics)
+    lse_ref[...] = m_scr[...] + jnp.log(l_scr[...])
+    l_scr[...] = 1.0 / l_scr[...]
+
+    def attend(tiles, patterns):
+        p = jnp.exp(scores(tiles, patterns) - m_scr[...]) * l_scr[...]
+        values = [vt_ref[t] for t in tiles]  # [head_dim, tile] each
+        values = values[0] if len(values) == 1 else jnp.concatenate(values, axis=1)
+        acc_scr[...] += _dot(values, p, _NN)  # [head_dim, queries]
+
+    _walk_chunks(plan_ref, layout, attend)
+    for r in range(group):
+        o_ref[:, r * head_dim:(r + 1) * head_dim] = acc_scr[:, r * tile:(r + 1) * tile].T.astype(
+            o_ref.dtype
+        )
+
+
+def _block_mask_bwd_kernel(
+    plan_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, bias_ref, dq_ref, dkt_ref, dvt_ref,
+    q_scr, do_scr, qt_scr, dot_scr, lse_scr, delta_scr, dq_scr,
+    *, layout: BlockMaskLayout, group: int,
+):
+    """One query tile: dq of its queries, and its share of the sequence's dk
+    and dv, which stay in VMEM across the query tiles, transposed ([tiles,
+    head_dim, tile]) so that they too are plain products — of the tile's q and
+    d_out transposed once, with the same p and ds that give dq — and the
+    group's heads add up in the contraction. Scores are [queries, keys]."""
+    tile, head_dim = layout.tile, k_ref.shape[1]
+    scale = head_dim**-0.5
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        dkt_ref[...] = jnp.zeros_like(dkt_ref)
+        dvt_ref[...] = jnp.zeros_like(dvt_ref)
+
+    real_rows = _real_rows(tile, layout.positions)
+    q_scr[...] = _stack_heads(q_ref, group, head_dim, real_rows)
+    do_scr[...] = _stack_heads(do_ref, group, head_dim, real_rows)
+    delta_scr[...] = jnp.sum(
+        do_scr[...] * _stack_heads(o_ref, group, head_dim, real_rows), axis=1, keepdims=True
+    )
+    for r in range(group):
+        rows = slice(r * tile, (r + 1) * tile)
+        lse_scr[rows, :] = jnp.where(real_rows, _to_col(lse_ref[:, rows]), 0.0)
+        qt_scr[:, rows] = q_scr[rows, :].T * scale  # (dk = ds^T q scale: scaled once, here)
+        dot_scr[:, rows] = do_scr[rows, :].T
+    dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    def fold(tiles, patterns):
+        k, v = _key_rows(k_ref, tiles, tile), _key_rows(v_ref, tiles, tile)
+        scores = _biased(_dot(q_scr[...], k, _NT) * scale, _patterns(bias_ref, patterns, 1), group)
+        p = jnp.exp(scores - lse_scr[...])
+        ds = p * (_dot(do_scr[...], v, _NT) - delta_scr[...])
+        dq_scr[...] += _dot(ds, k, _NN)
+        dvt = _dot(dot_scr[...], p, _NN)  # [head_dim, keys]
+        dkt = _dot(qt_scr[...], ds, _NN)
+        for n, t in enumerate(tiles):
+            dvt_ref[t] += dvt[:, n * tile:(n + 1) * tile]
+            dkt_ref[t] += dkt[:, n * tile:(n + 1) * tile]
+
+    _walk_chunks(plan_ref, layout, fold)
+    for r in range(group):
+        dq_ref[:, r, :] = (dq_scr[r * tile:(r + 1) * tile, :] * scale).astype(dq_ref.dtype)
+
+
+def _block_mask_call(kernel, name, spec, q, ins, outs, scratch):
+    """The call both kernels share: a grid of (sequence, key/value head, query
+    tile), the plan prefetched; `ins` and `outs` name each operand's block
+    spec, `outs` with its shape."""
+    layout_key, heads, kv_heads, interpret = spec
+    layout = block_mask_layout(*layout_key)
+    n, positions, _, group, head_dim = q.shape
+    tile, tiles = layout.tile, layout.tiles
+    specs = {
+        "q": pl.BlockSpec((None, tile, None, group, head_dim), lambda b, g, i, plan: (b, i, g, 0, 0)),
+        "out": pl.BlockSpec((None, tile, group * head_dim), lambda b, g, i, plan: (b, i, g)),
+        "kv": pl.BlockSpec((None, layout.padded, head_dim), lambda b, g, i, plan: (b, 0, g)),
+        "lse": pl.BlockSpec((None, None, 1, group * tile), lambda b, g, i, plan: (b, g, 0, i)),
+        "bias": pl.BlockSpec((layout.bias.shape[0], tile, tile), lambda b, g, i, plan: (0, 0, 0)),
+        "bias_t": pl.BlockSpec(
+            (layout.bias.shape[0], tile, group * tile), lambda b, g, i, plan: (0, 0, 0)
+        ),
+        "dkv_t": pl.BlockSpec(
+            (None, None, tiles, head_dim, tile), lambda b, g, i, plan: (b, g, 0, 0, 0)
+        ),
+    }
+    shapes = {
+        "q": (q.shape, q.dtype),
+        "out": ((n, positions, heads * head_dim), q.dtype),
+        "lse": ((n, kv_heads, 1, tiles * group * tile), jnp.float32),
+        "dkv_t": ((n, kv_heads, tiles, head_dim, tile), jnp.float32),
+    }
+    operands = tuple(operand for _, operand in ins)
+    rows = group * tile
+    sized = {
+        "rows": (rows, head_dim), "rows_t": (head_dim, rows), "col": (rows, 1), "row": (1, rows)
+    }
+    return pl.pallas_call(
+        functools.partial(kernel, layout=layout, group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n, kv_heads, tiles),
+            in_specs=[specs[kind] for kind, _ in ins],
+            out_specs=[specs[kind] for kind in outs],
+            scratch_shapes=[pltpu.VMEM(sized[kind], jnp.float32) for kind in scratch],
+        ),
+        out_shape=[_out_struct(*shapes[kind], *operands) for kind in outs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        name=name,
+        interpret=interpret,
+    )(jnp.asarray(layout.plan), *operands)
+
+
+def _block_mask_forward(q, k, v, spec):
+    """q [n, positions, kv_heads, group, hd], k and v [n, padded, kv_heads *
+    hd] -> (out [n, positions, heads * hd], log-sum-exp [n, kv_heads, 1,
+    tiles * group * tile]: a query tile's heads one after another)."""
+    layout = block_mask_layout(*spec[0])
+    kv_heads, group = spec[2], spec[1] // spec[2]
+    n, _, kv_width = v.shape
+    # Values transposed a tile, [n, kv_heads, tiles, hd, tile], and a pattern's
+    # bias transposed and repeated for the group's heads: small arrays both.
+    v_t = jnp.transpose(
+        v.reshape(n, layout.tiles, layout.tile, kv_heads, kv_width // kv_heads), (0, 3, 1, 4, 2)
+    )
+    bias_t = jnp.asarray(np.tile(layout.bias.transpose(0, 2, 1), (1, 1, group)))
+    return _block_mask_call(
+        _block_mask_fwd_kernel, "block_mask_attention_fwd", spec, q,
+        [("q", q), ("kv", k), ("dkv_t", v_t), ("bias_t", bias_t)], ["out", "lse"],
+        ["rows", "row", "row", "rows_t"],
+    )
+
+
+def _block_mask_backward(q, k, v, out, lse, d_out, spec):
+    bias = jnp.asarray(block_mask_layout(*spec[0]).bias)
+    dq, dk_t, dv_t = _block_mask_call(
+        _block_mask_bwd_kernel, "block_mask_attention_bwd", spec, q,
+        [("q", q), ("kv", k), ("kv", v), ("out", out), ("out", d_out), ("lse", lse), ("bias", bias)],
+        ["q", "dkv_t", "dkv_t"],
+        ["rows", "rows", "rows_t", "rows_t", "col", "col", "rows"],
+    )
+    # [n, kv_heads, tiles, hd, tile] -> [n, padded, kv_heads * hd]: small arrays.
+    back = lambda t: jnp.transpose(t, (0, 2, 4, 1, 3)).reshape(k.shape).astype(k.dtype)
+    return dq, back(dk_t), back(dv_t)
+
+
+# What the backward pass reads of the forward: kept by name, so that a
+# rematerialised caller (`jax.checkpoint` with a policy that saves these names)
+# does not run the forward kernel a second time.
+BLOCK_MASK_RESIDUALS = ("attended", "attended_lse")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _block_mask(q, k, v, spec):
+    return _block_mask_forward(q, k, v, spec)[0]
+
+
+def _block_mask_fwd(q, k, v, spec):
+    out, lse = _block_mask_forward(q, k, v, spec)
+    out = checkpoint_name(out, BLOCK_MASK_RESIDUALS[0])
+    lse = checkpoint_name(lse, BLOCK_MASK_RESIDUALS[1])
+    return out, (q, k, v, out, lse)
+
+
+def _block_mask_bwd(spec, residuals, d_out):
+    # The backward pass's ops carry the scope the forward's do, whatever name
+    # stack the rule is traced under.
+    with annotate(SCOPES["attention_scores"]):
+        return _block_mask_backward(*residuals, d_out, spec)
+
+
+_block_mask.defvjp(_block_mask_fwd, _block_mask_bwd)
+
+
+def block_mask_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, *, block_length: int, clean: int, copies: int,
+    tile: int = 128, interpret: bool = False,
+) -> jax.Array:
+    """The block-diffusion update's attention, forward and backward in Pallas.
+
+    q [n, P, heads, hd], k and v [n, P, kv_heads, hd], P = clean + copies *
+    (clean - block_length): the clean copy (prompt block, then the response)
+    first, then the noisy copies of the response. A query in copy c, block b
+    sees a key in copy c', block b' iff (c' = 0 and b' < b) or (c' = c and b'
+    = b); query head h reads key/value head h // (heads / kv_heads). Returns
+    [n, P, heads * hd], what `networks/sdar.py::_attend_copies` returns a
+    sequence.
+
+    One forward kernel over the (query tile, key tile) pairs that hold an
+    allowed pair (`block_mask_layout`: 49 of 169 at clean 516, 2 copies), a
+    key/value head's query heads stacked into one product a chunk, the
+    soft-max in two walks (log-sum-exp, then the normalised weights times the
+    values), and one backward kernel over the same pairs from the saved
+    output and log-sum-exp (`BLOCK_MASK_RESIDUALS`). q and its cotangent are
+    read and written where and as they lie ([n, P, heads, hd]: a head a
+    sublane), the result and its cotangent as the output projection has them
+    ([n, P, heads * hd]); only k and v (an eighth of q here) are copied,
+    padded to whole tiles. Operands are multiplied as they come, at DEFAULT
+    precision, and accumulated in float32. `interpret` runs the Pallas
+    interpreter (a test asks for it)."""
+    n, positions, heads, head_dim = q.shape
+    kv_heads = k.shape[2]
+    layout = block_mask_layout(block_length, clean, copies, tile)
+    if positions != layout.positions:
+        raise ValueError(f"{positions} positions, the layout has {layout.positions}")
+    padded = lambda x: jnp.pad(
+        x.reshape(n, positions, -1), ((0, 0), (0, layout.padded - positions), (0, 0))
+    )
+    spec = ((block_length, clean, copies, tile), heads, kv_heads, interpret)
+    grouped = q.reshape(n, positions, kv_heads, heads // kv_heads, head_dim)
+    return _block_mask(grouped, padded(k), padded(v), spec)

@@ -69,7 +69,7 @@ from stoix_tpu import envs
 from stoix_tpu.base_types import ActorCriticOptStates, ActorCriticParams, ExperimentOutput
 from stoix_tpu.evaluator import carry_evaluator_setup
 from stoix_tpu.networks import olmoe, sdar
-from stoix_tpu.observability import SCOPES, annotate, get_logger, span
+from stoix_tpu.observability import SCOPES, annotate, get_logger, get_registry, span
 from stoix_tpu.ops import (
     losses,
     shuffled_minibatch_epoch,
@@ -79,7 +79,7 @@ from stoix_tpu.ops.distributions import Categorical
 from stoix_tpu.parallel import is_coordinator
 from stoix_tpu.systems import anakin
 from stoix_tpu.systems.ppo.anakin.ff_lm_ppo import LMPPOLearnerState
-from stoix_tpu.systems.runner import AnakinSetup, run_anakin_experiment
+from stoix_tpu.systems.runner import LAST_RUN_STATS, AnakinSetup, run_anakin_experiment
 from stoix_tpu.utils import config as config_lib
 from stoix_tpu.utils.jax_utils import count_parameters
 from stoix_tpu.utils.training import make_learning_rate
@@ -440,6 +440,16 @@ def network_functions(
     )
 
 
+def _update_attention_gauge() -> Any:
+    return get_registry().gauge(
+        "stoix_tpu_sdar_update_attention",
+        "the block-diffusion update's attention as the learner was set up, by field: kernel "
+        "(1 = the Pallas kernel over the block mask, 0 = plain masked products), "
+        "tiles_visited and tiles_total (128 x 128 tiles of (query, key) positions with an "
+        "allowed pair, and all)",
+    )
+
+
 def sequence_length(env: envs.Environment) -> int:
     """Positions of one sequence: the prompt block and the response."""
     return int(env.block_length) + int(env.length)
@@ -558,6 +568,11 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
         learn_per_shard, mesh, state_specs, episode_metrics_spec=P(None, None, "data")
     )
 
+    # How the update multiplies its attention scores (decided by what the
+    # network sees of the backend and the shapes), for the run's record.
+    for field, value in actor.copies_attention(sequence_length(env), int(env.passes)).items():
+        _update_attention_gauge().set(value, {"field": field})
+
     if is_coordinator():
         get_logger("stoix_tpu.setup").info(
             "[setup] %s parameters | mesh %s | %s sequences x %s blocks of %s tokens, %s passes a "
@@ -585,10 +600,15 @@ def run_experiment(config: Any) -> float:
     init_cache = lambda batch: sdar.init_cache(
         int(net.get("num_layers", 1)), batch, max_len, int(net.num_kv_heads), int(net.head_dim)
     )
-    return run_anakin_experiment(
+    final_return = run_anakin_experiment(
         config, learner_setup,
         evaluator_setup_fn=carry_evaluator_setup(init_cache),
     )
+    LAST_RUN_STATS["update_attention"] = {
+        dict(labels)["field"]: int(value)
+        for labels, value in _update_attention_gauge().labels_and_values()
+    }
+    return final_return
 
 
 def main() -> float:

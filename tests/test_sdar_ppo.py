@@ -20,7 +20,8 @@ from stoix_tpu import envs
 from stoix_tpu.base_types import ActorCriticParams
 from stoix_tpu.envs.block_token_task import BlockTokenTask
 from stoix_tpu.networks import olmoe, sdar
-from stoix_tpu.observability import BLOCK_SCOPES, DIFFUSION_SCOPES, SCOPES
+from stoix_tpu.observability import BLOCK_SCOPES, DIFFUSION_SCOPES, SCOPES, get_registry
+from stoix_tpu.ops import pallas_attention
 from stoix_tpu.reference import sdar as reference
 from stoix_tpu.systems.ppo.anakin import ff_sdar_ppo
 from stoix_tpu.utils import config as config_lib
@@ -298,6 +299,157 @@ def test_the_updates_attention_in_runs_of_blocks_is_the_whole_rows(monkeypatch, 
         monkeypatch.setattr(sdar, "_KEY_GROUPS", groups)
         outs.append(jax.jit(nets.trunk_copies)(actor_params, copies["clean"], copies["noisy"])[0])
     _close(outs[0], outs[1])
+
+
+# --------------------------------------------------------------------------- #
+# The update's attention as a Pallas kernel (ops/pallas_attention.py), through
+# the interpreter: against the plain `_attend_copies`, which the tests above
+# pin against the reference
+# --------------------------------------------------------------------------- #
+
+# (block length, copies, response, tile): a clean part of 36, 88 and 52
+# positions is no whole number of tiles of 16, and 20 fills no tile of 128.
+KERNEL_CASES = [(4, 1, 32, 16), (4, 2, 84, 16), (8, 1, 80, 16), (8, 2, 48, 16), (4, 2, 16, 128)]
+
+
+@pytest.fixture(scope="module", params=KERNEL_CASES, ids=lambda c: "B%d-S%d-R%d-T%d" % c)
+def kernel_and_plain(request):
+    """Output and gradients (of a weighted sum) of both, at the tiny preset's
+    heads: 4 query heads on 2 key/value heads of 16."""
+    size, copies, response, tile = request.param
+    clean = size + response
+    positions = clean + copies * response
+    model = sdar.SdarLM(
+        vocab_size=VOCAB, hidden_size=64, num_heads=4, num_kv_heads=2, head_dim=16,
+        num_experts=EXPERTS, experts_held=4, experts_per_token=TOP_K, expert_width=32,
+        block_length=size,
+    )
+    keys = jax.random.split(jax.random.PRNGKey(size + copies), 4)
+    q = jax.random.normal(keys[0], (2, positions, 4, 16))
+    k, v = (jax.random.normal(key, (2, positions, 2, 16)) for key in keys[1:3])
+    weights = jax.random.normal(keys[3], (2, positions, 64))
+    plain = jax.vmap(lambda q, k, v: model._attend_copies(q, k, v, clean, copies))
+    kernel = lambda q, k, v: pallas_attention.block_mask_attention(
+        q, k, v, block_length=size, clean=clean, copies=copies, tile=tile, interpret=True
+    )
+    both = {}
+    for name, attend in (("plain", plain), ("kernel", kernel)):
+        loss = lambda q, k, v, attend=attend: jnp.sum(attend(q, k, v) * weights)
+        grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+        both[name] = dict(zip(("output", "dq", "dk", "dv"), (jax.jit(attend)(q, k, v),) + grads))
+    return both
+
+
+@pytest.mark.parametrize("what", ["output", "dq", "dk", "dv"])
+def test_the_block_kernel_is_the_plain_attention(kernel_and_plain, what):
+    _close(kernel_and_plain["kernel"][what], kernel_and_plain["plain"][what])
+
+
+@pytest.mark.parametrize("size,copies,response,tile", KERNEL_CASES + [(4, 2, 512, 128)])
+def test_the_kernels_mask_is_the_rule_counted(size, copies, response, tile):
+    """The numpy mask the kernels' tables are cut from allows B^2 (n + 1)(n +
+    2) / 2 + S B^2 sum(b + 1) pairs of real positions (402,448 at the
+    benchmark cell's shape), gives no padded position to a real query, and
+    leaves no row empty; the tables list every tile with an allowed pair
+    once (49 of 169 at the cell's shape)."""
+    layout = pallas_attention.block_mask_layout(size, size + response, copies, tile)
+    blocks, real = response // size, layout.positions
+    want = size * size * ((blocks + 1) * (blocks + 2) // 2 + copies * sum(b + 1 for b in range(1, blocks + 1)))
+    assert int(layout.mask[:real, :real].sum()) == want
+    assert not layout.mask[:real, real:].any() and layout.mask.any(axis=1).all()
+    where = reference.layout(blocks + 1, size, copies)
+    np.testing.assert_array_equal(
+        layout.mask[:real, :real], np.asarray(reference.block_mask(where["block"], where["copy"]))
+    )
+    by_tile = layout.mask.reshape(layout.tiles, tile, layout.tiles, tile).any(axis=(1, 3))
+    assert layout.tiles_visited == int(by_tile.sum()) and layout.tiles_total == layout.tiles**2
+    plan = layout.plan.reshape(layout.tiles, -1)
+    listed = set()
+    for i, row in enumerate(plan):
+        at = lambda kind, width: row[layout.offsets[kind]:][:row[kind] * width].reshape(-1, width)
+        tiles = [t for a in at(0, 1)[:, 0] for t in (a, a + 1)] + list(at(1, 1)[:, 0])
+        tiles += list(at(2, 4)[:, [0, 2]].reshape(-1)) + list(at(3, 2)[:, 0])
+        assert len(tiles) == len(set(tiles))
+        listed |= {(i, int(t)) for t in tiles}
+    assert listed == {(int(i), int(j)) for i, j in zip(*np.nonzero(by_tile))}
+    if (size, copies, response, tile) == (4, 2, 512, 128):
+        assert want == 402448 and (layout.tiles_visited, layout.tiles_total) == (49, 169)
+
+
+def test_the_block_kernels_padded_queries_are_finite_dropped_and_without_cotangent():
+    """The last tile's rows past the sequence's end hold whatever the
+    interpreter (NaN) or the chip left there: nothing of them reaches a real
+    row, forward or backward, the log-sum-exp rows they get are finite, and
+    padded keys receive no gradient."""
+    size, copies, response, tile = 4, 2, 24, 16
+    layout = pallas_attention.block_mask_layout(size, size + response, copies, tile)
+    real, padded = layout.positions, layout.padded
+    assert padded - real == 4
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(keys[0], (2, real, 2, 2, 16))  # [n, P, kv heads, heads a kv head, hd]
+    d_out = jax.random.normal(keys[1], (2, real, 64))
+    k, v = (
+        jnp.pad(jax.random.normal(key, (2, real, 32)), ((0, 0), (0, padded - real), (0, 0)))
+        for key in keys[2:]
+    )
+    spec = ((size, size + response, copies, tile), 4, 2, True)
+    out, lse = pallas_attention._block_mask_forward(q, k, v, spec)
+    assert out.shape == d_out.shape
+    assert bool(jnp.isfinite(out).all()) and bool(jnp.isfinite(lse).all())
+    dq, dk, dv = pallas_attention._block_mask_backward(q, k, v, out, lse, d_out, spec)
+    for grad in (dq, dk, dv):
+        assert bool(jnp.isfinite(grad).all())
+    assert dq.shape == q.shape
+    np.testing.assert_array_equal(np.asarray(dk[:, real:]), 0.0)
+    np.testing.assert_array_equal(np.asarray(dv[:, real:]), 0.0)
+
+
+def _through_the_kernel(monkeypatch):
+    """`trunk_copies` as a TPU runs it, the kernels through the interpreter."""
+    monkeypatch.setattr(
+        sdar.SdarLM, "copies_attention",
+        lambda self, clean, copies: {"kernel": 1, "tiles_visited": 0, "tiles_total": 0},
+    )
+    monkeypatch.setattr(
+        sdar, "block_mask_attention",
+        lambda *args, **kwargs: pallas_attention.block_mask_attention(
+            *args, **kwargs, tile=16, interpret=True
+        ),
+    )
+
+
+@pytest.mark.parametrize("what", ["hidden", "gradient", "kernels"])
+def test_the_teacher_forced_pass_through_the_kernel_is_the_plain_one(monkeypatch, what):
+    """Off a TPU `trunk_copies` holds no `pallas_call`; told to take the
+    kernel, its result and its gradient (through the rematerialised layers,
+    whose policy keeps the kernel's residuals) are the plain path's, and a
+    layer's backward pass runs the forward kernel no second time."""
+    batch = _record(6, sequences=2)
+    copies = ff_sdar_ppo.record_copies(batch, PASSES)
+    nets, actor_params, _ = _model(2)
+
+    def loss(params):
+        hidden, _ = nets.trunk_copies(params, copies["clean"], copies["noisy"])
+        return jnp.sum(jnp.sin(hidden))
+
+    forward = lambda: jax.jit(nets.trunk_copies)(actor_params, copies["clean"], copies["noisy"])[0]
+    jaxpr = lambda: str(jax.make_jaxpr(jax.grad(loss))(actor_params))
+    plain = {"hidden": forward, "gradient": lambda: jax.jit(jax.grad(loss))(actor_params), "kernels": jaxpr}[what]()
+    if what == "kernels":
+        assert "pallas_call" not in plain
+    _through_the_kernel(monkeypatch)
+    if what == "kernels":
+        text = jaxpr()
+        assert text.count("block_mask_attention_fwd") == 2 and text.count("block_mask_attention_bwd") == 2
+    elif what == "hidden":
+        _close(forward(), plain)
+    else:
+        got = jax.jit(jax.grad(loss))(actor_params)
+        for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(plain)):
+            scale = max(1.0, float(jnp.max(jnp.abs(b))))  # summation order, relative to the leaf
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5 * scale, err_msg=str(path)
+            )
 
 
 @pytest.mark.parametrize("output", ["log_probs", "value", "expert_index"])
@@ -605,6 +757,13 @@ def test_a_short_run_learns_the_block_token_task(devices):
     episode = logged[LogEvent.ACT][-1]
     assert np.asarray(episode["rollout_block"]).shape[-1] == SIZE
     assert {"rollout_commit", "rollout_token", "rollout_log_prob", "rollout_value"} <= set(episode)
+    # Which way the update's attention went is on the run's record: off a TPU
+    # the plain products, and the tiles the kernel would have visited.
+    layout = pallas_attention.block_mask_layout(SIZE, SIZE + RESPONSE, PASSES)
+    want = {"kernel": 0, "tiles_visited": layout.tiles_visited, "tiles_total": layout.tiles_total}
+    assert ff_sdar_ppo.LAST_RUN_STATS["update_attention"] == want
+    gauge = get_registry().gauge("stoix_tpu_sdar_update_attention")
+    assert {dict(labels)["field"]: int(value) for labels, value in gauge.labels_and_values()} == want
 
 
 def test_the_benchmark_keeps_a_copy_of_the_reference(model):

@@ -1,0 +1,67 @@
+"""The TPU's compiler on the kernels of the main path at their real widths,
+without a chip: libtpu compiles for a v5e that is described, not attached
+(the `on-chip-measurement` guide, section 2). What Mosaic refuses — a slice
+off the tiling, more VMEM than a kernel may take — it refuses here, at no
+chip time; nothing runs, so results and times stay the chip's to give.
+
+The topology is described inside a fixture, never while a module is imported:
+one process at a time may load the TPU's library, and every worker imports
+every test file."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from stoix_tpu.ops import pallas_attention
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu from describing a chip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache and
+    # cannot be read back without one: keep it out.
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+# The benchmark's block-diffusion cell: a minibatch of 16 sequences of [clean
+# 516 ; 2 noisy copies of 512], 32 query heads on 4 key/value heads of 128.
+CELL = dict(block_length=4, clean=516, copies=2)
+SEQUENCES, POSITIONS, HEADS, KV_HEADS, HEAD_DIM = 16, 1540, 32, 4, 128
+
+
+def _compiled(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip) for shape in shapes]
+    return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).compile()
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_mosaic_takes_the_block_mask_kernels_at_the_cells_shape(one_chip, what):
+    attend = lambda q, k, v: pallas_attention.block_mask_attention(q, k, v, **CELL)
+    q = (SEQUENCES, POSITIONS, HEADS, HEAD_DIM)
+    kv = (SEQUENCES, POSITIONS, KV_HEADS, HEAD_DIM)
+    if what == "forward":
+        text = _compiled(attend, one_chip, q, kv, kv).as_text()
+        assert text.count("block_mask_attention_fwd") and "block_mask_attention_bwd" not in text
+    else:
+        loss = lambda q, k, v, w: jnp.sum(attend(q, k, v) * w)
+        compiled = _compiled(
+            jax.grad(loss, argnums=(0, 1, 2)), one_chip, q, kv, kv, (SEQUENCES, POSITIONS, HEADS * HEAD_DIM)
+        )
+        text = compiled.as_text()
+        assert "block_mask_attention_fwd" in text and "block_mask_attention_bwd" in text
+        # q, the result and their cotangents pass to and from the kernels as
+        # they lie: nothing of their size is copied around them but what the
+        # entry's own layouts ask for.
+        assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 4 * SEQUENCES * POSITIONS * HEADS * HEAD_DIM
