@@ -307,13 +307,17 @@ def get_rnn_evaluator_fn(
     return evaluator
 
 
-def carry_evaluator_setup(init_carry: Callable[[int], Any]):
+def carry_evaluator_setup(init_carry: Optional[Callable[[int], Any]] = None):
     """The `evaluator_setup_fn` of a system whose policy carries state:
-    (evaluator, absolute-metric evaluator) over `get_rnn_evaluator_fn`."""
+    (evaluator, absolute-metric evaluator) over `get_rnn_evaluator_fn`.
+    Without `init_carry` the carry is the one the system's `act_fn` declares
+    (`act_fn.init_carry`: what its network built, known only once the
+    learner is set up)."""
 
     def setup(eval_env: Environment, act_fn: Any, config: Any, mesh: Mesh) -> Tuple[Any, Any]:
+        init = init_carry or act_fn.init_carry
         make = lambda multiplier: get_rnn_evaluator_fn(
-            eval_env, act_fn, config, mesh, init_carry, eval_multiplier=multiplier
+            eval_env, act_fn, config, mesh, init, eval_multiplier=multiplier
         )
         return make(1), make(int(config.arch.get("absolute_metric_multiplier", 10)))
 

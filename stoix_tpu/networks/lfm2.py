@@ -1,0 +1,383 @@
+"""The LFM2 mixture-of-experts decoder as a token policy: a stack whose layers
+differ — gated short convolutions beside grouped-query attention, two dense
+feed-forwards and then routed ones — with one decode carry over both kinds of
+state; one expert-parallel rank's share of each routed layer and of the
+vocabulary. Two entry points over ONE set of parameters, as networks/olmoe.py.
+
+Published layer (`model_type` `lfm2_moe`,
+https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json;
+stoix_tpu/reference/lfm2.py writes it out plainly and is what the tests and
+the benchmark compare this file with). Every layer l, no bias anywhere:
+
+    h = h + mixer_l(RMSNorm_op(h));   h = h + ffn_l(RMSNorm_ffn(h))
+
+  * `conv` mixer (`ShortConv`): [B ; C ; X] = u W_in; z = B * X; c_t =
+    sum_j w_j * z_{t-K+1+j} (depthwise, causal, K = `conv_kernel`, z before
+    the sequence is 0); (C * c) W_out. Its decode state is the last K - 1
+    rows of z a sequence (`ConvTail`), not keys and values.
+  * `full_attention` mixer (`GroupedQueryAttention`): networks/sdar.py's
+    grouped-query projections with a per-head q/k RMSNorm and rotate-half
+    RoPE; causal softmax. Its decode state is the keys and values (`KV`).
+  * feed-forward of the first `num_dense_layers` layers (`DenseMLP`): one
+    SwiGLU of width `dense_width`; of the others (`RoutedMLP`): float32
+    sigmoid scores over ALL `num_experts`, the top-k CHOSEN by score +
+    `expert_bias`, WEIGHTED by the scores themselves at the chosen experts
+    over (their sum + 1e-6), times `routed_scaling_factor`; SwiGLU experts of
+    width `expert_width`, no shared expert (networks/olmoe.py::moe).
+  * final RMSNorm; the head is the embedding's transpose (tied).
+
+The chip's share: `experts_held` experts from `expert_offset` on are here
+and `vocab_size` is the slice of the vocabulary held here; mixers, dense
+layers and the router are whole. What the absent experts would add is left
+out of the layer's result, and that partial result goes on to the next
+layer. Nothing stands in for the other ranks or their exchange.
+
+The stack is data: `layer_types` names each layer's mixer and
+`num_dense_layers` says which feed-forwards are dense. A `Block` is a mixer,
+a feed-forward and their two norms; a mixer has `forward` (whole sequence)
+and `step` (one token against its state).
+
+  * `Lfm2LM.forward(tokens [B, T])` — teacher-forced.
+  * `Lfm2LM.step(carry, token [B])` — one decode step. `Lfm2Carry` holds one
+    state a layer, of its mixer's kind, and `length` [B], each sequence's
+    next position. `init_carry(batch, max_len)` and `reset_carry(carry,
+    done)` are the network's own: a new sequence starts at length 0 with a
+    zero tail; cache entries at or beyond `length` are never read.
+
+Parameters, by name (the reference reads them by these names):
+  embed [V, D]; layer_<i>/{operator_norm [D], ffn_norm [D], mixer/{in_proj
+  [D, 3D], conv [K, D], out_proj [D, D]} or mixer/{wq [D, H*hd], wk wv [D,
+  KV*hd], wo [H*hd, D], q_norm k_norm [hd]}, ffn/{w1 w3 [D, F], w2 [F, D]} or
+  ffn/{router [D, E], expert_bias [E], gate up [held, D, Fm], down [held, Fm,
+  D]}}; final_norm [D].
+Initialisation is normal(0.02), norms start at one; `expert_bias` is a seeded
+normal(`expert_bias_scale`) buffer that only the CHOICE of experts reads, so
+it takes no gradient and an optimiser step leaves it as it was.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from stoix_tpu.networks.olmoe import _attend_cache, _stack, moe, rms_norm
+from stoix_tpu.networks.sdar import gqa_qkv
+from stoix_tpu.observability import SCOPES, annotate
+from stoix_tpu.ops.pallas_attention import best_attention
+
+_INIT = nn.initializers.normal(0.02)
+# Room of the held experts' chunk above the pairs uniform routing lands here,
+# in standard deviations of that count: the evaluator's 32 sequences land 32
+# +- 5 pairs on 8 of 32 experts, and at a quarter's room one decode step in
+# twenty took a second turn, each a second read of the held experts' weights:
+# a per cent of a window, swinging with the seed's routing (PERF.md §6, PR 33).
+# Five deviations are 64 rows for those 32 pairs and 192 for the rollout's 128.
+_HELD_ROOM_SIGMAS = 5.0
+
+
+class ConvTail(NamedTuple):
+    z: jax.Array  # [B, K - 1, D] the gated input's last rows, oldest first
+
+
+class KV(NamedTuple):
+    # Position-major as networks/olmoe.py's: a prefix of positions is one slab.
+    k: jax.Array  # [S, B, kv_heads, head_dim] float32
+    v: jax.Array
+
+
+class Lfm2Carry(NamedTuple):
+    layers: Tuple[Any, ...]  # a layer: its mixer's state, ConvTail or KV
+    length: jax.Array  # [B] int32: positions filled = the next token's position
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution. Input: the operator-normed hidden state."""
+
+    hidden_size: int
+    kernel: int
+    trace_scope = "conv_mixer"
+
+    def setup(self) -> None:
+        d = self.hidden_size
+        self.in_proj = self.param("in_proj", _INIT, (d, 3 * d))
+        self.conv = self.param("conv", _INIT, (self.kernel, d))
+        self.out_proj = self.param("out_proj", _INIT, (d, d))
+
+    def forward(self, u: jax.Array) -> jax.Array:
+        """u [B, T, D]: every position sees its own z and the K - 1 before."""
+        length = u.shape[1]
+        b, c, x = jnp.split(u @ self.in_proj, 3, axis=-1)
+        with annotate(SCOPES["conv_mixer_conv"]):
+            z = jnp.pad(b * x, ((0, 0), (self.kernel - 1, 0), (0, 0)))
+            mixed = sum(self.conv[j] * z[:, j:j + length] for j in range(self.kernel))
+            gated = c * mixed
+        return gated @ self.out_proj
+
+    def step(self, u: jax.Array, state: ConvTail, length: jax.Array):
+        """u [B, D] against the tail; the tail moves on by one row."""
+        b, c, x = jnp.split(u @ self.in_proj, 3, axis=-1)
+        with annotate(SCOPES["conv_mixer_conv"]):
+            window = jnp.concatenate([state.z, (b * x)[:, None]], axis=1)  # [B, K, D]
+            gated = c * jnp.sum(self.conv * window, axis=1)
+            state = ConvTail(window[:, 1:])
+        return gated @ self.out_proj, state
+
+
+class GroupedQueryAttention(nn.Module):
+    """Causal grouped-query attention with a per-head q/k RMSNorm and RoPE.
+    Input: the operator-normed hidden state."""
+
+    hidden_size: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rope_theta: float
+    rms_eps: float
+    trace_scope = "attention"
+
+    def setup(self) -> None:
+        d, q_width = self.hidden_size, self.num_heads * self.head_dim
+        kv_width = self.num_kv_heads * self.head_dim
+        ones = nn.initializers.ones
+        self.wq = self.param("wq", _INIT, (d, q_width))
+        self.wk = self.param("wk", _INIT, (d, kv_width))
+        self.wv = self.param("wv", _INIT, (d, kv_width))
+        self.wo = self.param("wo", _INIT, (q_width, d))
+        self.q_norm = self.param("q_norm", ones, (self.head_dim,))
+        self.k_norm = self.param("k_norm", ones, (self.head_dim,))
+
+    def _qkv(self, u: jax.Array, positions: jax.Array):
+        layer = {
+            "wq": self.wq, "wk": self.wk, "wv": self.wv, "q_norm": self.q_norm,
+            "k_norm": self.k_norm,
+        }
+        return gqa_qkv(
+            layer, u, positions, self.num_heads, self.num_kv_heads, self.head_dim,
+            self.rope_theta, self.rms_eps,
+        )
+
+    def forward(self, u: jax.Array) -> jax.Array:
+        batch, length, _ = u.shape
+        q, k, v = self._qkv(u, jnp.broadcast_to(jnp.arange(length), (batch, length)))
+        # `best_attention` (the Pallas flash kernel on a TPU) takes as many
+        # key/value heads as query heads: each is repeated for its group.
+        group = self.num_heads // self.num_kv_heads
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+        attended = best_attention(q, k, v, causal=True)  # [B, T, heads, head_dim]
+        return attended.reshape(batch, length, -1) @ self.wo
+
+    def step(self, u: jax.Array, state: KV, length: jax.Array):
+        batch = u.shape[0]
+        q, k, v = self._qkv(u, length)
+        at = (length, jnp.arange(batch))
+        state = KV(state.k.at[at].set(k), state.v.at[at].set(v))
+        grouped = q.reshape(batch, self.num_kv_heads, -1, self.head_dim)
+        attended = _attend_cache(grouped, state.k, state.v, length)
+        return attended.reshape(batch, -1) @ self.wo, state
+
+
+class DenseMLP(nn.Module):
+    """(silu(f W_1) * f W_3) W_2 on f [N, D]; no router, so no stats."""
+
+    hidden_size: int
+    width: int
+
+    def setup(self) -> None:
+        d, f = self.hidden_size, self.width
+        self.w1 = self.param("w1", _INIT, (d, f))
+        self.w3 = self.param("w3", _INIT, (d, f))
+        self.w2 = self.param("w2", _INIT, (f, d))
+
+    def __call__(self, f: jax.Array) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+        with annotate(SCOPES["dense_mlp"]):
+            return (jax.nn.silu(f @ self.w1) * (f @ self.w3)) @ self.w2, None
+
+
+class RoutedMLP(nn.Module):
+    """The held experts' part of the sigmoid-routed layer on f [N, D]."""
+
+    hidden_size: int
+    num_experts: int  # the router's width: every expert of the layer
+    experts_held: int  # of which this rank holds so many,
+    expert_offset: int  # from this one on
+    experts_per_token: int
+    width: int
+    scaling_factor: float
+    bias_scale: float
+
+    def setup(self) -> None:
+        d, e, held, f = self.hidden_size, self.num_experts, self.experts_held, self.width
+        self.router = self.param("router", _INIT, (d, e))
+        self.expert_bias = self.param(
+            "expert_bias", nn.initializers.normal(self.bias_scale), (e,)
+        )
+        self.gate = self.param("gate", _INIT, (held, d, f))
+        self.up = self.param("up", _INIT, (held, d, f))
+        self.down = self.param("down", _INIT, (held, f, d))
+
+    def __call__(self, f: jax.Array) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+        with annotate(SCOPES["moe"]):
+            return moe(
+                f, self.router, self.gate, self.up, self.down, self.experts_per_token,
+                held=(self.expert_offset, self.experts_held), renormalise=True,
+                held_room_sigmas=_HELD_ROOM_SIGMAS, score="sigmoid", bias=self.expert_bias,
+                epsilon=1e-6, scale=self.scaling_factor,
+            )
+
+
+class Block(nn.Module):
+    """h + mixer(norm(h)), then h + ffn(norm(h)); -> (h, the router's stats
+    or None). The mixer's scope covers its norm and its state's write."""
+
+    mixer: nn.Module
+    ffn: nn.Module
+    hidden_size: int
+    rms_eps: float
+
+    def setup(self) -> None:
+        ones = nn.initializers.ones
+        self.operator_norm = self.param("operator_norm", ones, (self.hidden_size,))
+        self.ffn_norm = self.param("ffn_norm", ones, (self.hidden_size,))
+
+    def _ffn(self, h: jax.Array):
+        normed = rms_norm(h, self.ffn_norm, self.rms_eps).reshape(-1, self.hidden_size)
+        out, stats = self.ffn(normed)
+        return h + out.reshape(h.shape), stats
+
+    def forward(self, x: jax.Array):
+        with annotate(SCOPES[self.mixer.trace_scope]):
+            h = x + self.mixer.forward(rms_norm(x, self.operator_norm, self.rms_eps))
+        return self._ffn(h)
+
+    def step(self, x: jax.Array, state: Any, length: jax.Array):
+        with annotate(SCOPES[self.mixer.trace_scope]):
+            mixed, state = self.mixer.step(
+                rms_norm(x, self.operator_norm, self.rms_eps), state, length
+            )
+            h = x + mixed
+        return (*self._ffn(h), state)
+
+
+class Lfm2LM(nn.Module):
+    """Embedding over the vocabulary slice, one `Block` a `layer_types` entry,
+    final norm, tied head over the slice. Both entry points return (logits
+    [.., V] un-normalised, hidden [.., D] after the final norm, the routed
+    layers' stats with a leading layer axis)."""
+
+    vocab_size: int
+    hidden_size: int
+    layer_types: Sequence[str]  # a layer: "conv" | "full_attention"
+    num_dense_layers: int
+    dense_width: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    num_experts: int
+    experts_held: int
+    experts_per_token: int
+    expert_width: int
+    expert_offset: int = 0
+    conv_kernel: int = 3
+    routed_scaling_factor: float = 1.0
+    expert_bias_scale: float = 0.01
+    rope_theta: float = 1000000.0
+    rms_eps: float = 1e-5
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return int(self.expert_offset), int(self.experts_held)
+
+    @property
+    def routed_layers(self) -> int:
+        return len(self.layer_types) - int(self.num_dense_layers)
+
+    def _mixer(self, kind: str) -> nn.Module:
+        if kind == "conv":
+            return ShortConv(self.hidden_size, self.conv_kernel)
+        if kind == "full_attention":
+            return GroupedQueryAttention(
+                self.hidden_size, self.num_heads, self.num_kv_heads, self.head_dim,
+                self.rope_theta, self.rms_eps,
+            )
+        raise ValueError(f"layer_types names {kind!r}: a mixer is conv or full_attention")
+
+    def _ffn(self, index: int) -> nn.Module:
+        if index < self.num_dense_layers:
+            return DenseMLP(self.hidden_size, self.dense_width)
+        return RoutedMLP(
+            self.hidden_size, self.num_experts, self.experts_held, self.expert_offset,
+            self.experts_per_token, self.expert_width, self.routed_scaling_factor,
+            self.expert_bias_scale,
+        )
+
+    def setup(self) -> None:
+        self.embed = self.param("embed", _INIT, (self.vocab_size, self.hidden_size))
+        self.layers = [
+            Block(self._mixer(kind), self._ffn(i), self.hidden_size, self.rms_eps, name=f"layer_{i}")
+            for i, kind in enumerate(self.layer_types)
+        ]
+        self.final_norm = self.param("final_norm", nn.initializers.ones, (self.hidden_size,))
+
+    def _head(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        with annotate(SCOPES["lm_head"]):
+            hidden = rms_norm(x, self.final_norm, self.rms_eps)
+            return hidden @ self.embed.T, hidden
+
+    def forward(self, tokens: jax.Array) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+        x = jnp.take(self.embed, tokens, axis=0)
+        stats = []
+        for layer in self.layers:
+            x, layer_stats = layer.forward(x)
+            stats.append(layer_stats)
+        logits, hidden = self._head(x)
+        return logits, hidden, _stack([s for s in stats if s is not None])
+
+    def step(
+        self, carry: Lfm2Carry, token: jax.Array
+    ) -> Tuple[jax.Array, jax.Array, Lfm2Carry, Dict[str, jax.Array]]:
+        x = jnp.take(self.embed, token, axis=0)
+        states, stats = [], []
+        for layer, state in zip(self.layers, carry.layers):
+            x, layer_stats, state = layer.step(x, state, carry.length)
+            states.append(state)
+            stats.append(layer_stats)
+        logits, hidden = self._head(x)
+        carry = Lfm2Carry(tuple(states), carry.length + 1)
+        return logits, hidden, carry, _stack([s for s in stats if s is not None])
+
+    @nn.nowrap
+    def init_carry(self, batch: int, max_len: int) -> Lfm2Carry:
+        tail = lambda: ConvTail(
+            jnp.zeros((batch, self.conv_kernel - 1, self.hidden_size), jnp.float32)
+        )
+        cache = lambda: jnp.zeros(
+            (max_len, batch, self.num_kv_heads, self.head_dim), jnp.float32
+        )
+        states = tuple(
+            tail() if kind == "conv" else KV(cache(), cache()) for kind in self.layer_types
+        )
+        return Lfm2Carry(states, jnp.zeros((batch,), jnp.int32))
+
+    @nn.nowrap
+    def reset_carry(self, carry: Lfm2Carry, done: jax.Array) -> Lfm2Carry:
+        """Start a new sequence where `done`: its conv tails are what precedes
+        a sequence (zeros, 16 KB); nothing of a KV cache beyond `length` is read."""
+        fresh = lambda state: (
+            ConvTail(jnp.where(done[:, None, None], 0.0, state.z))
+            if isinstance(state, ConvTail) else state
+        )
+        return Lfm2Carry(
+            tuple(fresh(state) for state in carry.layers), jnp.where(done, 0, carry.length)
+        )
+
+    @nn.nowrap
+    def carry_bytes(self, batch: int, max_len: int) -> Dict[str, int]:
+        carry = jax.eval_shape(lambda: self.init_carry(batch, max_len))
+        size = lambda kind: sum(
+            x.size * x.dtype.itemsize
+            for state in carry.layers if isinstance(state, kind) for x in state
+        )
+        return {"conv_tail": size(ConvTail), "kv": size(KV)}

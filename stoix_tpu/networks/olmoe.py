@@ -90,18 +90,32 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 
 def route(
-    x: jax.Array, router: jax.Array, top_k: int, renormalise: bool = False
+    x: jax.Array, router: jax.Array, top_k: int, renormalise: bool = False, *,
+    score: str = "softmax", bias: Optional[jax.Array] = None, epsilon: float = 0.0,
+    scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Float32 softmax over all experts, then top-k: (probs [N, E], weights
-    [N, k], index [N, k]). The top-k weights are the probabilities themselves
-    (OLMoE) or, with `renormalise` (`norm_topk_prob`), divided by their sum."""
+    """Float32 scores over all experts, then top-k: (scores [N, E], weights
+    [N, k], index [N, k]). The defaults are OLMoE's: the scores are a softmax
+    and the top-k weights the probabilities themselves or, with `renormalise`
+    (`norm_topk_prob`), divided by their sum. Otherwise: `score` "sigmoid"
+    scores each expert by itself; `bias` [E] (`expert_bias`) is added to the
+    scores for the CHOICE alone, the weights stay the scores at the chosen
+    experts; `epsilon` joins the sum they are divided by; `scale`
+    (`routed_scaling_factor`) multiplies them."""
     logits = jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
     )
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, index = jax.lax.top_k(probs, top_k)
+    probs = jax.nn.softmax(logits, axis=-1) if score == "softmax" else jax.nn.sigmoid(logits)
+    if bias is None:
+        weights, index = jax.lax.top_k(probs, top_k)
+    else:
+        _, index = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
+        weights = jnp.take_along_axis(probs, index, axis=-1)
     if renormalise:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (total + epsilon if epsilon else total)
+    if scale != 1.0:
+        weights = weights * scale
     return probs, weights, index
 
 
@@ -145,21 +159,31 @@ _permute.defvjp(_permute_fwd, _permute_bwd)
 def moe(
     x: jax.Array, router: jax.Array, gate: jax.Array, up: jax.Array, down: jax.Array, top_k: int,
     held: Optional[Tuple[int, int]] = None, renormalise: bool = False,
+    held_room_sigmas: float = 0.0, **routing: Any,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """x [N, D] -> (y [N, D], stats). Every (token, slot) pair is computed:
-    `stats["expert_count"]` sums to N * top_k.
+    `stats["expert_count"]` sums to N * top_k. `routing` is `route`'s `score`,
+    `bias`, `epsilon` and `scale`; with a selection bias the stats also
+    count the tokens it re-routed (`bias_changed_sum`).
 
     `held` = (offset, count) says that `gate`, `up`, `down` hold only the
     experts [offset, offset + count) of the router's E (one expert-parallel
     rank's share): the router still runs over all E and the stats stay over
     all E, but y is the part of the layer's result that the held experts
     give, and only the pairs routed to them are gathered and multiplied
-    (`_moe_held`). The default holds them all, on the path above."""
+    (`_moe_held`; `held_room_sigmas` widens its chunk for small batches). The
+    default holds them all, on the path above."""
     tokens, num_experts = x.shape[0], router.shape[-1]
     with annotate(SCOPES["moe_router"]):
-        probs, weights, index = route(x, router, top_k, renormalise)
+        probs, weights, index = route(x, router, top_k, renormalise, **routing)
+        changed = {} if routing.get("bias") is None else {
+            "bias_changed_sum": _bias_changed(probs, index)
+        }
     if held is not None:
-        return _moe_held(x, probs, weights, index, gate, up, down, held)
+        out, stats = _moe_held(
+            x, probs, weights, index, gate, up, down, held, held_room_sigmas
+        )
+        return out, {**stats, **changed}
     with annotate(SCOPES["moe_dispatch"]):
         flat = index.reshape(-1)  # pair p = token p // k, slot p % k
         order = jnp.argsort(flat, stable=True)  # pairs grouped by expert
@@ -180,8 +204,17 @@ def moe(
         "expert_count": counts,
         "router_prob_sum": jnp.sum(probs, axis=0),
         "router_entropy_sum": -jnp.sum(probs * jnp.log(jnp.maximum(probs, 1e-30))),
+        **changed,
     }
     return out, stats
+
+
+def _bias_changed(probs: jax.Array, index: jax.Array) -> jax.Array:
+    """Tokens whose chosen set `index` [N, k] is not the top-k of the scores
+    alone: what the selection bias re-routed."""
+    member = lambda chosen: jnp.any(jax.nn.one_hot(chosen, probs.shape[-1], dtype=bool), axis=-2)
+    _, plain = jax.lax.top_k(probs, index.shape[-1])
+    return jnp.sum(jnp.any(member(index) != member(plain), axis=-1), dtype=jnp.int32)
 
 
 class _HeldRows(NamedTuple):
@@ -299,15 +332,27 @@ _HELD_CHUNK_ROOM = 1.25
 # A large chunk is a whole number of the grouped-matmul kernel's row tiles:
 # 30,800 rows take eight times as long as 31,232 (PERF.md §6, PR 31).
 _HELD_CHUNK_TILE = 512
+# A small chunk asked for by `room_sigmas` is a whole number of these: on the
+# v5e one decode step of 8 held experts (2048 x 1792) takes 0.63 ms at 192
+# rows, 0.73 at 160 and 1.37 at 200; 0.53 at 64, 0.67 at 40 and at 72
+# (PERF.md §6, PR 33).
+_HELD_DECODE_TILE = 64
 
 
 def _moe_held(
     x: jax.Array, probs: jax.Array, weights: jax.Array, index: jax.Array,
     gate: jax.Array, up: jax.Array, down: jax.Array, held: Tuple[int, int],
+    room_sigmas: float = 0.0,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The held experts' part of the layer's result. Of the N * k routed
     pairs only the integer keys are ranked; rows of x are gathered, and
-    multiplied, for the pairs that land on [offset, offset + count) alone."""
+    multiplied, for the pairs that land on [offset, offset + count) alone.
+    A chunk has room for `_HELD_CHUNK_ROOM` times the pairs uniform routing
+    lands here. `room_sigmas` sizes it for a decode step's few pairs: room
+    for at least so many standard deviations of that count above it (a
+    binomial's is at most the root of its mean; a quarter more is 1.6
+    deviations at 32 expected pairs, and every second turn reads the held
+    experts' weights again), in whole `_HELD_DECODE_TILE`s of rows."""
     offset, count = held
     tokens, top_k = index.shape
     num_experts = probs.shape[-1]
@@ -320,8 +365,10 @@ def _moe_held(
         counts = jnp.sum(index.reshape(-1)[:, None] == experts[None, :], axis=0, dtype=jnp.int32)
         ends = jnp.cumsum(jax.lax.dynamic_slice_in_dim(counts, offset, count))
     expected = tokens * top_k * count / num_experts
-    tile = _HELD_CHUNK_TILE if expected >= 8 * _HELD_CHUNK_TILE else 8
-    rows = min(tokens * top_k, -(-int(_HELD_CHUNK_ROOM * expected) // tile) * tile)
+    small = _HELD_DECODE_TILE if room_sigmas else 8
+    tile = _HELD_CHUNK_TILE if expected >= 8 * _HELD_CHUNK_TILE else small
+    room = max(_HELD_CHUNK_ROOM * expected, expected + room_sigmas * expected**0.5)
+    rows = min(tokens * top_k, -(-int(room) // tile) * tile)
     # The last chunk may reach past the pairs: a slice that does is moved, not cut.
     order = jnp.concatenate([order, jnp.zeros((rows,), jnp.int32)])
     out = _held_experts(x, weights.astype(x.dtype), gate, up, down, order, slot, ends, rows)
@@ -412,9 +459,13 @@ def _attend_cache(
 ) -> jax.Array:
     """softmax(q k^T / sqrt(head_dim)) v over cache positions <= `length`
     ([B], the position just written). Only the leading blocks that hold a
-    live position are read."""
+    live position are read. q is [B, heads, head_dim] against a cache of as
+    many heads, or grouped [B, kv_heads, queries a kv head, head_dim] against
+    a cache [S, B, kv_heads, head_dim] (grouped-query attention: the cache is
+    read once for the group)."""
     max_len, head_dim = cache_k.shape[0], q.shape[-1]
     scale = 1.0 / jnp.sqrt(jnp.float32(head_dim))
+    grouped = q.ndim == 4
 
     def over(prefix: int):
         def attend(q, cache_k, cache_v, length):
@@ -423,8 +474,12 @@ def _attend_cache(
             # streams the float32 cache once, instead of first writing a
             # bfloat16 copy of it in the MXU's layout for a dot.
             keys, values = cache_k[:prefix], cache_v[:prefix]
+            if grouped:
+                keys, values = keys[:, :, :, None], values[:, :, :, None]
             scores = jnp.sum(q[None] * keys, axis=-1) * scale  # [prefix, B, heads]
             live = jnp.arange(prefix)[:, None, None] <= length[None, :, None]
+            if grouped:
+                live = live[..., None]
             scores = jnp.where(live, scores, jnp.finfo(jnp.float32).min)
             weights = jax.nn.softmax(scores, axis=0)
             return jnp.sum(weights[..., None] * values, axis=0)  # [B, heads, head_dim]
@@ -470,6 +525,28 @@ class OlmoeLM(nn.Module):
         ]
         self.final_norm = self.param("final_norm", nn.initializers.ones, (self.hidden_size,))
         self.lm_head = self.param("lm_head", init, (self.hidden_size, self.vocab_size))
+
+    # What a system asks of a token policy beside its two entry points: the
+    # decode carry, the layers with a router, and the experts held here
+    # (None: all of them).
+    held = None
+
+    @property
+    def routed_layers(self) -> int:
+        return int(self.num_layers)
+
+    @nn.nowrap
+    def init_carry(self, batch: int, max_len: int) -> KVCache:
+        return init_cache(self.num_layers, batch, max_len, self.num_heads, self.head_dim)
+
+    @nn.nowrap
+    def reset_carry(self, cache: KVCache, done: jax.Array) -> KVCache:
+        return reset_cache(cache, done)
+
+    @nn.nowrap
+    def carry_bytes(self, batch: int, max_len: int) -> Dict[str, int]:
+        cache = jax.eval_shape(lambda: self.init_carry(batch, max_len))
+        return {"kv": sum(x.size * x.dtype.itemsize for x in cache.k + cache.v)}
 
     def _head(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
         with annotate(SCOPES["lm_head"]):

@@ -97,6 +97,22 @@ def init_cache(
     return BlockCache(zeros(), zeros())
 
 
+def gqa_qkv(
+    layer: Dict[str, jax.Array], normed: jax.Array, positions: jax.Array, num_heads: int,
+    num_kv_heads: int, head_dim: int, rope_theta: float, rms_eps: float,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Grouped-query projections with the family's per-head q/k norm: normed
+    [..., D], positions [...] -> q [..., heads, head_dim], k and v [...,
+    kv_heads, head_dim]; q and k normalised over each head's `head_dim`
+    (`layer["q_norm"]`, `layer["k_norm"]`: one weight vector each), then
+    rotated. networks/lfm2.py's attention layers project through it too."""
+    heads = lambda t, n: t.reshape(t.shape[:-1] + (n, head_dim))
+    q = rms_norm(heads(normed @ layer["wq"], num_heads), layer["q_norm"], rms_eps)
+    k = rms_norm(heads(normed @ layer["wk"], num_kv_heads), layer["k_norm"], rms_eps)
+    rotate = lambda t: rope(t, positions, rope_theta)
+    return rotate(q), rotate(k), heads(normed @ layer["wv"], num_kv_heads)
+
+
 @dataclasses.dataclass(frozen=True)
 class SdarLM:
     """Embedding over the vocabulary slice, `num_layers` SDAR layers (this
@@ -155,15 +171,11 @@ class SdarLM:
     # ------------------------------------------------------------------ #
 
     def _qkv(self, layer: Dict[str, jax.Array], x: jax.Array, positions: jax.Array):
-        """x [..., D], positions [...] -> q [..., heads, head_dim], k and v
-        [..., kv_heads, head_dim]; q and k normalised over each head's
-        `head_dim`, then rotated."""
         normed = rms_norm(x, layer["input_norm"], self.rms_eps)
-        heads = lambda t, n: t.reshape(t.shape[:-1] + (n, self.head_dim))
-        q = rms_norm(heads(normed @ layer["wq"], self.num_heads), layer["q_norm"], self.rms_eps)
-        k = rms_norm(heads(normed @ layer["wk"], self.num_kv_heads), layer["k_norm"], self.rms_eps)
-        rotate = lambda t: rope(t, positions, self.rope_theta)
-        return rotate(q), rotate(k), heads(normed @ layer["wv"], self.num_kv_heads)
+        return gqa_qkv(
+            layer, normed, positions, self.num_heads, self.num_kv_heads, self.head_dim,
+            self.rope_theta, self.rms_eps,
+        )
 
     def _grouped(self, q: jax.Array) -> jax.Array:
         """[..., heads, head_dim] -> [..., kv_heads, heads / kv_heads, head_dim]."""

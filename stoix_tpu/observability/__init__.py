@@ -81,6 +81,7 @@ from stoix_tpu.observability.trace import (  # noqa: F401
     BLOCK_SCOPES,
     DIFFUSION_SCOPES,
     HOST_SPANS,
+    HYBRID_SCOPES,
     SCOPES,
     SetupClock,
     annotate,

@@ -56,6 +56,13 @@ SCOPES = {
     "denoise": "denoise",  # the policy's pass over a block: nothing is written to the cache
     "block_commit": "block_commit",  # the finished block's pass that writes its keys and values
     "attention_scores": "attention_scores",  # q k^T, the masked softmax, p v (both entry points)
+    # The hybrid stack (networks/lfm2.py): a mixer that is not attention and a
+    # feed-forward that is not routed, under `rollout`, `ppo_epoch` and in the
+    # evaluator alike.
+    # operator norm, W_in, the two gates, the 3-tap conv, W_out, the tail's write
+    "conv_mixer": "conv_mixer",
+    "conv_mixer_conv": "conv_mixer_conv",  # inside it: the gates and the conv, all that is no matmul
+    "dense_mlp": "dense_mlp",  # the dense SwiGLU feed-forward of the leading layers
 }
 
 # The scopes of the token policy's block: only the systems built on
@@ -64,6 +71,9 @@ BLOCK_SCOPES = ("attention", "moe", "moe_router", "moe_dispatch", "moe_experts",
 # What generation by diffusion over blocks adds to them: only the system
 # built on networks/sdar.py carries these.
 DIFFUSION_SCOPES = ("denoise", "block_commit", "attention_scores")
+# What a stack of convolution and attention layers adds: only the systems
+# built on networks/lfm2.py carry these.
+HYBRID_SCOPES = ("conv_mixer", "conv_mixer_conv", "dense_mlp")
 
 # Host spans that recur in steady state, by the thread that opens them. A
 # trace reduction attributes a device-idle gap to the innermost of these open

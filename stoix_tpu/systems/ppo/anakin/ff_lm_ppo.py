@@ -1,5 +1,7 @@
-"""Anakin PPO with a token policy: an OLMoE decoder block acts through a KV
-cache in the rollout and is updated teacher-forced over whole sequences.
+"""Anakin PPO with a token policy: a decoder (`network=olmoe`: OLMoE blocks
+and a KV cache; `network=lfm2_moe`: convolution and attention layers and a
+carry of both kinds of state) acts step by step through its carry in the
+rollout and is updated teacher-forced over whole sequences.
 
 The first system in which the policy, not the env, is the work (the LM
 post-training shape: generate a batch of fixed-length responses, score them
@@ -7,9 +9,11 @@ with a verifiable reward, one pass of minibatch updates). Scaffolding — mesh,
 shard_map, GAE, epoch/minibatch scans, `run_anakin_experiment` — is the
 canonical ff_ppo template's; what differs:
 
-  * ONE trunk (networks/olmoe.py) behind two entry points over the same
-    parameters: `step` (one cached decode step a rollout step, and the
-    evaluator's greedy decode) and `forward` (teacher-forced, in the loss).
+  * ONE trunk behind two entry points over the same parameters: `step`
+    (one decode step through the carry a rollout step, and the evaluator's
+    greedy decode) and `forward` (teacher-forced, in the loss). The network
+    declares its carry: `init_carry(batch, max_len)`, `reset_carry(carry,
+    done)`; this file names no network class.
     `ActorCriticParams.critic_params` holds only the scalar value head on the
     trunk's final hidden state; one loss, one backward pass.
   * The transition stores token ids, log-prob, value, reward, done — not
@@ -18,7 +22,9 @@ canonical ff_ppo template's; what differs:
     `ops/minibatch.shuffled_minibatch_epoch` (time is the feature axis).
   * The router's load-balancing loss (HF `router_aux_loss_coef`) joins the
     actor loss; TRAIN metrics carry the router's balance and the routed
-    (token, slot) pairs a token, which equal top-k while nothing is dropped.
+    (token, slot) pairs a token, which equal top-k while nothing is dropped;
+    of a network that holds one expert-parallel rank's share (`held`) also
+    the pairs that landed on it, and what a selection bias re-routed.
   * The rollout's record rides out with the episode metrics
     (`rollout_action`, `rollout_log_prob`, `rollout_value`, [T, E] like
     them): what was generated and what the policy said of it, for whoever
@@ -40,7 +46,7 @@ Layout (S = data shards, E = envs a shard):
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, NamedTuple, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,7 +57,7 @@ from stoix_tpu import envs
 from stoix_tpu.base_types import ActorCriticOptStates, ActorCriticParams, ExperimentOutput
 from stoix_tpu.evaluator import carry_evaluator_setup
 from stoix_tpu.networks import olmoe
-from stoix_tpu.observability import SCOPES, annotate, get_logger, span
+from stoix_tpu.observability import SCOPES, annotate, get_logger, get_registry, span
 from stoix_tpu.ops import (
     losses,
     shuffled_minibatch_epoch,
@@ -89,7 +95,18 @@ class LMNetworks(NamedTuple):
     forward: Callable  # (actor_params, tokens [B, T]) -> (logits, hidden, stats)
     step: Callable  # (actor_params, cache, token [B]) -> (logits, hidden, cache, stats)
     value: Callable  # (critic_params, hidden) -> value
-    init_cache: Callable  # (batch) -> KVCache
+    init_cache: Callable  # (batch) -> the network's decode carry, empty
+    reset_cache: Callable  # (carry, done [B]) -> carry: a new sequence where done
+    routed_layers: int  # layers with a router: the stats' leading axis
+    # (offset, count) of the experts held here, of a network that holds one
+    # expert-parallel rank's share; None: every expert is here.
+    held: Optional[Tuple[int, int]] = None
+
+
+def held_counts(expert_count: jax.Array, held: Tuple[int, int]) -> jax.Array:
+    """expert_count [layers, experts] -> [held] routed pairs of the held
+    experts, summed over layers."""
+    return jnp.sum(expert_count, axis=0)[held[0]:held[0] + held[1]]
 
 
 def lm_ppo_loss(
@@ -119,6 +136,17 @@ def lm_ppo_loss(
         "router_entropy": jnp.sum(stats["router_entropy_sum"]) / (layers * num_tokens),
         "routed_pairs_per_token": jnp.sum(load) / (layers * num_tokens),
     }
+    if networks.held is not None:
+        # One rank's share: the load that matters is the held experts'.
+        top_k = stats["expert_index"].shape[-1]
+        held = held_counts(stats["expert_count"], networks.held).astype(jnp.float32)
+        info["expert_load_max_over_mean"] = jnp.max(held) / jnp.mean(held)
+        info["held_pairs_per_token"] = jnp.sum(held) / (layers * num_tokens)
+        info["dropped_pairs"] = layers * num_tokens * top_k - jnp.sum(load)
+    if "bias_changed_sum" in stats:
+        info["router_bias_changed_share"] = jnp.sum(stats["bias_changed_sum"]) / (
+            layers * num_tokens
+        )
     return total, info
 
 
@@ -134,7 +162,15 @@ def get_learner_fn(
     vf_coef = float(config.system.vf_coef)
     aux_coef = float(config.system.router_aux_loss_coef)
     rollout_length = int(config.system.rollout_length)
-    num_layers = int(config.network.actor_network.get("num_layers", 1))
+    num_layers = networks.routed_layers
+
+    def _pairs(stats: Dict[str, jax.Array]) -> Any:
+        """What a rollout step keeps of the router's counts: the routed
+        pairs, and of a held share also those that landed on it."""
+        routed = jnp.sum(stats["expert_count"])
+        if networks.held is None:
+            return routed
+        return routed, jnp.sum(held_counts(stats["expert_count"], networks.held))
 
     def _rollout(params: ActorCriticParams, key: jax.Array, env_state: Any, timestep: Any):
         def _env_step(carry: Tuple, _: Any):
@@ -150,13 +186,13 @@ def get_learner_fn(
                     log_prob = policy.log_prob(action)
             with annotate(SCOPES["rollout_env"]):
                 env_state, timestep = env.step(env_state, action)
-            cache = olmoe.reset_cache(cache, timestep.last())
+            cache = networks.reset_cache(cache, timestep.last())
             transition = LMTransition(
                 token=token, action=action, log_prob=log_prob, value=value,
                 reward=timestep.reward, done=timestep.discount == 0.0,
                 info=timestep.extras["episode_metrics"],
             )
-            return (key, env_state, timestep, cache), (transition, jnp.sum(stats["expert_count"]))
+            return (key, env_state, timestep, cache), (transition, _pairs(stats))
 
         # The scope is on the scan, not on its body: the loop op itself then
         # carries it, and with it the grouped-matmul kernels inside, which
@@ -166,7 +202,7 @@ def get_learner_fn(
             (key, env_state, timestep, _), (traj, routed) = jax.lax.scan(
                 _env_step, (key, env_state, timestep, cache), None, rollout_length
             )
-        return key, env_state, timestep, traj, jnp.sum(routed)
+        return key, env_state, timestep, traj, jax.tree.map(jnp.sum, routed)
 
     @annotate(SCOPES["update_minibatch"])
     def _update_minibatch(train_state: Tuple, batch: Dict[str, jax.Array]):
@@ -227,6 +263,11 @@ def get_learner_fn(
             _update_epoch, ((params, opt_states), key), None, int(config.system.epochs)
         )
         learner_state = LMPPOLearnerState(params, opt_states, key, env_state, timestep)
+        if networks.held is not None:
+            routed, held = routed
+            info["rollout_held_pairs_per_token"] = held.astype(jnp.float32) / (
+                num_layers * traj.action.size
+            )
         info["rollout_routed_pairs_per_token"] = routed.astype(jnp.float32) / (
             num_layers * traj.action.size
         )
@@ -250,28 +291,41 @@ def get_learner_fn(
     return learner_fn
 
 
-def build_networks(env: envs.Environment, config: Any) -> Tuple[olmoe.OlmoeLM, olmoe.ValueHead]:
-    """(trunk + lm_head, value head) from the network config; the vocabulary
-    is the env's action count. The seam a harness wraps to see the networks."""
+def build_networks(env: envs.Environment, config: Any) -> Tuple[Any, Any]:
+    """(trunk + head, value head) from the network config, whatever network
+    it names; the vocabulary is the env's action count. The seam a harness
+    wraps to see the networks."""
     net_cfg = config.network
     actor = config_lib.instantiate(net_cfg.actor_network, vocab_size=env.num_actions)
     critic = config_lib.instantiate(net_cfg.critic_network)
     return actor, critic
 
 
-def network_functions(actor: olmoe.OlmoeLM, critic: olmoe.ValueHead, max_len: int) -> LMNetworks:
+def network_functions(actor: Any, critic: Any, max_len: int) -> LMNetworks:
+    """The network's entry points and what it declares of itself: its carry
+    (`init_carry`, `reset_carry`), its routed layers, the share it holds."""
     return LMNetworks(
         forward=lambda params, tokens: actor.apply(params, tokens, method="forward"),
         step=lambda params, cache, token: actor.apply(params, cache, token, method="step"),
         value=critic.apply,
-        init_cache=lambda batch: olmoe.init_cache(
-            actor.num_layers, batch, max_len, actor.num_heads, actor.head_dim
-        ),
+        init_cache=lambda batch: actor.init_carry(batch, max_len),
+        reset_cache=actor.reset_carry,
+        routed_layers=int(actor.routed_layers),
+        held=actor.held,
+    )
+
+
+def _carry_gauge() -> Any:
+    return get_registry().gauge(
+        "stoix_tpu_lm_carry_bytes",
+        "bytes of the token policy's decode carry on one shard as the learner was set up, by "
+        "kind of state: kv (keys and values, a row a position) or conv_tail (a short "
+        "convolution's last inputs)",
     )
 
 
 def make_init_state(
-    env: envs.Environment, config: Any, actor: olmoe.OlmoeLM, critic: olmoe.ValueHead,
+    env: envs.Environment, config: Any, actor: Any, critic: Any,
     optims: Tuple[Any, Any], n_shards: int,
 ) -> Callable[[jax.Array], LMPPOLearnerState]:
     """key -> the whole initial learner state, as one traceable function."""
@@ -349,6 +403,9 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
         learn_per_shard, mesh, state_specs, episode_metrics_spec=P(None, None, "data")
     )
 
+    for kind, size in actor.carry_bytes(envs_per_shard, rollout_length).items():
+        _carry_gauge().set(size, {"kind": kind})
+
     if is_coordinator():
         get_logger("stoix_tpu.setup").info(
             "[setup] %s parameters | mesh %s | %s sequences x %s tokens an update",
@@ -358,11 +415,9 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
 
     greedy = bool(config.arch.get("evaluation_greedy", False))
 
-    def act_fn(
-        params: Any, cache: olmoe.KVCache, observation: Any, done: jax.Array, keys: jax.Array
-    ):
+    def act_fn(params: Any, cache: Any, observation: Any, done: jax.Array, keys: jax.Array):
         """The evaluator's batched step through the same `step`."""
-        cache = olmoe.reset_cache(cache, done)
+        cache = networks.reset_cache(cache, done)
         logits, _, cache, _ = networks.step(params, cache, observation.agent_view[..., 0])
         with annotate(SCOPES["lm_head"]):
             action = (
@@ -370,6 +425,8 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
                 else jax.random.categorical(keys[0], logits, axis=-1)
             )
         return cache, action
+
+    act_fn.init_carry = networks.init_cache  # the evaluator's carry is the network's own
 
     return AnakinSetup(
         learn=learn,
@@ -383,14 +440,8 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
 
 def run_experiment(config: Any) -> float:
     """Train; returns the final evaluation episode-return mean."""
-    net = config.network.actor_network
-    init_cache = lambda batch: olmoe.init_cache(
-        int(net.get("num_layers", 1)), batch, int(config.system.rollout_length),
-        int(net.num_heads), int(net.head_dim),
-    )
     return run_anakin_experiment(
-        config, learner_setup,
-        evaluator_setup_fn=carry_evaluator_setup(init_cache),
+        config, learner_setup, evaluator_setup_fn=carry_evaluator_setup()
     )
 
 

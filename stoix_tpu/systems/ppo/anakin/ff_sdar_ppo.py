@@ -78,7 +78,7 @@ from stoix_tpu.ops import (
 from stoix_tpu.ops.distributions import Categorical
 from stoix_tpu.parallel import is_coordinator
 from stoix_tpu.systems import anakin
-from stoix_tpu.systems.ppo.anakin.ff_lm_ppo import LMPPOLearnerState
+from stoix_tpu.systems.ppo.anakin.ff_lm_ppo import LMPPOLearnerState, held_counts
 from stoix_tpu.systems.runner import LAST_RUN_STATS, AnakinSetup, run_anakin_experiment
 from stoix_tpu.utils import config as config_lib
 from stoix_tpu.utils.jax_utils import count_parameters
@@ -144,12 +144,6 @@ def choose(
 def block_value(networks: SdarNetworks, critic_params: Any, hidden: jax.Array) -> jax.Array:
     """hidden [..., B, D] -> the mean over the block of the value head."""
     return jnp.mean(networks.value(critic_params, hidden), axis=-1)
-
-
-def held_counts(expert_count: jax.Array, held: Tuple[int, int]) -> jax.Array:
-    """expert_count [layers, experts] -> [held] routed pairs of the held
-    experts, summed over layers."""
-    return jnp.sum(expert_count, axis=0)[held[0]:held[0] + held[1]]
 
 
 def record_copies(batch: Dict[str, jax.Array], passes: int) -> Dict[str, jax.Array]:
