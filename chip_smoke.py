@@ -17,6 +17,10 @@ cut to a few updates and weights made from the config's seed. Legs:
   lm_ppo         Anakin PPO with the OLMoE token policy at a tiny preset
                  (token_task): grouped matmuls over sorted experts, the KV
                  cache in rollout and evaluator, flash attention in the update.
+  lfm2_ppo       the same entry point with `network=lfm2_moe` at a tiny preset:
+                 conv tails and a KV cache of head size 64 in one decode
+                 carry, the row written at the one position of sequences that
+                 move together, two 128-position prefixes to switch between.
   sdar_ppo       Anakin PPO with the SDAR block-diffusion token policy at a
                  tiny preset (block_token_task): the held-experts loop of
                  grouped matmuls, block steps through the GQA cache in rollout
@@ -329,6 +333,40 @@ def leg_lm_ppo(n: int) -> Dict[str, Any]:
         ],
         expect_kernel=True,
     )
+
+
+def leg_lfm2_ppo(n: int) -> Dict[str, Any]:
+    """The hybrid token policy through the same `ff_lm_ppo`, data-parallel
+    over the chips: gated short convolutions beside grouped-query attention
+    in one decode carry, the held experts' chunk loop, the flash kernel at
+    the published head size of 64 (half a lane row: the size at which a
+    scattered cache write made every decode step copy the cache, PERF.md
+    section 6, PR 35). T = 256, so the cached attention switches between two
+    prefixes; the run has to have taken the one-slab write."""
+    from stoix_tpu.observability import get_registry
+
+    tiny = [
+        "hidden_size=128", "dense_width=256", "num_heads=4", "num_kv_heads=2", "head_dim=64",
+        "expert_width=64",
+    ]
+    facts = _anakin_leg(
+        n,
+        "stoix_tpu.systems.ppo.anakin.ff_lm_ppo",
+        "default/anakin/default_ff_lm_ppo.yaml",
+        ["network=lfm2_moe"] + [f"network.actor_network.{o}" for o in tiny] + [
+            "env.kwargs.vocab_size=512", "env.kwargs.length=256", "system.rollout_length=256",
+            f"arch.total_num_envs={16 * n}", "system.num_minibatches=4", "arch.num_updates=4",
+            "system.router_aux_loss_coef=0.0", "arch.evaluation_greedy=True",
+        ],
+        expect_kernel=True,
+    )
+    write = {
+        dict(labels)["form"]: value
+        for labels, value in get_registry().gauge("stoix_tpu_lm_cache_write").labels_and_values()
+    }
+    _require(write == {"slice": 1.0, "scatter": 0.0}, f"the cache write the run took: {write}")
+    facts["cache_write"] = write
+    return facts
 
 
 def leg_sdar_ppo(n: int) -> Dict[str, Any]:
@@ -711,6 +749,7 @@ LEGS: List[Tuple[str, Callable[[int], Dict[str, Any]]]] = [
     ("kernels", leg_kernels),
     ("trans_ppo", leg_trans_ppo),
     ("lm_ppo", leg_lm_ppo),
+    ("lfm2_ppo", leg_lfm2_ppo),
     ("sdar_ppo", leg_sdar_ppo),
     ("ppo_pallas_gae", leg_ppo_pallas_gae),
     ("sebulba", leg_sebulba),

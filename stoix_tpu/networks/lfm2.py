@@ -40,9 +40,11 @@ and `step` (one token against its state).
   * `Lfm2LM.forward(tokens [B, T])` — teacher-forced.
   * `Lfm2LM.step(carry, token [B])` — one decode step. `Lfm2Carry` holds one
     state a layer, of its mixer's kind, and `length` [B], each sequence's
-    next position. `init_carry(batch, max_len)` and `reset_carry(carry,
-    done)` are the network's own: a new sequence starts at length 0 with a
-    zero tail; cache entries at or beyond `length` are never read.
+    next position, or [] where the caller asked `init_carry(..., together=
+    True)` because its sequences move together (networks/olmoe.py).
+    `init_carry(batch, max_len)` and `reset_carry(carry, done)` are the
+    network's own: a new sequence starts at length 0 with a zero tail; cache
+    entries at or beyond `length` are never read.
 
 Parameters, by name (the reference reads them by these names):
   embed [V, D]; layer_<i>/{operator_norm [D], ffn_norm [D], mixer/{in_proj
@@ -63,7 +65,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from stoix_tpu.networks.olmoe import _attend_cache, _stack, moe, rms_norm
+from stoix_tpu.networks.olmoe import (
+    _attend_cache, _stack, init_length, moe, reset_length, rms_norm, write_cache_rows,
+)
 from stoix_tpu.networks.sdar import gqa_qkv
 from stoix_tpu.observability import SCOPES, annotate
 from stoix_tpu.ops.pallas_attention import best_attention
@@ -90,7 +94,7 @@ class KV(NamedTuple):
 
 class Lfm2Carry(NamedTuple):
     layers: Tuple[Any, ...]  # a layer: its mixer's state, ConvTail or KV
-    length: jax.Array  # [B] int32: positions filled = the next token's position
+    length: jax.Array  # [B] or [] int32: positions filled = the next token's position
 
 
 class ShortConv(nn.Module):
@@ -171,9 +175,8 @@ class GroupedQueryAttention(nn.Module):
 
     def step(self, u: jax.Array, state: KV, length: jax.Array):
         batch = u.shape[0]
-        q, k, v = self._qkv(u, length)
-        at = (length, jnp.arange(batch))
-        state = KV(state.k.at[at].set(k), state.v.at[at].set(v))
+        q, k, v = self._qkv(u, jnp.broadcast_to(length, (batch,)))
+        state = KV(*write_cache_rows(state.k, state.v, k, v, length))
         grouped = q.reshape(batch, self.num_kv_heads, -1, self.head_dim)
         attended = _attend_cache(grouped, state.k, state.v, length)
         return attended.reshape(batch, -1) @ self.wo, state
@@ -349,7 +352,9 @@ class Lfm2LM(nn.Module):
         return logits, hidden, carry, _stack([s for s in stats if s is not None])
 
     @nn.nowrap
-    def init_carry(self, batch: int, max_len: int) -> Lfm2Carry:
+    def init_carry(self, batch: int, max_len: int, together: bool = False) -> Lfm2Carry:
+        """`together`: the caller's sequences all start and end at once, so
+        the carry holds one position for all of them."""
         tail = lambda: ConvTail(
             jnp.zeros((batch, self.conv_kernel - 1, self.hidden_size), jnp.float32)
         )
@@ -359,7 +364,7 @@ class Lfm2LM(nn.Module):
         states = tuple(
             tail() if kind == "conv" else KV(cache(), cache()) for kind in self.layer_types
         )
-        return Lfm2Carry(states, jnp.zeros((batch,), jnp.int32))
+        return Lfm2Carry(states, init_length(batch, together))
 
     @nn.nowrap
     def reset_carry(self, carry: Lfm2Carry, done: jax.Array) -> Lfm2Carry:
@@ -370,7 +375,7 @@ class Lfm2LM(nn.Module):
             if isinstance(state, ConvTail) else state
         )
         return Lfm2Carry(
-            tuple(fresh(state) for state in carry.layers), jnp.where(done, 0, carry.length)
+            tuple(fresh(state) for state in carry.layers), reset_length(carry.length, done)
         )
 
     @nn.nowrap

@@ -15,7 +15,10 @@ experts are SwiGLUs; final RMSNorm and an untied `lm_head`.
   * `OlmoeLM.step(cache, token [B])` — one decode step through the cache.
     `KVCache.length [B]` is each sequence's next position; entries at or
     beyond it are never attended, so `reset_cache` (length := 0 where done)
-    is the whole reset and costs nothing.
+    is the whole reset and costs nothing. A caller whose sequences move
+    together (all start and end at once) asks `init_carry(..., together=True)`
+    for ONE position, `length []`: the step reads that shape and nothing else,
+    and writes the new key/value row as one slab (`write_cache_rows`).
 
 No token is ever dropped and there is no capacity factor: the (token, slot)
 pairs are sorted by expert and the three expert matmuls run as grouped
@@ -58,19 +61,53 @@ class KVCache(NamedTuple):
     # (a decode step reads cache[:prefix] without a copy).
     k: Tuple[jax.Array, ...]  # a layer: [S, B, heads, head_dim] float32
     v: Tuple[jax.Array, ...]
-    length: jax.Array  # [B] int32: positions filled = the next token's position
+    # int32, positions filled = the next token's position: [B], a sequence
+    # its own, or [] where the sequences move together.
+    length: jax.Array
 
 
-def init_cache(num_layers: int, batch: int, max_len: int, heads: int, head_dim: int) -> KVCache:
+def init_length(batch: int, together: bool) -> jax.Array:
+    return jnp.zeros(() if together else (batch,), jnp.int32)
+
+
+def init_cache(
+    num_layers: int, batch: int, max_len: int, heads: int, head_dim: int, together: bool = False
+) -> KVCache:
     zeros = lambda: tuple(
         jnp.zeros((max_len, batch, heads, head_dim), jnp.float32) for _ in range(num_layers)
     )
-    return KVCache(zeros(), zeros(), jnp.zeros((batch,), jnp.int32))
+    return KVCache(zeros(), zeros(), init_length(batch, together))
+
+
+def reset_length(length: jax.Array, done: jax.Array) -> jax.Array:
+    """`length` with 0 where `done` [B]; the one position of sequences that
+    move together goes to 0 once they are done, which they are together."""
+    return jnp.where(jnp.all(done) if length.ndim == 0 else done, 0, length)
 
 
 def reset_cache(cache: KVCache, done: jax.Array) -> KVCache:
     """Start a new sequence where `done`: nothing beyond `length` is read."""
-    return cache._replace(length=jnp.where(done, 0, cache.length))
+    return cache._replace(length=reset_length(cache.length, done))
+
+
+def write_cache_rows(
+    cache_k: jax.Array, cache_v: jax.Array, k: jax.Array, v: jax.Array, length: jax.Array
+) -> Tuple[jax.Array, jax.Array]:
+    """The caches [S, B, heads, head_dim] with the rows `k`, `v` [B, heads,
+    head_dim] at each sequence's position `length` [B], or at the one
+    position `length` [] of sequences that move together. The second is one
+    slab written in place; the first is a scatter, which pins the cache's
+    layout to its own, and at a head size under a lane row the reads of
+    `_attend_cache` then copy the whole cache into theirs every step
+    (PERF.md §6, PR 35)."""
+    if length.ndim == 0:
+        at = (length, 0, 0, 0)
+        return (
+            jax.lax.dynamic_update_slice(cache_k, k[None], at),
+            jax.lax.dynamic_update_slice(cache_v, v[None], at),
+        )
+    at = (length, jnp.arange(k.shape[0]))
+    return cache_k.at[at].set(k), cache_v.at[at].set(v)
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
@@ -441,13 +478,12 @@ class OlmoeLayer(nn.Module):
         self, x: jax.Array, cache_k: jax.Array, cache_v: jax.Array, length: jax.Array
     ) -> Tuple[jax.Array, jax.Array, jax.Array, Dict[str, jax.Array]]:
         """One token a sequence: x [B, D], this layer's cache [S, B, heads,
-        head_dim], `length` [B] the token's position."""
+        head_dim], `length` [B] or [] the token's position."""
         batch = x.shape[0]
         with annotate(SCOPES["attention"]):
-            q, k, v = self._qkv(rms_norm(x, self.input_norm, self.rms_eps), length)
-            at = (length, jnp.arange(batch))
-            cache_k = cache_k.at[at].set(k)
-            cache_v = cache_v.at[at].set(v)
+            positions = jnp.broadcast_to(length, (batch,))
+            q, k, v = self._qkv(rms_norm(x, self.input_norm, self.rms_eps), positions)
+            cache_k, cache_v = write_cache_rows(cache_k, cache_v, k, v, length)
             attended = _attend_cache(q, cache_k, cache_v, length)
             h = x + attended.reshape(batch, -1) @ self.wo
         y, stats = self._moe(h)
@@ -458,7 +494,7 @@ def _attend_cache(
     q: jax.Array, cache_k: jax.Array, cache_v: jax.Array, length: jax.Array
 ) -> jax.Array:
     """softmax(q k^T / sqrt(head_dim)) v over cache positions <= `length`
-    ([B], the position just written). Only the leading blocks that hold a
+    ([B] or [], the position just written). Only the leading blocks that hold a
     live position are read. q is [B, heads, head_dim] against a cache of as
     many heads, or grouped [B, kv_heads, queries a kv head, head_dim] against
     a cache [S, B, kv_heads, head_dim] (grouped-query attention: the cache is
@@ -477,7 +513,8 @@ def _attend_cache(
             if grouped:
                 keys, values = keys[:, :, :, None], values[:, :, :, None]
             scores = jnp.sum(q[None] * keys, axis=-1) * scale  # [prefix, B, heads]
-            live = jnp.arange(prefix)[:, None, None] <= length[None, :, None]
+            last = jnp.broadcast_to(length, q.shape[:1])
+            live = jnp.arange(prefix)[:, None, None] <= last[None, :, None]
             if grouped:
                 live = live[..., None]
             scores = jnp.where(live, scores, jnp.finfo(jnp.float32).min)
@@ -536,8 +573,12 @@ class OlmoeLM(nn.Module):
         return int(self.num_layers)
 
     @nn.nowrap
-    def init_carry(self, batch: int, max_len: int) -> KVCache:
-        return init_cache(self.num_layers, batch, max_len, self.num_heads, self.head_dim)
+    def init_carry(self, batch: int, max_len: int, together: bool = False) -> KVCache:
+        """`together`: the caller's sequences all start and end at once, so
+        the carry holds one position for all of them."""
+        return init_cache(
+            self.num_layers, batch, max_len, self.num_heads, self.head_dim, together
+        )
 
     @nn.nowrap
     def reset_carry(self, cache: KVCache, done: jax.Array) -> KVCache:

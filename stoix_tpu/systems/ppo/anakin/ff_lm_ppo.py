@@ -32,7 +32,10 @@ canonical ff_ppo template's; what differs:
 
 v1 contract, checked at set-up: the env's episode length equals
 `system.rollout_length`, so every rollout starts at a reset with an empty
-cache and the teacher-forced pass needs no stored prefix; the cache lives for
+cache and the teacher-forced pass needs no stored prefix; every sequence of a
+batch is then at the same position at every step, in the rollout and in the
+evaluator's decode alike, which is what licenses the carry of ONE position
+that `network_functions` asks the network for; the cache lives for
 the rollout only and is not part of the learner state (2 GB at the published
 widths, dead through the update). `arch.update_batch_size` must be 1: a
 sort of all tokens by expert does not vmap, so there is no in-shard replica
@@ -303,12 +306,14 @@ def build_networks(env: envs.Environment, config: Any) -> Tuple[Any, Any]:
 
 def network_functions(actor: Any, critic: Any, max_len: int) -> LMNetworks:
     """The network's entry points and what it declares of itself: its carry
-    (`init_carry`, `reset_carry`), its routed layers, the share it holds."""
+    (`init_carry`, `reset_carry`), its routed layers, the share it holds. The
+    carry is the one of sequences that move together: `learner_setup` checks
+    that every episode is exactly one rollout."""
     return LMNetworks(
         forward=lambda params, tokens: actor.apply(params, tokens, method="forward"),
         step=lambda params, cache, token: actor.apply(params, cache, token, method="step"),
         value=critic.apply,
-        init_cache=lambda batch: actor.init_carry(batch, max_len),
+        init_cache=lambda batch: actor.init_carry(batch, max_len, together=True),
         reset_cache=actor.reset_carry,
         routed_layers=int(actor.routed_layers),
         held=actor.held,
@@ -321,6 +326,15 @@ def _carry_gauge() -> Any:
         "bytes of the token policy's decode carry on one shard as the learner was set up, by "
         "kind of state: kv (keys and values, a row a position) or conv_tail (a short "
         "convolution's last inputs)",
+    )
+
+
+def _cache_write_gauge() -> Any:
+    return get_registry().gauge(
+        "stoix_tpu_lm_cache_write",
+        "1 on the form in which the decode step writes a key/value row as the learner was set "
+        "up, 0 on the other: slice (one position for all sequences, one slab in place) or "
+        "scatter (a position a sequence); read from the shape of the carry's length",
     )
 
 
@@ -405,6 +419,9 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
 
     for kind, size in actor.carry_bytes(envs_per_shard, rollout_length).items():
         _carry_gauge().set(size, {"kind": kind})
+    together = jax.eval_shape(lambda: networks.init_cache(envs_per_shard)).length.ndim == 0
+    for form, took in (("slice", together), ("scatter", not together)):
+        _cache_write_gauge().set(float(took), {"form": form})
 
     if is_coordinator():
         get_logger("stoix_tpu.setup").info(

@@ -139,7 +139,8 @@ def test_a_reset_on_done_starts_a_new_sequence(model):
     its neighbour goes on as if nothing had happened."""
     nets, actor_params, critic_params, tokens = model
     step = jax.jit(nets.step)
-    carry = nets.init_cache(2)
+    # A position a sequence (`length [B]`): these two end apart.
+    carry = _actor().init_carry(2, LENGTH)
     for t in range(5):
         _, _, carry, _ = step(actor_params, carry, tokens[:2, t])
     assert float(jnp.abs(carry.layers[0].z[0]).max()) > 0.0
@@ -147,7 +148,7 @@ def test_a_reset_on_done_starts_a_new_sequence(model):
     assert carry.length.tolist() == [0, 5]
     assert float(jnp.abs(carry.layers[0].z[0]).max()) == 0.0
     assert float(jnp.abs(carry.layers[0].z[1]).max()) > 0.0
-    fresh = nets.init_cache(1)
+    fresh = _actor().init_carry(1, LENGTH)
     for t in range(3):
         logits, _, carry, _ = step(actor_params, carry, tokens[2:4, t])
         want, _, fresh, _ = step(actor_params, fresh, tokens[2:3, t])

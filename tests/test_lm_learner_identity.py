@@ -1,10 +1,14 @@
-"""The OLMoE and SDAR learners are the programs they were before the router
-took a scoring function, a selection bias and a scale as arguments and
-before `ff_lm_ppo` asked its network for the carry (PR 33): the StableHLO of
+"""The token-policy learners are the programs they were: the StableHLO of
 each `learner_fn` at its tiny preset, source locations taken off, hashes to
-what the parent commit's did (recorded there with this very function; the
-check PRs 29 and 30 made by hand). A change that is MEANT to alter one of
-these programs records its new digest here and says so in CHANGES.md."""
+the digest recorded here with this very function (the check PRs 29 and 30
+made by hand). A change that is MEANT to alter one of these programs records
+its new digest here and says so in CHANGES.md.
+
+`sdar` is the parent commit's program since before PR 33 (the router's new
+arguments and the network-declared carry left it as it was). `olmoe` and
+`olmoe_2layers` were re-recorded in PR 35, which made `ff_lm_ppo` ask for the
+carry of ONE position (`length []`: the key/value row written as one slab);
+`lfm2` was first recorded there, after the same change."""
 
 import hashlib
 import importlib
@@ -16,17 +20,22 @@ import pytest
 from stoix_tpu import envs
 from stoix_tpu.utils import config as config_lib
 
+from test_lfm2_ppo import TINY as LFM2_TINY
 from test_lm_ppo import TINY as OLMOE_TINY
 from test_sdar_ppo import TINY as SDAR_TINY
 
 LEARNERS = {
     "olmoe": (
         "stoix_tpu.systems.ppo.anakin.ff_lm_ppo", "default/anakin/default_ff_lm_ppo.yaml",
-        OLMOE_TINY, "e9c8cdb597985fc46051771308411fdd477df5f002fd72ba64180c4874fc1c2b",
+        OLMOE_TINY, "5460d1cc8a6475ff7f2aa7957f99f68b131da9b736bcffd644ef791a223217aa",
     ),
     "olmoe_2layers": (
         "stoix_tpu.systems.ppo.anakin.ff_lm_ppo", "default/anakin/default_ff_lm_ppo.yaml",
-        OLMOE_TINY + ["network.actor_network.num_layers=2"], "4c1be9f6a79401ca6477ed2c9cb6cc0b1b8020cd7e52cda9fb4411949b61ec01",
+        OLMOE_TINY + ["network.actor_network.num_layers=2"], "19a79201f07047178d73fdd81dccf5aef9508f235e25db24981d70aaba38159f",
+    ),
+    "lfm2": (
+        "stoix_tpu.systems.ppo.anakin.ff_lm_ppo", "default/anakin/default_ff_lm_ppo.yaml",
+        LFM2_TINY, "b822d1be53da6e9c2300ac46f52984cd49c8bbdf2442fef1986d98f1950c7b29",
     ),
     "sdar": (
         "stoix_tpu.systems.ppo.anakin.ff_sdar_ppo", "default/anakin/default_ff_sdar_ppo.yaml",

@@ -101,14 +101,15 @@ def test_cached_steps_reproduce_the_teacher_forced_forward(model, output):
 def test_cache_reset_on_done_starts_a_new_sequence(model):
     """After `reset_cache` a sequence's stale entries are never read: its
     next steps equal a fresh cache's, and its neighbours are untouched."""
-    _, nets, actor_params, _, tokens = model
+    layers, nets, actor_params, _, tokens = model
     step = jax.jit(nets.step)
-    cache = nets.init_cache(2)
+    # A position a sequence (`length [B]`): these two end apart.
+    cache = olmoe.init_cache(layers, 2, LENGTH, 4, 16)
     for t in range(5):
         _, _, cache, _ = step(actor_params, cache, tokens[:2, t])
     cache = olmoe.reset_cache(cache, jnp.array([True, False]))
     assert cache.length.tolist() == [0, 5]
-    fresh = nets.init_cache(1)
+    fresh = olmoe.init_cache(layers, 1, LENGTH, 4, 16)
     for t in range(3):
         logits, _, cache, _ = step(actor_params, cache, tokens[2:4, t])
         want, _, fresh, _ = step(actor_params, fresh, tokens[2:3, t])

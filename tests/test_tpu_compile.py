@@ -8,12 +8,15 @@ The topology is described inside a fixture, never while a module is imported:
 one process at a time may load the TPU's library, and every worker imports
 every test file."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from stoix_tpu.ops import pallas_attention
+from stoix_tpu.utils import config as config_lib
 
 
 @pytest.fixture(scope="module")
@@ -65,3 +68,43 @@ def test_mosaic_takes_the_block_mask_kernels_at_the_cells_shape(one_chip, what):
         # they lie: nothing of their size is copied around them but what the
         # entry's own layouts ask for.
         assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 4 * SEQUENCES * POSITIONS * HEADS * HEAD_DIM
+
+
+# The benchmark's LFM2 cell: 128 sequences decode 512 tokens through the
+# published widths' first six layers (configs/network/lfm2_moe.yaml), one of
+# them attention over a cache [512, 128, 8, 64]: head size 64 is half a lane
+# row, so the cache's reads want the 128-wide batch axis minor.
+LFM2_SEQUENCES, LFM2_TOKENS, LFM2_VOCAB = 128, 512, 16384
+
+
+@pytest.mark.parametrize("together", [True, False], ids=["one_position", "a_position_a_sequence"])
+def test_the_lfm2_decode_copies_no_cache_where_the_sequences_move_together(one_chip, together):
+    """The decode scan of `Lfm2LM.step` at the cell's shape. With a position a
+    sequence the row is scattered, the scatter keeps the cache as it is
+    carried, and every branch of `_attend_cache` begins with a copy of the
+    whole cache, keys and values, into the layout its reads want: 1.07 GB a
+    step beside a decode that reads 0.27 GB at most (PERF.md section 6, PR
+    35). With one position for all, one slab is written in place into a cache
+    that lies as it is read, and nothing of the cache's size is copied."""
+    network = config_lib.compose(config_lib.default_config_dir(), "network/lfm2_moe.yaml", [])
+    actor = config_lib.instantiate(network.actor_network, vocab_size=LFM2_VOCAB)
+    struct = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    params = jax.tree.map(struct, jax.eval_shape(
+        lambda: actor.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32), method="forward")
+    ))
+    tokens = jax.ShapeDtypeStruct((LFM2_TOKENS, LFM2_SEQUENCES), jnp.int32, sharding=one_chip)
+
+    def decode(params, tokens):
+        def one(carry, token):
+            logits, _, carry, _ = actor.apply(params, carry, token, method="step")
+            return carry, jnp.argmax(logits, axis=-1)
+
+        carry = actor.init_carry(LFM2_SEQUENCES, LFM2_TOKENS, together=together)
+        return jax.lax.scan(one, carry, tokens)[1]
+
+    lowered = jax.jit(decode).trace(params, tokens).lower(lowering_platforms=("tpu",))
+    text = lowered.compile().as_text()
+    cache = rf"f32\[{LFM2_TOKENS},{LFM2_SEQUENCES},{actor.num_kv_heads},{actor.head_dim}\]"
+    assert re.search(cache, text)  # the cache is in the program under this name
+    copies = re.findall(rf"= {cache}\{{[^}}]*\}} copy\(", text)
+    assert (not copies) if together else copies
