@@ -1,8 +1,11 @@
 """Goodput/badput ledger: wall-clock attribution over a fixed taxonomy
 (docs/DESIGN.md §2.13).
 
-Every second of a run is classified into exactly one of nine phases —
+Every second of a run is classified into exactly one of ten phases —
 
+    setup       from `run_experiment`'s first statement to the first completed
+                window or update (`SetupClock`'s wall), less the compile,
+                recovery and stall seconds noted inside it
     compute     device learn steps making training progress (goodput)
     eval        evaluator dispatch/execution
     checkpoint  orbax serialization handed off on the host path
@@ -18,8 +21,15 @@ the serve worker already record. The ledger is pure host arithmetic over a
 monotonic clock: no threads, no device work, always safe to run (the
 `logger.telemetry.http` bit-identity pin holds with it active).
 
+Set-up is not goodput. Between `begin_setup()` and `end_setup(wall)` (the
+run's `SetupClock` calls both) a note under a steady-state phase is dropped:
+the first window's dispatches and waits, the first update's queue wait and
+learn step are set-up's, and `end_setup` books the whole of set-up's wall as
+`setup`, less what was noted inside it as `compile`, `stall` or `recovery`.
+
 The attribution invariant: `finalize()` assigns the residual wall time (wall
-minus the explicitly timed phases) to `compute`. In the pipelined Anakin
+minus the explicitly timed phases) to `compute`; with set-up booked whole,
+that residual is steady state's alone. In the pipelined Anakin
 loop that residual IS device compute — the host dispatches in microseconds
 and idles while the accelerator executes the window — so goodput is measured
 as "wall time not proven to be anything else", the same convention Google's
@@ -41,6 +51,7 @@ from stoix_tpu.observability.registry import MetricsRegistry, get_registry
 
 # The fixed taxonomy. Order is presentation order in /statusz and DESIGN.md.
 PHASES = (
+    "setup",
     "compute",
     "eval",
     "checkpoint",
@@ -51,6 +62,9 @@ PHASES = (
     "stall",
     "recovery",
 )
+
+# What a note inside set-up may still be booked as: not part of `setup`.
+_KEPT_IN_SETUP = ("compile", "stall", "recovery")
 
 # Anakin runner phase-clock names (stoix_tpu_runner_phase_seconds_total
 # labels) -> taxonomy. learn_s is dispatch cost in the pipelined loop; the
@@ -101,6 +115,7 @@ class GoodputLedger:
         self._lock = threading.Lock()
         self._seconds: Dict[str, float] = {phase: 0.0 for phase in PHASES}
         self._t0: Optional[float] = None
+        self._in_setup = False
 
     def start(self) -> "GoodputLedger":
         self._t0 = time.perf_counter()
@@ -112,11 +127,24 @@ class GoodputLedger:
                 f"unknown goodput phase {phase!r} (taxonomy: {PHASES})"
             )
         seconds = max(0.0, float(seconds))
-        if seconds == 0.0:
+        if seconds == 0.0 or (self._in_setup and phase not in _KEPT_IN_SETUP):
             return
         with self._lock:
             self._seconds[phase] += seconds
         self._counter.inc(seconds, {"phase": phase})
+
+    def begin_setup(self) -> None:
+        """From here to `end_setup`, steady-state notes are dropped: those
+        seconds are set-up's, booked whole when it ends."""
+        self._in_setup = True
+
+    def end_setup(self, wall_s: float) -> None:
+        """Set-up took `wall_s` seconds: book as `setup` what of them was not
+        noted as compile, stall or recovery meanwhile. Once a run."""
+        if not self._in_setup:
+            return
+        self._in_setup = False
+        self.note("setup", wall_s - sum(self.seconds().values()))
 
     def note_phases(
         self, breakdown: Mapping[str, float], mapping: Optional[Mapping[str, str]] = None

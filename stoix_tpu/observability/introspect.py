@@ -10,9 +10,12 @@ live-buffer count (`jax.live_arrays()`), publishing gauges:
     stoix_tpu_device_live_buffers{}
     stoix_tpu_device_poll_errors_total{}
 
-Cumulative XLA compile time is a registry counter
-(`stoix_tpu_runner_compile_seconds_total`) fed by the Anakin runner's AOT
-warmup phase — the poller only samples what the runtime exposes.
+Cumulative compile time is a registry counter too, and no business of the
+poller's, which only samples what the runtime exposes:
+`stoix_tpu_compile_seconds_total{program, stage=trace|lower|backend}`, fed
+at compile events by `utils/compilecache.py`'s listener of jax's stage
+durations (every program of either architecture, where the counter it
+replaced saw the Anakin runner's warm-up span alone).
 
 CPU backends expose no `memory_stats()` (returns None / raises): for those,
 `bytes_in_use` is estimated by summing live-buffer nbytes per device (source

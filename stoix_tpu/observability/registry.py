@@ -84,6 +84,11 @@ class Gauge(_Instrument):
     def dec(self, amount: float = 1.0, labels: Optional[Dict[str, str]] = None) -> None:
         self.inc(-amount, labels)
 
+    def remove(self, labels: Optional[Dict[str, str]] = None) -> None:
+        """Take the series away: what no longer holds is absent, not stale."""
+        with self._lock:
+            self._series.pop(_label_key(labels), None)
+
     def value(self, labels: Optional[Dict[str, str]] = None) -> float:
         with self._lock:
             return float(self._series.get(_label_key(labels), 0.0))

@@ -28,9 +28,14 @@ tables the systems import and the benchmark's readers and tests read.
 
 from __future__ import annotations
 
+import contextlib
+import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+import stoix_tpu
 
 # Scopes inside the jitted programs (path components of the ops' framework
 # path in the device trace). One table for both architectures.
@@ -98,10 +103,14 @@ _trace_annotation: Any = None
 
 
 def _annotation(name: str) -> Any:
-    """`jax.profiler.TraceAnnotation(name)`; jax is imported on first use so
-    that importing the telemetry package stays free of it."""
+    """`jax.profiler.TraceAnnotation(name)`; jax is looked up on first use so
+    that importing the telemetry package stays free of it. A process that has
+    not imported jax (a tool that only composes a config) has no profiler
+    session to annotate, and is not made to import it."""
     global _trace_annotation
     if _trace_annotation is None:
+        if "jax" not in sys.modules:
+            return contextlib.nullcontext()
         import jax
 
         _trace_annotation = jax.profiler.TraceAnnotation
@@ -237,19 +246,108 @@ def annotate(name: str):
     return jax.named_scope(name)
 
 
-class SetupClock:
-    """`span(..., clock=SetupClock(), phase=...)` sink for the once-a-run
-    set-up phases of a `run_experiment`: seconds per phase, published as the
-    gauge `stoix_tpu_setup_phase_seconds{phase=...}` as each phase closes."""
+def process_started_at() -> Optional[float]:
+    """When the OS started this process, on the `perf_counter` clock: the
+    start time of `/proc/self/stat` (field 22, clock ticks since boot) against
+    `/proc/uptime`, both good to a hundredth of a second. None where the OS
+    keeps no such files: the phase `process_boot` is then left out, not guessed."""
+    try:
+        with open("/proc/self/stat", "r", encoding="ascii") as handle:
+            # The command's name (field 2) may hold spaces: count from its ")".
+            start_ticks = float(handle.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime", "r", encoding="ascii") as handle:
+            uptime = float(handle.read().split()[0])
+        age = uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+    return time.perf_counter() - age
 
-    PHASES = (
-        "env_build", "network_init", "learner_setup", "evaluator_setup",
-        "aot_warmup", "first_tick",
-    )
+
+class _Launch:
+    """What passes between the package's import and the first `run_experiment`
+    of a process, as the sites that know report it: the two runner modules the
+    seconds of their own import blocks (two plain floats each: no span can be
+    open before the package exists; neither module imports the other, so the
+    blocks do not overlap), `config_lib.compose` its span. `take`
+    hands it to the first `SetupClock` and closes the book: a later run in the
+    same process was not launched by what launched the first."""
 
     def __init__(self) -> None:
+        self._imports = 0.0
+        self._compose = 0.0
+        self._open = True
+
+    def note_imports(self, began: float, ended: float) -> None:
+        if self._open:
+            self._imports += ended - began
+
+    def record(self, phase: str, seconds: float) -> None:
+        """The `span("compose", clock=LAUNCH, phase="compose")` sink."""
+        if self._open:
+            self._compose += seconds
+
+    def take(self, entered: float) -> Optional[Dict[str, float]]:
+        """The phases before a `run_experiment` entered at `entered`, the
+        first time it is asked; None ever after."""
+        if not self._open:
+            return None
+        self._open = False
+        phases = {
+            "imports": self._imports,
+            "compose": self._compose,
+            "launch": max(0.0, entered - stoix_tpu.IMPORTED_AT - self._imports - self._compose),
+        }
+        started = process_started_at()
+        if started is not None:
+            phases["process_boot"] = max(0.0, stoix_tpu.IMPORTED_AT - started)
+        return phases
+
+
+LAUNCH = _Launch()
+
+
+def _backend_is_up() -> bool:
+    """Whether this process has started a jax backend yet (a module-level
+    array at import starts one long before any `run_experiment`)."""
+    from jax._src import xla_bridge
+
+    return bool(xla_bridge.backends_are_initialized())
+
+
+class SetupClock:
+    """`span(..., clock=SetupClock(ledger), phase=...)` sink for the once-a-run
+    set-up phases: seconds per phase, published as the gauge
+    `stoix_tpu_setup_phase_seconds{phase=...}` as each phase closes. Built as
+    the first statement of a `run_experiment`, it is open from there to the
+    close of `first_tick` (the first completed window or update), and its
+    phases partition that wall: what no span covered is `{phase="unspanned"}`.
+
+    The first clock of a process also publishes what came before it
+    (`LAUNCH_PHASES`): `process_boot` (the OS's start of the process to the
+    package's import), `imports` (the runner modules' import blocks),
+    `compose`, and `launch`, the rest up to `run_experiment`. With those the
+    gauge adds up from the process's start to the first tick. A later clock
+    takes those four series away again. `stoix_tpu_setup_backend_up_at_entry`
+    says whether a jax backend was already up when the clock opened.
+
+    The run's `GoodputLedger` books the clock's whole wall as `setup`, less
+    what it was told of it under another name (`compile`: the warm-up;
+    `recovery`: a restore; `stall`)."""
+
+    LAUNCH_PHASES = ("process_boot", "imports", "compose", "launch")
+    PHASES = (
+        "preflight", "mesh_build", "env_build", "rng_key", "network_init", "learner_setup",
+        "state_warmup", "restore", "evaluator_setup", "logger_build", "aot_warmup",
+        "first_tick", "unspanned",
+    )
+
+    def __init__(self, ledger: Any = None) -> None:
+        self._opened = time.perf_counter()
         from stoix_tpu.observability.registry import get_registry
 
+        self._ledger = ledger
+        if ledger is not None:
+            ledger.begin_setup()
         self._gauge = get_registry().gauge(
             "stoix_tpu_setup_phase_seconds",
             "Wall seconds of each set-up phase of the most recent run",
@@ -257,10 +355,36 @@ class SetupClock:
         self._seconds: Dict[str, float] = {}
         for phase in self.PHASES:  # a fresh run does not show the last one's
             self._gauge.set(0.0, {"phase": phase})
+        self.launch = LAUNCH.take(self._opened)
+        for phase in self.LAUNCH_PHASES:
+            if self.launch is not None and phase in self.launch:
+                self._gauge.set(self.launch[phase], {"phase": phase})
+            else:
+                self._gauge.remove({"phase": phase})
+        get_registry().gauge(
+            "stoix_tpu_setup_backend_up_at_entry",
+            "1 if a jax backend was already started when the most recent run_experiment was entered",
+        ).set(float(_backend_is_up()))
 
     def record(self, phase: str, seconds: float) -> None:
         self._seconds[phase] = self._seconds.get(phase, 0.0) + seconds
         self._gauge.set(self._seconds[phase], {"phase": phase})
 
+    def open_first_tick(self) -> contextlib.ExitStack:
+        """Set-up's last phase, `first_tick`, as a stack its owner closes where
+        the first window or update completes (and once more, to no effect,
+        where a run that never got there ends). Its close is the clock's."""
+        stack = contextlib.ExitStack()
+        stack.callback(self._close)
+        stack.enter_context(span("first_tick", clock=self, phase="first_tick"))
+        return stack
+
+    def _close(self) -> None:
+        wall = time.perf_counter() - self._opened
+        self.record("unspanned", wall - sum(self._seconds.values()))
+        if self._ledger is not None:
+            self._ledger.end_setup(wall)
+
     def seconds(self) -> Dict[str, float]:
+        """This run's own phases (those of `PHASES` that a span closed)."""
         return dict(self._seconds)

@@ -29,7 +29,6 @@ guards the gradient step against non-finite losses/grads.
 
 from __future__ import annotations
 
-import contextlib
 import os
 import queue
 import sys
@@ -37,6 +36,8 @@ import threading
 import time
 import traceback
 from typing import Any, Callable, Dict, List, NamedTuple
+
+_IMPORTS_BEGAN = time.perf_counter()  # set-up's phase `imports`: this block's seconds
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +60,7 @@ from stoix_tpu.observability import (
     goodput,
     span,
 )
+from stoix_tpu.observability.trace import LAUNCH as setup_launch
 from stoix_tpu.ops import scan_kernels
 from stoix_tpu.parallel import MeshRoles
 from stoix_tpu.resilience import (
@@ -76,6 +78,8 @@ from stoix_tpu.sebulba.sources import SourceContext
 from stoix_tpu.utils import compilecache
 from stoix_tpu.utils.logger import LogEvent, StoixLogger
 from stoix_tpu.utils.timing import StepAccumulator, TimingTracker
+
+setup_launch.note_imports(_IMPORTS_BEGAN, time.perf_counter())
 
 # Throughput stats of the most recent run_experiment call in this process
 # (steady-state window: after the first eval block, i.e. post-compile). The
@@ -307,6 +311,13 @@ class _Run:
     (whatever happened) and `close_out`."""
 
     def __init__(self, config: Any, system: SebulbaSystem) -> None:
+        # Goodput ledger (docs/DESIGN.md §2.13) and the set-up phases ->
+        # stoix_tpu_setup_phase_seconds{phase}, as in the Anakin runner (host
+        # memory only): both open before any set-up work, the clock until the
+        # close of `first_tick`, and it books its wall as the ledger's `setup`.
+        self.ledger = ledger = goodput.GoodputLedger().start()
+        goodput.set_active(ledger)
+        self.setup_phases = setup_phases = SetupClock(ledger)
         # Resilience (docs/DESIGN.md §2.3): arm the chaos plan before anything is
         # traced (the in-jit nan_loss fault binds at trace time) and resolve the
         # divergence-guard mode for the learner loop's host-side checks.
@@ -318,34 +329,34 @@ class _Run:
         # before the learner is traced.
         compilecache.configure(config)
         scan_kernels.configure_from_config(config)
-        # Set-up phases -> stoix_tpu_setup_phase_seconds{phase}, as in the
-        # Anakin runner (host memory only).
-        self.setup_phases = setup_phases = SetupClock()
         # Launch hardening (docs/DESIGN.md §2.4, arch.preflight): subprocess
         # backend probe + config cross-validation before any device work — the
         # actor/learner device-id split below is exactly the class of config this
         # catches (ids out of range, envs not divisible by actors).
         pf = preflight.settings_from_config(config)
         if pf.enabled:
-            probe = preflight.probe_backend(
-                timeout_s=pf.probe_timeout_s,
-                attempts=pf.probe_attempts,
-                backoff_base_s=pf.probe_backoff_base_s,
-                backoff_max_s=pf.probe_backoff_max_s,
-            )
-            preflight.validate_config(config, device_count=probe.device_count)
+            with span("preflight", clock=setup_phases, phase="preflight"):
+                probe = preflight.probe_backend(
+                    timeout_s=pf.probe_timeout_s,
+                    attempts=pf.probe_attempts,
+                    backoff_base_s=pf.probe_backoff_base_s,
+                    backoff_max_s=pf.probe_backoff_max_s,
+                )
+                preflight.validate_config(config, device_count=probe.device_count)
         # Device assignment through the unified mesh-role abstraction
         # (parallel/roles.py, docs/DESIGN.md §2.11): the actor/learner/evaluator
         # split arrives as one validated MeshRoles object (the same object the
-        # Anakin runner, serve, and the population runner consume).
-        roles = MeshRoles.from_config(config)
-        actor_devices = roles.role_devices("act")
-        learner_devices = roles.role_devices("learn")
-        learner_mesh = roles.learn_mesh()
+        # Anakin runner, serve, and the population runner consume). The
+        # program's first touch of the devices on an operator's path.
+        with span("mesh_build", clock=setup_phases, phase="mesh_build"):
+            roles = MeshRoles.from_config(config)
+            actor_devices = roles.role_devices("act")
+            learner_devices = roles.role_devices("learn")
+            learner_mesh = roles.learn_mesh()
 
-        actors_per_device = int(config.arch.actor.actor_per_device)
-        num_actors = len(actor_devices) * actors_per_device
-        steps_per_update = _resolve_budget(config, num_actors)
+            actors_per_device = int(config.arch.actor.actor_per_device)
+            num_actors = len(actor_devices) * actors_per_device
+            steps_per_update = _resolve_budget(config, num_actors)
 
         with span("env_build", clock=setup_phases, phase="env_build"):
             # The C++ pool's first build (g++, once a checkout) is in here.
@@ -375,71 +386,69 @@ class _Run:
                 config, env_factory, learner.eval_apply, roles.role_mesh("evaluate")
             )
 
-        self.logger = logger = StoixLogger(config)
-        # Ops plane (docs/DESIGN.md §2.13): StoixLogger's configure() just reset
-        # the health monitor and flight recorder — and started the ops HTTP
-        # server if `logger.telemetry.http.enabled` — so register THIS run's
-        # identity, goodput ledger, and heartbeat board on the fresh instances.
-        http_cfg = dict(dict(config.logger.get("telemetry") or {}).get("http") or {})
-        self.ledger = ledger = goodput.GoodputLedger().start()
-        goodput.set_active(ledger)
-        self.recorder = recorder = flightrec.get_flight_recorder()
-        recorder.set_context(
-            architecture="sebulba",
-            system=str(config.system.system_name),
-            seed=int(config.arch.seed),
-        )
-        self.status = status = get_status_board()
-        status.update(
-            {
-                "run_id": f"{config.system.system_name}_seed{config.arch.seed}",
-                "architecture": "sebulba",
-                "system": str(config.system.system_name),
-                "step": 0,
-            }
-        )
-        self.lifetime = lifetime = ThreadLifetime()
-        # Fleet coordination (docs/DESIGN.md §2.6, arch.fleet): in a multi-host
-        # Sebulba deployment the learner loop exchanges window-indexed stop votes
-        # through the jax.distributed KV store (there is no coalesced device
-        # fetch to piggyback on here), publishes heartbeats, and fails collects
-        # fast on a declared partition. Off (default) = None = unchanged loop.
-        self.fleet = fleet_coord = fleet.fleet_from_config(config)
-        if fleet_coord is not None:
-            fleet_coord.start()
-        self.timer = timer = TimingTracker()
-        self.source = source = learner.make_source(
-            SourceContext(
-                num_actors, learner_devices, learner_mesh, fleet_coord, timer, ledger,
-                steps_per_update,
+        with span("logger_build", clock=setup_phases, phase="logger_build"):
+            self.logger = logger = StoixLogger(config)
+            # Ops plane (docs/DESIGN.md §2.13): StoixLogger's configure() just reset
+            # the health monitor and flight recorder — and started the ops HTTP
+            # server if `logger.telemetry.http.enabled` — so register THIS run's
+            # identity and heartbeat board on the fresh instances.
+            http_cfg = dict(dict(config.logger.get("telemetry") or {}).get("http") or {})
+            self.recorder = recorder = flightrec.get_flight_recorder()
+            recorder.set_context(
+                architecture="sebulba",
+                system=str(config.system.system_name),
+                seed=int(config.arch.seed),
             )
-        )
-        pipeline = source.pipeline
-        # One heartbeat board for the whole run: actor beats come from the
-        # pipeline, param-server and evaluator beats land on the same board so
-        # the stall detector sees every component's age — and /healthz reads the
-        # same board through the process-wide health monitor.
-        self.monitor = monitor = get_health_monitor()
-        monitor.register_board(
-            "sebulba-pipeline",
-            pipeline.heartbeats,
-            stale_after_s=float(http_cfg.get("stale_after_s", 60.0) or 60.0),
-        )
-        self.param_server = param_server = ParameterServer(
-            actor_devices, actors_per_device, heartbeats=pipeline.heartbeats
-        )
-        self.actor_metrics = actor_metrics = _ActorMetrics()
-        self.eval_results = eval_results = []
+            self.status = status = get_status_board()
+            status.update(
+                {
+                    "run_id": f"{config.system.system_name}_seed{config.arch.seed}",
+                    "architecture": "sebulba",
+                    "system": str(config.system.system_name),
+                    "step": 0,
+                }
+            )
+            self.lifetime = lifetime = ThreadLifetime()
+            # Fleet coordination (docs/DESIGN.md §2.6, arch.fleet): in a multi-host
+            # Sebulba deployment the learner loop exchanges window-indexed stop votes
+            # through the jax.distributed KV store (there is no coalesced device
+            # fetch to piggyback on here), publishes heartbeats, and fails collects
+            # fast on a declared partition. Off (default) = None = unchanged loop.
+            self.fleet = fleet_coord = fleet.fleet_from_config(config)
+            if fleet_coord is not None:
+                fleet_coord.start()
+            self.timer = timer = TimingTracker()
+            self.source = source = learner.make_source(
+                SourceContext(
+                    num_actors, learner_devices, learner_mesh, fleet_coord, timer, ledger,
+                    steps_per_update,
+                )
+            )
+            pipeline = source.pipeline
+            # One heartbeat board for the whole run: actor beats come from the
+            # pipeline, param-server and evaluator beats land on the same board so
+            # the stall detector sees every component's age — and /healthz reads the
+            # same board through the process-wide health monitor.
+            self.monitor = monitor = get_health_monitor()
+            monitor.register_board(
+                "sebulba-pipeline",
+                pipeline.heartbeats,
+                stale_after_s=float(http_cfg.get("stale_after_s", 60.0) or 60.0),
+            )
+            self.param_server = param_server = ParameterServer(
+                actor_devices, actors_per_device, heartbeats=pipeline.heartbeats
+            )
+            self.actor_metrics = actor_metrics = _ActorMetrics()
+            self.eval_results = eval_results = []
 
-        def on_eval_result(metrics, params_used, t):
-            logger.log(metrics, t, len(eval_results), LogEvent.EVAL)
-            eval_results.append(float(jnp.mean(metrics["episode_return"])))
+            def on_eval_result(metrics, params_used, t):
+                logger.log(metrics, t, len(eval_results), LogEvent.EVAL)
+                eval_results.append(float(jnp.mean(metrics["episode_return"])))
 
         # Set-up's last phase: from the first thread started to the first
         # completed learner update (the actors' first rollouts and every first
         # compile — act_fn, the learn step — are in it).
-        self.first_tick = first_tick = contextlib.ExitStack()
-        first_tick.enter_context(span("first_tick", clock=setup_phases, phase="first_tick"))
+        self.first_tick = setup_phases.open_first_tick()
         self.async_evaluator = async_evaluator = AsyncEvaluator(
             eval_fn, lifetime, on_eval_result, heartbeats=pipeline.heartbeats
         )
@@ -714,6 +723,7 @@ class _Run:
         LAST_RUN_STATS["setup_phases"] = {
             k: round(v, 6) for k, v in self.setup_phases.seconds().items()
         }
+        LAST_RUN_STATS["launch_phases"] = self.setup_phases.launch
         LAST_RUN_STATS.update(self.source.run_stats())
         supervisor = self.supervisor
         LAST_RUN_STATS["resilience"] = {

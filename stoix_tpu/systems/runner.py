@@ -60,9 +60,12 @@ any other profiler session) shows them on the device ops' clock; with
 `logger.telemetry.enabled=true` they are also recorded for the Perfetto JSON
 export. In the pipelined loop the phases are HOST attribution: device time
 spent in learn/eval surfaces as fetch_s (the materialize wait), while
-learn_s/eval_s shrink to dispatch cost. Set-up (env build, learner_setup,
-evaluator set-up, AOT warm-up, and from there to the first completed
-window) goes to `stoix_tpu_setup_phase_seconds{phase=...}` the same way.
+learn_s/eval_s shrink to dispatch cost. Set-up goes to
+`stoix_tpu_setup_phase_seconds{phase=...}` the same way, through a
+`SetupClock` that is open from this function's first statement to the first
+completed window: its phases (mesh_build, env_build, rng_key, learner_setup,
+state_warmup, restore, evaluator_setup, logger_build, aot_warmup,
+first_tick) partition that wall, and what no span covered is `unspanned`.
 
 Resilience (stoix_tpu/resilience, docs/DESIGN.md §2.3): SIGTERM/SIGINT
 request a graceful stop at the next window boundary — the loop drains the
@@ -110,6 +113,8 @@ import os
 import time
 from typing import Any, Callable, NamedTuple, Optional
 
+_IMPORTS_BEGAN = time.perf_counter()  # set-up's phase `imports`: this block's seconds
+
 import jax
 import jax.numpy as jnp
 
@@ -129,6 +134,7 @@ from stoix_tpu.observability import (
     span,
 )
 from stoix_tpu.observability import aggregate as fleet_metrics
+from stoix_tpu.observability.trace import LAUNCH as setup_launch
 from stoix_tpu.parallel import (
     MeshRoles,
     fetch_global,
@@ -154,6 +160,8 @@ from stoix_tpu.utils.jax_utils import aot_warmup
 from stoix_tpu.utils.logger import LogEvent, StoixLogger
 from stoix_tpu.utils.timestep_checker import check_total_timesteps
 
+setup_launch.note_imports(_IMPORTS_BEGAN, time.perf_counter())
+
 # Stats of the most recent run_anakin_experiment call (this process):
 # phase_breakdown {compile_s, learn_s, snapshot_s, eval_s, fetch_dispatch_s,
 # fetch_s, log_s, host_s, ckpt_s [, gossip_s]}, loop_wall_s,
@@ -171,10 +179,12 @@ _PHASE_NAMES = (
 
 class _PhaseClock:
     """Per-run view over the cumulative registry phase counter: records into
-    `stoix_tpu_runner_phase_seconds_total{phase=...}` and reports this run's
-    deltas (the registry is process-wide; LAST_RUN_STATS is per-run)."""
+    `stoix_tpu_runner_phase_seconds_total{phase=...}` and the run's goodput
+    ledger, and reports this run's deltas (the registry is process-wide;
+    LAST_RUN_STATS is per-run)."""
 
-    def __init__(self) -> None:
+    def __init__(self, ledger: goodput.GoodputLedger) -> None:
+        self._ledger = ledger
         self._counter = get_registry().counter(
             "stoix_tpu_runner_phase_seconds_total",
             "Cumulative Anakin host-loop wall time per phase",
@@ -185,9 +195,11 @@ class _PhaseClock:
         self._touched: set = set()
 
     def record(self, name: str, seconds: float) -> None:
-        """The `span(..., clock=phases, phase=name)` sink."""
+        """The `span(..., clock=phases, phase=name)` sink. The goodput ledger
+        is told as the span closes (it drops what set-up's wall covers)."""
         self._touched.add(name)
         self._counter.inc(seconds, {"phase": name})
+        self._ledger.note(goodput.RUNNER_PHASE_MAP.get(name, name), seconds)
 
     def breakdown(self) -> dict:
         # gossip_s appears only in runs that actually dispatched a gossip step;
@@ -260,34 +272,36 @@ def run_anakin_experiment(
     evaluator_setup_fn: Callable = None,
 ) -> float:
     """Generic Anakin experiment: returns final eval episode-return mean."""
-    # Resilience (docs/DESIGN.md §2.3): arm the chaos plan (no-op unless
-    # STOIX_TPU_FAULT / arch.fault_spec is set) BEFORE the learner is built —
-    # the in-jit nan_loss guard reads it at trace time — and resolve the
-    # divergence-guard mode for the host-side checks below.
-    faultinject.configure(config.arch.get("fault_spec"))
-    guard_mode = guards.resolve_mode(config)
     # Goodput ledger (docs/DESIGN.md §2.13): opened before any setup work so
     # restore/compile/stall seconds are all inside the attributed wall. Pure
     # host arithmetic — always on, bit-identity untouched. set_active lets
     # out-of-loop sites (faultinject stalls, watchdog) charge their seconds.
     ledger = goodput.GoodputLedger().start()
     goodput.set_active(ledger)
+    # Set-up phases -> stoix_tpu_setup_phase_seconds{phase}: what the wait
+    # before the first window is made of (host memory only). Open from here
+    # to the close of `first_tick`; no statement between is outside a span
+    # that costs more than the span would.
+    setup_phases = SetupClock(ledger)
+    # Resilience (docs/DESIGN.md §2.3): arm the chaos plan (no-op unless
+    # STOIX_TPU_FAULT / arch.fault_spec is set) BEFORE the learner is built —
+    # the in-jit nan_loss guard reads it at trace time — and resolve the
+    # divergence-guard mode for the host-side checks below.
+    faultinject.configure(config.arch.get("fault_spec"))
+    guard_mode = guards.resolve_mode(config)
     # Compile economy (docs/DESIGN.md §2.7): the persistent cache must be
     # configured before the FIRST compile this process does (network init
     # included), and the multistep scan-kernel default before the learner is
     # traced — both are trace/compile-time statics.
     compilecache.configure(config)
     scan_kernels.configure_from_config(config)
-    # Set-up phases -> stoix_tpu_setup_phase_seconds{phase}: what the wait
-    # before the first window is made of (host memory only).
-    setup_phases = SetupClock()
     # Launch hardening (docs/DESIGN.md §2.4): probe the backend in a
     # SUBPROCESS and cross-validate the config BEFORE this process commits to
     # device work — a wedged PJRT runtime or a bad shape aborts here with a
     # typed error, not twenty minutes in. Off by default (zero added work).
     pf = preflight.settings_from_config(config)
     if pf.enabled:
-        with span("preflight"):
+        with span("preflight", clock=setup_phases, phase="preflight"):
             probe = preflight.probe_backend(
                 timeout_s=pf.probe_timeout_s,
                 attempts=pf.probe_attempts,
@@ -300,34 +314,39 @@ def run_anakin_experiment(
                 "cross-checks pass", probe.platform, probe.device_count,
                 probe.attempts,
             )
-    maybe_initialize_distributed(config)
-    # Device assignment goes through the unified mesh-role abstraction
-    # (parallel/roles.py, docs/DESIGN.md §2.11): Anakin's learn role owns the
-    # whole `arch.mesh` (colocated act/learn/evaluate), so this is the same
-    # mesh create_mesh built directly before MeshRoles existed — and the
-    # population runner's ("pop", "data") mesh arrives through the same path.
-    roles = MeshRoles.from_config(config)
-    mesh = roles.learn_mesh()
-    # Fleet coordination (docs/DESIGN.md §2.6, arch.fleet): cross-host agreed
-    # stop decisions (flags piggybacked on the coalesced metric fetch),
-    # heartbeat-based partition detection, straggler skew telemetry, and the
-    # local-shard emergency checkpoint. Off (the default) = None = zero extra
-    # work, bit-identical host loop.
-    fleet_coord = fleet.fleet_from_config(config)
-    if fleet_coord is not None:
-        fleet_coord.start()
-    # State-integrity sentinel (docs/DESIGN.md §2.9, arch.integrity): bound
-    # below once the learner state exists. None (the default) = zero extra
-    # dispatches, zero host work, bit-identical host loop.
-    sentinel = integrity.sentinel_from_config(config)
-    config = check_total_timesteps(config, int(mesh.shape["data"]))
-    config.logger.system_name = config.system.system_name
+    # The program's first touch of the devices on an operator's path (the
+    # backend starts here unless an import already started it).
+    with span("mesh_build", clock=setup_phases, phase="mesh_build"):
+        maybe_initialize_distributed(config)
+        # Device assignment goes through the unified mesh-role abstraction
+        # (parallel/roles.py, docs/DESIGN.md §2.11): Anakin's learn role owns the
+        # whole `arch.mesh` (colocated act/learn/evaluate), so this is the same
+        # mesh create_mesh built directly before MeshRoles existed — and the
+        # population runner's ("pop", "data") mesh arrives through the same path.
+        roles = MeshRoles.from_config(config)
+        mesh = roles.learn_mesh()
+        # Fleet coordination (docs/DESIGN.md §2.6, arch.fleet): cross-host agreed
+        # stop decisions (flags piggybacked on the coalesced metric fetch),
+        # heartbeat-based partition detection, straggler skew telemetry, and the
+        # local-shard emergency checkpoint. Off (the default) = None = zero extra
+        # work, bit-identical host loop.
+        fleet_coord = fleet.fleet_from_config(config)
+        if fleet_coord is not None:
+            fleet_coord.start()
+        # State-integrity sentinel (docs/DESIGN.md §2.9, arch.integrity): bound
+        # below once the learner state exists. None (the default) = zero extra
+        # dispatches, zero host work, bit-identical host loop.
+        sentinel = integrity.sentinel_from_config(config)
+        config = check_total_timesteps(config, int(mesh.shape["data"]))
+        config.logger.system_name = config.system.system_name
 
     with span("env_build", clock=setup_phases, phase="env_build"):
         env, eval_env = envs.make(config)
 
-    key = jax.random.PRNGKey(int(config.arch.seed))
-    key, setup_key = jax.random.split(key)
+    # The process's first eager programs, unless an import ran some.
+    with span("rng_key", clock=setup_phases, phase="rng_key"):
+        key = jax.random.PRNGKey(int(config.arch.seed))
+        key, setup_key = jax.random.split(key)
     # Network init and the learner's build are both the system's own
     # learner_setup; the systems mark `network_init` inside it.
     with span("learner_setup", clock=setup_phases, phase="learner_setup"):
@@ -335,8 +354,9 @@ def run_anakin_experiment(
     learner_state = setup.learner_state
 
     if warmup_fn is not None:
-        learner_state = warmup_fn(learner_state)
-        jax.block_until_ready(jax.tree.leaves(learner_state)[0])
+        with span("state_warmup", clock=setup_phases, phase="state_warmup"):
+            learner_state = warmup_fn(learner_state)
+            jax.block_until_ready(jax.tree.leaves(learner_state)[0])
 
     # Resume: restore a saved learner state into the freshly built (correctly
     # sharded) template (reference ff_ppo.py:504-512 via Checkpointer.restore).
@@ -344,39 +364,39 @@ def run_anakin_experiment(
     start_step = 0
     restore_skipped = 0
     restore_report: list = []
-    t_restore = time.perf_counter()
     if ckpt_cfg.get("load_model", False):
-        load_args = ckpt_cfg.get("load_args") or {}
-        load_path = load_args.get("load_path")
-        if load_path and fleet.is_emergency_store(load_path):
-            # A fleet local-shard emergency store (a partition survivor's
-            # rescue save, docs/DESIGN.md §2.6): restore through the same
-            # tree-path placement as the topology-elastic path — params
-            # round-trip bit-identical onto the (possibly shrunk) new mesh.
-            learner_state, start_step = fleet.restore_emergency(
-                learner_state, load_path,
-                raw_transform=getattr(setup, "restore_transform", None),
-            )
-        else:
-            from stoix_tpu.utils.checkpointing import Checkpointer
+        with span("restore", clock=setup_phases, phase="restore"):
+            load_args = ckpt_cfg.get("load_args") or {}
+            load_path = load_args.get("load_path")
+            if load_path and fleet.is_emergency_store(load_path):
+                # A fleet local-shard emergency store (a partition survivor's
+                # rescue save, docs/DESIGN.md §2.6): restore through the same
+                # tree-path placement as the topology-elastic path — params
+                # round-trip bit-identical onto the (possibly shrunk) new mesh.
+                learner_state, start_step = fleet.restore_emergency(
+                    learner_state, load_path,
+                    raw_transform=getattr(setup, "restore_transform", None),
+                )
+            else:
+                from stoix_tpu.utils.checkpointing import Checkpointer
 
-            loader = Checkpointer(
-                model_name=config.system.system_name,
-                rel_dir=load_path or "checkpoints",
-                checkpoint_uid=load_args.get("checkpoint_uid"),
-            )
-            loader.check_version()
-            learner_state, start_step = loader.restore(
-                learner_state, load_args.get("timestep")
-            )
-            # How many newer-but-unusable checkpoints the fallback walk
-            # rejected (with typed reasons — structure / non_finite /
-            # digest), surfaced in LAST_RUN_STATS.resilience below.
-            restore_skipped = len(loader.last_restore_report)
-            restore_report = list(loader.last_restore_report)
+                loader = Checkpointer(
+                    model_name=config.system.system_name,
+                    rel_dir=load_path or "checkpoints",
+                    checkpoint_uid=load_args.get("checkpoint_uid"),
+                )
+                loader.check_version()
+                learner_state, start_step = loader.restore(
+                    learner_state, load_args.get("timestep")
+                )
+                # How many newer-but-unusable checkpoints the fallback walk
+                # rejected (with typed reasons — structure / non_finite /
+                # digest), surfaced in LAST_RUN_STATS.resilience below.
+                restore_skipped = len(loader.last_restore_report)
+                restore_report = list(loader.last_restore_report)
         # Restore wall time is recovery, not compute: a relaunch spending
         # minutes re-reading checkpoints must show up in the badput ledger.
-        ledger.note("recovery", time.perf_counter() - t_restore)
+        ledger.note("recovery", setup_phases.seconds()["restore"])
         if is_coordinator():
             get_logger("stoix_tpu.checkpoint").info(
                 "[checkpoint] restored state from step %d%s", start_step,
@@ -389,70 +409,71 @@ def run_anakin_experiment(
         evaluator, absolute_evaluator = make_evaluators(
             eval_env, setup.eval_act_fn, config, mesh
         )
-    logger = StoixLogger(config)
-    checkpointer = checkpointer_from_config(config, config.system.system_name)
+    with span("logger_build", clock=setup_phases, phase="logger_build"):
+        logger = StoixLogger(config)
+        checkpointer = checkpointer_from_config(config, config.system.system_name)
 
-    # Ops plane (docs/DESIGN.md §2.13), wired AFTER StoixLogger: its
-    # observability.configure() call is the per-run reset (fresh
-    # HealthMonitor + flight-recorder ring) and starts the /metrics·/healthz
-    # ·/statusz·/varz server when logger.telemetry.http.enabled. Everything
-    # below is host-memory bookkeeping — always on, bit-identity untouched.
-    telemetry_cfg = dict(config.logger.get("telemetry") or {})
-    http_cfg = dict(telemetry_cfg.get("http") or {})
-    recorder = flightrec.get_flight_recorder()
-    recorder.set_context(
-        architecture="anakin",
-        system=str(config.system.system_name),
-        seed=int(config.arch.seed),
-    )
-    status = get_status_board()
-    status.update(
-        {
-            "run_id": f"{config.system.system_name}_seed{int(config.arch.seed)}",
-            "architecture": "anakin",
-            "system": str(config.system.system_name),
-            "step": start_step,
-            "restore_skipped": restore_skipped,
-            "last_restore_report": restore_report,
-            "quarantine_file": dict(config.arch.get("integrity") or {}).get(
-                "quarantine_file", "checkpoints/quarantine.json"
-            ),
-        }
-    )
-    # /healthz source: the host loop beats once per window; an injected
-    # host_stall (or a genuinely wedged loop) lets the age cross
-    # stale_after_s and the endpoint flips to 503. Registered fresh each run
-    # — configure() above already dropped any previous incarnation's board.
-    monitor = get_health_monitor()
-    loop_beats = HeartbeatBoard()
-    monitor.register_board(
-        "anakin-host-loop",
-        loop_beats,
-        stale_after_s=float(http_cfg.get("stale_after_s", 60.0) or 60.0),
-    )
-    ops_server = get_ops_server()
-    aggregator = None
-    if ops_server is not None and fleet_coord is not None:
-        # Host-level metric federation over the fleet KV store: publish this
-        # host's snapshots off the hot path; /metrics/fleet folds every
-        # host's newest blob with per-host labels (aggregate.py).
-        aggregator = fleet_metrics.aggregator_from_fleet(
-            fleet_coord,
-            interval_s=float(http_cfg.get("aggregate_interval_s", 10.0) or 10.0),
+        # Ops plane (docs/DESIGN.md §2.13), wired AFTER StoixLogger: its
+        # observability.configure() call is the per-run reset (fresh
+        # HealthMonitor + flight-recorder ring) and starts the /metrics·/healthz
+        # ·/statusz·/varz server when logger.telemetry.http.enabled. Everything
+        # below is host-memory bookkeeping — always on, bit-identity untouched.
+        telemetry_cfg = dict(config.logger.get("telemetry") or {})
+        http_cfg = dict(telemetry_cfg.get("http") or {})
+        recorder = flightrec.get_flight_recorder()
+        recorder.set_context(
+            architecture="anakin",
+            system=str(config.system.system_name),
+            seed=int(config.arch.seed),
         )
-        if aggregator is not None:
-            aggregator.start()
-            ops_server.set_aggregator(aggregator)
+        status = get_status_board()
+        status.update(
+            {
+                "run_id": f"{config.system.system_name}_seed{int(config.arch.seed)}",
+                "architecture": "anakin",
+                "system": str(config.system.system_name),
+                "step": start_step,
+                "restore_skipped": restore_skipped,
+                "last_restore_report": restore_report,
+                "quarantine_file": dict(config.arch.get("integrity") or {}).get(
+                    "quarantine_file", "checkpoints/quarantine.json"
+                ),
+            }
+        )
+        # /healthz source: the host loop beats once per window; an injected
+        # host_stall (or a genuinely wedged loop) lets the age cross
+        # stale_after_s and the endpoint flips to 503. Registered fresh each run
+        # — configure() above already dropped any previous incarnation's board.
+        monitor = get_health_monitor()
+        loop_beats = HeartbeatBoard()
+        monitor.register_board(
+            "anakin-host-loop",
+            loop_beats,
+            stale_after_s=float(http_cfg.get("stale_after_s", 60.0) or 60.0),
+        )
+        ops_server = get_ops_server()
+        aggregator = None
+        if ops_server is not None and fleet_coord is not None:
+            # Host-level metric federation over the fleet KV store: publish this
+            # host's snapshots off the hot path; /metrics/fleet folds every
+            # host's newest blob with per-host labels (aggregate.py).
+            aggregator = fleet_metrics.aggregator_from_fleet(
+                fleet_coord,
+                interval_s=float(http_cfg.get("aggregate_interval_s", 10.0) or 10.0),
+            )
+            if aggregator is not None:
+                aggregator.start()
+                ops_server.set_aggregator(aggregator)
 
-    if sentinel is not None:
-        # Bind AFTER restore: the fingerprint program is built once for this
-        # mesh + state structure (never per window — STX012). The resume info
-        # points a rc-88 relaunch at THIS run's orbax store, whose newest
-        # digest-verified step is the recovery target.
-        sentinel.bind(mesh, learner_state)
-        if checkpointer is not None:
-            sentinel.set_resume_info(checkpointer.directory)
-        sentinel.install_excepthook()
+        if sentinel is not None:
+            # Bind AFTER restore: the fingerprint program is built once for this
+            # mesh + state structure (never per window — STX012). The resume info
+            # points a rc-88 relaunch at THIS run's orbax store, whose newest
+            # digest-verified step is the recovery target.
+            sentinel.bind(mesh, learner_state)
+            if checkpointer is not None:
+                sentinel.set_resume_info(checkpointer.directory)
+            sentinel.install_excepthook()
 
     steps_per_eval = (
         int(config.system.rollout_length)
@@ -492,11 +513,7 @@ def run_anakin_experiment(
         if gossip_step is not None
         else None
     )
-    phases = _PhaseClock()
-    compile_counter = get_registry().counter(
-        "stoix_tpu_runner_compile_seconds_total",
-        "Cumulative XLA compile time paid by AOT warmup",
-    )
+    phases = _PhaseClock(ledger)
 
     if fused:
         # One XLA program per window: learn + eval-params selection + the FF
@@ -546,13 +563,12 @@ def run_anakin_experiment(
                 )
     compile_s = phases.breakdown()["compile_s"]  # this run's: that one span
     setup_phases.record("aot_warmup", compile_s)
-    compile_counter.inc(compile_s)
     # From here to the first completed window: the snapshot and evaluator
     # programs' compiles, the first dispatches and the first window itself.
-    first_tick = contextlib.ExitStack()
-    first_tick.enter_context(span("first_tick", clock=setup_phases, phase="first_tick"))
-    # Per-entry compile observability (docs/DESIGN.md §2.7): which program
-    # paid how much compile, and whether the persistent cache absorbed it.
+    first_tick = setup_phases.open_first_tick()
+    # Whether the persistent cache absorbed the warm-up (docs/DESIGN.md §2.7);
+    # which program paid how much, by stage, is
+    # `stoix_tpu_compile_seconds_total{program, stage}` (utils/compilecache.py).
     cache_after = compilecache.cache_stats()
     compile_stats = {
         "compile_s": round(compile_s, 6),
@@ -560,10 +576,6 @@ def run_anakin_experiment(
         "cache_misses": cache_after["misses"] - cache_before["misses"],
         "aot_source": aot_info["source"],
     }
-    get_registry().gauge(
-        "stoix_tpu_compile_entry_seconds",
-        "AOT warmup wall seconds of the most recent compile, per entry point",
-    ).set(compile_s, {"entry": "fused_step" if fused else "learn"})
     if pf.enabled:
         preflight.check_device_memory(
             fused_step if fused else learn, headroom=pf.hbm_headroom
@@ -1023,11 +1035,11 @@ def run_anakin_experiment(
         "stoix_tpu_runner_steady_state_sps",
         "Post-first-window env-steps/sec of the most recent Anakin run",
     ).set(steady)
-    # Close the goodput books: attribute this run's phase-clock deltas, then
-    # assign the residual wall (host idle while the device computes, in the
-    # pipelined loop) to compute. Fractions sum to 1 by construction
+    # Close the goodput books: the phase clock told the ledger of each span as
+    # it closed and set-up's wall is booked whole, so the residual is steady
+    # state's (host idle while the device computes, in the pipelined loop) and
+    # goes to compute. Fractions sum to 1 by construction
     # (tests/test_opsplane.py pins it on a real pipelined run).
-    ledger.note_phases(phases.breakdown())
     goodput_report = ledger.finalize()
     LAST_RUN_STATS.clear()
     LAST_RUN_STATS.update(
@@ -1035,6 +1047,7 @@ def run_anakin_experiment(
             "phase_breakdown": {k: round(v, 6) for k, v in phases.breakdown().items()},
             "loop_wall_s": round(loop_wall_s, 6),
             "setup_phases": {k: round(v, 6) for k, v in setup_phases.seconds().items()},
+            "launch_phases": setup_phases.launch,
             "goodput": goodput_report,
             "steady_state_sps": steady,
             "pipelined": pipelined,

@@ -19,7 +19,14 @@
    hits/misses are observable: jax's `/jax/compilation_cache/*` monitoring
    events are folded into the metrics registry as
    `stoix_tpu_compile_persistent_cache_events_total{event=hit|miss}` and
-   surfaced by `cache_stats()`. A corrupted cache entry degrades to a
+   surfaced by `cache_stats()`. What every program costs to bring to an
+   executable is observable by stage: one listener of jax's duration events
+   (`_CompileStages`) keeps `stoix_tpu_compile_seconds_total{program,
+   stage=trace|lower|backend}`, `stoix_tpu_compiles_total{program}` and
+   `stoix_tpu_compile_cache_retrieval_seconds_total` (the part of `backend`
+   that read an executable back from the cache). It runs at compile events
+   only: in steady state never, and an increment there names the program
+   that recompiled. A corrupted cache entry degrades to a
    recompile, never a crash (`jax_raise_persistent_cache_errors` stays
    False; tests/test_compilecache.py pins it).
 
@@ -57,6 +64,28 @@ _EVENT_MISSES = "/jax/compilation_cache/cache_misses"
 
 _CACHE_EVENTS_METRIC = "stoix_tpu_compile_persistent_cache_events_total"
 
+# jax's duration events of the three stages from a Python function to an
+# executable (each carries `fun_name`), and of a persistent-cache read (none).
+_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_EVENT_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+COMPILE_SECONDS_METRIC = "stoix_tpu_compile_seconds_total"
+COMPILES_METRIC = "stoix_tpu_compiles_total"
+RETRIEVAL_SECONDS_METRIC = "stoix_tpu_compile_cache_retrieval_seconds_total"
+# Set-up compiles dozens of one-op programs for eager `jnp` calls (41 names
+# before `learner_fn` in a tiny MLP PPO run), and a long-lived process may see
+# any number of names: the first this many keep their own, and so do as many
+# again whose stage took `NAMED_FROM_SECONDS` or more (jax's own measure of a
+# program worth caching), so that a learner is not lost behind the one-op
+# programs. The rest share `OTHER_PROGRAM`.
+MAX_PROGRAM_LABELS = 64
+NAMED_FROM_SECONDS = 1.0
+OTHER_PROGRAM = "other"
+
 _listener_lock = threading.Lock()
 _listener_installed = False
 
@@ -70,10 +99,85 @@ def _cache_counter():
     )
 
 
+def _retrieval_counter():
+    return get_registry().counter(
+        RETRIEVAL_SECONDS_METRIC,
+        "Seconds of the backend stage spent reading executables from the persistent cache",
+    )
+
+
+class _CompileStages:
+    """The `jax.monitoring` duration listener behind
+    `stoix_tpu_compile_seconds_total{program, stage}`: every second jax spends
+    tracing, lowering or compiling (a cache read included) goes to ONE
+    program and ONE stage.
+
+    jax times each stage from outside, so its events nest: a function traced
+    inside another's trace (every `jnp` call is a `jit`) reports seconds the
+    outer trace reports too, and an eager op met while tracing is lowered and
+    compiled inside that trace. A nested trace is the outer program's tracing
+    and is dropped; anything else that ended inside a stage (it began after
+    the stage did, on the same thread) is taken off that stage's seconds and
+    stays under its own name. `program` is jax's `fun_name` without its
+    `jit(...)` wrapper, so the three stages of one program share a label."""
+
+    _KEPT_ENDED = 1024  # stages a thread remembers for an enclosing one to claim
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._programs: set = set()
+        self._threads = threading.local()
+
+    def _label(self, fun_name: Any, duration: float) -> str:
+        name = str(fun_name)
+        if name.startswith("jit(") and name.endswith(")"):
+            name = name[4:-1]
+        with self._lock:
+            if name not in self._programs:
+                room = MAX_PROGRAM_LABELS * (2 if duration >= NAMED_FROM_SECONDS else 1)
+                if len(self._programs) >= room:
+                    return OTHER_PROGRAM
+                self._programs.add(name)
+        return name
+
+    def __call__(self, event: str, duration: float, **kwargs: Any) -> None:
+        stage = _STAGE_EVENTS.get(event)
+        if stage is None:
+            if event == _EVENT_RETRIEVAL:
+                _retrieval_counter().inc(duration)
+            return
+        if stage == "trace" and not jax.core.trace_ctx.is_top_level():
+            return
+        ended = time.perf_counter()
+        began = ended - duration
+        done = getattr(self._threads, "done", None)
+        if done is None:
+            done = self._threads.done = []
+        inside = 0.0
+        while done and done[-1][0] >= began:
+            inside += done.pop()[1]
+        done.append((began, duration))
+        if len(done) > self._KEPT_ENDED:
+            del done[: -self._KEPT_ENDED // 2]
+        program = self._label(kwargs.get("fun_name", "unnamed"), duration)
+        # Looked up an event, as `_cache_counter` is: a cleared registry (tests)
+        # must not leave the listener feeding instruments nobody can read.
+        get_registry().counter(
+            COMPILE_SECONDS_METRIC,
+            "Seconds jax spent bringing each program to an executable, by stage (trace, "
+            "lower, backend: a compile or a cache read); a stage inside another counted once",
+        ).inc(max(0.0, duration - inside), {"program": program, "stage": stage})
+        if stage == "backend":
+            get_registry().counter(
+                COMPILES_METRIC, "Backend compilations (or cache reads) of each program"
+            ).inc(1.0, {"program": program})
+
+
 def install_cache_metrics_listener() -> None:
-    """Idempotently fold jax's compilation-cache monitoring events into the
-    metrics registry. Installed by `configure()`; safe to call repeatedly
-    (and from tests) — only the first call registers."""
+    """Idempotently fold jax's compilation-cache monitoring events, and the
+    durations of the compile stages, into the metrics registry. Installed by
+    `configure()`; safe to call repeatedly (and from tests) — only the first
+    call registers."""
     global _listener_installed
     with _listener_lock:
         if _listener_installed:
@@ -86,6 +190,9 @@ def install_cache_metrics_listener() -> None:
                 _cache_counter().inc(1.0, {"event": "miss"})
 
         jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_CompileStages())
+        # Present from the first run on: a run that read nothing back reads 0.
+        _retrieval_counter().inc(0.0)
         _listener_installed = True
 
 

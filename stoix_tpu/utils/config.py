@@ -28,6 +28,8 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import yaml
 
+from stoix_tpu.observability.trace import LAUNCH, span
+
 
 class Config(dict):
     """A nested dict with attribute access. Always mutable ("struct off")."""
@@ -119,7 +121,16 @@ def compose(
     root_file: str,
     overrides: Optional[Sequence[str]] = None,
 ) -> Config:
-    """Compose a config from a root file's defaults list plus CLI overrides."""
+    """Compose a config from a root file's defaults list plus CLI overrides.
+    Before a process's first `run_experiment` its seconds are set-up's phase
+    `compose` (observability/trace.py::SetupClock)."""
+    with span("compose", clock=LAUNCH, phase="compose"):
+        return _compose(config_dir, root_file, overrides)
+
+
+def _compose(
+    config_dir: str, root_file: str, overrides: Optional[Sequence[str]]
+) -> Config:
     overrides = list(overrides or [])
     root_path = os.path.join(config_dir, root_file)
     root = _load_yaml(root_path)

@@ -22,7 +22,7 @@ PHASE_KEYS = (
 
 GOODPUT_KEYS = ("wall_s", "fraction", "stall_s", "recovery_s", "fractions")
 GOODPUT_PHASES = {
-    "compute", "eval", "checkpoint", "fetch_wait", "queue_wait",
+    "setup", "compute", "eval", "checkpoint", "fetch_wait", "queue_wait",
     "gossip", "compile", "stall", "recovery",
 }
 

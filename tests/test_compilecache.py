@@ -260,3 +260,166 @@ def test_aot_warmup_raises_a_compile_error_at_the_warmup_site():
     # A plain wrapper has nothing to compile ahead of time: returned as is.
     wrapper = lambda w, d, i: refused(w, d, i)
     assert aot_warmup(wrapper, ones, ones, ones[0]) is wrapper
+
+
+# ---------------------------------------------------------------------------
+# The compile stages by program (one jax.monitoring duration listener)
+# ---------------------------------------------------------------------------
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _stage_seconds(program):
+    from stoix_tpu.observability import get_registry
+    from stoix_tpu.utils import compilecache
+
+    counter = get_registry().counter(compilecache.COMPILE_SECONDS_METRIC)
+    return {
+        stage: counter.value({"program": program, "stage": stage})
+        for stage in ("trace", "lower", "backend")
+    }
+
+
+def _compiles(program):
+    from stoix_tpu.observability import get_registry
+    from stoix_tpu.utils import compilecache
+
+    return get_registry().counter(compilecache.COMPILES_METRIC).value({"program": program})
+
+
+@pytest.fixture
+def fresh_stage_listener():
+    """A listener with no names taken yet, beside the process's own: a worker
+    that has run other tests has long used up its 64 names, and the probe
+    programs below would be `other` to it."""
+    import jax
+    from jax._src import monitoring
+
+    from stoix_tpu.utils import compilecache
+
+    own = [
+        listener for listener in monitoring.get_event_duration_listeners()
+        if isinstance(listener, compilecache._CompileStages)
+    ]
+    for listener in own:  # or both would count every event
+        monitoring.unregister_event_duration_listener(listener)
+    listen = compilecache._CompileStages()
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    yield listen
+    monitoring.unregister_event_duration_listener(listen)
+    for listener in own:
+        jax.monitoring.register_event_duration_secs_listener(listener)
+
+
+@pytest.mark.parametrize("stage", ["trace", "lower", "backend"])
+def test_a_forced_recompile_is_attributed_to_its_program_and_stage(stage, fresh_stage_listener):
+    """A named `jit` called with a second shape compiles again: one more
+    compilation under its own `program` label, and seconds in every stage."""
+    import jax
+    import jax.numpy as jnp
+
+    def recompiled_probe_program(x):
+        return jnp.tanh(x) * 3.0
+
+    probe = jax.jit(recompiled_probe_program)
+    probe(jnp.ones(3)).block_until_ready()
+    seconds, compiles = _stage_seconds("recompiled_probe_program"), _compiles("recompiled_probe_program")
+    assert compiles >= 1.0 and seconds[stage] > 0.0
+    probe(jnp.ones(3)).block_until_ready()  # same shape: nothing compiles, nothing moves
+    assert _stage_seconds("recompiled_probe_program") == seconds
+    probe(jnp.ones(5)).block_until_ready()  # the forced recompile
+    assert _compiles("recompiled_probe_program") == compiles + 1.0
+    assert _stage_seconds("recompiled_probe_program")[stage] > seconds[stage]
+
+
+def test_a_function_traced_inside_another_is_the_outer_programs_tracing(fresh_stage_listener):
+    """jax times a nested `jit`'s trace inside the outer trace's seconds: the
+    inner name gets no series, and each second is counted once."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def nested_probe_inner(x):
+        time.sleep(0.05)  # tracing time, inside the outer trace
+        return jnp.cos(x)
+
+    def nested_probe_outer(x):
+        return nested_probe_inner(x) + 1.0
+
+    started = time.perf_counter()
+    jax.jit(nested_probe_outer)(jnp.ones(4)).block_until_ready()
+    wall = time.perf_counter() - started
+    outer, inner = _stage_seconds("nested_probe_outer"), _stage_seconds("nested_probe_inner")
+    assert inner == {"trace": 0.0, "lower": 0.0, "backend": 0.0}
+    assert 0.05 <= outer["trace"] and sum(outer.values()) <= wall
+
+
+def test_compile_stage_listener_counts_each_second_once_and_bounds_its_labels(monkeypatch):
+    """The listener on synthetic events: a stage that ended inside another
+    (it began later, on the same thread) is taken off the outer's seconds; the
+    first MAX_PROGRAM_LABELS names keep their own, as many again if a stage
+    took a second or more, the rest are `other`; a cache read is its own
+    counter."""
+    from stoix_tpu.observability import get_registry
+    from stoix_tpu.utils import compilecache
+
+    import types
+
+    monkeypatch.setattr(compilecache, "MAX_PROGRAM_LABELS", 2)
+    now = [0.0]
+    monkeypatch.setattr(compilecache, "time", types.SimpleNamespace(perf_counter=lambda: now[0]))
+    listen = compilecache._CompileStages()
+
+    def ended(at, event, seconds, fun_name):
+        now[0] = at
+        listen(event, seconds, fun_name=fun_name)
+
+    # A trace from 100 to 110 s that met an eager op at 102 s: lowered in a
+    # quarter of a second, compiled in half a second, inside the trace.
+    ended(102.25, LOWER_EVENT, 0.25, "jit(labels_eager_op)")
+    ended(102.75, BACKEND_EVENT, 0.5, "jit(labels_eager_op)")
+    ended(110.0, TRACE_EVENT, 10.0, "labels_outer")
+    assert _stage_seconds("labels_eager_op") == {"trace": 0.0, "lower": 0.25, "backend": 0.5}
+    assert _stage_seconds("labels_outer")["trace"] == pytest.approx(10.0 - 0.75)
+    assert _compiles("labels_eager_op") == 1.0 and _compiles("labels_outer") == 0.0
+    # Its own lowering and compilation follow the trace, and are whole.
+    ended(111.0, LOWER_EVENT, 1.0, "jit(labels_outer)")
+    ended(115.0, BACKEND_EVENT, 4.0, "jit(labels_outer)")
+    assert _stage_seconds("labels_outer") == {
+        "trace": pytest.approx(9.25), "lower": 1.0, "backend": 4.0
+    }
+    # Two names are in: a third is `other`, unless a stage took a second.
+    other = _stage_seconds(compilecache.OTHER_PROGRAM)
+    ended(120.0, BACKEND_EVENT, 0.125, "jit(labels_third_small)")
+    assert _stage_seconds("labels_third_small")["backend"] == 0.0
+    assert _stage_seconds(compilecache.OTHER_PROGRAM)["backend"] == other["backend"] + 0.125
+    ended(130.0, BACKEND_EVENT, 2.0, "jit(labels_third_large)")
+    ended(140.0, BACKEND_EVENT, 2.0, "jit(labels_fourth_large)")
+    ended(150.0, BACKEND_EVENT, 2.0, "jit(labels_fifth_large)")  # beyond twice the bound
+    assert _stage_seconds("labels_third_large")["backend"] == 2.0
+    assert _stage_seconds("labels_fourth_large")["backend"] == 2.0
+    assert _stage_seconds("labels_fifth_large")["backend"] == 0.0
+    assert _compiles(compilecache.OTHER_PROGRAM) >= 2.0
+    retrieval = get_registry().counter(compilecache.RETRIEVAL_SECONDS_METRIC)
+    before = retrieval.value()
+    listen("/jax/compilation_cache/cache_retrieval_time_sec", 1.5)
+    listen("/jax/some/other/duration", 99.0, fun_name="ignored")
+    assert retrieval.value() == before + 1.5
+
+
+def test_the_two_series_the_stage_counters_replaced_are_gone():
+    """`stoix_tpu_compile_entry_seconds` and
+    `stoix_tpu_runner_compile_seconds_total` recorded one span's seconds and
+    had no reader: no source file of the package names them any more."""
+    root = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "stoix_tpu")
+    gone = ("stoix_tpu_compile_entry_seconds", "stoix_tpu_runner_compile_seconds_total")
+    for folder, _, files in os.walk(root):
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(folder, name), encoding="utf-8") as handle:
+                    text = handle.read()
+                assert not any(series in text for series in gone), os.path.join(folder, name)

@@ -517,6 +517,28 @@ def test_goodput_ledger_residual_fractions_and_export():
     )
 
 
+def test_goodput_setup_is_booked_whole_and_is_not_compute():
+    """Between begin_setup and end_setup steady-state notes are dropped (the
+    first window's waits are set-up's) and compile, stall and recovery kept;
+    end_setup books what is left of set-up's wall as `setup`, once."""
+    ledger = goodput.GoodputLedger(registry=MetricsRegistry()).start()
+    ledger.begin_setup()
+    ledger.note("compile", 5.0)
+    ledger.note("recovery", 1.0)
+    ledger.note("fetch_wait", 7.0)  # window 0's wait: dropped
+    ledger.note("compute", 0.5)  # window 0's dispatches: dropped
+    ledger.end_setup(20.0)
+    ledger.end_setup(99.0)  # a run's finally closes the clock again: no effect
+    ledger.note("fetch_wait", 2.0)
+    report = ledger.finalize(wall_s=30.0)
+    assert report["seconds"]["setup"] == pytest.approx(14.0)
+    assert report["seconds"]["compile"] == 5.0 and report["seconds"]["recovery"] == 1.0
+    assert report["seconds"]["fetch_wait"] == 2.0
+    assert report["seconds"]["compute"] == pytest.approx(30.0 - 20.0 - 2.0)  # the residual
+    assert sum(report["fractions"].values()) == pytest.approx(1.0, abs=1e-9)
+    assert set(report["fractions"]) == set(goodput.PHASES) and len(goodput.PHASES) == 10
+
+
 def test_goodput_overattribution_clamps_to_attributed_wall():
     ledger = goodput.GoodputLedger(registry=MetricsRegistry()).start()
     ledger.note("compute", 2.0)

@@ -330,7 +330,8 @@ def test_sebulba_dqn_reports_what_the_one_runner_reports(sebulba_dqn_run, what):
     stats, misc = sebulba_dqn_run["stats"], sebulba_dqn_run["misc"]
     if what == "setup_phases":
         assert set(stats["setup_phases"]) == {
-            "env_build", "network_init", "learner_setup", "evaluator_setup", "first_tick"
+            "mesh_build", "env_build", "network_init", "learner_setup", "evaluator_setup",
+            "logger_build", "first_tick", "unspanned",
         }
         assert all(seconds > 0.0 for seconds in stats["setup_phases"].values())
     elif what == "actor_step_timers":

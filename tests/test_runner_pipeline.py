@@ -182,7 +182,8 @@ def test_fetch_materialize_is_its_own_phase(devices, monkeypatch):
     # Set-up is split the same way, once a run.
     setup = runner.LAST_RUN_STATS["setup_phases"]
     assert set(setup) == {
-        "env_build", "learner_setup", "evaluator_setup", "aot_warmup", "first_tick"
+        "mesh_build", "env_build", "rng_key", "learner_setup", "evaluator_setup",
+        "logger_build", "aot_warmup", "first_tick", "unspanned",
     }
     assert setup["aot_warmup"] == pytest.approx(phases["compile_s"], abs=1e-5)
     assert setup["first_tick"] > 0.0

@@ -302,7 +302,8 @@ def test_sebulba_run_gauges_lag_splits_the_actor_step_and_setup(traced_sebulba_r
         assert 0.0 < split <= step * 1.001, (split, step)
     setup = run["stats"]["setup_phases"]
     assert set(setup) == {
-        "env_build", "network_init", "learner_setup", "evaluator_setup", "first_tick"
+        "mesh_build", "env_build", "network_init", "learner_setup", "evaluator_setup",
+        "logger_build", "first_tick", "unspanned",
     }
     assert all(seconds > 0.0 for seconds in setup.values()), setup
     gauge = obs.get_registry().gauge("stoix_tpu_setup_phase_seconds")
