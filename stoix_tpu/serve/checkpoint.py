@@ -35,7 +35,7 @@ from stoix_tpu.observability import get_logger
 from stoix_tpu.resilience import fleet
 from stoix_tpu.resilience.errors import CheckpointIntegrityError
 from stoix_tpu.utils import config as config_lib
-from stoix_tpu.utils.checkpointing import place_host_leaves, read_host_leaves
+from stoix_tpu.utils.checkpointing import _orbax, place_host_leaves, read_host_leaves
 
 DEFAULT_PARAMS_PATH = "params/actor_params"
 OBS_STATS_PATH = "obs_stats"
@@ -63,8 +63,7 @@ def store_metadata(path: str) -> Dict[str, Any]:
     """The custom metadata dict an orbax store root carries ({} when absent
     or unreadable). The training Checkpointer writes the full composed config
     there, which is what makes `serve` self-describing."""
-    import orbax.checkpoint as ocp
-
+    ocp = _orbax()
     try:
         manager = ocp.CheckpointManager(os.path.abspath(path))
     except Exception as exc:  # noqa: BLE001 — any unreadable store => no metadata

@@ -34,12 +34,13 @@ from __future__ import annotations
 
 import json
 import os
+import sys
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import orbax.checkpoint as ocp
 
 from stoix_tpu.resilience.errors import CheckpointIntegrityError
 
@@ -65,6 +66,29 @@ TOPOLOGY_SIDECAR = "_topology.json"
 # as garbage). Shares the digest helpers with the fleet emergency store and
 # the serving canary (resilience/integrity.py).
 DIGEST_SIDECAR = "_digests.json"
+
+
+def _orbax() -> Any:
+    """`orbax.checkpoint`, imported by the first caller that builds or reads a
+    store (docs/DESIGN.md §2.2, "A feature's library is imported where the
+    feature is built"): seconds on the chip's host, most of them
+    `google.cloud.logging`, that a run with checkpointing off never pays and a
+    run that saves or restores pays inside its `logger_build` or `restore`
+    phase. That one import is timed into the gauge
+    `stoix_tpu_checkpoint_library_import_seconds`, absent until then. The only
+    way the package reaches orbax."""
+    first = "orbax.checkpoint" not in sys.modules
+    began = time.perf_counter()
+    import orbax.checkpoint as ocp
+
+    if first:
+        from stoix_tpu.observability import get_registry
+
+        get_registry().gauge(
+            "stoix_tpu_checkpoint_library_import_seconds",
+            "Wall seconds of this process's one import of orbax.checkpoint",
+        ).set(time.perf_counter() - began)
+    return ocp
 
 
 def saved_digest_record(store_dir: str) -> Dict[int, Dict[str, str]]:
@@ -196,6 +220,7 @@ def read_host_leaves(store_dir: str, step: int) -> Dict[Tuple[str, ...], Any]:
     MANAGER's restore (with or without a template) reconstructs jax.Arrays on
     the devices recorded AT SAVE TIME, which need not exist on the restoring
     host — forcing numpy never touches device placement."""
+    ocp = _orbax()
     step_path = os.path.join(store_dir, str(step), "default")
     reader = ocp.Checkpointer(ocp.PyTreeCheckpointHandler())
     try:
@@ -227,8 +252,7 @@ class Checkpointer:
         max_to_keep: Optional[int] = 1,
         keep_period: Optional[int] = None,
     ):
-        import time
-
+        ocp = _orbax()
         uid = checkpoint_uid
         if uid is None:
             uid = time.strftime("%Y%m%d%H%M%S")
@@ -311,7 +335,7 @@ class Checkpointer:
         footprint = _device_footprint(state)
         saved = self._manager.save(
             timestep,
-            args=ocp.args.StandardSave(jax.tree.map(jax.numpy.asarray, state)),
+            args=_orbax().args.StandardSave(jax.tree.map(jax.numpy.asarray, state)),
             metrics={"episode_return": float(episode_return)},
             force=force,
         )
@@ -598,7 +622,7 @@ class Checkpointer:
                 else:
                     try:
                         restored = self._manager.restore(
-                            step, args=ocp.args.StandardRestore(template)
+                            step, args=_orbax().args.StandardRestore(template)
                         )
                     except (CheckpointIntegrityError, FileNotFoundError):
                         raise

@@ -1,7 +1,13 @@
 """Child process of tests/test_setup_clock.py: launched as an operator
 launches a system — import the system module, compose, `run_experiment` —
 twice in one process, with the set-up gauge read after each run. Prints one
-JSON line. Not a test module (no `test_` prefix)."""
+JSON line. Not a test module (no `test_` prefix).
+
+`anakin_saving` is the Anakin child with checkpointing ON: its first run
+saves (under the working directory, which the test makes a scratch one), its
+second restores what the first saved. Every child also reports, at each
+moment of `moments`, whether the checkpoint library is loaded and what its
+gauge reads (docs/DESIGN.md §2.2, ISSUE 37)."""
 
 import os
 import sys
@@ -27,11 +33,42 @@ SEBULBA_TINY = [
 ]
 
 
+CHECKPOINT_UID = "setup-clock-child"
+# Per run of the `anakin_saving` child: save, then restore what was saved.
+CHECKPOINTING = [
+    ["logger.checkpointing.save_model=True",
+     f"logger.checkpointing.save_args.checkpoint_uid={CHECKPOINT_UID}"],
+    ["logger.checkpointing.load_model=True",
+     f"logger.checkpointing.load_args.checkpoint_uid={CHECKPOINT_UID}"],
+]
+
+
+def checkpoint_library(moment: str) -> dict:
+    """Is orbax (and the `google.cloud.logging` it brings) loaded, and what
+    does `stoix_tpu_checkpoint_library_import_seconds` read (None: absent)?
+    `google.cloud` itself is a namespace a `.pth` file of the installation
+    puts in `sys.modules` at interpreter start: its submodules are the test."""
+    from stoix_tpu import observability as obs
+
+    series = obs.get_registry().snapshot().get(
+        "stoix_tpu_checkpoint_library_import_seconds", {}
+    ).get("series") or []
+    return {
+        "moment": moment,
+        "orbax": any(m == "orbax" or m.startswith("orbax.") for m in sys.modules),
+        "google_cloud": any(m.startswith("google.cloud.") for m in sys.modules),
+        "import_seconds": series[0]["value"] if series else None,
+    }
+
+
 def main(architecture: str) -> None:
     import json
 
-    if architecture == "anakin":
+    moments = []
+    if architecture.startswith("anakin"):
         from stoix_tpu.systems import runner as stats_of
+
+        moments.append(checkpoint_library("runner_imported"))
         from stoix_tpu.systems.ppo.anakin import ff_ppo as system
         root, overrides = "default/anakin/default_ff_ppo.yaml", ANAKIN_TINY
     else:
@@ -51,8 +88,9 @@ def main(architecture: str) -> None:
 
     trace.SetupClock._close = stamping_close
     runs = []
-    for _ in range(2):
-        config = config_lib.compose(config_lib.default_config_dir(), root, overrides)
+    for run in range(2):
+        extra = CHECKPOINTING[run] if architecture == "anakin_saving" else []
+        config = config_lib.compose(config_lib.default_config_dir(), root, overrides + extra)
         entered = time.perf_counter()
         system.run_experiment(config)
         snapshot = obs.get_registry().snapshot()
@@ -68,6 +106,7 @@ def main(architecture: str) -> None:
             "stats_launch_phases": stats_of.LAST_RUN_STATS["launch_phases"],
             "goodput": stats_of.LAST_RUN_STATS["goodput"],
         })
+        moments.append(checkpoint_library(f"run_{run}"))
     # Steady state: set-up is over, and a program that compiles now is named.
     import jax
     import jax.numpy as jnp
@@ -80,7 +119,13 @@ def main(architecture: str) -> None:
     before = compiles.value({"program": "steady_state_recompile_probe"})
     jax.jit(steady_state_recompile_probe)(jnp.ones(7)).block_until_ready()
     after = compiles.value({"program": "steady_state_recompile_probe"})
-    print(json.dumps({"runs": runs, "steady_state_recompiles": [before, after]}), flush=True)
+    print(json.dumps({
+        "runs": runs, "steady_state_recompiles": [before, after], "moments": moments,
+        "saved_steps": sorted(
+            int(d) for d in os.listdir(os.path.join("checkpoints", CHECKPOINT_UID, "ff_ppo"))
+            if d.isdigit()
+        ) if architecture == "anakin_saving" else None,
+    }), flush=True)
 
 
 if __name__ == "__main__":

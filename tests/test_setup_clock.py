@@ -5,7 +5,12 @@ process to the first completed window (Anakin) or update (Sebulba),
 `unspanned` is what no span covered, a second run in the same process shows
 no stale launch phases, and set-up is the goodput ledger's `setup`, not its
 `compute`. Each architecture is one child process (tests/setup_clock_child.py,
-two runs)."""
+two runs).
+
+The checkpoint library loads where a checkpointer is built (§2.2, ISSUE 37):
+the same children say at each moment whether orbax is in `sys.modules` and
+what `stoix_tpu_checkpoint_library_import_seconds` reads, and a third child,
+`anakin_saving`, runs with checkpointing on: it saves, then restores."""
 
 import json
 import os
@@ -31,13 +36,13 @@ OWN = {
 }
 
 
-def _launch(architecture):
+def _launch(architecture, cwd=None):
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env["JAX_PLATFORMS"] = "cpu"
     spawned = time.time()
     done = subprocess.run(
         [sys.executable, CHILD, architecture], env=env, capture_output=True, text=True,
-        timeout=600,
+        timeout=600, cwd=cwd,
     )
     assert done.returncode == 0, done.stderr[-4000:]
     report = json.loads(done.stdout.strip().splitlines()[-1])
@@ -55,9 +60,27 @@ def sebulba_child():
     return _launch("sebulba")
 
 
+@pytest.fixture(scope="module")
+def anakin_saving_child(tmp_path_factory):
+    """Saves under its working directory: a scratch one."""
+    return _launch("anakin_saving", cwd=str(tmp_path_factory.mktemp("saving_child")))
+
+
 @pytest.fixture(params=["anakin", "sebulba"])
 def child(request):
     return request.param, request.getfixturevalue(f"{request.param}_child")
+
+
+def _own_phases_partition_the_wall(run):
+    """A run's own phases (the launch phases apart) cover 98% of the wall from
+    `run_experiment`'s entry to the first tick; `unspanned` is the rest."""
+    own = {p: s for p, s in run["phases"].items() if p not in LAUNCH}
+    spanned = sum(s for p, s in own.items() if p != "unspanned")
+    wall = run["entry_to_first_tick_s"]
+    assert spanned >= 0.98 * wall, (spanned, wall, own)
+    assert own["unspanned"] == pytest.approx(wall - spanned, abs=0.02)
+    assert 0.0 < own["unspanned"] < 0.5
+    return own
 
 
 def test_first_run_partitions_the_wall_from_process_start_to_first_tick(child):
@@ -77,9 +100,10 @@ def test_first_run_launch_phases_are_what_passed_before_run_experiment(child):
     first = report["runs"][0]
     phases = first["phases"]
     assert first["stats_launch_phases"] == {p: phases[p] for p in LAUNCH}
-    # The Anakin runner's import block pulls in jax, flax, orbax, the envs:
-    # seconds. A Sebulba system module has imported most of that itself before
-    # it reaches the runner's block, and those seconds are `launch`'s.
+    # The Anakin runner's import block pulls in jax, flax, the envs (not orbax,
+    # since PR 37): seconds. A Sebulba system module has imported most of that
+    # itself before it reaches the runner's block, and those seconds are
+    # `launch`'s.
     assert phases["imports"] > (0.5 if architecture == "anakin" else 0.0)
     assert phases["process_boot"] > 0.0
     assert 0.0 < phases["compose"] < 1.0
@@ -89,18 +113,13 @@ def test_first_run_launch_phases_are_what_passed_before_run_experiment(child):
 def test_each_run_names_its_own_phases_and_unspanned_is_what_is_left(child):
     architecture, report = child
     for run in report["runs"]:
-        own = {p: s for p, s in run["phases"].items() if p not in LAUNCH}
+        own = _own_phases_partition_the_wall(run)
         # Every phase of the table is a series; the ones this run closed are > 0.
         assert set(own) == set(SetupClock.PHASES)
         assert {p for p, s in own.items() if s > 0.0} == OWN[architecture], own
         assert {p: s for p, s in own.items() if s > 0.0} == pytest.approx(
             run["stats_setup_phases"], abs=1e-5
         )
-        spanned = sum(s for p, s in own.items() if p != "unspanned")
-        wall = run["entry_to_first_tick_s"]
-        assert spanned >= 0.98 * wall, (spanned, wall, own)
-        assert own["unspanned"] == pytest.approx(wall - spanned, abs=0.02)
-        assert 0.0 < own["unspanned"] < 0.5
 
 
 def test_second_run_in_one_process_publishes_no_stale_launch_phases(child):
@@ -134,3 +153,50 @@ def test_a_steady_state_recompile_is_named(child):
     found a compilation inside the interval."""
     _, report = child
     assert report["steady_state_recompiles"] == [0.0, 1.0]
+
+
+@pytest.mark.parametrize(
+    "architecture, moment, loaded",
+    [
+        ("anakin", "runner_imported", False),
+        ("anakin", "run_0", False),
+        ("anakin", "run_1", False),
+        ("sebulba", "run_1", False),
+        ("anakin_saving", "runner_imported", False),
+        ("anakin_saving", "run_0", True),
+        ("anakin_saving", "run_1", True),
+    ],
+)
+def test_the_checkpoint_library_is_loaded_only_by_a_run_that_checkpoints(
+    request, architecture, moment, loaded
+):
+    """After `import stoix_tpu.systems.runner` and after whole runs with
+    checkpointing off, neither orbax nor a `google.cloud` module is loaded and
+    the gauge is absent; a run that saves loads it, once (the restoring run
+    after it reads the same seconds)."""
+    report = request.getfixturevalue(f"{architecture}_child")
+    at = {m["moment"]: m for m in report["moments"]}[moment]
+    assert at["orbax"] is loaded, at
+    assert loaded or not at["google_cloud"], at  # it comes with orbax or not at all
+    assert (at["import_seconds"] is not None) is loaded, at
+    if loaded:
+        assert at["import_seconds"] > 0.0
+        assert at["import_seconds"] == report["moments"][-1]["import_seconds"]
+
+
+def test_a_saving_run_pays_the_import_in_logger_build_and_its_phases_add_up(
+    anakin_saving_child,
+):
+    """The seconds moved from `imports` (over before orbax was loaded: the
+    `runner_imported` case above) to the phase that builds the checkpointer;
+    the table of the run that saves, and of the one that restores, still
+    partitions its wall."""
+    report = anakin_saving_child
+    saving, restoring = report["runs"]
+    assert report["saved_steps"], report["saved_steps"]
+    imported = report["moments"][-1]["import_seconds"]
+    assert saving["phases"]["logger_build"] >= imported > 0.0
+    assert restoring["phases"]["restore"] > 0.0
+    assert restoring["phases"]["logger_build"] < imported
+    for run in report["runs"]:
+        _own_phases_partition_the_wall(run)
