@@ -65,6 +65,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from stoix_tpu.networks.mla import Latent, LatentAttention
 from stoix_tpu.networks.olmoe import (
     _attend_cache, _stack, init_length, moe, reset_length, rms_norm, write_cache_rows,
 )
@@ -93,7 +94,7 @@ class KV(NamedTuple):
 
 
 class Lfm2Carry(NamedTuple):
-    layers: Tuple[Any, ...]  # a layer: its mixer's state, ConvTail or KV
+    layers: Tuple[Any, ...]  # a layer: its mixer's state, ConvTail, KV or Latent
     length: jax.Array  # [B] or [] int32: positions filled = the next token's position
 
 
@@ -187,6 +188,7 @@ class DenseMLP(nn.Module):
 
     hidden_size: int
     width: int
+    trace_scope: str = "dense_mlp"  # `shared_expert` where it stands beside routed experts
 
     def setup(self) -> None:
         d, f = self.hidden_size, self.width
@@ -195,12 +197,15 @@ class DenseMLP(nn.Module):
         self.w2 = self.param("w2", _INIT, (f, d))
 
     def __call__(self, f: jax.Array) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
-        with annotate(SCOPES["dense_mlp"]):
+        with annotate(SCOPES[self.trace_scope]):
             return (jax.nn.silu(f @ self.w1) * (f @ self.w3)) @ self.w2, None
 
 
 class RoutedMLP(nn.Module):
-    """The held experts' part of the sigmoid-routed layer on f [N, D]."""
+    """The held experts' part of the sigmoid-routed layer on f [N, D], plus,
+    with `shared_width`, the shared expert every token passes (a `DenseMLP`
+    under `shared`): what every rank computes alike, so the ranks' parts add
+    up to the uncut layer with it counted once."""
 
     hidden_size: int
     num_experts: int  # the router's width: every expert of the layer
@@ -210,6 +215,8 @@ class RoutedMLP(nn.Module):
     width: int
     scaling_factor: float
     bias_scale: float
+    epsilon: float = 1e-6  # joins the chosen scores' sum the weights are divided by
+    shared_width: int = 0
 
     def setup(self) -> None:
         d, e, held, f = self.hidden_size, self.num_experts, self.experts_held, self.width
@@ -220,15 +227,20 @@ class RoutedMLP(nn.Module):
         self.gate = self.param("gate", _INIT, (held, d, f))
         self.up = self.param("up", _INIT, (held, d, f))
         self.down = self.param("down", _INIT, (held, f, d))
+        if self.shared_width:
+            self.shared = DenseMLP(d, self.shared_width, trace_scope="shared_expert")
 
     def __call__(self, f: jax.Array) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
         with annotate(SCOPES["moe"]):
-            return moe(
+            out, stats = moe(
                 f, self.router, self.gate, self.up, self.down, self.experts_per_token,
                 held=(self.expert_offset, self.experts_held), renormalise=True,
                 held_room_sigmas=_HELD_ROOM_SIGMAS, score="sigmoid", bias=self.expert_bias,
-                epsilon=1e-6, scale=self.scaling_factor,
+                epsilon=self.epsilon, scale=self.scaling_factor,
             )
+        if self.shared_width:
+            out = out + self.shared(f)[0]
+        return out, stats
 
 
 class Block(nn.Module):
@@ -272,7 +284,7 @@ class Lfm2LM(nn.Module):
 
     vocab_size: int
     hidden_size: int
-    layer_types: Sequence[str]  # a layer: "conv" | "full_attention"
+    layer_types: Sequence[str]  # a layer: "conv" | "full_attention" | "latent_attention"
     num_dense_layers: int
     dense_width: int
     num_heads: int
@@ -288,6 +300,17 @@ class Lfm2LM(nn.Module):
     expert_bias_scale: float = 0.01
     rope_theta: float = 1000000.0
     rms_eps: float = 1e-5
+    router_epsilon: float = 1e-6
+    # The `deepseek_v3` keys of a `latent_attention` layer (networks/mla.py) ...
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # ... of the shared expert beside the routed ones: `n_shared_experts`
+    # SwiGLUs of `expert_width`, built as one of that many times the width ...
+    n_shared_experts: int = 0
+    # ... and of the head: the embedding's transpose, or a matrix of its own.
+    tie_word_embeddings: bool = True
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -305,7 +328,14 @@ class Lfm2LM(nn.Module):
                 self.hidden_size, self.num_heads, self.num_kv_heads, self.head_dim,
                 self.rope_theta, self.rms_eps,
             )
-        raise ValueError(f"layer_types names {kind!r}: a mixer is conv or full_attention")
+        if kind == "latent_attention":
+            return LatentAttention(
+                self.hidden_size, self.num_heads, self.kv_lora_rank, self.qk_nope_head_dim,
+                self.qk_rope_head_dim, self.v_head_dim, self.rope_theta, self.rms_eps,
+            )
+        raise ValueError(
+            f"layer_types names {kind!r}: a mixer is conv, full_attention or latent_attention"
+        )
 
     def _ffn(self, index: int) -> nn.Module:
         if index < self.num_dense_layers:
@@ -313,7 +343,8 @@ class Lfm2LM(nn.Module):
         return RoutedMLP(
             self.hidden_size, self.num_experts, self.experts_held, self.expert_offset,
             self.experts_per_token, self.expert_width, self.routed_scaling_factor,
-            self.expert_bias_scale,
+            self.expert_bias_scale, self.router_epsilon,
+            self.n_shared_experts * self.expert_width,
         )
 
     def setup(self) -> None:
@@ -323,11 +354,13 @@ class Lfm2LM(nn.Module):
             for i, kind in enumerate(self.layer_types)
         ]
         self.final_norm = self.param("final_norm", nn.initializers.ones, (self.hidden_size,))
+        if not self.tie_word_embeddings:
+            self.lm_head = self.param("lm_head", _INIT, (self.hidden_size, self.vocab_size))
 
     def _head(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
         with annotate(SCOPES["lm_head"]):
             hidden = rms_norm(x, self.final_norm, self.rms_eps)
-            return hidden @ self.embed.T, hidden
+            return hidden @ (self.embed.T if self.tie_word_embeddings else self.lm_head), hidden
 
     def forward(self, tokens: jax.Array) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
         x = jnp.take(self.embed, tokens, axis=0)
@@ -361,15 +394,22 @@ class Lfm2LM(nn.Module):
         cache = lambda: jnp.zeros(
             (max_len, batch, self.num_kv_heads, self.head_dim), jnp.float32
         )
-        states = tuple(
-            tail() if kind == "conv" else KV(cache(), cache()) for kind in self.layer_types
+        latent = lambda: Latent(jnp.zeros(
+            (batch, max_len, self.kv_lora_rank + self.qk_rope_head_dim), jnp.float32
+        ))
+        fresh = {
+            "conv": tail, "full_attention": lambda: KV(cache(), cache()),
+            "latent_attention": latent,
+        }
+        return Lfm2Carry(
+            tuple(fresh[kind]() for kind in self.layer_types), init_length(batch, together)
         )
-        return Lfm2Carry(states, init_length(batch, together))
 
     @nn.nowrap
     def reset_carry(self, carry: Lfm2Carry, done: jax.Array) -> Lfm2Carry:
         """Start a new sequence where `done`: its conv tails are what precedes
-        a sequence (zeros, 16 KB); nothing of a KV cache beyond `length` is read."""
+        a sequence (zeros, 16 KB); nothing of a KV cache or of the latent rows
+        beyond `length` is read."""
         fresh = lambda state: (
             ConvTail(jnp.where(done[:, None, None], 0.0, state.z))
             if isinstance(state, ConvTail) else state
@@ -385,4 +425,8 @@ class Lfm2LM(nn.Module):
             x.size * x.dtype.itemsize
             for state in carry.layers if isinstance(state, kind) for x in state
         )
-        return {"conv_tail": size(ConvTail), "kv": size(KV)}
+        kinds = {"conv_tail": ConvTail, "kv": KV, "latent": Latent}
+        return {
+            name: size(kind) for name, kind in kinds.items()
+            if any(isinstance(state, kind) for state in carry.layers)
+        }

@@ -82,6 +82,7 @@ from stoix_tpu.observability.trace import (  # noqa: F401
     DIFFUSION_SCOPES,
     HOST_SPANS,
     HYBRID_SCOPES,
+    LATENT_SCOPES,
     SCOPES,
     SetupClock,
     annotate,

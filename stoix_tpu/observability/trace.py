@@ -68,6 +68,14 @@ SCOPES = {
     "conv_mixer": "conv_mixer",
     "conv_mixer_conv": "conv_mixer_conv",  # inside it: the gates and the conv, all that is no matmul
     "dense_mlp": "dense_mlp",  # the dense SwiGLU feed-forward of the leading layers
+    # Multi-head latent attention (networks/mla.py) inside `attention`, and the
+    # always-on expert beside the routed ones, in both entry points alike.
+    # down-projection W_kva, the latent's norm, the partial rotation, the cache row's write
+    "latent_project": "latent_project",
+    # decode: W_uk into the query, scores and values against the latent rows, W_uv;
+    # update: the expansion W_kvb and the attention kernel, forward and backward
+    "latent_attend": "latent_attend",
+    "shared_expert": "shared_expert",  # the SwiGLU every token passes, beside the routed experts
 }
 
 # The scopes of the token policy's block: only the systems built on
@@ -79,6 +87,9 @@ DIFFUSION_SCOPES = ("denoise", "block_commit", "attention_scores")
 # What a stack of convolution and attention layers adds: only the systems
 # built on networks/lfm2.py carry these.
 HYBRID_SCOPES = ("conv_mixer", "conv_mixer_conv", "dense_mlp")
+# What latent attention and a shared expert add: only a stack with a
+# `latent_attention` layer and `n_shared_experts` carries these.
+LATENT_SCOPES = ("latent_project", "latent_attend", "shared_expert")
 
 # Host spans that recur in steady state, by the thread that opens them. A
 # trace reduction attributes a device-idle gap to the innermost of these open

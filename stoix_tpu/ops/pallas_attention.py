@@ -99,7 +99,7 @@ def _init_carry(block_q: int, head_dim: int):
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, *, scale: float, block_k: int, causal: bool, kv_len: int
 ):
-    block_q, head_dim = q_ref.shape
+    block_q = q_ref.shape[0]
     s_pad = k_ref.shape[0]
     num_kv_blocks = s_pad // block_k
 
@@ -130,8 +130,9 @@ def _flash_kernel(
         )
     else:
         last = num_kv_blocks
+    # The accumulator is as wide as the values, which may differ from q and k.
     m_acc, l_acc, acc = jax.lax.fori_loop(
-        0, last, body, _init_carry(block_q, head_dim)
+        0, last, body, _init_carry(block_q, v_ref.shape[1])
     )
 
     l_safe = jnp.where(l_acc == 0.0, 1.0, l_acc)
@@ -166,9 +167,10 @@ def _pad_axis(x: jax.Array, axis: int, multiple: int) -> jax.Array:
 
 def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
     b, s, h, d = q.shape
+    d_v = v.shape[-1]  # the values' head size: the output's, and not the scale's
     scale = d**-0.5
-    fold = functools.partial(_fold_heads, b=b, h=h, d=d)
-    qf, kf, vf = fold(q), fold(k), fold(v)
+    fold = functools.partial(_fold_heads, b=b, h=h)
+    qf, kf, vf = fold(q, d=d), fold(k, d=d), fold(v, d=d_v)
     qf = _pad_axis(qf, 1, block_q)
     kf = _pad_axis(kf, 1, block_k)
     vf = _pad_axis(vf, 1, block_k)
@@ -183,16 +185,16 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((None, s_kv_pad, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, s_kv_pad, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, s_kv_pad, d_v), lambda i, j: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-        out_shape=_out_struct((b * h, s_q_pad, d), q.dtype, qf, kf, vf),
+        out_specs=pl.BlockSpec((None, block_q, d_v), lambda i, j: (i, j, 0)),
+        out_shape=_out_struct((b * h, s_q_pad, d_v), q.dtype, qf, kf, vf),
         name="flash_attention",
         interpret=interpret,
     )(qf, kf, vf)
 
     out = out[:, :s]  # strip query padding
-    return jnp.transpose(out.reshape(b, h, s, d), (0, 2, 1, 3))
+    return jnp.transpose(out.reshape(b, h, s, d_v), (0, 2, 1, 3))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -227,7 +229,10 @@ def flash_attention(
 ) -> jax.Array:
     """Fused online-softmax attention. [B, S, H, D] -> [B, S, H, D].
 
-    Self-attention shapes only (q and k share a sequence length). The forward
+    Self-attention shapes only (q and k share a sequence length). The values'
+    head size may differ from the queries' and keys' (latent attention: 192
+    and 128): the scale is the queries' 1/sqrt(D), the output [B, S, H, D_v].
+    The forward
     is the Pallas kernel; the backward (`jax.custom_vjp`) recomputes
     `full_attention` in plain JAX and returns ITS gradient, so `jax.grad`
     through this function is the gradient of `full_attention` at (q, k, v).
@@ -878,3 +883,109 @@ def block_mask_attention(
     spec = ((block_length, clean, copies, tile), heads, kv_heads, interpret)
     grouped = q.reshape(n, positions, kv_heads, heads // kv_heads, head_dim)
     return _block_mask(grouped, padded(k), padded(v), spec)
+
+
+# --------------------------------------------------------------------------- #
+# Latent attention's decode: many query heads against ONE row a position
+# --------------------------------------------------------------------------- #
+
+# Sequences a grid step: their live blocks are walked together, so a step of
+# 128 sequences is 16 x (live blocks) grid steps and not 128 x.
+_LATENT_SEQUENCES = 8
+
+
+def _latent_decode_kernel(
+    lengths_ref, longest_ref, q_ref, rows_ref, o_ref, m_scr, l_scr, acc_scr,
+    *, scale: float, rank: int, block: int, sequences: int,
+):
+    """One block of `block` positions of `sequences` sequences: every head's
+    score against each row (the row's whole width), the online softmax, and
+    the weights times the rows' first `rank` columns. Blocks past the last
+    live position of these sequences are neither fetched anew (the index map
+    repeats the last live block) nor computed."""
+    first, j = pl.program_id(0) * sequences, pl.program_id(1)
+    heads = q_ref.shape[1]
+
+    @pl.when(j == 0)
+    def _start():
+        m_scr[...] = jnp.full_like(m_scr, _MASKED_SCORE)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j * block <= longest_ref[pl.program_id(0)])
+    def _fold():
+        at = j * block + jax.lax.broadcasted_iota(jnp.int32, (heads, block), 1)
+        row_at = j * block + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+        for n in range(sequences):
+            # A row past the sequence's length was never written and may hold
+            # anything (a NaN times a weight of 0 is a NaN): it counts as zeros.
+            rows = jnp.where(row_at <= lengths_ref[first + n], rows_ref[n], 0.0)  # [block, rank + rotated]
+            scores = _dot(q_ref[n], rows, _NT) * scale  # [heads, block]
+            scores = jnp.where(at <= lengths_ref[first + n], scores, _MASKED_SCORE)
+            m_prev = m_scr[n]
+            m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+            p = jnp.exp(scores - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[n] = alpha * l_scr[n] + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[n] = alpha * acc_scr[n] + _dot(p, rows[:, :rank], _NN)
+            m_scr[n] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+def latent_decode_attention(
+    q: jax.Array, rows: jax.Array, lengths: jax.Array, *, rank: int, scale: float,
+    block: int = 128, interpret: bool = False,
+) -> jax.Array:
+    """softmax(q . rows^T * scale) rows[..., :rank] over each sequence's
+    positions <= `lengths` [B] (the position just written): q [B, H, w]
+    absorbed queries against the latent rows [B, S, w] -> [B, H, rank].
+
+    The decode of multi-head latent attention: all H heads share one row a
+    position, so a sequence's scores and values are two matrix products
+    ([H, w] x [w, block] and [H, block] x [block, rank]) and the kernel is
+    bound by reading the float32 rows once, the live blocks alone — XLA's own
+    plan for the same two products first writes a bfloat16 copy of the cache
+    in another layout every step (PERF.md §6, PR 38). Operands are multiplied
+    as they come, at DEFAULT precision (one bfloat16 pass: casting them first
+    changed neither the time nor one bit of the result on the chip), and
+    accumulated in float32; the softmax is float32, online over the blocks. `interpret` runs the
+    Pallas interpreter (a test asks for it)."""
+    batch, heads, width = q.shape
+    max_len = rows.shape[1]
+    if max_len % block:
+        raise ValueError(f"blocks of {block} positions do not tile a cache of {max_len}")
+    sequences = _LATENT_SEQUENCES if batch % _LATENT_SEQUENCES == 0 else 1
+    lengths = lengths.astype(jnp.int32)
+    longest = jnp.max(lengths.reshape(batch // sequences, sequences), axis=1)  # a grid step's sequences
+    kernel = functools.partial(
+        _latent_decode_kernel, scale=scale, rank=rank, block=block, sequences=sequences
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(batch // sequences, max_len // block),
+            in_specs=[
+                pl.BlockSpec((sequences, heads, width), lambda b, j, *_: (b, 0, 0)),
+                pl.BlockSpec(
+                    (sequences, block, width),
+                    lambda b, j, lengths_ref, longest_ref: (b, jnp.minimum(j, longest_ref[b] // block), 0),
+                ),
+            ],
+            out_specs=pl.BlockSpec((sequences, heads, rank), lambda b, j, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((sequences, heads, 1), jnp.float32),
+                pltpu.VMEM((sequences, heads, 1), jnp.float32),
+                pltpu.VMEM((sequences, heads, rank), jnp.float32),
+            ],
+        ),
+        out_shape=_out_struct((batch, heads, rank), q.dtype, q, rows),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT
+        ),
+        name="latent_decode_attention",
+        interpret=interpret,
+    )(lengths, longest, q, rows)

@@ -41,7 +41,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 def full_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False
 ) -> jax.Array:
-    """Plain softmax attention. [B, S, H, D] -> [B, S, H, D]."""
+    """Plain softmax attention. [B, S, H, D] -> [B, S, H, D]; with values of
+    another head size D_v than q's and k's, [B, S, H, D_v] (the scale is q's)."""
     scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:

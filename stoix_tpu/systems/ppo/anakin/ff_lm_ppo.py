@@ -324,8 +324,8 @@ def _carry_gauge() -> Any:
     return get_registry().gauge(
         "stoix_tpu_lm_carry_bytes",
         "bytes of the token policy's decode carry on one shard as the learner was set up, by "
-        "kind of state: kv (keys and values, a row a position) or conv_tail (a short "
-        "convolution's last inputs)",
+        "kind of state: kv (keys and values, a row a position), conv_tail (a short "
+        "convolution's last inputs) or latent (latent attention's compressed rows)",
     )
 
 
@@ -417,6 +417,8 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
         learn_per_shard, mesh, state_specs, episode_metrics_spec=P(None, None, "data")
     )
 
+    for labels, _ in _carry_gauge().labels_and_values():  # an earlier learner's kinds
+        _carry_gauge().remove(dict(labels))
     for kind, size in actor.carry_bytes(envs_per_shard, rollout_length).items():
         _carry_gauge().set(size, {"kind": kind})
     together = jax.eval_shape(lambda: networks.init_cache(envs_per_shard)).length.ndim == 0

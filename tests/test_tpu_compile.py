@@ -108,3 +108,40 @@ def test_the_lfm2_decode_copies_no_cache_where_the_sequences_move_together(one_c
     assert re.search(cache, text)  # the cache is in the program under this name
     copies = re.findall(rf"= {cache}\{{[^}}]*\}} copy\(", text)
     assert (not copies) if together else copies
+
+
+# The benchmark's Kanana-2 cell: 128 sequences decode 512 tokens through five
+# latent-attention layers at the published widths
+# (configs/network/kanana2_moe.yaml), each against latent rows [128, 512, 576].
+
+
+def test_the_latent_decode_copies_no_cache_and_goes_through_the_kernel(one_chip, monkeypatch):
+    """The decode scan of the stack's `step` at the cell's shape, steered onto
+    the TPU's branches (code that asks `jax.default_backend()` sees the CPU
+    here): the new row is one slab written in place into rows that lie as the
+    Pallas kernel reads them, sequence-major, and nothing of a cache's size is
+    copied — XLA's own plan for the two batched products wrote a bfloat16
+    copy of the cache in another layout every step (PERF.md section 6, PR 38)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    network = config_lib.compose(config_lib.default_config_dir(), "network/kanana2_moe.yaml", [])
+    actor = config_lib.instantiate(network.actor_network, vocab_size=16032)
+    struct = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    params = jax.tree.map(struct, jax.eval_shape(
+        lambda: actor.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32), method="forward")
+    ))
+    tokens = jax.ShapeDtypeStruct((512, 128), jnp.int32, sharding=one_chip)
+
+    def decode(params, tokens):
+        def one(carry, token):
+            logits, _, carry, _ = actor.apply(params, carry, token, method="step")
+            return carry, jnp.argmax(logits, axis=-1)
+
+        return jax.lax.scan(one, actor.init_carry(128, 512, together=True), tokens)[1]
+
+    lowered = jax.jit(decode).trace(params, tokens).lower(lowering_platforms=("tpu",))
+    text = lowered.compile().as_text()
+    cache = r"f32\[128,512,576\]"
+    assert re.search(cache, text) and "latent_decode_attention" in text
+    assert not re.findall(rf"= {cache}\{{[^}}]*\}} copy\(", text)
+    assert set(re.findall(rf"{cache}\{{(\d,\d,\d)", text)) == {"2,1,0"}  # sequence-major, rows minor
+    assert not re.search(r"bf16\[128,\d+,576\]", text)  # no lower-precision copy of the rows
