@@ -21,6 +21,11 @@ cut to a few updates and weights made from the config's seed. Legs:
                  conv tails and a KV cache of head size 64 in one decode
                  carry, the row written at the one position of sequences that
                  move together, two 128-position prefixes to switch between.
+  kanana2_ppo    the same entry point with `network=kanana2_moe` at a tiny preset
+                 with the published head sizes (192 | 128): the absorbed
+                 decode through the latent rows' Pallas kernel, a shared
+                 expert beside the held ones, and the flash kernel pair,
+                 forward and backward, in the update.
   sdar_ppo       Anakin PPO with the SDAR block-diffusion token policy at a
                  tiny preset (block_token_task): the held-experts loop of
                  grouped matmuls, block steps through the GQA cache in rollout
@@ -82,13 +87,17 @@ RING_SHARD_LEN = 256  # per-chip sequence shard: 2 x the 128-row kernel block
 FLASH_LONG = (4, 4096, 8, 64)  # [B, S, H, D] bfloat16
 FLASH_PADDED_LEN = 4000  # not a multiple of the 128-row block
 FLASH_TRANS_PPO = (64, 16, 4, 32)  # ff_trans_ppo's window: S=16 padded to 128
+FLASH_KANANA2 = (16, 512, 32, 192)  # the latent-attention cell's minibatch in float32 ...
+FLASH_KANANA2_VALUES = 128  # ... whose values are narrower than its queries and keys
 
 # Stated tolerances: max abs error on unit-normal inputs. The recurrence is
 # bitwise. Attention outputs are compared with float32 full_attention at
 # HIGHEST matmul precision; the kernels accumulate in float32 but the MXU
 # multiplies in bfloat16 passes at default precision (~2^-8 relative per
-# product), which — not float32 rounding — sets the bound. Gradients are
-# compared with jax.grad(full_attention) at the same default precision.
+# product), which — not float32 rounding — sets the bound. The flash
+# kernels' gradients are compared with jax.grad of the same HIGHEST
+# reference, ring attention's with jax.grad(full_attention) at the default
+# precision.
 TOL_RECURRENCE = 0.0
 TOL_ATTN = 3e-2
 TOL_GRAD = 5e-2
@@ -335,6 +344,16 @@ def leg_lm_ppo(n: int) -> Dict[str, Any]:
     )
 
 
+def _forms(gauge: str) -> Dict[str, float]:
+    """A gauge labelled by `form`, as the run's traced programs left it."""
+    from stoix_tpu.observability import get_registry
+
+    return {
+        dict(labels)["form"]: value
+        for labels, value in get_registry().gauge(gauge).labels_and_values()
+    }
+
+
 def leg_lfm2_ppo(n: int) -> Dict[str, Any]:
     """The hybrid token policy through the same `ff_lm_ppo`, data-parallel
     over the chips: gated short convolutions beside grouped-query attention
@@ -343,8 +362,6 @@ def leg_lfm2_ppo(n: int) -> Dict[str, Any]:
     scattered cache write made every decode step copy the cache, PERF.md
     section 6, PR 35). T = 256, so the cached attention switches between two
     prefixes; the run has to have taken the one-slab write."""
-    from stoix_tpu.observability import get_registry
-
     tiny = [
         "hidden_size=128", "dense_width=256", "num_heads=4", "num_kv_heads=2", "head_dim=64",
         "expert_width=64",
@@ -360,12 +377,42 @@ def leg_lfm2_ppo(n: int) -> Dict[str, Any]:
         ],
         expect_kernel=True,
     )
-    write = {
-        dict(labels)["form"]: value
-        for labels, value in get_registry().gauge("stoix_tpu_lm_cache_write").labels_and_values()
-    }
+    write = _forms("stoix_tpu_lm_cache_write")
     _require(write == {"slice": 1.0, "scatter": 0.0}, f"the cache write the run took: {write}")
     facts["cache_write"] = write
+    return facts
+
+
+def leg_kanana2_ppo(n: int) -> Dict[str, Any]:
+    """The latent-attention token policy through the same `ff_lm_ppo`,
+    data-parallel over the chips, at the published head sizes (queries and
+    keys 128 + 64 rotated, values 128): the rollout and the evaluator decode
+    absorbed, against one 192-wide latent row a position through
+    `latent_decode_attention`; the update expands keys and values a head and
+    takes the flash kernel pair, whose backward rule says which form it was
+    traced in."""
+    tiny = [
+        "hidden_size=128", "dense_width=256", "num_heads=4", "num_kv_heads=4", "kv_lora_rank=128",
+        "qk_nope_head_dim=128", "qk_rope_head_dim=64", "v_head_dim=128", "num_experts=32",
+        "experts_held=4", "experts_per_token=3", "expert_width=64",
+    ]
+    facts = _anakin_leg(
+        n,
+        "stoix_tpu.systems.ppo.anakin.ff_lm_ppo",
+        "default/anakin/default_ff_lm_ppo.yaml",
+        ["network=kanana2_moe"] + [f"network.actor_network.{o}" for o in tiny] + [
+            "env.kwargs.vocab_size=512", "env.kwargs.length=128", "system.rollout_length=128",
+            f"arch.total_num_envs={16 * n}", "system.num_minibatches=4", "arch.num_updates=4",
+            "system.router_aux_loss_coef=0.0", "arch.evaluation_greedy=True",
+        ],
+        expect_kernel=True,
+    )
+    for gauge, want in (
+        ("stoix_tpu_mla_decode", {"absorbed": 1.0, "expanded": 0.0}),
+        ("stoix_tpu_attention_backward", {"pallas": 1.0, "plain": 0.0}),
+    ):
+        facts[gauge] = _forms(gauge)
+        _require(facts[gauge] == want, f"{gauge}: the run took {facts[gauge]}")
     return facts
 
 
@@ -686,34 +733,47 @@ def leg_kernels(n: int) -> Dict[str, Any]:
     )
 
     # 2. flash_attention forward: the long bfloat16 shape (causal, not, and a
-    #    length that needs padding) and ff_trans_ppo's own shape.
+    #    length that needs padding), ff_trans_ppo's own shape and the
+    #    latent-attention cell's (values narrower than queries and keys).
     padded = (FLASH_LONG[0], FLASH_PADDED_LEN) + FLASH_LONG[2:]
-    for name, shape, dtype, causal in (
-        ("flash_bf16_long", FLASH_LONG, jnp.bfloat16, False),
-        ("flash_bf16_long_causal", FLASH_LONG, jnp.bfloat16, True),
-        ("flash_bf16_padded_causal", padded, jnp.bfloat16, True),
-        ("flash_f32_trans_ppo", FLASH_TRANS_PPO, jnp.float32, False),
-        ("flash_f32_trans_ppo_causal", FLASH_TRANS_PPO, jnp.float32, True),
+    narrow = lambda qkv, d_v: qkv if d_v is None else (*qkv[:2], qkv[2][..., :d_v])
+    for name, shape, d_v, dtype, causal in (
+        ("flash_bf16_long", FLASH_LONG, None, jnp.bfloat16, False),
+        ("flash_bf16_long_causal", FLASH_LONG, None, jnp.bfloat16, True),
+        ("flash_bf16_padded_causal", padded, None, jnp.bfloat16, True),
+        ("flash_f32_trans_ppo", FLASH_TRANS_PPO, None, jnp.float32, False),
+        ("flash_f32_trans_ppo_causal", FLASH_TRANS_PPO, None, jnp.float32, True),
+        ("flash_f32_kanana2_causal", FLASH_KANANA2, FLASH_KANANA2_VALUES, jnp.float32, True),
     ):
-        q, k, v = _qkv(1, shape, dtype)
+        q, k, v = narrow(_qkv(1, shape, dtype), d_v)
         attend = functools.partial(flash_attention, causal=causal)
         _require(_has_pallas_call(attend, q, k, v), f"{name}: no pallas_call traced")
         check(name, attend(q, k, v), reference(q, k, v, causal), TOL_ATTN)
 
-    # 3. Its gradient (custom_vjp: plain-JAX backward) against the gradient of
-    #    full_attention, under a non-uniform cotangent.
+    # 3. Its gradient — the backward kernel from the saved result and
+    #    log-sum-exp — against the gradient of full_attention on the chip,
+    #    under a non-uniform cotangent: ff_trans_ppo's window, the bfloat16
+    #    shapes, and the latent-attention cell's minibatch in float32 (32
+    #    heads of 192 | 128 over 512 positions).
     def grads(attend, q, k, v, weight):
         loss = lambda q_, k_, v_: jnp.sum(attend(q_, k_, v_).astype(jnp.float32) * weight)
         return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
-    for name, shape, dtype, causal in (
-        ("flash_grad_f32_trans_ppo_causal", FLASH_TRANS_PPO, jnp.float32, True),
-        ("flash_grad_bf16_S512", (2, 512, 4, 64), jnp.bfloat16, False),
+    for name, shape, d_v, dtype, causal in (
+        ("flash_grad_f32_trans_ppo_causal", FLASH_TRANS_PPO, None, jnp.float32, True),
+        ("flash_grad_bf16_S512", (2, 512, 4, 64), None, jnp.bfloat16, False),
+        # (one sequence of the long shape: the reference's gradient holds
+        # five [B, 8, 4096, 4096] float32 arrays, 0.5 GB each at B = 1)
+        ("flash_grad_bf16_long_causal", (1,) + FLASH_LONG[1:], None, jnp.bfloat16, True),
+        ("flash_grad_f32_kanana2_causal", FLASH_KANANA2, FLASH_KANANA2_VALUES, jnp.float32, True),
     ):
-        q, k, v = _qkv(2, shape, dtype)
-        weight = jax.random.normal(jax.random.PRNGKey(3), shape, jnp.float32)
-        got = grads(functools.partial(flash_attention, causal=causal), q, k, v, weight)
-        want = grads(functools.partial(full_attention, causal=causal), q, k, v, weight)
+        q, k, v = narrow(_qkv(2, shape, dtype), d_v)
+        weight = jax.random.normal(jax.random.PRNGKey(3), v.shape, jnp.float32)
+        flash = functools.partial(flash_attention, causal=causal)
+        traced = jax.make_jaxpr(lambda *a: grads(flash, *a, weight))(q, k, v)
+        _require(str(traced).count("pallas_call") == 2, f"{name}: not one kernel each way")
+        got = grads(flash, q, k, v, weight)
+        want = grads(functools.partial(reference, causal=causal), q, k, v, weight)
         for axis, g, r in zip("qkv", got, want):
             check(f"{name}_d{axis}", g, r, TOL_GRAD)
 
@@ -750,6 +810,7 @@ LEGS: List[Tuple[str, Callable[[int], Dict[str, Any]]]] = [
     ("trans_ppo", leg_trans_ppo),
     ("lm_ppo", leg_lm_ppo),
     ("lfm2_ppo", leg_lfm2_ppo),
+    ("kanana2_ppo", leg_kanana2_ppo),
     ("sdar_ppo", leg_sdar_ppo),
     ("ppo_pallas_gae", leg_ppo_pallas_gae),
     ("sebulba", leg_sebulba),

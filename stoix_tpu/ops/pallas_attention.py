@@ -2,33 +2,43 @@
 
 The pure-JAX `full_attention` (ops/ring_attention.py) materializes the full
 [S, S] score matrix in HBM; XLA fuses some of it but the memory traffic still
-scales O(S^2). This kernel runs the online-softmax recurrence entirely in
-VMEM: each grid step holds one query block plus one (batch*head)'s K/V in
-VMEM, streams K/V blocks through the MXU, and never writes scores to HBM.
+scales O(S^2), forward and backward. `flash_attention` runs both directions in
+VMEM: a forward kernel (online soft-max; saves the result and each row's
+log-sum-exp) and a backward kernel (dq, dk, dv from those and d_result, the
+weights recomputed a tile at a time), neither of which writes anything of
+[queries, keys] size to HBM.
 
 Layout notes (see /opt/skills/guides/pallas_guide.md):
-  - grid = (B*H, ceil(S / block_q)); one kernel instance owns one query block;
-  - K/V for the (b, h) slice live in VMEM whole (S×D ≤ ~2 MB at S=8192, D=64,
-    bf16) and are walked with `pl.ds` dynamic slices, block_k at a time;
-  - accumulators (m, l, acc) are fp32 regardless of input dtype; all matmuls
-    request `preferred_element_type=float32` so bf16 inputs still accumulate
-    in fp32 on the MXU;
+  - operands are [B, H, width, S], positions minor: how XLA:TPU itself lays a
+    `[B, S, heads, width]` activation between the projections and here, so
+    the transposes around the kernels move nothing (`_flash_call`); a head's
+    tile is whole rows, whatever its width (192 | 128, 128, 64, 32);
+  - grid = (B, H / heads a step, ceil(S / tile)); a step holds its heads'
+    K/V (and in the backward dk and dv) whole in VMEM and walks them with
+    `pl.ds` slices of the lanes, a tile at a time, up to the diagonal when
+    causal; heads a step and the tile are chosen at trace time from the
+    shapes (`_heads_a_step`, `_tile`);
+  - scores are [keys, queries], so the soft-max's sums run down the sublanes
+    and its statistics are rows; accumulators are fp32 regardless of input
+    dtype and all matmuls request `preferred_element_type=float32`, so bf16
+    inputs still accumulate in fp32 on the MXU;
   - sequence padding to the block size is masked with statically-known
-    lengths; causal masking uses 2-D `broadcasted_iota` (TPU needs ≥2-D iota).
+    lengths; masks use 2-D `broadcasted_iota` (TPU needs ≥2-D iota).
 
 What is compiled where. Every kernel here is compiled by Mosaic on TPU
 (`interpret=False`, the default) and checked there against its plain-JAX
-reference by `chip_smoke.py`. The BACKWARD of `flash_attention` and
-`flash_attention_chunk` is plain JAX: each has a `jax.custom_vjp` whose
-backward recomputes the reference (`full_attention` / `_block_attend`) and
-differentiates that — exact, but it materializes the [S, S] scores the
-forward avoids. Without the `custom_vjp`, reverse-mode through a
-`pallas_call` whose body reads `pl.program_id` fails in JAX's generic
-pallas_call JVP rule, so the learner could not take a gradient step on the
-chip. `block_mask_attention` (the block-diffusion update's attention, at the
-end of this file) is the first kernel here with a Pallas backward: one kernel
-forward, one backward (dq, dk and dv from the saved output and log-sum-exp),
-nothing of [queries, keys] in HBM either way.
+reference by `chip_smoke.py`, and for a described v5e at the benchmark cells'
+shapes by `tests/test_tpu_compile.py`. `flash_attention` and
+`block_mask_attention` (the block-diffusion update's attention, further down)
+have a Pallas backward: one kernel forward, one backward, nothing of [queries,
+keys] in HBM either way; the gauge `stoix_tpu_attention_backward{form}` says
+which form `flash_attention`'s backward rule was traced in. The BACKWARD of
+`flash_attention_chunk` (the ring's per-chunk kernel) is plain JAX: its
+`jax.custom_vjp` recomputes `_block_attend` and differentiates that — exact,
+but it materializes the chunk's scores. Without a `custom_vjp`, reverse-mode
+through a `pallas_call` whose body reads `pl.program_id` fails in JAX's
+generic pallas_call JVP rule, so the learner could not take a gradient step on
+the chip.
 
 `flash_attention` is a drop-in for `full_attention` ([B, S, H, D] in/out) and
 is the default `attention_fn` for the transformer torso on TPU; on other
@@ -50,10 +60,17 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from stoix_tpu.observability import SCOPES, annotate
+from stoix_tpu.observability import SCOPES, annotate, get_registry
 from stoix_tpu.ops.ring_attention import _block_attend, full_attention
 
 _NEG_INF = float("-inf")
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
 def _fold_block(q, k_blk, v_blk, mask, carry):
@@ -96,52 +113,120 @@ def _init_carry(block_q: int, head_dim: int):
     )
 
 
-def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, *, scale: float, block_k: int, causal: bool, kv_len: int
-):
-    block_q = q_ref.shape[0]
-    s_pad = k_ref.shape[0]
-    num_kv_blocks = s_pad // block_k
-
-    q = q_ref[:].astype(jnp.float32) * scale  # [Bq, D]
-    q_block_idx = pl.program_id(1)
-    q_pos = q_block_idx * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-
-    def body(j, carry):
-        rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
-        k_blk = k_ref[rows, :].astype(jnp.float32)
-        v_blk = v_ref[rows, :].astype(jnp.float32)
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = k_pos < kv_len  # strip the padded tail
-        if causal:
-            mask = jnp.logical_and(mask, q_pos >= k_pos)
-        return _fold_block(q, k_blk, v_blk, mask, carry)
-
+def _walk(q_tile, block_q: int, block_k: int, kv_tiles: int, kv_len: int, causal: bool, fold, carry):
+    """`fold(columns of a key tile, mask or None, carry)` over the key tiles
+    query tile `q_tile` sees: first tiles [0, whole), which hold only pairs
+    that are allowed (every key real, and at or before the tile's first query
+    when `causal`) and need no mask, then [whole, last), which hold some.
+    Tiles from `last` on lie wholly in the future and are not visited. A mask
+    is [keys, queries]: the scores lie so in both kernels."""
+    whole, last = kv_len // block_k, kv_tiles
     if causal:
-        # Blocks fully in the future contribute nothing; bound the walk at the
-        # last block that can contain key ≤ the block's max query position.
-        last = jnp.minimum(
-            (q_block_idx * block_q + block_q + block_k - 1) // block_k,
-            num_kv_blocks,
-        )
-    else:
-        last = num_kv_blocks
-    # The accumulator is as wide as the values, which may differ from q and k.
-    m_acc, l_acc, acc = jax.lax.fori_loop(
-        0, last, body, _init_carry(block_q, v_ref.shape[1])
+        first = q_tile * block_q
+        whole = jnp.minimum((first + 1) // block_k, whole)
+        last = jnp.minimum((first + block_q + block_k - 1) // block_k, last)
+    q_pos = q_tile * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
+
+    def step(j, carry, masked):
+        mask = None
+        if masked:
+            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
+            mask = k_pos < kv_len  # strip the padded tail
+            if causal:
+                mask = jnp.logical_and(mask, q_pos >= k_pos)
+        return fold(pl.ds(pl.multiple_of(j * block_k, block_k), block_k), mask, carry)
+
+    carry = jax.lax.fori_loop(0, whole, functools.partial(step, masked=False), carry)
+    return jax.lax.fori_loop(whole, last, functools.partial(step, masked=True), carry)
+
+
+def _flash_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float, block_k: int, causal: bool, kv_len: int
+):
+    """One query tile of some heads of one sequence, whose keys and values
+    stay in VMEM across its query tiles. Every operand is [heads, width,
+    positions], positions minor: a head's tile is whole rows, the scores are
+    [keys, queries], so the soft-max's sums run down the sublanes and its
+    statistics — and the log-sum-exp the backward kernel reads — are rows, and
+    the result [width, queries] is a plain product of the values with them."""
+    heads, _, block_q = q_ref.shape
+    walk = functools.partial(
+        _walk, pl.program_id(2), block_q, block_k, k_ref.shape[2] // block_k, kv_len, causal
     )
 
-    l_safe = jnp.where(l_acc == 0.0, 1.0, l_acc)
-    o_ref[:] = (acc / l_safe).astype(o_ref.dtype)
+    def one_head(h, _):
+        q = q_ref[h].astype(jnp.float32) * scale  # [D, Bq]
+
+        def fold(cols, mask, carry):
+            m_acc, l_acc, acc = carry
+            scores = _dot(k_ref[h, :, cols].astype(jnp.float32), q, _TN)  # [Bk, Bq]
+            if mask is not None:
+                scores = jnp.where(mask, scores, _MASKED_SCORE)
+            # No row is empty in the first tile seen (a query sees key 0), so
+            # the maximum is a real score from there on and a masked one's
+            # weight is exp(-huge) = 0.
+            m_new = jnp.maximum(m_acc, jnp.max(scores, axis=0, keepdims=True))
+            p = jnp.exp(scores - m_new)
+            alpha = jnp.exp(m_acc - m_new)
+            l_new = alpha * l_acc + jnp.sum(p, axis=0, keepdims=True)
+            return m_new, l_new, alpha * acc + _dot(v_ref[h, :, cols].astype(jnp.float32), p, _NN)
+
+        # The accumulator is as wide as the values, which may differ from q and k.
+        m_acc, l_acc, acc = walk(fold, (
+            jnp.full((1, block_q), _MASKED_SCORE, jnp.float32),
+            jnp.zeros((1, block_q), jnp.float32),
+            jnp.zeros((v_ref.shape[1], block_q), jnp.float32),
+        ))
+        o_ref[h] = (acc / l_acc).astype(o_ref.dtype)
+        lse_ref[pl.ds(h, 1), :] = m_acc + jnp.log(l_acc)
+        return 0
+
+    # A loop, not `heads` copies of the body: the program Mosaic is handed,
+    # and the seconds of set-up that lowering it takes, do not grow with them.
+    jax.lax.fori_loop(0, heads, one_head, 0)
 
 
-def _fold_heads(x: jax.Array, b: int, h: int, d: int) -> jax.Array:
-    """[B, S, H, D] -> [B*H, S, D]."""
-    return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, x.shape[1], d)
+def _flash_bwd_kernel(
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
+    *, scale: float, block_k: int, causal: bool, kv_len: int,
+):
+    """One query tile: dq of its queries, and its share of the sequence's dk
+    and dv, which stay in VMEM (float32) across the query tiles. The weights
+    are recomputed a tile at a time from the saved log-sum-exp and the same
+    scaled q the forward multiplied; laid as the forward's, [keys, queries],
+    dq, dk and dv [width, positions] are plain products of them."""
+    heads, _, block_q = q_ref.shape
+    q_tile = pl.program_id(2)
+
+    @pl.when(q_tile == 0)
+    def _zero():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    walk = functools.partial(
+        _walk, q_tile, block_q, block_k, k_ref.shape[2] // block_k, kv_len, causal
+    )
+    def one_head(h, _):
+        q = q_ref[h].astype(jnp.float32) * scale  # [D, Bq]
+        d_out = do_ref[h].astype(jnp.float32)  # [Dv, Bq]
+        delta = jnp.sum(d_out * o_ref[h].astype(jnp.float32), axis=0, keepdims=True)  # [1, Bq]
+        lse = lse_ref[pl.ds(h, 1), :]
+
+        def fold(cols, mask, dq):
+            k_blk = k_ref[h, :, cols].astype(jnp.float32)  # [D, Bk]
+            p = jnp.exp(_dot(k_blk, q, _TN) - lse)  # [Bk, Bq]
+            if mask is not None:
+                p = jnp.where(mask, p, 0.0)
+            ds = p * (_dot(v_ref[h, :, cols].astype(jnp.float32), d_out, _TN) - delta)
+            dv_ref[h, :, cols] += _dot(d_out, p, _NT)
+            dk_ref[h, :, cols] += _dot(q, ds, _NT)  # (q is scaled already)
+            return dq + _dot(k_blk, ds, _NN)
+
+        dq = walk(fold, jnp.zeros(q.shape, jnp.float32))
+        dq_ref[h] = (dq * scale).astype(dq_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, heads, one_head, 0)
 
 
 def _out_struct(shape, dtype, *arrays: jax.Array) -> jax.ShapeDtypeStruct:
@@ -165,51 +250,127 @@ def _pad_axis(x: jax.Array, axis: int, multiple: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
+# What a grid step's resident blocks may take of VMEM: keys and values, and in
+# the backward kernel dk and dv, each held twice by the pipeline.
+_FLASH_RESIDENT_BYTES = 32 * 1024 * 1024
+
+
+# The widest tile a walk takes. Measured at the token cells' shapes on a v5e
+# (PERF.md section 6, PR 39): a step's products are too small to fill the
+# MXUs' pipelines at 128 x 128, and at S = 512 one tile of the whole sequence,
+# its upper triangle masked, beats two tiles a side that skip a quarter.
+_FLASH_TILE = 512
+
+
+def _tile(block: int, length: int) -> int:
+    """The tile a walk takes along an axis of `length` positions, padded to
+    `block`s: the largest multiple of `block` up to `_FLASH_TILE` that divides
+    the padded length — or `block` itself where it is no whole number of lane
+    tiles, which only a test asks for, to see several tiles of a short
+    sequence."""
+    if block % 128:
+        return block
+    padded = length + (-length) % block
+    return max(t for t in range(block, max(block, _FLASH_TILE) + 1, block) if padded % t == 0)
+
+
+def _heads_a_step(h: int, d: int, d_v: int, kv_len: int, itemsize: int) -> int:
+    """Heads a grid step, from what the kernel sees: the most, up to 8, that
+    divide H and whose keys, values, dk and dv fit `_FLASH_RESIDENT_BYTES`."""
+    resident = lambda n: 2 * n * (d + d_v) * kv_len * (itemsize + 4)
+    fits = [n for n in range(2, 9) if h % n == 0 and resident(n) <= _FLASH_RESIDENT_BYTES]
+    return max(fits, default=1)
+
+
+def _flash_call(kernel, name, causal, block_q, block_k, interpret, q, k, v, more, outs):
+    """The call both kernels share: a grid of (sequence, heads a step, query
+    tile) over q, k, v [B, S, H, D | D_v] seen as [B, H, width, S]. That is
+    how XLA:TPU lays a `[.., heads, width]` activation whose width is not a
+    whole number of lane tiles (192, 64), and how its projections write and
+    read one whose width is (128): the transposes here and on the results
+    change no byte's place in the compiled learners (`tests/test_tpu_compile.py`
+    holds them to that), and only a length that is no whole number of tiles is
+    copied, to pad it. `more` are further operands and `outs` the results,
+    each named by kind: "q" / "v" a query tile D / D_v wide, "k" / "kv" the
+    sequence's keys D / D_v wide, "lse" a row a head."""
     b, s, h, d = q.shape
-    d_v = v.shape[-1]  # the values' head size: the output's, and not the scale's
-    scale = d**-0.5
-    fold = functools.partial(_fold_heads, b=b, h=h)
-    qf, kf, vf = fold(q, d=d), fold(k, d=d), fold(v, d=d_v)
-    qf = _pad_axis(qf, 1, block_q)
-    kf = _pad_axis(kf, 1, block_k)
-    vf = _pad_axis(vf, 1, block_k)
-    s_q_pad, s_kv_pad = qf.shape[1], kf.shape[1]
-
-    kernel = functools.partial(
-        _flash_kernel, scale=scale, block_k=block_k, causal=causal, kv_len=s
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid=(b * h, s_q_pad // block_q),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((None, s_kv_pad, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((None, s_kv_pad, d_v), lambda i, j: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, block_q, d_v), lambda i, j: (i, j, 0)),
-        out_shape=_out_struct((b * h, s_q_pad, d_v), q.dtype, qf, kf, vf),
-        name="flash_attention",
+    d_v = v.shape[-1]
+    block_q, block_k = _tile(block_q, s), _tile(block_k, s)
+    s_q, s_kv = s + (-s) % block_q, s + (-s) % block_k
+    heads = _heads_a_step(h, d, d_v, s_kv, q.dtype.itemsize)
+    lay = lambda x, block: _pad_axis(jnp.transpose(x, (0, 2, 3, 1)), 3, block)
+    tile = lambda width: pl.BlockSpec((None, heads, width, block_q), lambda n, g, i: (n, g, 0, i))
+    keys = lambda width: pl.BlockSpec((None, heads, width, s_kv), lambda n, g, i: (n, g, 0, 0))
+    kinds = {
+        "q": (tile(d), (b, h, d, s_q), q.dtype),
+        "v": (tile(d_v), (b, h, d_v, s_q), q.dtype),
+        "k": (keys(d), (b, h, d, s_kv), jnp.float32),
+        "kv": (keys(d_v), (b, h, d_v, s_kv), jnp.float32),
+        "lse": (
+            pl.BlockSpec((None, None, heads, block_q), lambda n, g, i: (n, g, 0, i)),
+            (b, h // heads, heads, s_q), jnp.float32,
+        ),
+    }
+    operands = [lay(q, block_q), lay(k, block_k), lay(v, block_k)] + [
+        x if kind == "lse" else lay(x, block_q) for kind, x in more
+    ]
+    results = pl.pallas_call(
+        functools.partial(kernel, scale=d**-0.5, block_k=block_k, causal=causal, kv_len=s),
+        grid=(b, h // heads, s_q // block_q),
+        in_specs=[kinds[kind][0] for kind in ["q", "k", "kv"] + [kind for kind, _ in more]],
+        out_specs=[kinds[kind][0] for kind in outs],
+        out_shape=[_out_struct(*kinds[kind][1:], *operands) for kind in outs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        name=name,
         interpret=interpret,
-    )(qf, kf, vf)
+    )(*operands)
+    # Strip the padding; a result a head is [B, S, H, width] again.
+    return [
+        x if kind == "lse" else jnp.transpose(x[..., :s], (0, 3, 1, 2))
+        for kind, x in zip(outs, results)
+    ]
 
-    out = out[:, :s]  # strip query padding
-    return jnp.transpose(out.reshape(b, h, s, d_v), (0, 2, 1, 3))
+
+def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
+    """-> (out [B, S, H, D_v], log-sum-exp [B, H / heads a step, heads a step,
+    padded S] float32)."""
+    return _flash_call(
+        _flash_kernel, "flash_attention", causal, block_q, block_k, interpret, q, k, v, [],
+        ["v", "lse"],
+    )
+
+
+def _backward_form_gauge():
+    return get_registry().gauge(
+        "stoix_tpu_attention_backward",
+        "1 on the form flash_attention's backward rule was traced in: pallas (one kernel: dq, "
+        "dk and dv from the saved result and log-sum-exp) or plain (full_attention recomputed "
+        "and differentiated)",
+    )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, causal, block_q, block_k, interpret):
-    return _flash_forward(q, k, v, causal, block_q, block_k, interpret)
+    return _flash_forward(q, k, v, causal, block_q, block_k, interpret)[0]
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
-    return _flash_forward(q, k, v, causal, block_q, block_k, interpret), (q, k, v)
+    out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret)
+    return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, residuals, g):
-    # Plain-JAX backward: recompute full_attention and differentiate it.
-    _, vjp = jax.vjp(functools.partial(full_attention, causal=causal), *residuals)
-    return vjp(g)
+def _flash_bwd(causal, block_q, block_k, interpret, residuals, d_out):
+    for form, took in (("pallas", 1.0), ("plain", 0.0)):
+        _backward_form_gauge().set(took, {"form": form})
+    q, k, v, out, lse = residuals
+    dq, dk, dv = _flash_call(
+        _flash_bwd_kernel, "flash_attention_bwd", causal, block_q, block_k, interpret, q, k, v,
+        [("v", out), ("v", d_out), ("lse", lse)], ["q", "k", "kv"],
+    )
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -232,10 +393,17 @@ def flash_attention(
     Self-attention shapes only (q and k share a sequence length). The values'
     head size may differ from the queries' and keys' (latent attention: 192
     and 128): the scale is the queries' 1/sqrt(D), the output [B, S, H, D_v].
-    The forward
-    is the Pallas kernel; the backward (`jax.custom_vjp`) recomputes
-    `full_attention` in plain JAX and returns ITS gradient, so `jax.grad`
-    through this function is the gradient of `full_attention` at (q, k, v).
+    Forward and backward are one Pallas kernel each (`jax.custom_vjp`): the
+    forward saves its result and each row's log-sum-exp, the backward
+    recomputes the scores a tile at a time in VMEM and returns dq, dk and dv,
+    so nothing of [queries, keys] size reaches HBM in either direction and
+    `jax.grad` through this function is the gradient of `full_attention` at
+    (q, k, v) to float32's rounding. Both read q, k, v and write their
+    results positions-minor, as XLA:TPU lays them between the projections
+    and here (`_flash_call`); several heads a grid step (`_heads_a_step`), a
+    tile chosen from the length (`_tile`), key tiles past the diagonal not
+    visited when `causal`. Operands are multiplied at DEFAULT precision and
+    accumulated in float32; the soft-max statistics are float32.
     `interpret` runs the Pallas interpreter (slow; a test asks for it).
     """
     return _flash(q, k, v, causal, block_q, block_k, interpret)
@@ -297,6 +465,11 @@ def _flash_chunk_kernel(
     # same guard as the pure-JAX _block_attend.
     m_ref[:] = jnp.where(jnp.isfinite(m_acc), m_acc, 0.0)
     l_ref[:] = l_acc
+
+
+def _fold_heads(x: jax.Array, b: int, h: int, d: int) -> jax.Array:
+    """[B, S, H, D] -> [B*H, S, D]."""
+    return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, x.shape[1], d)
 
 
 def _chunk_forward(q, k, v, q_positions, k_positions, causal, block_q, block_k, interpret):
@@ -548,14 +721,6 @@ def _to_col(row):
         jnp.int32, (n, n), 1
     )
     return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
-
-
-_NT = (((1,), (1,)), ((), ()))  # a @ b.T
-_NN = (((1,), (0,)), ((), ()))  # a @ b
-
-
-def _dot(a, b, dims):
-    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
 def _stack_heads(ref, group: int, head_dim: int, real_rows):

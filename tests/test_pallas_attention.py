@@ -134,7 +134,7 @@ def test_ring_attention_matches_full_attention(use_flash):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
-# ---- gradients: the kernels' custom_vjp backward is plain JAX -----------------
+# ---- gradients: flash_attention's backward is a Pallas kernel, the chunk kernel's plain JAX ----
 
 GRAD_TOL = 1e-4  # float32; forward kernel vs reference differ by reassociation
 
@@ -211,3 +211,123 @@ def test_ring_never_interprets_by_itself():
     jaxpr = str(jax.make_jaxpr(ring)(q, k, v))
     assert "pallas_call" in jaxpr
     assert "interpret=False" in jaxpr and "interpret=True" not in jaxpr
+
+
+# ---- flash_attention's backward kernel ------------------------------------------
+
+
+# name -> (length, D, D_v, dtype, the blocks asked for, tolerance)
+CASES = {
+    "padded_length": (40, 16, 16, jnp.float32, (16, 16), GRAD_TOL),
+    "narrower_values": (48, 24, 16, jnp.float32, (16, 16), GRAD_TOL),
+    "several_query_blocks": (64, 16, 16, jnp.float32, (16, 32), GRAD_TOL),
+    "lane_tiles_widened": (256, 16, 16, jnp.float32, (128, 128), GRAD_TOL),
+    "lane_tiles_that_do_not_widen": (300, 8, 8, jnp.float32, (128, 128), GRAD_TOL),
+    "bfloat16": (48, 16, 16, jnp.bfloat16, (16, 16), 4e-2),
+}
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("case", CASES)
+def test_the_backward_kernel_is_the_gradient_of_full_attention(case, causal):
+    """dq, dk and dv of the kernel pair under a cotangent that is not all
+    ones, against `jax.grad` of the plain path (on the widened inputs where
+    they are bfloat16: the kernel accumulates in float32)."""
+    length, d, d_v, dtype, (block_q, block_k), tol = CASES[case]
+    q, k, v = (
+        jax.random.normal(key, (2, length, 2, width), dtype)
+        for key, width in zip(jax.random.split(jax.random.PRNGKey(11), 3), (d, d, d_v))
+    )
+    w = jax.random.normal(jax.random.PRNGKey(12), v.shape, jnp.float32)
+    loss = lambda attend: lambda *a: jnp.sum(attend(*a).astype(jnp.float32) * w)
+    flash = lambda *a: flash_attention(
+        *a, causal=causal, block_q=block_q, block_k=block_k, interpret=True
+    )
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    f32 = lambda x: x.astype(jnp.float32)
+    want = jax.grad(loss(lambda *a: full_attention(*a, causal=causal)), argnums=(0, 1, 2))(
+        f32(q), f32(k), f32(v)
+    )
+    for g, r, x in zip(got, want, (q, k, v)):
+        assert g.dtype == x.dtype and g.shape == x.shape
+        np.testing.assert_allclose(f32(g), r, atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_the_forward_saves_each_rows_log_sum_exp(causal):
+    """What the backward kernel reads beside the result: [B, H / heads a
+    step, heads a step, padded S] float32, a row a head."""
+    from stoix_tpu.ops.pallas_attention import _flash_forward
+
+    q, k, v = _rand_qkv(jax.random.PRNGKey(13), 2, 40, 3, 16)
+    out, lse = _flash_forward(q, k, v, causal, 16, 16, True)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * 16**-0.5
+    if causal:
+        scores = jnp.where(jnp.tril(jnp.ones((40, 40), bool)), scores, -jnp.inf)
+    want = jax.scipy.special.logsumexp(scores, axis=-1)  # [B, H, S]
+    assert lse.dtype == jnp.float32 and lse.shape[0] == 2 and lse.shape[-1] == 48
+    np.testing.assert_allclose(lse.reshape(2, 3, 48)[..., :40], want, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(out, full_attention(q, k, v, causal=causal), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_the_gradient_is_two_kernels_and_nothing_of_queries_by_keys(causal):
+    """The traced gradient: the forward kernel under its name, the backward
+    kernel, and no array with two axes of the sequence's (padded) length —
+    the scores the plain backward wrote."""
+    q, k, v = _rand_qkv(jax.random.PRNGKey(14), 2, 200, 2, 16)
+    loss = lambda *a: jnp.sum(flash_attention(*a, causal=causal, interpret=True) ** 2)
+    eqns = list(_flat_eqns(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr))
+    kernels = [eqn.params["name"] for eqn in eqns if eqn.primitive.name == "pallas_call"]
+    assert kernels == ["flash_attention", "flash_attention_bwd"]
+    shapes = [var.aval.shape for eqn in eqns for var in eqn.outvars]
+    assert shapes and not [shape for shape in shapes if sum(n in (200, 256) for n in shape) > 1]
+
+
+def _flat_eqns(jaxpr):
+    """Every equation outside a pallas_call's body, nested calls opened."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _flat_eqns(sub)
+
+
+def test_the_backward_rule_says_which_form_it_took():
+    from stoix_tpu.observability import get_registry
+
+    q, k, v = _rand_qkv(jax.random.PRNGKey(15), 1, 32, 1, 16)
+    flash = lambda *a: jnp.sum(flash_attention(*a, causal=True, block_q=16, block_k=16, interpret=True))
+    jax.grad(flash)(q, k, v)
+    gauge = get_registry().gauge("stoix_tpu_attention_backward")
+    assert gauge.value({"form": "pallas"}) == 1.0 and gauge.value({"form": "plain"}) == 0.0
+
+
+@pytest.mark.parametrize(
+    "block, length, tile",
+    [(128, 512, 512), (128, 16, 128), (128, 4000, 512), (128, 640, 128), (128, 768, 384),
+     (128, 256, 256), (16, 40, 16), (64, 256, 64)],
+)
+def test_the_tile_is_chosen_from_the_length(block, length, tile):
+    from stoix_tpu.ops.pallas_attention import _tile
+
+    assert _tile(block, length) == tile
+
+
+@pytest.mark.parametrize(
+    "shape, heads",
+    [
+        ((32, 192, 128, 512, 4), 8),  # the latent-attention cell
+        ((16, 128, 128, 512, 4), 8),
+        ((32, 64, 64, 512, 4), 8),
+        ((8, 64, 64, 4096, 2), 4),  # a long bfloat16 sequence: dk and dv of 8 heads do not fit
+        ((4, 32, 32, 128, 4), 4),
+        ((3, 24, 16, 48, 4), 3),
+        ((6, 128, 128, 65536, 4), 1),  # one head always goes
+    ],
+)
+def test_the_heads_a_step_divide_the_heads_and_fit(shape, heads):
+    from stoix_tpu.ops.pallas_attention import _heads_a_step
+
+    assert _heads_a_step(*shape) == heads

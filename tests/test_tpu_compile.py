@@ -145,3 +145,97 @@ def test_the_latent_decode_copies_no_cache_and_goes_through_the_kernel(one_chip,
     assert not re.findall(rf"= {cache}\{{[^}}]*\}} copy\(", text)
     assert set(re.findall(rf"{cache}\{{(\d,\d,\d)", text)) == {"2,1,0"}  # sequence-major, rows minor
     assert not re.search(r"bf16\[128,\d+,576\]", text)  # no lower-precision copy of the rows
+
+
+# The three token cells' update attention: a minibatch of 16 sequences of 512
+# tokens through `flash_attention` — 32 heads of 192 | 128 (Kanana-2's latent
+# attention), 16 of 128 (OLMoE), 32 of 64 after the groups' repeat (LFM2) —
+# and chip_smoke.py's shapes: the long bfloat16 one and ff_trans_ppo's window.
+FLASH_SHAPES = {
+    "kanana2": ((16, 512, 32, 192), (16, 512, 32, 128), jnp.float32),
+    "olmoe": ((16, 512, 16, 128), (16, 512, 16, 128), jnp.float32),
+    "lfm2": ((16, 512, 32, 64), (16, 512, 32, 64), jnp.float32),
+    "long_bfloat16": ((4, 4096, 8, 64), (4, 4096, 8, 64), jnp.bfloat16),
+    "padded_bfloat16": ((4, 4000, 8, 64), (4, 4000, 8, 64), jnp.bfloat16),
+    "trans_ppo": ((64, 16, 4, 32), (64, 16, 4, 32), jnp.float32),
+}
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+@pytest.mark.parametrize("cell", list(FLASH_SHAPES))
+def test_mosaic_takes_the_flash_kernels_at_the_cells_shapes(one_chip, cell, what):
+    """The forward kernel under the name the benchmark finds it by, the
+    backward kernel beside it in the gradient, and nothing of [queries, keys]
+    size in either program."""
+    qk, values, dtype = FLASH_SHAPES[cell]
+    attend = lambda q, k, v: pallas_attention.flash_attention(q, k, v, causal=True)
+    struct = lambda shape: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    if what == "forward":
+        fn, args = attend, (qk, qk, values)
+    else:
+        loss = lambda q, k, v, w: jnp.sum((attend(q, k, v) * w).astype(jnp.float32))
+        fn, args = jax.grad(loss, argnums=(0, 1, 2)), (qk, qk, values, values)
+    text = jax.jit(fn).trace(*map(struct, args)).lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert re.search(r"flash_attention\b(?!_bwd)", text)
+    assert ("flash_attention_bwd" in text) == (what == "gradient")
+    batch, length, heads, _ = qk
+    assert not re.search(rf"\[{batch},{heads},{length},{length}\]", text)
+
+
+def _mixer_gradient(cell, one_chip):
+    """The compiled gradient (parameters and input) of one attention layer at
+    the cell's published widths — projections, norms, rotation, the kernels,
+    the output projection — on 16 sequences of 512 tokens."""
+    from stoix_tpu.networks import lfm2, mla
+
+    if cell == "olmoe":
+        network = config_lib.compose(config_lib.default_config_dir(), "network/olmoe.yaml", [])
+        module = config_lib.instantiate(network.actor_network, vocab_size=50304)
+        inputs = jax.ShapeDtypeStruct((16, 512), jnp.int32, sharding=one_chip)
+        example, out = jnp.zeros((1, 2), jnp.int32), lambda result: result[0]
+    else:
+        name = {"kanana2": "kanana2_moe", "lfm2": "lfm2_moe"}[cell]
+        c = config_lib.compose(config_lib.default_config_dir(), f"network/{name}.yaml", []).actor_network
+        module = (
+            mla.LatentAttention(
+                c.hidden_size, c.num_heads, c.kv_lora_rank, c.qk_nope_head_dim, c.qk_rope_head_dim,
+                c.v_head_dim, c.rope_theta, c.rms_eps,
+            ) if cell == "kanana2" else lfm2.GroupedQueryAttention(
+                c.hidden_size, c.num_heads, c.num_kv_heads, c.head_dim, c.rope_theta, c.rms_eps
+            )
+        )
+        inputs = jax.ShapeDtypeStruct((16, 512, c.hidden_size), jnp.float32, sharding=one_chip)
+        example, out = jnp.zeros((1, 2, c.hidden_size)), lambda result: result
+    struct = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    params = jax.tree.map(struct, jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0), example, method="forward")
+    ))
+    loss = lambda params, x: jnp.sum(out(module.apply(params, x, method="forward")) ** 2)
+    argnums = 0 if cell == "olmoe" else (0, 1)
+    lowered = jax.jit(jax.grad(loss, argnums=argnums)).trace(params, inputs).lower(
+        lowering_platforms=("tpu",)
+    )
+    return lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("cell", ["kanana2", "lfm2", "olmoe"])
+def test_the_update_attention_reads_its_operands_where_they_lie(one_chip, monkeypatch, cell):
+    """A layer's gradient at the cell's widths, steered onto the TPU's branch
+    of `best_attention`: both kernels are in it, no [16, heads, 512, 512]
+    scores, and q, k, v, the result and their cotangents pass between the
+    projections and the kernels as XLA lays them — positions minor — so no
+    `copy` or `transpose` of an array of their size stands between. In the
+    OLMoE block alone XLA keeps q and k features-minor for the norm over the
+    whole projection that precedes the rotation, and copies those two, and dq
+    and dk back: four copies of [16, 512, 2048] where the parent's
+    `_fold_heads` made five and the plain backward wrote the scores."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _mixer_gradient(cell, one_chip)
+    assert "flash_attention_bwd" in text and text.count("tpu_custom_call") >= 2
+    heads = {"kanana2": 32, "lfm2": 32, "olmoe": 16}[cell]
+    assert not re.search(rf"f32\[16,{heads},512,512\]", text)
+    copies = [
+        line for line in text.splitlines()
+        if re.search(r"= f32\[16,(512,\d+(,\d+)?|\d+,\d+,512)\]\{[^}]*\} copy\(", line)
+    ]
+    assert len(copies) == (4 if cell == "olmoe" else 0)
