@@ -26,6 +26,12 @@ cut to a few updates and weights made from the config's seed. Legs:
                  decode through the latent rows' Pallas kernel, a shared
                  expert beside the held ones, and the flash kernel pair,
                  forward and backward, in the update.
+  ling3_ppo      the same entry point with `network=ling3_flash_moe` at a tiny
+                 preset with the published head size (128): five delta-rule
+                 layers — the matrix state rewritten a token through the
+                 Pallas kernel `delta_rule_step` in rollout and evaluator,
+                 the chunked recurrence and its backward in the update — to
+                 one gated latent-attention layer, a group-limited router.
   sdar_ppo       Anakin PPO with the SDAR block-diffusion token policy at a
                  tiny preset (block_token_task): the held-experts loop of
                  grouped matmuls, block steps through the GQA cache in rollout
@@ -413,6 +419,50 @@ def leg_kanana2_ppo(n: int) -> Dict[str, Any]:
     ):
         facts[gauge] = _forms(gauge)
         _require(facts[gauge] == want, f"{gauge}: the run took {facts[gauge]}")
+    return facts
+
+
+def leg_ling3_ppo(n: int) -> Dict[str, Any]:
+    """The delta-rule hybrid token policy through the same `ff_lm_ppo`,
+    data-parallel over the chips, at the published head size (8 heads of 128:
+    a state of whole tiles, one grid step of the decode kernel a sequence):
+    rollout and evaluator rewrite five matrix states a token through
+    `delta_rule_step_kernel` and decode the sixth layer absorbed; the update
+    runs the chunked
+    delta rule (eight chunks of 16 a sequence) with its rematerialised
+    backward and the flash kernel pair in the latent layer; the router
+    chooses inside the 2 best of 4 groups."""
+    tiny = [
+        "hidden_size=128", "dense_width=256", "num_heads=8", "num_kv_heads=8", "head_dim=128",
+        "kv_lora_rank=128", "qk_nope_head_dim=128", "qk_rope_head_dim=64", "v_head_dim=128",
+        "num_experts=32", "experts_held=4", "experts_per_token=3", "expert_width=64", "n_group=4",
+        "topk_group=2",
+    ]
+    facts = _anakin_leg(
+        n,
+        "stoix_tpu.systems.ppo.anakin.ff_lm_ppo",
+        "default/anakin/default_ff_lm_ppo.yaml",
+        ["network=ling3_flash_moe"] + [f"network.actor_network.{o}" for o in tiny] + [
+            "env.kwargs.vocab_size=512", "env.kwargs.length=128", "system.rollout_length=128",
+            f"arch.total_num_envs={16 * n}", "system.num_minibatches=4", "arch.num_updates=4",
+            "system.router_aux_loss_coef=0.0", "arch.evaluation_greedy=True",
+        ],
+        expect_kernel=True,
+    )
+    for gauge, want in (
+        ("stoix_tpu_delta_rule_update", {"chunked": 1.0, "scan": 0.0}),
+        ("stoix_tpu_mla_decode", {"absorbed": 1.0, "expanded": 0.0}),
+    ):
+        facts[gauge] = _forms(gauge)
+        _require(facts[gauge] == want, f"{gauge}: the run took {facts[gauge]}")
+    from stoix_tpu.observability import get_registry
+
+    carry = {
+        dict(labels)["kind"]: value
+        for labels, value in get_registry().gauge("stoix_tpu_lm_carry_bytes").labels_and_values()
+    }
+    _require(set(carry) == {"delta_state", "latent"}, f"the carry's kinds: {carry}")
+    facts["carry_bytes"] = carry
     return facts
 
 
@@ -811,6 +861,7 @@ LEGS: List[Tuple[str, Callable[[int], Dict[str, Any]]]] = [
     ("lm_ppo", leg_lm_ppo),
     ("lfm2_ppo", leg_lfm2_ppo),
     ("kanana2_ppo", leg_kanana2_ppo),
+    ("ling3_ppo", leg_ling3_ppo),
     ("sdar_ppo", leg_sdar_ppo),
     ("ppo_pallas_gae", leg_ppo_pallas_gae),
     ("sebulba", leg_sebulba),

@@ -18,6 +18,10 @@ the benchmark compare this file with). Every layer l, no bias anywhere:
   * `full_attention` mixer (`GroupedQueryAttention`): networks/sdar.py's
     grouped-query projections with a per-head q/k RMSNorm and rotate-half
     RoPE; causal softmax. Its decode state is the keys and values (`KV`).
+  * `latent_attention` (networks/mla.py) and `delta_attention`
+    (networks/kda.py) mixers are other models' (Kanana-2; Ling-3.0): a
+    compressed row a position (`Latent`), and a matrix a head that every
+    token rewrites with three convolutions' tails (`DeltaState`).
   * feed-forward of the first `num_dense_layers` layers (`DenseMLP`): one
     SwiGLU of width `dense_width`; of the others (`RoutedMLP`): float32
     sigmoid scores over ALL `num_experts`, the top-k CHOSEN by score +
@@ -65,6 +69,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from stoix_tpu.networks.kda import DeltaState, KimiDeltaAttention
 from stoix_tpu.networks.mla import Latent, LatentAttention
 from stoix_tpu.networks.olmoe import (
     _attend_cache, _stack, init_length, moe, reset_length, rms_norm, write_cache_rows,
@@ -94,7 +99,7 @@ class KV(NamedTuple):
 
 
 class Lfm2Carry(NamedTuple):
-    layers: Tuple[Any, ...]  # a layer: its mixer's state, ConvTail, KV or Latent
+    layers: Tuple[Any, ...]  # a layer: its mixer's state, ConvTail, KV, Latent or DeltaState
     length: jax.Array  # [B] or [] int32: positions filled = the next token's position
 
 
@@ -217,6 +222,8 @@ class RoutedMLP(nn.Module):
     bias_scale: float
     epsilon: float = 1e-6  # joins the chosen scores' sum the weights are divided by
     shared_width: int = 0
+    groups: int = 1  # `n_group`: the experts lie in so many groups, of which the
+    top_groups: int = 1  # `topk_group` best are open to a token's choice
 
     def setup(self) -> None:
         d, e, held, f = self.hidden_size, self.num_experts, self.experts_held, self.width
@@ -236,7 +243,8 @@ class RoutedMLP(nn.Module):
                 f, self.router, self.gate, self.up, self.down, self.experts_per_token,
                 held=(self.expert_offset, self.experts_held), renormalise=True,
                 held_room_sigmas=_HELD_ROOM_SIGMAS, score="sigmoid", bias=self.expert_bias,
-                epsilon=self.epsilon, scale=self.scaling_factor,
+                epsilon=self.epsilon, scale=self.scaling_factor, groups=self.groups,
+                top_groups=self.top_groups,
             )
         if self.shared_width:
             out = out + self.shared(f)[0]
@@ -284,7 +292,8 @@ class Lfm2LM(nn.Module):
 
     vocab_size: int
     hidden_size: int
-    layer_types: Sequence[str]  # a layer: "conv" | "full_attention" | "latent_attention"
+    # a layer: "conv" | "full_attention" | "latent_attention" | "delta_attention"
+    layer_types: Sequence[str]
     num_dense_layers: int
     dense_width: int
     num_heads: int
@@ -309,6 +318,14 @@ class Lfm2LM(nn.Module):
     # ... of the shared expert beside the routed ones: `n_shared_experts`
     # SwiGLUs of `expert_width`, built as one of that many times the width ...
     n_shared_experts: int = 0
+    # ... of a `bailing_hybrid` stack: the head-wise output gate of its latent
+    # layers, the floor of a `delta_attention` layer's log-decay
+    # (networks/kda.py; its heads are `num_heads` of `head_dim`, its taps
+    # `conv_kernel`), the router's group-limited choice ...
+    attention_gate: bool = False
+    kda_lower_bound: float = -5.0
+    n_group: int = 1
+    topk_group: int = 1
     # ... and of the head: the embedding's transpose, or a matrix of its own.
     tie_word_embeddings: bool = True
 
@@ -332,9 +349,16 @@ class Lfm2LM(nn.Module):
             return LatentAttention(
                 self.hidden_size, self.num_heads, self.kv_lora_rank, self.qk_nope_head_dim,
                 self.qk_rope_head_dim, self.v_head_dim, self.rope_theta, self.rms_eps,
+                self.attention_gate,
+            )
+        if kind == "delta_attention":
+            return KimiDeltaAttention(
+                self.hidden_size, self.num_heads, self.head_dim, self.conv_kernel,
+                self.kda_lower_bound, self.rms_eps,
             )
         raise ValueError(
-            f"layer_types names {kind!r}: a mixer is conv, full_attention or latent_attention"
+            f"layer_types names {kind!r}: a mixer is conv, full_attention, latent_attention or "
+            "delta_attention"
         )
 
     def _ffn(self, index: int) -> nn.Module:
@@ -344,7 +368,7 @@ class Lfm2LM(nn.Module):
             self.hidden_size, self.num_experts, self.experts_held, self.expert_offset,
             self.experts_per_token, self.expert_width, self.routed_scaling_factor,
             self.expert_bias_scale, self.router_epsilon,
-            self.n_shared_experts * self.expert_width,
+            self.n_shared_experts * self.expert_width, self.n_group, self.topk_group,
         )
 
     def setup(self) -> None:
@@ -397,9 +421,15 @@ class Lfm2LM(nn.Module):
         latent = lambda: Latent(jnp.zeros(
             (batch, max_len, self.kv_lora_rank + self.qk_rope_head_dim), jnp.float32
         ))
+        width = self.num_heads * self.head_dim
+        delta = lambda: DeltaState(
+            jnp.zeros((batch, self.num_heads, self.head_dim, self.head_dim), jnp.float32),
+            jnp.zeros((batch, self.conv_kernel - 1, 3 * width), jnp.float32),
+            jnp.zeros((batch,), bool),
+        )
         fresh = {
             "conv": tail, "full_attention": lambda: KV(cache(), cache()),
-            "latent_attention": latent,
+            "latent_attention": latent, "delta_attention": delta,
         }
         return Lfm2Carry(
             tuple(fresh[kind]() for kind in self.layer_types), init_length(batch, together)
@@ -408,12 +438,20 @@ class Lfm2LM(nn.Module):
     @nn.nowrap
     def reset_carry(self, carry: Lfm2Carry, done: jax.Array) -> Lfm2Carry:
         """Start a new sequence where `done`: its conv tails are what precedes
-        a sequence (zeros, 16 KB); nothing of a KV cache or of the latent rows
-        beyond `length` is read."""
-        fresh = lambda state: (
-            ConvTail(jnp.where(done[:, None, None], 0.0, state.z))
-            if isinstance(state, ConvTail) else state
-        )
+        a sequence (zeros, 16 KB), and so is a delta layer's matrix state,
+        which the next step reads whole — as zeros, where `fresh` says so
+        (networks/kda.py: no pass over the matrices here); nothing of a KV
+        cache or of the latent rows beyond `length` is read."""
+
+        def fresh(state: Any) -> Any:
+            if isinstance(state, ConvTail):
+                return ConvTail(jnp.where(done[:, None, None], 0.0, state.z))
+            if isinstance(state, DeltaState):
+                return DeltaState(
+                    state.s, jnp.where(done[:, None, None], 0.0, state.conv), state.fresh | done
+                )
+            return state
+
         return Lfm2Carry(
             tuple(fresh(state) for state in carry.layers), reset_length(carry.length, done)
         )
@@ -425,7 +463,7 @@ class Lfm2LM(nn.Module):
             x.size * x.dtype.itemsize
             for state in carry.layers if isinstance(state, kind) for x in state
         )
-        kinds = {"conv_tail": ConvTail, "kv": KV, "latent": Latent}
+        kinds = {"conv_tail": ConvTail, "kv": KV, "latent": Latent, "delta_state": DeltaState}
         return {
             name: size(kind) for name, kind in kinds.items()
             if any(isinstance(state, kind) for state in carry.layers)

@@ -32,8 +32,13 @@ are sequence-major, [B, S, c + r]: a sequence's live rows are one matrix, the
 product's batch axis leads, and a step's new row is one
 `dynamic_update_slice` at the one position of sequences that move together.
 
+`gate` (off by default; `gated_attention_proj_granularity_type` `head_wise`
+of a `bailing_hybrid` stack) multiplies each head's result by sigmoid(u W_g)_h
+before W_o, in both entry points.
+
 Parameters, by name: wq [D, H (n + r)], wkv_a [D, c + r], kv_norm [c], wkv_b
-[c, H (n + v)], wo [H v, D]; normal(0.02), the norm starts at one.
+[c, H (n + v)], wo [H v, D], with `gate` wg [D, H]; normal(0.02), the norm
+starts at one.
 """
 
 from __future__ import annotations
@@ -138,6 +143,7 @@ class LatentAttention(nn.Module):
     v_head_dim: int
     rope_theta: float
     rms_eps: float
+    gate: bool = False  # a sigmoid gate a head on the heads' results, before W_o
     trace_scope = "attention"
 
     def setup(self) -> None:
@@ -148,6 +154,12 @@ class LatentAttention(nn.Module):
         self.kv_norm = self.param("kv_norm", nn.initializers.ones, (c,))
         self.wkv_b = self.param("wkv_b", _INIT, (c, h * (n + v)))
         self.wo = self.param("wo", _INIT, (h * v, d))
+        if self.gate:
+            self.wg = self.param("wg", _INIT, (d, h))
+
+    def _gated(self, attended: jax.Array, u: jax.Array) -> jax.Array:
+        """attended [..., H, v] times sigmoid(u W_g) a head, where `gate`."""
+        return attended * jax.nn.sigmoid(u @ self.wg)[..., None] if self.gate else attended
 
     @property
     def scale(self) -> float:
@@ -180,7 +192,7 @@ class LatentAttention(nn.Module):
             k_rope = jnp.broadcast_to(k_rope[:, :, None], q_rope.shape)
             q, k = jnp.concatenate([q_nope, q_rope], -1), jnp.concatenate([k_nope, k_rope], -1)
             attended = best_attention(q, k, v, causal=True)  # [B, T, H, v]
-        return attended.reshape(batch, length, -1) @ self.wo
+        return self._gated(attended, u).reshape(batch, length, -1) @ self.wo
 
     def step(self, u: jax.Array, state: Latent, length: jax.Array):
         """u [B, D] against the latent rows; the new row is written first."""
@@ -201,4 +213,4 @@ class LatentAttention(nn.Module):
                 jnp.concatenate([absorbed, q_rope], -1), rows, length, self.kv_lora_rank, self.scale
             )
             out = jnp.einsum("bhc,chv->bhv", attended, w_uv)
-        return out.reshape(u.shape[0], -1) @ self.wo, Latent(rows)
+        return self._gated(out, u).reshape(u.shape[0], -1) @ self.wo, Latent(rows)

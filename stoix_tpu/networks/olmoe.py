@@ -129,7 +129,7 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 def route(
     x: jax.Array, router: jax.Array, top_k: int, renormalise: bool = False, *,
     score: str = "softmax", bias: Optional[jax.Array] = None, epsilon: float = 0.0,
-    scale: float = 1.0,
+    scale: float = 1.0, groups: int = 1, top_groups: int = 1,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Float32 scores over all experts, then top-k: (scores [N, E], weights
     [N, k], index [N, k]). The defaults are OLMoE's: the scores are a softmax
@@ -138,15 +138,21 @@ def route(
     scores each expert by itself; `bias` [E] (`expert_bias`) is added to the
     scores for the CHOICE alone, the weights stay the scores at the chosen
     experts; `epsilon` joins the sum they are divided by; `scale`
-    (`routed_scaling_factor`) multiplies them."""
+    (`routed_scaling_factor`) multiplies them; with `groups` > 1 (`n_group`)
+    the choice is group-limited: the experts lie in `groups` equal groups in
+    order, a group's score is the sum of its two largest score + bias, and
+    the top-k are chosen inside the `top_groups` (`topk_group`) best groups."""
     logits = jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
     )
     probs = jax.nn.softmax(logits, axis=-1) if score == "softmax" else jax.nn.sigmoid(logits)
-    if bias is None:
+    if bias is None and groups == 1:
         weights, index = jax.lax.top_k(probs, top_k)
     else:
-        _, index = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
+        choice = probs if bias is None else probs + bias.astype(jnp.float32)
+        if groups > 1:
+            choice = _inside_best_groups(choice, groups, top_groups)
+        _, index = jax.lax.top_k(choice, top_k)
         weights = jnp.take_along_axis(probs, index, axis=-1)
     if renormalise:
         total = jnp.sum(weights, axis=-1, keepdims=True)
@@ -154,6 +160,16 @@ def route(
     if scale != 1.0:
         weights = weights * scale
     return probs, weights, index
+
+
+def _inside_best_groups(choice: jax.Array, groups: int, top_groups: int) -> jax.Array:
+    """`choice` [N, E] with -inf outside each token's `top_groups` best of
+    `groups` groups, a group scored by the sum of its two largest entries."""
+    grouped = choice.reshape(choice.shape[0], groups, -1)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # [N, groups]
+    _, best = jax.lax.top_k(group_score, top_groups)
+    kept = jnp.any(jax.nn.one_hot(best, groups, dtype=bool), axis=-2)  # [N, groups]
+    return jnp.where(kept[..., None], grouped, -jnp.inf).reshape(choice.shape)
 
 
 @jax.custom_vjp
@@ -200,8 +216,10 @@ def moe(
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """x [N, D] -> (y [N, D], stats). Every (token, slot) pair is computed:
     `stats["expert_count"]` sums to N * top_k. `routing` is `route`'s `score`,
-    `bias`, `epsilon` and `scale`; with a selection bias the stats also
-    count the tokens it re-routed (`bias_changed_sum`).
+    `bias`, `epsilon`, `scale`, `groups` and `top_groups`; with a selection
+    bias the stats also count the tokens it re-routed (`bias_changed_sum`),
+    with a group-limited choice the tokens whose chosen set is not the plain
+    top-k of score + bias (`group_changed_sum`).
 
     `held` = (offset, count) says that `gate`, `up`, `down` hold only the
     experts [offset, offset + count) of the router's E (one expert-parallel
@@ -216,6 +234,10 @@ def moe(
         changed = {} if routing.get("bias") is None else {
             "bias_changed_sum": _bias_changed(probs, index)
         }
+        if routing.get("groups", 1) > 1:
+            bias = routing.get("bias")
+            choice = probs if bias is None else probs + bias.astype(jnp.float32)
+            changed["group_changed_sum"] = _bias_changed(choice, index)
     if held is not None:
         out, stats = _moe_held(
             x, probs, weights, index, gate, up, down, held, held_room_sigmas
@@ -247,8 +269,9 @@ def moe(
 
 
 def _bias_changed(probs: jax.Array, index: jax.Array) -> jax.Array:
-    """Tokens whose chosen set `index` [N, k] is not the top-k of the scores
-    alone: what the selection bias re-routed."""
+    """Tokens whose chosen set `index` [N, k] is not the top-k of `probs`:
+    of the scores alone, what the selection bias re-routed; of score + bias,
+    what the group limit did."""
     member = lambda chosen: jnp.any(jax.nn.one_hot(chosen, probs.shape[-1], dtype=bool), axis=-2)
     _, plain = jax.lax.top_k(probs, index.shape[-1])
     return jnp.sum(jnp.any(member(index) != member(plain), axis=-1), dtype=jnp.int32)

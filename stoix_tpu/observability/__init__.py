@@ -79,6 +79,7 @@ from stoix_tpu.observability.registry import (  # noqa: F401
 )
 from stoix_tpu.observability.trace import (  # noqa: F401
     BLOCK_SCOPES,
+    DELTA_SCOPES,
     DIFFUSION_SCOPES,
     HOST_SPANS,
     HYBRID_SCOPES,

@@ -76,6 +76,15 @@ SCOPES = {
     # update: the expansion W_kvb and the attention kernel, forward and backward
     "latent_attend": "latent_attend",
     "shared_expert": "shared_expert",  # the SwiGLU every token passes, beside the routed experts
+    # The delta-rule linear-attention layer (networks/kda.py), under `rollout`,
+    # `ppo_epoch` and in the evaluator alike.
+    # operator norm, W_q W_k W_v W_f and the gates, the convolutions, the recurrence, the
+    # output norm and gate, W_o
+    "delta_mixer": "delta_mixer",
+    "delta_conv": "delta_conv",  # inside it: the three 4-tap convolutions, SiLU, the q/k norms, the tails
+    # inside it: the recurrence alone (ops/delta_rule.py) — the chunked form in the update,
+    # one token against its matrix state in the decode
+    "delta_rule": "delta_rule",
 }
 
 # The scopes of the token policy's block: only the systems built on
@@ -90,6 +99,9 @@ HYBRID_SCOPES = ("conv_mixer", "conv_mixer_conv", "dense_mlp")
 # What latent attention and a shared expert add: only a stack with a
 # `latent_attention` layer and `n_shared_experts` carries these.
 LATENT_SCOPES = ("latent_project", "latent_attend", "shared_expert")
+# What a delta-rule linear-attention layer adds: only a stack with a
+# `delta_attention` layer carries these.
+DELTA_SCOPES = ("delta_mixer", "delta_conv", "delta_rule")
 
 # Host spans that recur in steady state, by the thread that opens them. A
 # trace reduction attributes a device-idle gap to the innermost of these open

@@ -10,7 +10,7 @@ Encapsulates the mesh/layout conventions every Anakin system uses
 
 from __future__ import annotations
 
-from typing import Any, Callable, Tuple
+from typing import Any, Callable, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -89,8 +89,13 @@ def shardmap_learner(
     mesh: Mesh,
     state_specs: Any,
     episode_metrics_spec: P = P(None, None, None, "data"),
+    compiler_options: Optional[Mapping[str, Any]] = None,
 ) -> Callable[[Any], ExperimentOutput]:
     """Wrap a per-shard learner in shard_map + jit with the standard specs.
+
+    `compiler_options` are XLA options this one program is compiled with (a
+    network's yaml names them where its learner needs any, and says why);
+    none by default, which is `jax.jit` as it was.
 
     The learner state is donated (donate_argnums): the host loop's
     `state = learn(state).learner_state` never reads the old state again, and
@@ -110,7 +115,9 @@ def shardmap_learner(
     """
     import os
 
-    donate = {} if os.environ.get("STOIX_TPU_NO_DONATE") else {"donate_argnums": (0,)}
+    jit_options = {} if os.environ.get("STOIX_TPU_NO_DONATE") else {"donate_argnums": (0,)}
+    if compiler_options:
+        jit_options["compiler_options"] = dict(compiler_options)
     return jax.jit(
         jax.shard_map(
             learn_per_shard,
@@ -132,7 +139,7 @@ def shardmap_learner(
             # varying-ness was fixed where real (wrappers._ensure_truncation).
             check_vma=False,
         ),
-        **donate,
+        **jit_options,
     )
 
 

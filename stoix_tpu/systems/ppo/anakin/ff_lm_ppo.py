@@ -1,7 +1,9 @@
 """Anakin PPO with a token policy: a decoder (`network=olmoe`: OLMoE blocks
-and a KV cache; `network=lfm2_moe`: convolution and attention layers and a
-carry of both kinds of state) acts step by step through its carry in the
-rollout and is updated teacher-forced over whole sequences.
+and a KV cache; `network=lfm2_moe`, `kanana2_moe`, `ling3_flash_moe`:
+networks/lfm2.py's stack of convolution, attention, latent-attention and
+delta-rule layers and a carry of each kind of state) acts step by step
+through its carry in the rollout and is updated teacher-forced over whole
+sequences.
 
 The first system in which the policy, not the env, is the work (the LM
 post-training shape: generate a batch of fixed-length responses, score them
@@ -148,6 +150,10 @@ def lm_ppo_loss(
         info["dropped_pairs"] = layers * num_tokens * top_k - jnp.sum(load)
     if "bias_changed_sum" in stats:
         info["router_bias_changed_share"] = jnp.sum(stats["bias_changed_sum"]) / (
+            layers * num_tokens
+        )
+    if "group_changed_sum" in stats:
+        info["group_limited_changed_share"] = jnp.sum(stats["group_changed_sum"]) / (
             layers * num_tokens
         )
     return total, info
@@ -325,7 +331,8 @@ def _carry_gauge() -> Any:
         "stoix_tpu_lm_carry_bytes",
         "bytes of the token policy's decode carry on one shard as the learner was set up, by "
         "kind of state: kv (keys and values, a row a position), conv_tail (a short "
-        "convolution's last inputs) or latent (latent attention's compressed rows)",
+        "convolution's last inputs), latent (latent attention's compressed rows) or delta_state "
+        "(a delta-rule layer's matrix a head and its convolutions' last inputs)",
     )
 
 
@@ -413,8 +420,13 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
     learn_per_shard = get_learner_fn(
         env, networks, (actor_optim.update, critic_optim.update), config
     )
+    # XLA options a network's yaml names for its learner on a TPU
+    # (configs/network/ling3_flash_moe.yaml has one, and why); none elsewhere.
+    on_tpu = jax.default_backend() == "tpu"
+    options = config.network.get("learner_compiler_options") if on_tpu else None
     learn = anakin.shardmap_learner(
-        learn_per_shard, mesh, state_specs, episode_metrics_spec=P(None, None, "data")
+        learn_per_shard, mesh, state_specs, episode_metrics_spec=P(None, None, "data"),
+        compiler_options=options,
     )
 
     for labels, _ in _carry_gauge().labels_and_values():  # an earlier learner's kinds

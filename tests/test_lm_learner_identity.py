@@ -8,7 +8,10 @@ its new digest here and says so in CHANGES.md.
 arguments and the network-declared carry left it as it was). `olmoe` and
 `olmoe_2layers` were re-recorded in PR 35, which made `ff_lm_ppo` ask for the
 carry of ONE position (`length []`: the key/value row written as one slab);
-`lfm2` was first recorded there, after the same change."""
+`lfm2` was first recorded there, after the same change. `kanana2` is the
+parent commit's program of PR 40 (computed on `git archive` of it: the
+head-wise gate, the group-limited choice and the delta mixer's keys left it
+as it was); `ling3` was first recorded in PR 40."""
 
 import hashlib
 import importlib
@@ -20,7 +23,9 @@ import pytest
 from stoix_tpu import envs
 from stoix_tpu.utils import config as config_lib
 
+from test_kanana2_ppo import TINY as KANANA2_TINY
 from test_lfm2_ppo import TINY as LFM2_TINY
+from test_ling3_ppo import TINY as LING3_TINY
 from test_lm_ppo import TINY as OLMOE_TINY
 from test_sdar_ppo import TINY as SDAR_TINY
 
@@ -36,6 +41,14 @@ LEARNERS = {
     "lfm2": (
         "stoix_tpu.systems.ppo.anakin.ff_lm_ppo", "default/anakin/default_ff_lm_ppo.yaml",
         LFM2_TINY, "b822d1be53da6e9c2300ac46f52984cd49c8bbdf2442fef1986d98f1950c7b29",
+    ),
+    "kanana2": (
+        "stoix_tpu.systems.ppo.anakin.ff_lm_ppo", "default/anakin/default_ff_lm_ppo.yaml",
+        KANANA2_TINY, "16b86da1380421516c624d64c58d133794e56c455d4d4a05073093fd84d6eadc",
+    ),
+    "ling3": (
+        "stoix_tpu.systems.ppo.anakin.ff_lm_ppo", "default/anakin/default_ff_lm_ppo.yaml",
+        LING3_TINY, "41c6415fad0bc604e2142359baf954d1afdf96c5f43217b68034cb3f829e5e71",
     ),
     "sdar": (
         "stoix_tpu.systems.ppo.anakin.ff_sdar_ppo", "default/anakin/default_ff_sdar_ppo.yaml",
