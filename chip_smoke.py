@@ -11,7 +11,8 @@ cut to a few updates and weights made from the config's seed. Legs:
 
   kernels        every Pallas kernel compiled by Mosaic (interpret=False) and
                  compared with its plain-JAX reference at a stated tolerance,
-                 forward and gradient; ring attention over all the chips.
+                 forward and gradient; ring attention over all the chips; the
+                 delta rule's update pair against the recurrence.
   trans_ppo      Anakin transformer PPO (identity_game): the flash-attention
                  kernel in the learner's forward pass, gradient steps taken.
   lm_ppo         Anakin PPO with the OLMoE token policy at a tiny preset
@@ -30,7 +31,8 @@ cut to a few updates and weights made from the config's seed. Legs:
                  preset with the published head size (128): five delta-rule
                  layers — the matrix state rewritten a token through the
                  Pallas kernel `delta_rule_step` in rollout and evaluator,
-                 the chunked recurrence and its backward in the update — to
+                 the recurrence's kernel pair (`delta_rule_update`, state in
+                 VMEM across chunks of 64) forward and backward in the update — to
                  one gated latent-attention layer, a group-limited router.
   sdar_ppo       Anakin PPO with the SDAR block-diffusion token policy at a
                  tiny preset (block_token_task): the held-experts loop of
@@ -167,8 +169,6 @@ def _observe_learner_setup(
     `pallas_call`s in the jaxpr of the very `learn` the runner compiles — are
     recorded. The jitted+shard_mapped learner's outputs keep the input specs,
     so the placement holds for the whole run."""
-    import jax
-
     original = module.learner_setup
 
     def observing(env, config, mesh, key):
@@ -176,7 +176,8 @@ def _observe_learner_setup(
         setup = result if hasattr(result, "learn") else result[0]
         observed["placement"] = _placement(setup.learner_state)
         if count_kernels:
-            jaxpr = str(jax.make_jaxpr(setup.learn)(setup.learner_state))
+            # (the jit's own trace: `make_jaxpr` would nest a jit that names compiler options)
+            jaxpr = str(setup.learn.trace(setup.learner_state).jaxpr)
             observed["pallas_calls"] = jaxpr.count("pallas_call")
         return result
 
@@ -428,9 +429,10 @@ def leg_ling3_ppo(n: int) -> Dict[str, Any]:
     a state of whole tiles, one grid step of the decode kernel a sequence):
     rollout and evaluator rewrite five matrix states a token through
     `delta_rule_step_kernel` and decode the sixth layer absorbed; the update
-    runs the chunked
-    delta rule (eight chunks of 16 a sequence) with its rematerialised
-    backward and the flash kernel pair in the latent layer; the router
+    runs the delta rule's Pallas kernel pair (`delta_rule_update_kernel`: two
+    chunks of 64 a sequence, the state in VMEM across them, its own backward
+    pass) inside the rematerialised mixer and the flash kernel pair in the
+    latent layer; the router
     chooses inside the 2 best of 4 groups."""
     tiny = [
         "hidden_size=128", "dense_width=256", "num_heads=8", "num_kv_heads=8", "head_dim=128",
@@ -450,7 +452,7 @@ def leg_ling3_ppo(n: int) -> Dict[str, Any]:
         expect_kernel=True,
     )
     for gauge, want in (
-        ("stoix_tpu_delta_rule_update", {"chunked": 1.0, "scan": 0.0}),
+        ("stoix_tpu_delta_rule_update", {"kernel": 1.0, "chunked": 0.0, "scan": 0.0}),
         ("stoix_tpu_mla_decode", {"absorbed": 1.0, "expanded": 0.0}),
     ):
         facts[gauge] = _forms(gauge)
@@ -848,6 +850,42 @@ def leg_kernels(n: int) -> Dict[str, Any]:
         want = grads(functools.partial(full_attention, causal=causal), q, k, v, weight)
         for axis, g, r in zip("qkv", got, want):
             check(f"{name}_grad_d{axis}", g, r, TOL_GRAD)
+
+    # 5. The delta rule's update pair (ops/delta_rule.py) against the recurrence
+    #    position by position at full precision: two sequences of two chunks,
+    #    8 heads of 128, from a state; outputs, the state left, every gradient
+    #    (each over its reference's largest entry: the products are one
+    #    bfloat16 pass and the gradients span three decades).
+    from stoix_tpu.ops import delta_rule
+
+    keys = jax.random.split(jax.random.PRNGKey(6), 7)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    shape = (2, 2 * delta_rule.UPDATE_CHUNK, 8, 128)
+    q, k = (unit(jax.random.normal(x, shape)) for x in keys[:2])
+    operands = (
+        q * 128.0**-0.5, k, jax.random.normal(keys[2], shape),
+        -5.0 * jax.nn.sigmoid(jax.random.normal(keys[3], shape) * 2.0 - 2.0),
+        jax.nn.sigmoid(jax.random.normal(keys[4], shape[:3])),
+        0.1 * jax.random.normal(keys[5], (2, 8, 128, 128)),
+    )
+    weight = jax.random.normal(keys[6], shape)
+    _require(
+        _has_pallas_call(delta_rule.delta_rule_update_kernel, *operands),
+        "delta rule: no pallas_call traced",
+    )
+    loss = lambda rule: lambda *a: jnp.sum(rule(*a)[0] * weight) + jnp.sum(rule(*a)[1] ** 2)
+    both = lambda rule: jax.jit(lambda *a: (rule(*a), jax.grad(loss(rule), argnums=range(6))(*a)))
+    (out, state), got = both(delta_rule.delta_rule_update_kernel)(*operands)
+    with jax.default_matmul_precision("highest"):
+        (want_out, want_state), want = both(delta_rule.delta_rule_scan)(*operands)
+    in_scale = lambda x, ref: x / jnp.max(jnp.abs(ref))
+    check("delta_rule_update", in_scale(out, want_out), in_scale(want_out, want_out), TOL_ATTN)
+    check(
+        "delta_rule_update_state", in_scale(state, want_state), in_scale(want_state, want_state),
+        TOL_ATTN,
+    )
+    for name, g, r in zip(("q", "k", "v", "g", "beta", "state"), got, want):
+        check(f"delta_rule_update_d{name}", in_scale(g, r), in_scale(r, r), TOL_GRAD)
 
     return {"max_abs_error": {k: float(f"{v:.3e}") for k, v in errors.items()}}
 

@@ -15,23 +15,24 @@ K = `conv_kernel` taps (4), no bias anywhere:
     y = W_o [ RMSNorm(o_t) * sigmoid(u W_g)_h ]                      (ONE norm over all H d outputs)
 
 The layer is not rotated: its decay carries position. `lower_bound` = -5
-(`kda_lower_bound`, `kda_safe_gate`) is what lets the chunked form of the
+(`kda_lower_bound`, `kda_safe_gate`) is what lets the chunked forms of the
 recurrence divide by cumulative decays over 16 positions in float32
-(ops/delta_rule.py, which holds the three forms of the recurrence).
+(ops/delta_rule.py, which holds the four forms of the recurrence).
 
-  * `KimiDeltaAttention.forward` (whole sequences, the update): the chunked
-    form, rematerialised in the backward pass (`jax.checkpoint`): what the
-    projections, the chunk products and the loop over chunks keep for their
-    gradient is 2.6 GiB a layer at 8 sequences of 512 (a chunk's starting
-    state alone is 16 MiB, 32 of them a sequence), five layers of it do not
-    fit beside 10.7 GiB of state (the learner compiled for a described v5e
-    asks for 18.9 GiB of the chip's 15.75), and the block's input is 40 MiB.
-    Inside, the recurrence runs `_HEAD_GROUPS` groups of heads in turn, each
-    rematerialised too, so that one group's chunk arrays are alive at a time
-    (15.15 GiB -> 13.37; PERF.md section 6, PR 40). The gauge
-    `stoix_tpu_delta_rule_update{form}` reads 1 on `chunked`, the one form
-    the update takes, and 0 on `scan` (the position-by-position recurrence
-    is the reference's and the tests').
+  * `KimiDeltaAttention.forward` (whole sequences, the update):
+    `delta_rule_update` — on a TPU, for heads of 128 in blocks of 8 and
+    sequences of whole chunks of 64, the Pallas kernel pair (a head's state
+    in VMEM across the chunks, its own backward pass, whose residuals are
+    the operands and 128 MiB a layer of chunk-starting states at 8 sequences
+    of 512); elsewhere the chunked form in plain JAX. The mixer is
+    rematerialised in the backward pass (`jax.checkpoint`): what its five
+    projections keep for their gradient does not fit five times beside 10.7
+    GiB of state, and the block's input is 40 MiB (the learner compiled for
+    a described v5e: 13.08 GiB of the chip's 15.75; PERF.md section 6, PR
+    41). The gauge `stoix_tpu_delta_rule_update{form}` reads 1 on the form
+    the most recently traced update took — `kernel` or `chunked` — and 0 on
+    the others (`scan`, the position-by-position recurrence, is the
+    reference's and the tests').
   * `KimiDeltaAttention.step` (one token, the decode): the convolutions
     against their tails, then `delta_rule_step` against the matrix state.
 
@@ -65,13 +66,10 @@ import jax.numpy as jnp
 
 from stoix_tpu.networks.olmoe import rms_norm
 from stoix_tpu.observability import SCOPES, annotate, get_registry
-from stoix_tpu.ops.delta_rule import delta_rule_chunked, delta_rule_step
+from stoix_tpu.ops.delta_rule import delta_rule_step, delta_rule_update, update_form
 
 _INIT = nn.initializers.normal(0.02)
 _L2_EPS = 1e-6  # joins the sum of squares a head's q and k are divided by the root of
-# The heads are independent of each other: the update's recurrence runs them
-# in so many groups, one after the other, and keeps one group's arrays.
-_HEAD_GROUPS = 4
 
 
 class DeltaState(NamedTuple):
@@ -96,26 +94,10 @@ def _update_form_gauge():
     return get_registry().gauge(
         "stoix_tpu_delta_rule_update",
         "1 on the form of the gated delta rule the most recently traced update (a delta-attention "
-        "layer's pass over whole sequences) took, 0 on the other: chunked (chunks of 16 positions, "
-        "a loop over chunks) or scan (position by position)",
+        "layer's pass over whole sequences) took, 0 on the others: kernel (the Pallas kernel "
+        "pair, a head's state in VMEM across chunks of 64 positions), chunked (chunks of 16 "
+        "positions, a loop over chunks, plain JAX) or scan (position by position)",
     )
-
-
-def _in_head_groups(q, k, v, g, beta) -> jax.Array:
-    """`delta_rule_chunked(q, k, v, g, beta)[0]` ([B, T, H, d]; beta [B, T, H])
-    computed a group of heads at a time, each group rematerialised in the
-    backward pass."""
-    heads = q.shape[2]
-    groups = _HEAD_GROUPS if heads % _HEAD_GROUPS == 0 else 1
-    # [B, T, H, ...] -> [groups, B, T, H / groups, ...]
-    split = lambda x: jnp.moveaxis(
-        x.reshape(x.shape[:2] + (groups, heads // groups) + x.shape[3:]), 2, 0
-    )
-    out = jax.lax.map(
-        lambda group: jax.checkpoint(lambda *args: delta_rule_chunked(*args)[0])(*group),
-        tuple(split(x) for x in (q, k, v, g, beta)),
-    )
-    return jnp.moveaxis(out, 0, 2).reshape(q.shape[:3] + out.shape[4:])
 
 
 def _l2_normalise(x: jax.Array) -> jax.Array:
@@ -185,13 +167,14 @@ class KimiDeltaAttention(nn.Module):
             ))
         g, beta, gate = self._gates(w, u)
         with annotate(SCOPES["delta_rule"]):
-            out = _in_head_groups(q, k, v, g, beta)
+            out, _ = delta_rule_update(q, k, v, g, beta)
         return self._out(w, out, gate)
 
     def forward(self, u: jax.Array) -> jax.Array:
         """u [B, T, D]: every position reads the state its predecessors left."""
-        for form, took in (("chunked", 1.0), ("scan", 0.0)):
-            _update_form_gauge().set(took, {"form": form})
+        took = update_form(u.shape[1], self.num_heads, self.head_dim, self.head_dim)
+        for form in ("kernel", "chunked", "scan"):
+            _update_form_gauge().set(float(form == took), {"form": form})
         return jax.checkpoint(self._mix)(dict(self.weights), u)
 
     def step(self, u: jax.Array, state: DeltaState, length: jax.Array):

@@ -268,6 +268,107 @@ def test_the_chunked_form_is_finite_at_the_lower_bound_on_every_channel():
     assert delta_rule.CHUNK * -LOWER < 88.0  # float32's largest exponent
 
 
+# The update's kernel pair, through the Pallas interpreter: 8 heads of 128, one
+# sequence (a grid step is a chunk of 8 heads).
+KERNEL_HEADS, KERNEL_DIM = 8, 128
+
+
+def _kernel_inputs(length, seed=4):
+    q, k, v, g, beta = _recurrence_inputs(length, seed, batch=1, heads=KERNEL_HEADS, d=KERNEL_DIM)
+    state = 0.1 * jax.random.normal(jax.random.PRNGKey(seed + 1), (1, KERNEL_HEADS, KERNEL_DIM, KERNEL_DIM))
+    return q, k, v, g, beta, state
+
+
+def _close_in_scale(got, want, tol=2e-5):
+    """Within `tol` of the largest entry: sums of 128 and more products in
+    another order."""
+    scale = float(jnp.abs(want).max())
+    assert scale > 0.01
+    np.testing.assert_allclose(np.asarray(got) / scale, np.asarray(want) / scale, rtol=0, atol=tol)
+
+
+@pytest.mark.parametrize("start", ["from_zeros", "from_a_state"])
+@pytest.mark.parametrize("chunks", [1, 2, 3])
+def test_the_update_kernel_is_the_sequential_recurrence(chunks, start):
+    """`delta_rule_update_kernel` at its own chunk size over one, two and
+    three chunks, from zeros and from a state: outputs and the state it
+    leaves against `delta_rule_scan`."""
+    *args, state = _kernel_inputs(chunks * delta_rule.UPDATE_CHUNK)
+    state = state if start == "from_a_state" else None
+    want, want_state = jax.jit(delta_rule.delta_rule_scan)(*args, state)
+    got, got_state = delta_rule.delta_rule_update_kernel(*args, state, interpret=True)
+    _close_in_scale(got, want)
+    _close_in_scale(got_state, want_state)
+
+
+@pytest.fixture(scope="module")
+def kernel_gradients():
+    """By q, k, v, g, beta and the starting state, of a loss over the outputs
+    and the state left, two chunks: the kernel pair's and `jax.grad` of the
+    sequential recurrence's."""
+    args = _kernel_inputs(2 * delta_rule.UPDATE_CHUNK)
+    loss = lambda form: lambda *a: jnp.sum(jnp.sin(8.0 * form(*a)[0])) + jnp.sum(form(*a)[1] ** 2)
+    kernel = lambda *a: delta_rule.delta_rule_update_kernel(*a, interpret=True)
+    got = jax.jit(jax.grad(loss(kernel), argnums=range(6)))(*args)
+    want = jax.jit(jax.grad(loss(delta_rule.delta_rule_scan), argnums=range(6)))(*args)
+    return dict(zip(["q", "k", "v", "g", "beta", "state"], zip(got, want)))
+
+
+@pytest.mark.parametrize("argument", ["q", "k", "v", "g", "beta", "state"])
+def test_the_update_kernels_gradient_is_jax_grad_of_the_sequential_one(kernel_gradients, argument):
+    got, want = kernel_gradients[argument]
+    assert got.shape == want.shape
+    _close_in_scale(got, want)
+
+
+def test_the_update_kernel_is_finite_at_the_lower_bound_on_every_channel():
+    """g = -5 on every channel of every position of a chunk of 64: exp(-G)
+    would reach exp(320); the kernel divides inside sub-blocks of 16 (exp(80),
+    which float32 holds), so outputs and gradients are finite and the scan's."""
+    q, k, v, g, beta, state = _kernel_inputs(delta_rule.UPDATE_CHUNK)
+    g = jnp.full_like(g, LOWER)
+    kernel = lambda *a: delta_rule.delta_rule_update_kernel(*a, interpret=True)
+    got, got_state = kernel(q, k, v, g, beta, state)
+    want, want_state = jax.jit(delta_rule.delta_rule_scan)(q, k, v, g, beta, state)
+    assert bool(jnp.isfinite(got).all()) and bool(jnp.isfinite(got_state).all())
+    _close_in_scale(got, want)
+    _close_in_scale(got_state, want_state)
+    grads = jax.jit(jax.grad(lambda *a: jnp.sum(kernel(*a)[0] ** 2), argnums=range(6)))(
+        q, k, v, g, beta, state
+    )
+    assert all(bool(jnp.isfinite(x).all()) for x in grads)
+    assert delta_rule._SUB * -LOWER < 88.0 < delta_rule.UPDATE_CHUNK * -LOWER
+
+
+@pytest.mark.parametrize("why", ["off_the_chip", "a_remainder", "heads_of_16", "five_heads"])
+def test_the_update_takes_the_chunked_form_off_the_chip_and_for_shapes_that_are_no_whole_tiles(
+    why, monkeypatch
+):
+    """`delta_rule_update` is the kernel pair on a TPU for heads of 128 in
+    blocks of 8 and whole chunks, and `delta_rule_chunked` anywhere else."""
+    chunk = delta_rule.UPDATE_CHUNK
+    shape = {
+        "off_the_chip": (chunk, 8, 128), "a_remainder": (chunk + 16, 8, 128),
+        "heads_of_16": (chunk, 8, 16), "five_heads": (chunk, 5, 128),
+    }[why]
+    if why != "off_the_chip":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert delta_rule.update_form(chunk, 8, 128, 256) == "kernel"
+    length, heads, d = shape
+    assert delta_rule.update_form(length, heads, d, d) == "chunked"
+    args = _recurrence_inputs(length, batch=1, heads=heads, d=d)
+    taken = []
+    real = delta_rule.delta_rule_chunked
+    monkeypatch.setattr(
+        delta_rule, "delta_rule_chunked", lambda *a: taken.append("chunked") or real(*a)
+    )
+    got, state = delta_rule.delta_rule_update(*args)
+    want, want_state = real(*args)
+    assert taken == ["chunked"]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(state), np.asarray(want_state))
+
+
 def _one_delta_mixer():
     mixer = kda.KimiDeltaAttention(64, HEADS, HEAD_DIM, TAPS, LOWER, 1e-6)
     u = jax.random.normal(jax.random.PRNGKey(2), (3, LENGTH, 64))
@@ -300,7 +401,8 @@ def test_the_delta_mixer_is_the_references_layer_and_step_by_step(scale):
     whole = jax.jit(lambda p, x: mixer.apply(p, x, method="forward"))(params, u)
     _close(whole, reference.delta_attention(params["params"], u, _spec()))
     gauge = get_registry().gauge("stoix_tpu_delta_rule_update")
-    assert gauge.value({"form": "chunked"}) == 1.0 and gauge.value({"form": "scan"}) == 0.0
+    forms = ("kernel", "chunked", "scan")
+    assert [gauge.value({"form": form}) for form in forms] == [0.0, 1.0, 0.0]
     state = kda.DeltaState(
         jnp.zeros((3, HEADS, HEAD_DIM, HEAD_DIM)), jnp.zeros((3, TAPS - 1, 3 * HEADS * HEAD_DIM)),
         jnp.zeros((3,), bool),
@@ -739,7 +841,9 @@ def test_learner_setup_publishes_the_carry_kinds_and_the_updates_form(program_sc
     }
     assert by(registry.gauge("stoix_tpu_lm_cache_write"), "form") == {"slice": 1.0, "scatter": 0.0}
     assert by(registry.gauge("stoix_tpu_mla_decode"), "form") == {"absorbed": 1.0, "expanded": 0.0}
-    assert by(registry.gauge("stoix_tpu_delta_rule_update"), "form") == {"chunked": 1.0, "scan": 0.0}
+    assert by(registry.gauge("stoix_tpu_delta_rule_update"), "form") == {
+        "kernel": 0.0, "chunked": 1.0, "scan": 0.0
+    }
 
 
 def _logged_run(extra):

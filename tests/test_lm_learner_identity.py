@@ -11,7 +11,12 @@ carry of ONE position (`length []`: the key/value row written as one slab);
 `lfm2` was first recorded there, after the same change. `kanana2` is the
 parent commit's program of PR 40 (computed on `git archive` of it: the
 head-wise gate, the group-limited choice and the delta mixer's keys left it
-as it was); `ling3` was first recorded in PR 40."""
+as it was); `ling3` was first recorded in PR 40 and re-recorded in PR 41,
+ALONE: the delta mixer's update no longer runs the chunked recurrence in
+four rematerialised groups of heads (`_in_head_groups` went with the Pallas
+kernel pair that made it unnecessary on the chip; on the CPU the mixer now
+calls `delta_rule_chunked` once), and the other five are to the digit what
+they were."""
 
 import hashlib
 import importlib
@@ -48,7 +53,7 @@ LEARNERS = {
     ),
     "ling3": (
         "stoix_tpu.systems.ppo.anakin.ff_lm_ppo", "default/anakin/default_ff_lm_ppo.yaml",
-        LING3_TINY, "41c6415fad0bc604e2142359baf954d1afdf96c5f43217b68034cb3f829e5e71",
+        LING3_TINY, "7b450a12541031ff826da493afa5612606ce8212a3d30ba975d493d1f7c69db5",
     ),
     "sdar": (
         "stoix_tpu.systems.ppo.anakin.ff_sdar_ppo", "default/anakin/default_ff_sdar_ppo.yaml",

@@ -70,6 +70,39 @@ def test_mosaic_takes_the_block_mask_kernels_at_the_cells_shape(one_chip, what):
         assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 4 * SEQUENCES * POSITIONS * HEADS * HEAD_DIM
 
 
+# The benchmark's Ling-3 cell: a minibatch of 8 sequences of 512 tokens, 32
+# heads of 128, through a delta-rule layer's recurrence.
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_mosaic_takes_the_delta_rules_kernel_pair_at_the_cells_shape(one_chip, what):
+    """`delta_rule_update_kernel` at [8, 512, 32, 128]: Mosaic takes the
+    forward kernel and the pair; q, k, v, g and their cotangents pass to and
+    from the kernels as they lie (no `copy`, `transpose` or `reshape` of an
+    array of their size), and what the gradient keeps beside its operands
+    and results is the chunks' starting states, 128 MiB."""
+    from stoix_tpu.ops import delta_rule
+
+    operand, beta = (8, 512, 32, 128), (8, 512, 32)
+    rule = lambda q, k, v, g, b: delta_rule.delta_rule_update_kernel(q, k, v, g, b)[0]
+    if what == "forward":
+        compiled = _compiled(rule, one_chip, *[operand] * 4, beta)
+        text = compiled.as_text()
+        assert "delta_rule_update" in text and "delta_rule_update_bwd" not in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 2**25  # the starting state, zeros
+    else:
+        loss = lambda q, k, v, g, b, w: jnp.sum(rule(q, k, v, g, b) * w)
+        grad = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+        compiled = _compiled(grad, one_chip, *[operand] * 4, beta, operand)
+        text = compiled.as_text()
+        assert "delta_rule_update_bwd" in text and text.count("tpu_custom_call") == 2
+        states = 8 * (512 // delta_rule.UPDATE_CHUNK) * 32 * 128 * 128 * 4
+        assert states <= compiled.memory_analysis().temp_size_in_bytes < states + 2**26
+    moved = [
+        line for line in text.splitlines()
+        if re.search(r"= f32\[8,512,(32,128|4096)\]\{[^}]*\} (copy|transpose|reshape)\(", line)
+    ]
+    assert not moved, moved
+
+
 # The benchmark's LFM2 cell: 128 sequences decode 512 tokens through the
 # published widths' first six layers (configs/network/lfm2_moe.yaml), one of
 # them attention over a cache [512, 128, 8, 64]: head size 64 is half a lane
