@@ -12,7 +12,8 @@ cut to a few updates and weights made from the config's seed. Legs:
   kernels        every Pallas kernel compiled by Mosaic (interpret=False) and
                  compared with its plain-JAX reference at a stated tolerance,
                  forward and gradient; ring attention over all the chips; the
-                 delta rule's update pair against the recurrence.
+                 delta rule's update pair against the recurrence; q's and k's
+                 norm-and-rotate pair against `rms_norm` + `rope`.
   trans_ppo      Anakin transformer PPO (identity_game): the flash-attention
                  kernel in the learner's forward pass, gradient steps taken.
   lm_ppo         Anakin PPO with the OLMoE token policy at a tiny preset
@@ -38,8 +39,10 @@ cut to a few updates and weights made from the config's seed. Legs:
                  tiny preset (block_token_task): the held-experts loop of
                  grouped matmuls, block steps through the GQA cache in rollout
                  and evaluator, the [clean ; noisy copies] update with its
-                 attention in the block-mask kernels (forward and backward),
-                 then `trunk_copies` against the plain masked products.
+                 attention in the block-mask kernels (forward and backward)
+                 behind q's and k's norm-and-rotate pair (the gauge
+                 `stoix_tpu_qk_norm_rope{form=kernel}` = 1), then
+                 `trunk_copies` against the plain masked products.
   ppo_pallas_gae Anakin ff_ppo with system.multistep_impl=pallas: the
                  recurrence kernel inside the learner.
   sebulba        Sebulba ff_ppo on the native C++ CartPole pool, 512 envs,
@@ -109,6 +112,8 @@ FLASH_KANANA2_VALUES = 128  # ... whose values are narrower than its queries and
 TOL_RECURRENCE = 0.0
 TOL_ATTN = 3e-2
 TOL_GRAD = 5e-2
+# float32 elementwise work on both sides: sums added up in another order
+TOL_ELEMENTWISE = 1e-5
 
 
 class CheckFailed(AssertionError):
@@ -475,8 +480,10 @@ def leg_sdar_ppo(n: int) -> Dict[str, Any]:
     through the grouped-query cache, the teacher-forced pass under the block
     mask with its layers rematerialised — its attention through the Pallas
     kernels, forward and backward (heads of 128: whole lanes), which the
-    learner the runner compiled has to hold; then `trunk_copies` and its
-    gradient as the chip runs them against the plain masked products."""
+    learner the runner compiled has to hold, q and k handed to them by the
+    norm-and-rotate kernel pair; then `trunk_copies` and its gradient as the
+    chip runs them against the plain masked products behind `rms_norm` +
+    `rope`."""
     tiny = [
         "hidden_size=128", "num_heads=4", "num_kv_heads=2", "head_dim=128", "num_experts=16",
         "experts_held=4", "experts_per_token=4", "expert_width=64", "num_layers=2",
@@ -491,6 +498,11 @@ def leg_sdar_ppo(n: int) -> Dict[str, Any]:
             "arch.evaluation_greedy=True",
         ],
         expect_kernel=True,
+    )
+    facts["stoix_tpu_qk_norm_rope"] = _forms("stoix_tpu_qk_norm_rope")
+    _require(
+        facts["stoix_tpu_qk_norm_rope"] == {"kernel": 1.0, "plain": 0.0},
+        f"stoix_tpu_qk_norm_rope: the run took {facts['stoix_tpu_qk_norm_rope']}",
     )
     facts["trunk_copies_kernel_vs_plain_rms"] = _sdar_trunk_copies_against_plain()
     return facts
@@ -529,8 +541,9 @@ def _sdar_trunk_copies_against_plain() -> Dict[str, float]:
         "sdar trunk_copies: no pallas_call traced",
     )
     got = hidden_and_gradient()
-    chosen = sdar.SdarLM.copies_attention
+    chosen, form = sdar.SdarLM.copies_attention, sdar.norm_rope_form
     sdar.SdarLM.copies_attention = lambda self, *a: {**chosen(self, *a), "kernel": 0}
+    sdar.norm_rope_form = lambda *a: "plain"
     try:
         _require(
             not _has_pallas_call(model.trunk_copies, params, clean, noisy),
@@ -538,7 +551,7 @@ def _sdar_trunk_copies_against_plain() -> Dict[str, float]:
         )
         want = hidden_and_gradient()
     finally:
-        sdar.SdarLM.copies_attention = chosen
+        sdar.SdarLM.copies_attention, sdar.norm_rope_form = chosen, form
     errors = {}
     for name in got:
         _require(bool(jnp.all(jnp.isfinite(got[name]))), f"sdar trunk_copies {name}: non-finite")
@@ -886,6 +899,37 @@ def leg_kernels(n: int) -> Dict[str, Any]:
     )
     for name, g, r in zip(("q", "k", "v", "g", "beta", "state"), got, want):
         check(f"delta_rule_update_d{name}", in_scale(g, r), in_scale(r, r), TOL_GRAD)
+
+    # 7. q's and k's per-head norm and rotation as one pass each way
+    #    (`ops/qk_norm_rope.py`) against `rms_norm` + `rope` as XLA compiles
+    #    them, both float32 elementwise: 32 heads written a head a sublane and
+    #    4 side by side, a last row tile of 4 rows, positions that repeat as
+    #    `trunk_copies` repeats them; the result and the gradients by the rows
+    #    and by the norm's weight, each over its reference's largest entry.
+    from stoix_tpu.networks.olmoe import rms_norm, rope, rope_angles
+    from stoix_tpu.ops.qk_norm_rope import qk_norm_rope
+
+    keys = jax.random.split(jax.random.PRNGKey(8), 3)
+    own = jnp.arange(132)
+    positions = jnp.broadcast_to(jnp.concatenate([own, jnp.tile(own[4:], 2)]), (2, 388))
+    for name, heads in (("q", 32), ("k", 4)):
+        x = 0.7 * jax.random.normal(keys[0], (2, 388, heads * 128))
+        gain = 1.0 + 0.1 * jax.random.normal(keys[1], (128,))
+        weight = jax.random.normal(keys[2], (2, 388, heads, 128))
+        plain = lambda x, gain: rope(
+            rms_norm(x.reshape(2, 388, heads, 128), gain, 1e-6), positions, 1e6
+        )
+        kernel = lambda x, gain: qk_norm_rope(
+            x, gain, rope_angles(positions, 128, 1e6), heads=heads, eps=1e-6
+        )
+        _require(_has_pallas_call(kernel, x, gain), "qk_norm_rope: no pallas_call traced")
+        both = lambda fn: jax.jit(lambda x, gain: (
+            fn(x, gain), jax.grad(lambda x, gain: jnp.sum(fn(x, gain) * weight), (0, 1))(x, gain)
+        ))
+        (out, got), (want_out, want) = both(kernel)(x, gain), both(plain)(x, gain)
+        check(f"qk_norm_rope_{name}", in_scale(out, want_out), in_scale(want_out, want_out), TOL_ELEMENTWISE)
+        for axis, g, r in zip(("rows", "weight"), got, want):
+            check(f"qk_norm_rope_{name}_d{axis}", in_scale(g, r), in_scale(r, r), TOL_ELEMENTWISE)
 
     return {"max_abs_error": {k: float(f"{v:.3e}") for k, v in errors.items()}}
 

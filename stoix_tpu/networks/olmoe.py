@@ -115,12 +115,18 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * weight
 
 
+def rope_angles(positions: jax.Array, head_dim: int, theta: float) -> jax.Array:
+    """The angles rotate-half turns a head by at `positions` [...]: [...,
+    head_dim], the half's frequencies twice."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    freqs = positions[..., None].astype(jnp.float32) * inv_freq
+    return jnp.concatenate([freqs, freqs], axis=-1)
+
+
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """x [..., heads, head_dim] rotated at `positions` [...] (rotate-half)."""
     head_dim = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
-    freqs = positions[..., None].astype(jnp.float32) * inv_freq
-    emb = jnp.concatenate([freqs, freqs], axis=-1)[..., None, :]  # broadcast over heads
+    emb = rope_angles(positions, head_dim, theta)[..., None, :]  # broadcast over heads
     half = head_dim // 2
     rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
     return x * jnp.cos(emb) + rotated * jnp.sin(emb)

@@ -61,13 +61,14 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from stoix_tpu.networks.olmoe import _stack, moe, rms_norm, rope
+from stoix_tpu.networks.olmoe import _stack, moe, rms_norm, rope, rope_angles
 from stoix_tpu.observability import SCOPES, annotate
 from stoix_tpu.ops.pallas_attention import (
     BLOCK_MASK_RESIDUALS,
     block_mask_attention,
     block_mask_layout,
 )
+from stoix_tpu.ops.qk_norm_rope import norm_rope_form, qk_norm_rope
 
 # A block step at block b reads the leading cache blocks of this many
 # positions that hold a committed position, not the whole cache.
@@ -105,8 +106,25 @@ def gqa_qkv(
     [..., D], positions [...] -> q [..., heads, head_dim], k and v [...,
     kv_heads, head_dim]; q and k normalised over each head's `head_dim`
     (`layer["q_norm"]`, `layer["k_norm"]`: one weight vector each), then
-    rotated. networks/lfm2.py's attention layers project through it too."""
+    rotated. Where `norm_rope_form` says `kernel` (a TPU, heads of whole lane
+    groups, sequences of a row tile or more: the update's teacher-forced
+    pass), norm and rotation are ONE Pallas pass over each projection's rows
+    where they lie (`ops/qk_norm_rope.py`), q written a head a sublane as the
+    block-mask kernels read it; elsewhere `rms_norm` + `rope`.
+    networks/lfm2.py's attention layers project through it too."""
     heads = lambda t, n: t.reshape(t.shape[:-1] + (n, head_dim))
+    if normed.ndim > 1 and norm_rope_form(normed.shape[-2], head_dim) == "kernel":
+        rows = normed.shape[-2]
+        angles = rope_angles(positions, head_dim, rope_theta).reshape(-1, rows, head_dim)
+
+        def norm_rope(projected, weight, n):
+            folded = projected.reshape(-1, rows, n * head_dim)  # (leading axes alone: no copy)
+            out = qk_norm_rope(folded, weight, angles, heads=n, eps=rms_eps)
+            return out.reshape(projected.shape[:-1] + (n, head_dim))
+
+        q = norm_rope(normed @ layer["wq"], layer["q_norm"], num_heads)
+        k = norm_rope(normed @ layer["wk"], layer["k_norm"], num_kv_heads)
+        return q, k, heads(normed @ layer["wv"], num_kv_heads)
     q = rms_norm(heads(normed @ layer["wq"], num_heads), layer["q_norm"], rms_eps)
     k = rms_norm(heads(normed @ layer["wk"], num_kv_heads), layer["k_norm"], rms_eps)
     rotate = lambda t: rope(t, positions, rope_theta)

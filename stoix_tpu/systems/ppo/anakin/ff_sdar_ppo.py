@@ -76,6 +76,7 @@ from stoix_tpu.ops import (
     truncated_generalized_advantage_estimation,
 )
 from stoix_tpu.ops.distributions import Categorical
+from stoix_tpu.ops.qk_norm_rope import norm_rope_form
 from stoix_tpu.parallel import is_coordinator
 from stoix_tpu.systems import anakin
 from stoix_tpu.systems.ppo.anakin.ff_lm_ppo import LMPPOLearnerState, held_counts
@@ -444,6 +445,16 @@ def _update_attention_gauge() -> Any:
     )
 
 
+def _norm_rope_gauge() -> Any:
+    return get_registry().gauge(
+        "stoix_tpu_qk_norm_rope",
+        "1 on the form q's and k's per-head norm and rotation take in the update's "
+        "teacher-forced pass as the learner was set up, 0 on the other: kernel (one Pallas "
+        "pass each way over the projection's rows, ops/qk_norm_rope.py) or plain (rms_norm + "
+        "rope as XLA compiles them)",
+    )
+
+
 def sequence_length(env: envs.Environment) -> int:
     """Positions of one sequence: the prompt block and the response."""
     return int(env.block_length) + int(env.length)
@@ -566,6 +577,10 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
     # network sees of the backend and the shapes), for the run's record.
     for field, value in actor.copies_attention(sequence_length(env), int(env.passes)).items():
         _update_attention_gauge().set(value, {"field": field})
+    update_positions = sequence_length(env) + int(env.passes) * int(env.length)
+    for form in ("kernel", "plain"):
+        taken = norm_rope_form(update_positions, actor.head_dim) == form
+        _norm_rope_gauge().set(float(taken), {"form": form})
 
     if is_coordinator():
         get_logger("stoix_tpu.setup").info(

@@ -21,7 +21,7 @@ from stoix_tpu.base_types import ActorCriticParams
 from stoix_tpu.envs.block_token_task import BlockTokenTask
 from stoix_tpu.networks import olmoe, sdar
 from stoix_tpu.observability import BLOCK_SCOPES, DIFFUSION_SCOPES, SCOPES, get_registry
-from stoix_tpu.ops import pallas_attention
+from stoix_tpu.ops import pallas_attention, qk_norm_rope
 from stoix_tpu.reference import sdar as reference
 from stoix_tpu.systems.ppo.anakin import ff_sdar_ppo
 from stoix_tpu.utils import config as config_lib
@@ -416,6 +416,13 @@ def _through_the_kernel(monkeypatch):
             *args, **kwargs, tile=16, interpret=True
         ),
     )
+    # and q's and k's norm and rotation through theirs (a TPU asks for heads
+    # of whole lanes and a row tile a sequence; the interpreter for neither)
+    monkeypatch.setattr(sdar, "norm_rope_form", lambda rows, head_dim: "kernel")
+    monkeypatch.setattr(
+        sdar, "qk_norm_rope",
+        lambda *args, **kwargs: qk_norm_rope.qk_norm_rope(*args, **kwargs, interpret=True),
+    )
 
 
 @pytest.mark.parametrize("what", ["hidden", "gradient", "kernels"])
@@ -441,6 +448,9 @@ def test_the_teacher_forced_pass_through_the_kernel_is_the_plain_one(monkeypatch
     if what == "kernels":
         text = jaxpr()
         assert text.count("block_mask_attention_fwd") == 2 and text.count("block_mask_attention_bwd") == 2
+        # q and k of each of the two layers: forward, rematerialised, backward
+        assert len(re.findall(r"name=qk_norm_rope\n", text)) == 8
+        assert text.count("name=qk_norm_rope_bwd\n") == 4
     elif what == "hidden":
         _close(forward(), plain)
     else:
@@ -764,6 +774,9 @@ def test_a_short_run_learns_the_block_token_task(devices):
     assert ff_sdar_ppo.LAST_RUN_STATS["update_attention"] == want
     gauge = get_registry().gauge("stoix_tpu_sdar_update_attention")
     assert {dict(labels)["field"]: int(value) for labels, value in gauge.labels_and_values()} == want
+    # and which way q's and k's norm and rotation: `rms_norm` + `rope` off a TPU
+    forms = get_registry().gauge("stoix_tpu_qk_norm_rope").labels_and_values()
+    assert {dict(labels)["form"]: value for labels, value in forms} == {"kernel": 0.0, "plain": 1.0}
 
 
 def test_the_benchmark_keeps_a_copy_of_the_reference(model):

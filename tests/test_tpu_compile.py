@@ -70,6 +70,46 @@ def test_mosaic_takes_the_block_mask_kernels_at_the_cells_shape(one_chip, what):
         assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 4 * SEQUENCES * POSITIONS * HEADS * HEAD_DIM
 
 
+def test_q_and_k_pass_from_the_projections_to_the_block_mask_kernels_as_they_lie(one_chip, monkeypatch):
+    """The gradient of `gqa_qkv` followed by `block_mask_attention` at the
+    cell's shape, steered onto the TPU's branch of `norm_rope_form`: Mosaic
+    takes `qk_norm_rope` and `qk_norm_rope_bwd` (32 | 4 heads of 128, a last
+    row tile of 4 rows), and between the projections and the score kernels
+    nothing of q's size is written but the pair's own results — no `copy`,
+    `transpose` or fusion in any spelling of q's shape, and none of the
+    rotation's halves. `rms_norm` + `rope` as XLA compiles them put six such
+    ops before the forward kernel and five after the backward one (PERF.md
+    section 6, PR 42)."""
+    from stoix_tpu.networks import sdar
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hidden = 2048
+    layer = {
+        "wq": (hidden, HEADS * HEAD_DIM), "wk": (hidden, KV_HEADS * HEAD_DIM),
+        "wv": (hidden, KV_HEADS * HEAD_DIM), "q_norm": (HEAD_DIM,), "k_norm": (HEAD_DIM,),
+    }
+    struct = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(layer, x, positions, w):
+        q, k, v = sdar.gqa_qkv(layer, x, positions, HEADS, KV_HEADS, HEAD_DIM, 1e6, 1e-6)
+        return jnp.sum(pallas_attention.block_mask_attention(q, k, v, **CELL) * w)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(
+        jax.tree.map(struct, layer, is_leaf=lambda x: isinstance(x, tuple)),
+        struct((SEQUENCES, POSITIONS, hidden)), struct((SEQUENCES, POSITIONS), jnp.int32),
+        struct((SEQUENCES, POSITIONS, HEADS * HEAD_DIM)),
+    ).lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert re.search(r"qk_norm_rope\b(?!_bwd)", text) and "qk_norm_rope_bwd" in text
+    # q's and k's pass each way, and the score kernels
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 6
+    of_q_size = r"16,1540,32,128|16,1540,4,8,128|16,49280,128|3080,8,32,128|16,1540,32,64"
+    moved = [
+        line for line in text.splitlines()
+        if re.search(rf"= f32\[({of_q_size})\]\{{[^}}]*\}} (copy|transpose|fusion)\(", line)
+    ]
+    assert not moved, moved
+
+
 # The benchmark's Ling-3 cell: a minibatch of 8 sequences of 512 tokens, 32
 # heads of 128, through a delta-rule layer's recurrence.
 @pytest.mark.parametrize("what", ["forward", "gradient"])
