@@ -43,8 +43,8 @@ binary doubling over the window instead: O(log n) shifted compositions rather
 than the reference's n unrolled vector passes.
 
 The process-wide default is set once per run from `system.multistep_impl`
-(systems/runner.py and the Sebulba learner both call `configure_from_config`
-before any learner is traced); estimators also accept an explicit `impl=`
+(`run_host.RunHost`, which both runners build first, calls
+`configure_from_config` before any learner is traced); estimators also accept an explicit `impl=`
 override. The default read is trace-time static: changing it never triggers a
 recompile of an already-traced program.
 """

@@ -107,9 +107,9 @@ def probe_backend(
     already holds the chip makes the child fail or hang — and rely on the
     child having exited before this returns: `subprocess.run` waits for it,
     and kills and reaps it on timeout. Every caller keeps that order
-    (bench.py main, systems/runner.py and both Sebulba systems probe before
-    `maybe_initialize_distributed`/mesh construction; launcher.py re-probes
-    between child incarnations)."""
+    (bench.py main probes first; both runners probe in `run_host.RunHost`, built
+    before `maybe_initialize_distributed`/mesh construction; launcher.py
+    re-probes between child incarnations)."""
     log = get_logger("stoix_tpu.resilience")
     counter = get_registry().counter(
         "stoix_tpu_preflight_probe_attempts_total",

@@ -9,7 +9,10 @@ runs over a learner-device mesh; fresh params return via the ParameterServer;
 evaluation runs asynchronously on its own device. A system file
 (`systems/*/sebulba/*.py`) hands `run_experiment` a `SebulbaSystem`: how to
 build its networks and its learner. Everything else is here, once: the actor
-thread, and `_Run`'s set-up, `learn` (the loop), `shut_down` and `close_out`.
+thread, and `_Run`'s `set_up`, `learn` (the loop), `shut_down` and `close_out`.
+What a run opens and closes on the host beside them — ledger, set-up clock,
+fault plan, compile cache, preflight, fleet, sentinel, ops plane, stop
+handling — is the run host's (`stoix_tpu/run_host.py`, docs/DESIGN.md §2.16).
 
 TPU-native differences from the reference (SURVEY.md §7.1.3):
   - the learner consumes GLOBAL arrays assembled with
@@ -49,33 +52,14 @@ from stoix_tpu.evaluator import (
     get_ff_evaluator_fn,
     get_stateful_evaluator_fn,
 )
-from stoix_tpu.observability import (
-    RunStats,
-    SetupClock,
-    flightrec,
-    get_health_monitor,
-    get_logger,
-    get_registry,
-    get_status_board,
-    goodput,
-    span,
-)
+from stoix_tpu.observability import RunStats, get_logger, get_registry, goodput, span
 from stoix_tpu.observability.trace import LAUNCH as setup_launch
-from stoix_tpu.ops import scan_kernels
 from stoix_tpu.parallel import MeshRoles
-from stoix_tpu.resilience import (
-    PreemptionHandler,
-    faultinject,
-    fleet,
-    guards,
-    integrity,
-    preflight,
-    supervisor_from_config,
-)
+from stoix_tpu.resilience import faultinject, fleet, guards, supervisor_from_config
 from stoix_tpu.resilience.errors import ComponentFailure, EvaluatorStallError
+from stoix_tpu.run_host import RunHost
 from stoix_tpu.sebulba.core import AsyncEvaluator, ParameterServer, ThreadLifetime
 from stoix_tpu.sebulba.sources import SourceContext
-from stoix_tpu.utils import compilecache
 from stoix_tpu.utils.logger import LogEvent, StoixLogger
 from stoix_tpu.utils.timing import StepAccumulator, TimingTracker
 
@@ -307,42 +291,19 @@ def _evaluator_fn(config: Any, env_factory: Callable, eval_apply: Callable, eval
 
 
 class _Run:
-    """One run of a system: set up by `__init__`, then `learn`, `shut_down`
-    (whatever happened) and `close_out`."""
+    """One run of a system: `set_up`, then `learn`, `shut_down` (whatever
+    happened, however far set-up got) and `close_out`."""
 
     def __init__(self, config: Any, system: SebulbaSystem) -> None:
-        # Goodput ledger (docs/DESIGN.md §2.13) and the set-up phases ->
-        # stoix_tpu_setup_phase_seconds{phase}, as in the Anakin runner (host
-        # memory only): both open before any set-up work, the clock until the
-        # close of `first_tick`, and it books its wall as the ledger's `setup`.
-        self.ledger = ledger = goodput.GoodputLedger().start()
-        goodput.set_active(ledger)
-        self.setup_phases = setup_phases = SetupClock(ledger)
-        # Resilience (docs/DESIGN.md §2.3): arm the chaos plan before anything is
-        # traced (the in-jit nan_loss fault binds at trace time) and resolve the
-        # divergence-guard mode for the learner loop's host-side checks.
-        faultinject.configure(config.arch.get("fault_spec"))
-        self.config = config
-        self.guard_mode = guards.resolve_mode(config)
-        # Compile economy (docs/DESIGN.md §2.7): persistent XLA cache knobs must
-        # land before the first compile, and the multistep scan-kernel default
-        # before the learner is traced.
-        compilecache.configure(config)
-        scan_kernels.configure_from_config(config)
-        # Launch hardening (docs/DESIGN.md §2.4, arch.preflight): subprocess
-        # backend probe + config cross-validation before any device work — the
-        # actor/learner device-id split below is exactly the class of config this
-        # catches (ids out of range, envs not divisible by actors).
-        pf = preflight.settings_from_config(config)
-        if pf.enabled:
-            with span("preflight", clock=setup_phases, phase="preflight"):
-                probe = preflight.probe_backend(
-                    timeout_s=pf.probe_timeout_s,
-                    attempts=pf.probe_attempts,
-                    backoff_base_s=pf.probe_backoff_base_s,
-                    backoff_max_s=pf.probe_backoff_max_s,
-                )
-                preflight.validate_config(config, device_count=probe.device_count)
+        self.host = RunHost(config, "sebulba", "sebulba-pipeline")
+        self.config, self.system = config, system
+        # What `shut_down` stops and closes, once `set_up` has built them.
+        self.logger = self.async_evaluator = self.supervisor = None
+        self.actor_threads: List[threading.Thread] = []
+
+    def set_up(self) -> None:
+        host, config, system = self.host, self.config, self.system
+        setup_phases = host.setup_phases
         # Device assignment through the unified mesh-role abstraction
         # (parallel/roles.py, docs/DESIGN.md §2.11): the actor/learner/evaluator
         # split arrives as one validated MeshRoles object (the same object the
@@ -353,6 +314,11 @@ class _Run:
             actor_devices = roles.role_devices("act")
             learner_devices = roles.role_devices("learn")
             learner_mesh = roles.learn_mesh()
+            # In a multi-host deployment the learner loop exchanges
+            # window-indexed stop votes through the jax.distributed KV store
+            # (there is no coalesced device fetch to piggyback on here) and
+            # fails collects fast on a declared partition.
+            host.open_fleet()
 
             actors_per_device = int(config.arch.actor.actor_per_device)
             num_actors = len(actor_devices) * actors_per_device
@@ -371,15 +337,13 @@ class _Run:
         with span("learner_setup", clock=setup_phases, phase="learner_setup"):
             learner, self.key = system.setup_learner(config, networks, key, learner_mesh)
             self.learner, self.learner_state = learner, learner.state
-            # State-integrity sentinel (docs/DESIGN.md §2.9, arch.integrity): Sebulba
-            # has no coalesced fetch to piggyback fingerprints on, so the learner
-            # loop checks the replicated learner state synchronously at each eval
-            # boundary (the vector is [num_learner_devices] uint32 — tiny). Off (the
-            # default) = None = unchanged loop.
-            self.sentinel = sentinel = integrity.sentinel_from_config(config)
-            if sentinel is not None:
-                sentinel.bind(learner_mesh, learner.state)
-                sentinel.install_excepthook()
+            if host.sentinel is not None:
+                # Sebulba has no coalesced fetch to piggyback fingerprints on, so
+                # the learner loop checks the replicated learner state
+                # synchronously at each eval boundary (the vector is
+                # [num_learner_devices] uint32 — tiny; docs/DESIGN.md §2.9).
+                host.sentinel.bind(learner_mesh, learner.state)
+                host.sentinel.install_excepthook()
 
         with span("evaluator_setup", clock=setup_phases, phase="evaluator_setup"):
             eval_fn = _evaluator_fn(
@@ -388,39 +352,11 @@ class _Run:
 
         with span("logger_build", clock=setup_phases, phase="logger_build"):
             self.logger = logger = StoixLogger(config)
-            # Ops plane (docs/DESIGN.md §2.13): StoixLogger's configure() just reset
-            # the health monitor and flight recorder — and started the ops HTTP
-            # server if `logger.telemetry.http.enabled` — so register THIS run's
-            # identity and heartbeat board on the fresh instances.
-            http_cfg = dict(dict(config.logger.get("telemetry") or {}).get("http") or {})
-            self.recorder = recorder = flightrec.get_flight_recorder()
-            recorder.set_context(
-                architecture="sebulba",
-                system=str(config.system.system_name),
-                seed=int(config.arch.seed),
-            )
-            self.status = status = get_status_board()
-            status.update(
-                {
-                    "run_id": f"{config.system.system_name}_seed{config.arch.seed}",
-                    "architecture": "sebulba",
-                    "system": str(config.system.system_name),
-                    "step": 0,
-                }
-            )
             self.lifetime = lifetime = ThreadLifetime()
-            # Fleet coordination (docs/DESIGN.md §2.6, arch.fleet): in a multi-host
-            # Sebulba deployment the learner loop exchanges window-indexed stop votes
-            # through the jax.distributed KV store (there is no coalesced device
-            # fetch to piggyback on here), publishes heartbeats, and fails collects
-            # fast on a declared partition. Off (default) = None = unchanged loop.
-            self.fleet = fleet_coord = fleet.fleet_from_config(config)
-            if fleet_coord is not None:
-                fleet_coord.start()
             self.timer = timer = TimingTracker()
             self.source = source = learner.make_source(
                 SourceContext(
-                    num_actors, learner_devices, learner_mesh, fleet_coord, timer, ledger,
+                    num_actors, learner_devices, learner_mesh, host.fleet, timer, host.ledger,
                     steps_per_update,
                 )
             )
@@ -429,12 +365,7 @@ class _Run:
             # pipeline, param-server and evaluator beats land on the same board so
             # the stall detector sees every component's age — and /healthz reads the
             # same board through the process-wide health monitor.
-            self.monitor = monitor = get_health_monitor()
-            monitor.register_board(
-                "sebulba-pipeline",
-                pipeline.heartbeats,
-                stale_after_s=float(http_cfg.get("stale_after_s", 60.0) or 60.0),
-            )
+            host.open_ops_plane(pipeline.heartbeats, step=0)
             self.param_server = param_server = ParameterServer(
                 actor_devices, actors_per_device, heartbeats=pipeline.heartbeats
             )
@@ -448,7 +379,7 @@ class _Run:
         # Set-up's last phase: from the first thread started to the first
         # completed learner update (the actors' first rollouts and every first
         # compile — act_fn, the learn step — are in it).
-        self.first_tick = setup_phases.open_first_tick()
+        host.first_tick = setup_phases.open_first_tick()
         self.async_evaluator = async_evaluator = AsyncEvaluator(
             eval_fn, lifetime, on_eval_result, heartbeats=pipeline.heartbeats
         )
@@ -464,7 +395,7 @@ class _Run:
         self.supervisor = supervisor = supervisor_from_config(
             config, lifetime, pipeline, param_server
         )
-        self.actor_threads = actor_threads = []
+        actor_threads = self.actor_threads
 
         def _actor_factory(actor_id: int, device) -> Callable[[], threading.Thread]:
             return lambda: threading.Thread(
@@ -488,9 +419,8 @@ class _Run:
         # Graceful preemption: SIGTERM/SIGINT stop the learner loop at the next
         # update boundary and run the orderly shutdown path (lifetime stop, queue
         # drain, evaluator drain) instead of dying mid-handoff.
-        self.preempt = PreemptionHandler().install()
+        host.watch_for_stop()
         self.evaluator_device = roles.device("evaluate")
-        self.skipped_base = guards.skipped_counter().value()
         # Written by `learn`.
         self.t_steps = 0
         self.run_start_time = 0.0  # whole-run FPS denominator (incl.
@@ -529,7 +459,8 @@ class _Run:
         `num_updates_per_eval`-th update log, submit an evaluation and hold the
         window's votes. Nothing in it asks which system or which source runs."""
         config, source, learner, timer = self.config, self.source, self.learner, self.timer
-        param_server, preempt, fleet_coord = self.param_server, self.preempt, self.fleet
+        host, param_server = self.host, self.param_server
+        preempt, fleet_coord, sentinel = host.preempt, host.fleet, host.sentinel
         updates_per_eval = int(config.arch.num_updates_per_eval)
         profile = _ProfileWindow(config)
         self.run_start_time = fleet_window_started = time.perf_counter()
@@ -540,11 +471,11 @@ class _Run:
                     learner.step, self.learner_state, batch
                 )
                 jax.block_until_ready(train_metrics)
-            self.ledger.note(goodput.SEBULBA_PHASE_MAP["learn"], timer.latest("learn"))
+            host.ledger.note(goodput.SEBULBA_PHASE_MAP["learn"], timer.latest("learn"))
             if (update_idx + 1) % source.param_sync_interval == 0:
                 param_server.distribute_params(learner.actor_params(self.learner_state))
             if update_idx == 0:
-                self.first_tick.close()
+                host.first_tick.close()
             profile.after_update(update_idx)
             source.after_update(self.learner_state)
             self.t_steps += batch.env_steps
@@ -552,7 +483,7 @@ class _Run:
             # Divergence guard, host half: count skipped updates; halt mode
             # raises DivergenceError here (metrics are already materialized
             # by the block_until_ready above — no extra sync).
-            guards.publish_guard_metrics(self.guard_mode, train_metrics, t_steps)
+            guards.publish_guard_metrics(host.guard_mode, train_metrics, t_steps)
             self.actor_metrics.drain()
             if fleet_coord is None:
                 if preempt.stop_requested():
@@ -565,11 +496,7 @@ class _Run:
                 # partition declared by the monitor raises the typed error
                 # here instead of wedging a future collective.
                 fleet_coord.check_partition()
-                if preempt.stop_requested():
-                    fleet_coord.request_stop(
-                        fleet.FLAG_PREEMPT,
-                        note=f"{preempt.signal_name} at update {update_idx}",
-                    )
+                host.vote_to_stop(f"at update {update_idx}")
 
             if (update_idx + 1) % updates_per_eval != 0:
                 continue
@@ -601,22 +528,22 @@ class _Run:
                 self.steady_start_time = time.perf_counter()
                 self.steady_start_steps = t_steps
             window_idx = (update_idx + 1) // updates_per_eval
-            self.status.update({"window": window_idx, "step": t_steps})
-            self.recorder.record(
+            host.status.update({"window": window_idx, "step": t_steps})
+            host.recorder.record(
                 "window", window=window_idx, step=t_steps,
                 updates=update_idx + 1,
                 queue_wait_s=round(timer.mean(source.wait_phase), 6),
                 learn_s=round(timer.mean("learn"), 6),
             )
             corruption = None
-            if self.sentinel is not None:
+            if sentinel is not None:
                 # Integrity check at the eval boundary (docs/DESIGN.md
                 # §2.9): synchronous fingerprint + compare of the
                 # replicated learner state. A verdict becomes this
                 # host's FLAG_CORRUPT on the window's fleet vote (so the
                 # stop reason is agreed and visible fleet-wide) and is
                 # raised below — never swallowed by the agreed break.
-                corruption = self.sentinel.check_state(self.learner_state, window_idx, t_steps)
+                corruption = sentinel.check_state(self.learner_state, window_idx, t_steps)
                 if corruption is not None and fleet_coord is not None:
                     fleet_coord.request_stop(fleet.FLAG_CORRUPT, note=str(corruption))
             if fleet_coord is not None:
@@ -648,18 +575,15 @@ class _Run:
         self.steady_end_time = time.perf_counter()
 
     def shut_down(self) -> None:
-        """Runs in `run_experiment`'s `finally`, a failure possibly propagating."""
-        self.first_tick.close()  # a run that never completed an update
-        self.preempt.uninstall()
-        goodput.set_active(None)
-        self.monitor.unregister("sebulba-pipeline")
-        if self.sentinel is not None:
-            # BEFORE fleet stop: the excepthook chain unwinds in reverse
-            # install order. Keeps the hook across a propagating corruption
-            # verdict (it must still translate to exit code 88).
-            self.sentinel.deactivate()
-        if self.fleet is not None:
-            self.fleet.stop()
+        """Runs in `run_experiment`'s `finally`, a failure possibly
+        propagating, set-up possibly unfinished."""
+        self.host.close()
+        if self.async_evaluator is not None:  # the first thread `set_up` starts
+            self._stop_threads()
+        if self.logger is not None:
+            self.logger.close()
+
+    def _stop_threads(self) -> None:
         self.lifetime.stop()
         self.param_server.shutdown()
         # Unblock actors waiting to enqueue (uninstrumented: drain gets are
@@ -690,7 +614,7 @@ class _Run:
             )
 
     def close_out(self) -> float:
-        """`LAST_RUN_STATS`, the logger's close, the last evaluation's return."""
+        """`LAST_RUN_STATS` and the last evaluation's return."""
         t_steps = self.t_steps
         if self.steady_start_time is not None and t_steps > self.steady_start_steps:
             steady = (t_steps - self.steady_start_steps) / (
@@ -715,31 +639,20 @@ class _Run:
             ).set(fps)
             LAST_RUN_STATS["fps"] = fps
             LAST_RUN_STATS["total_env_steps"] = t_steps
-        # Goodput close-out (docs/DESIGN.md §2.13): queue_wait/compute were noted
-        # per update; finalize() attributes the residual learner-loop wall (host
-        # work concurrent with actor rollouts, teardown joins) to compute per the
-        # pipelined-residual rule, so the fractions sum to 1.
-        LAST_RUN_STATS["goodput"] = self.ledger.finalize()
-        LAST_RUN_STATS["setup_phases"] = {
-            k: round(v, 6) for k, v in self.setup_phases.seconds().items()
-        }
-        LAST_RUN_STATS["launch_phases"] = self.setup_phases.launch
-        LAST_RUN_STATS.update(self.source.run_stats())
+        # queue_wait/compute were noted per update; the residual learner-loop wall
+        # (host work concurrent with actor rollouts, teardown joins) goes to
+        # compute, so the goodput fractions sum to 1.
         supervisor = self.supervisor
-        LAST_RUN_STATS["resilience"] = {
-            "update_guard": self.guard_mode,
-            "skipped_updates": guards.skipped_counter().value() - self.skipped_base,
-            "actor_restarts": supervisor.restart_count() if supervisor is not None else 0,
-            "preempted": self.preempt.stop_requested(),
-            # Sebulba has no checkpoint path yet: a preemption stops cleanly but
-            # cannot resume mid-self.
-            "resume_capable": False,
-            "fleet": self.fleet is not None,
-        }
-        LAST_RUN_STATS["integrity"] = (
-            self.sentinel.stats() if self.sentinel is not None else integrity.disabled_stats()
+        LAST_RUN_STATS.update(
+            self.host.run_stats(
+                self.host.preempt.stop_requested(),
+                actor_restarts=supervisor.restart_count() if supervisor is not None else 0,
+                # Sebulba has no checkpoint path yet: a preemption stops cleanly
+                # but cannot resume mid-run.
+                resume_capable=False,
+            )
         )
-        self.logger.close()
+        LAST_RUN_STATS.update(self.source.run_stats())
         return self.eval_results[-1] if self.eval_results else 0.0
 
 
@@ -747,16 +660,9 @@ def run_experiment(config: Any, system: SebulbaSystem) -> float:
     LAST_RUN_STATS.clear()
     run = _Run(config, system)
     try:
-        run.learn()
-    except KeyboardInterrupt:
-        # The fleet monitor interrupts the main thread when a peer dies (it
-        # may be blocked in collect_rollouts' bounded get). Convert its
-        # interrupt into the typed error — the excepthook then translates it
-        # to EXIT_CODE_FLEET_PARTITION for the supervising launcher, exactly
-        # as in the Anakin runner. A genuine operator ^C re-raises untouched.
-        if run.fleet is not None and run.fleet.partition_event.is_set():
-            raise run.fleet.partition_error from None
-        raise
+        run.set_up()
+        with run.host.interrupt_as_partition():
+            run.learn()
     finally:
         run.shut_down()
     return run.close_out()
