@@ -22,6 +22,14 @@ the benchmark compare this file with). Every layer l, no bias anywhere:
     (networks/kda.py) mixers are other models' (Kanana-2; Ling-3.0): a
     compressed row a position (`Latent`), and a matrix a head that every
     token rewrites with three convolutions' tails (`DeltaState`).
+  * `sliding_attention` is another's too (Laguna): the same
+    `GroupedQueryAttention` with `window` = `sliding_window` W: query t sees
+    the keys 0 <= t - j < W. Its decode state is a RING of W rows a sequence
+    (`WindowKV`: position t's row at t % W), not `max_len`. Beside it that
+    model gives a layer its own head count (`num_heads_per_layer`), a layer
+    KIND its own rotation (`rope_parameters`: theta, the rotated part of a
+    head, YaRN) and every attention layer a sigmoid gate a head before W_o
+    (`attention_gate`).
   * feed-forward of the first `num_dense_layers` layers (`DenseMLP`): one
     SwiGLU of width `dense_width`; of the others (`RoutedMLP`): float32
     sigmoid scores over ALL `num_experts`, the top-k CHOSEN by score +
@@ -63,7 +71,7 @@ it takes no gradient and an optimiser step leaves it as it was.
 
 from __future__ import annotations
 
-from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
+from typing import Any, Dict, Mapping, NamedTuple, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
@@ -72,11 +80,11 @@ import jax.numpy as jnp
 from stoix_tpu.networks.kda import DeltaState, KimiDeltaAttention
 from stoix_tpu.networks.mla import Latent, LatentAttention
 from stoix_tpu.networks.olmoe import (
-    _attend_cache, _stack, init_length, moe, reset_length, rms_norm, write_cache_rows,
+    Yarn, _attend_cache, _stack, init_length, moe, reset_length, rms_norm, write_cache_rows,
 )
 from stoix_tpu.networks.sdar import gqa_qkv
-from stoix_tpu.observability import SCOPES, annotate
-from stoix_tpu.ops.pallas_attention import best_attention
+from stoix_tpu.observability import SCOPES, annotate, get_registry
+from stoix_tpu.ops.pallas_attention import best_attention, gqa_decode_attention
 
 _INIT = nn.initializers.normal(0.02)
 # Room of the held experts' chunk above the pairs uniform routing lands here,
@@ -98,8 +106,16 @@ class KV(NamedTuple):
     v: jax.Array
 
 
+class WindowKV(NamedTuple):
+    # A ring: position t's row lies at t % W, and row i is live while i <=
+    # min(t, W - 1). Position-major as `KV`.
+    k: jax.Array  # [W, B, kv_heads, head_dim] float32
+    v: jax.Array
+
+
 class Lfm2Carry(NamedTuple):
-    layers: Tuple[Any, ...]  # a layer: its mixer's state, ConvTail, KV, Latent or DeltaState
+    # a layer: its mixer's state, ConvTail, KV, WindowKV, Latent or DeltaState
+    layers: Tuple[Any, ...]
     length: jax.Array  # [B] or [] int32: positions filled = the next token's position
 
 
@@ -136,9 +152,34 @@ class ShortConv(nn.Module):
         return gated @ self.out_proj, state
 
 
+def attend_rows(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array, last: jax.Array) -> jax.Array:
+    """softmax(q k^T / sqrt(head_dim)) v over the rows <= `last` ([B] or []) of
+    a growing cache or a ring [S, B, kv_heads, head_dim]: grouped queries [B,
+    kv_heads, group, head_dim] -> the same shape. On a TPU, for heads of whole
+    lane groups and rows in whole blocks of 128, the Pallas kernel
+    `gqa_decode_attention` (two small products a head a block on the MXU);
+    else `_attend_cache`, whose multiply-and-reduce is written for the one to
+    four queries a row of 64 the other stacks have (PERF.md section 6, PR 44)."""
+    if jax.default_backend() == "tpu" and q.shape[3] % 128 == 0 and cache_k.shape[0] % 128 == 0:
+        return gqa_decode_attention(q, cache_k, cache_v, jnp.broadcast_to(last, q.shape[:1]))
+    return _attend_cache(q, cache_k, cache_v, last)
+
+
+def _window_form_gauge():
+    return get_registry().gauge(
+        "stoix_tpu_window_attend_update",
+        "1 on the form a window layer's teacher-forced attention was most recently traced in, 0 "
+        "on the other: banded (the flash kernel pair, key tiles before the band not visited) or "
+        "masked (every causal pair multiplied, those outside the band masked)",
+    )
+
+
 class GroupedQueryAttention(nn.Module):
     """Causal grouped-query attention with a per-head q/k RMSNorm and RoPE.
-    Input: the operator-normed hidden state."""
+    Input: the operator-normed hidden state. With `window` W a query sees the
+    W newest keys alone and the decode state is a ring of W rows (`WindowKV`);
+    with `gate` a sigmoid gate a head, from the same input, multiplies the
+    heads' results before W_o; `rotary_dim` and `yarn` are `rope`'s."""
 
     hidden_size: int
     num_heads: int
@@ -146,7 +187,18 @@ class GroupedQueryAttention(nn.Module):
     head_dim: int
     rope_theta: float
     rms_eps: float
-    trace_scope = "attention"
+    window: Optional[int] = None
+    gate: bool = False
+    rotary_dim: Optional[int] = None
+    yarn: Optional[Yarn] = None
+
+    @property
+    def trace_scope(self) -> str:
+        return "window_mixer" if self.window else "attention"
+
+    @property
+    def attend_scope(self) -> str:
+        return "window_attend" if self.window else "attention_scores"
 
     def setup(self) -> None:
         d, q_width = self.hidden_size, self.num_heads * self.head_dim
@@ -158,6 +210,8 @@ class GroupedQueryAttention(nn.Module):
         self.wo = self.param("wo", _INIT, (q_width, d))
         self.q_norm = self.param("q_norm", ones, (self.head_dim,))
         self.k_norm = self.param("k_norm", ones, (self.head_dim,))
+        if self.gate:
+            self.wg = self.param("wg", _INIT, (d, self.num_heads))
 
     def _qkv(self, u: jax.Array, positions: jax.Array):
         layer = {
@@ -166,8 +220,12 @@ class GroupedQueryAttention(nn.Module):
         }
         return gqa_qkv(
             layer, u, positions, self.num_heads, self.num_kv_heads, self.head_dim,
-            self.rope_theta, self.rms_eps,
+            self.rope_theta, self.rms_eps, self.rotary_dim, self.yarn,
         )
+
+    def _gated(self, attended: jax.Array, u: jax.Array) -> jax.Array:
+        """attended [..., H, head_dim] times sigmoid(u W_g) a head, where `gate`."""
+        return attended * jax.nn.sigmoid(u @ self.wg)[..., None] if self.gate else attended
 
     def forward(self, u: jax.Array) -> jax.Array:
         batch, length, _ = u.shape
@@ -176,15 +234,31 @@ class GroupedQueryAttention(nn.Module):
         # key/value heads as query heads: each is repeated for its group.
         group = self.num_heads // self.num_kv_heads
         k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
-        attended = best_attention(q, k, v, causal=True)  # [B, T, heads, head_dim]
-        return attended.reshape(batch, length, -1) @ self.wo
+        if self.window:
+            banded = jax.default_backend() == "tpu" and self.window < length
+            for form, took in (("banded", banded), ("masked", not banded)):
+                _window_form_gauge().set(float(took), {"form": form})
+        with annotate(SCOPES[self.attend_scope]):
+            # [B, T, heads, head_dim]
+            attended = best_attention(q, k, v, causal=True, window=self.window)
+        return self._gated(attended, u).reshape(batch, length, -1) @ self.wo
 
-    def step(self, u: jax.Array, state: KV, length: jax.Array):
+    def step(self, u: jax.Array, state: Any, length: jax.Array):
+        """u [B, D] against a `KV` of `max_len` rows or, with `window`, a
+        `WindowKV`: the new row goes to `length % W` and the live rows are
+        those up to min(length, W - 1), in whatever order the ring holds them
+        (keys are rotated at their own positions when written)."""
         batch = u.shape[0]
         q, k, v = self._qkv(u, jnp.broadcast_to(length, (batch,)))
-        state = KV(*write_cache_rows(state.k, state.v, k, v, length))
+        at, last = length, length
+        if self.window:
+            at, last = length % self.window, jnp.minimum(length, self.window - 1)
+        state = type(state)(*write_cache_rows(state.k, state.v, k, v, at))
         grouped = q.reshape(batch, self.num_kv_heads, -1, self.head_dim)
-        attended = _attend_cache(grouped, state.k, state.v, length)
+        with annotate(SCOPES[self.attend_scope]):
+            attended = attend_rows(grouped, state.k, state.v, last)
+        if self.gate:
+            attended = self._gated(attended.reshape(batch, self.num_heads, self.head_dim), u)
         return attended.reshape(batch, -1) @ self.wo, state
 
 
@@ -292,7 +366,8 @@ class Lfm2LM(nn.Module):
 
     vocab_size: int
     hidden_size: int
-    # a layer: "conv" | "full_attention" | "latent_attention" | "delta_attention"
+    # a layer: "conv" | "full_attention" | "sliding_attention" | "latent_attention" |
+    # "delta_attention"
     layer_types: Sequence[str]
     num_dense_layers: int
     dense_width: int
@@ -326,6 +401,16 @@ class Lfm2LM(nn.Module):
     kda_lower_bound: float = -5.0
     n_group: int = 1
     topk_group: int = 1
+    # ... of a `laguna` stack: the window of its `sliding_attention` layers, a
+    # layer's own number of query heads (else `num_heads`), a layer KIND's
+    # rotation (else `rope_theta` over the whole head): {kind: {rope_theta,
+    # partial_rotary_factor, rope_type "default" | "yarn" with factor,
+    # original_max_position_embeddings, beta_fast, beta_slow,
+    # attention_factor}}, the published block as it is; `attention_gate` is
+    # then the gate of its grouped-query layers too ...
+    sliding_window: int = 0
+    num_heads_per_layer: Optional[Sequence[int]] = None
+    rope_parameters: Optional[Mapping[str, Mapping[str, Any]]] = None
     # ... and of the head: the embedding's transpose, or a matrix of its own.
     tie_word_embeddings: bool = True
 
@@ -337,13 +422,38 @@ class Lfm2LM(nn.Module):
     def routed_layers(self) -> int:
         return len(self.layer_types) - int(self.num_dense_layers)
 
-    def _mixer(self, kind: str) -> nn.Module:
+    def _rotation(self, kind: str) -> Tuple[float, Optional[int], Optional[Yarn]]:
+        """(theta, the rotated part of a head or None for all of it, YaRN's
+        keys or None) of the attention layers of `kind`."""
+        stated = dict((self.rope_parameters or {}).get(kind) or {})
+        if not stated:
+            return self.rope_theta, None, None
+        rotary_dim = int(self.head_dim * float(stated.get("partial_rotary_factor", 1.0)))
+        yarn = None
+        if stated.get("rope_type", "default") == "yarn":
+            yarn = Yarn(
+                float(stated["factor"]), int(stated["original_max_position_embeddings"]),
+                float(stated["beta_fast"]), float(stated["beta_slow"]),
+                float(stated["attention_factor"]),
+            )
+        return (
+            float(stated["rope_theta"]), None if rotary_dim == self.head_dim else rotary_dim, yarn
+        )
+
+    def _mixer(self, kind: str, index: int = 0) -> nn.Module:
+        heads = self.num_heads
+        if self.num_heads_per_layer is not None:
+            heads = int(self.num_heads_per_layer[index])
         if kind == "conv":
             return ShortConv(self.hidden_size, self.conv_kernel)
-        if kind == "full_attention":
+        if kind in ("full_attention", "sliding_attention"):
+            if kind == "sliding_attention" and self.sliding_window < 1:
+                raise ValueError("a sliding_attention layer needs sliding_window >= 1")
+            theta, rotary_dim, yarn = self._rotation(kind)
             return GroupedQueryAttention(
-                self.hidden_size, self.num_heads, self.num_kv_heads, self.head_dim,
-                self.rope_theta, self.rms_eps,
+                self.hidden_size, heads, self.num_kv_heads, self.head_dim, theta, self.rms_eps,
+                window=int(self.sliding_window) if kind == "sliding_attention" else None,
+                gate=self.attention_gate, rotary_dim=rotary_dim, yarn=yarn,
             )
         if kind == "latent_attention":
             return LatentAttention(
@@ -357,8 +467,8 @@ class Lfm2LM(nn.Module):
                 self.kda_lower_bound, self.rms_eps,
             )
         raise ValueError(
-            f"layer_types names {kind!r}: a mixer is conv, full_attention, latent_attention or "
-            "delta_attention"
+            f"layer_types names {kind!r}: a mixer is conv, full_attention, sliding_attention, "
+            "latent_attention or delta_attention"
         )
 
     def _ffn(self, index: int) -> nn.Module:
@@ -374,7 +484,7 @@ class Lfm2LM(nn.Module):
     def setup(self) -> None:
         self.embed = self.param("embed", _INIT, (self.vocab_size, self.hidden_size))
         self.layers = [
-            Block(self._mixer(kind), self._ffn(i), self.hidden_size, self.rms_eps, name=f"layer_{i}")
+            Block(self._mixer(kind, i), self._ffn(i), self.hidden_size, self.rms_eps, name=f"layer_{i}")
             for i, kind in enumerate(self.layer_types)
         ]
         self.final_norm = self.param("final_norm", nn.initializers.ones, (self.hidden_size,))
@@ -415,9 +525,10 @@ class Lfm2LM(nn.Module):
         tail = lambda: ConvTail(
             jnp.zeros((batch, self.conv_kernel - 1, self.hidden_size), jnp.float32)
         )
-        cache = lambda: jnp.zeros(
-            (max_len, batch, self.num_kv_heads, self.head_dim), jnp.float32
+        cache = lambda rows=max_len: jnp.zeros(
+            (rows, batch, self.num_kv_heads, self.head_dim), jnp.float32
         )
+        ring = int(self.sliding_window)  # rows a window layer needs, whatever `max_len`
         latent = lambda: Latent(jnp.zeros(
             (batch, max_len, self.kv_lora_rank + self.qk_rope_head_dim), jnp.float32
         ))
@@ -429,6 +540,7 @@ class Lfm2LM(nn.Module):
         )
         fresh = {
             "conv": tail, "full_attention": lambda: KV(cache(), cache()),
+            "sliding_attention": lambda: WindowKV(cache(ring), cache(ring)),
             "latent_attention": latent, "delta_attention": delta,
         }
         return Lfm2Carry(
@@ -441,7 +553,8 @@ class Lfm2LM(nn.Module):
         a sequence (zeros, 16 KB), and so is a delta layer's matrix state,
         which the next step reads whole — as zeros, where `fresh` says so
         (networks/kda.py: no pass over the matrices here); nothing of a KV
-        cache or of the latent rows beyond `length` is read."""
+        cache or of the latent rows beyond `length` is read, nor of a ring
+        beyond min(`length`, W - 1)."""
 
         def fresh(state: Any) -> Any:
             if isinstance(state, ConvTail):
@@ -463,7 +576,10 @@ class Lfm2LM(nn.Module):
             x.size * x.dtype.itemsize
             for state in carry.layers if isinstance(state, kind) for x in state
         )
-        kinds = {"conv_tail": ConvTail, "kv": KV, "latent": Latent, "delta_state": DeltaState}
+        kinds = {
+            "conv_tail": ConvTail, "kv": KV, "window_kv": WindowKV, "latent": Latent,
+            "delta_state": DeltaState,
+        }
         return {
             name: size(kind) for name, kind in kinds.items()
             if any(isinstance(state, kind) for state in carry.layers)

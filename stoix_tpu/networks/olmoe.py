@@ -41,11 +41,13 @@ trunk: the critic side of `ActorCriticParams` holds only it.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from stoix_tpu.observability import SCOPES, annotate
 from stoix_tpu.ops.pallas_attention import best_attention
@@ -115,21 +117,67 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * weight
 
 
-def rope_angles(positions: jax.Array, head_dim: int, theta: float) -> jax.Array:
+class Yarn(NamedTuple):
+    """A rotation's YaRN keys (`rope_type` `yarn`): the inverse frequencies
+    are blended by wavelength between the published ones and those divided by
+    `factor`, and cos and sin are multiplied by `attention_factor`."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+
+def yarn_ramp(rotary_dim: int, theta: float, yarn: Yarn) -> Tuple[int, int, np.ndarray]:
+    """(low, high, ramp [rotary_dim / 2]): a frequency that turns `beta` times
+    over the original positions has index c(beta) = rotary_dim ln(L0 / (2 pi
+    beta)) / (2 ln theta); low = floor(c(beta_fast)), high = ceil(c(
+    beta_slow)), and the ramp climbs from 0 at `low` to 1 at `high`. Static:
+    it does not depend on a sequence's length."""
+    at = lambda beta: rotary_dim * math.log(
+        yarn.original_max_position_embeddings / (2.0 * math.pi * beta)
+    ) / (2.0 * math.log(theta))
+    low = max(math.floor(at(yarn.beta_fast)), 0)
+    high = min(math.ceil(at(yarn.beta_slow)), rotary_dim - 1)
+    span = float(high - low) or 0.001
+    ramp = np.clip((np.arange(rotary_dim // 2, dtype=np.float32) - low) / span, 0.0, 1.0)
+    return low, high, ramp
+
+
+def rope_angles(
+    positions: jax.Array, head_dim: int, theta: float, yarn: Optional[Yarn] = None
+) -> jax.Array:
     """The angles rotate-half turns a head by at `positions` [...]: [...,
-    head_dim], the half's frequencies twice."""
+    head_dim], the half's frequencies twice. With `yarn`, frequency i is
+    (f_i / factor) ramp_i + f_i (1 - ramp_i) (`yarn_ramp`)."""
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if yarn is not None:
+        ramp = yarn_ramp(head_dim, theta, yarn)[2]
+        inv_freq = inv_freq / yarn.factor * ramp + inv_freq * (1.0 - ramp)
     freqs = positions[..., None].astype(jnp.float32) * inv_freq
     return jnp.concatenate([freqs, freqs], axis=-1)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x [..., heads, head_dim] rotated at `positions` [...] (rotate-half)."""
+def rope(
+    x: jax.Array, positions: jax.Array, theta: float, rotary_dim: Optional[int] = None,
+    yarn: Optional[Yarn] = None,
+) -> jax.Array:
+    """x [..., heads, head_dim] rotated at `positions` [...] (rotate-half).
+    With `rotary_dim` only a head's FIRST so many dims are rotated (paired
+    inside them) and the rest passes; with `yarn` the frequencies are blended
+    and cos and sin multiplied by its `attention_factor`."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        turned = rope(x[..., :rotary_dim], positions, theta, None, yarn)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     head_dim = x.shape[-1]
-    emb = rope_angles(positions, head_dim, theta)[..., None, :]  # broadcast over heads
+    emb = rope_angles(positions, head_dim, theta, yarn)[..., None, :]  # broadcast over heads
     half = head_dim // 2
     rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-    return x * jnp.cos(emb) + rotated * jnp.sin(emb)
+    if yarn is None:  # (in this order: the accepted learners' programs, op for op)
+        return x * jnp.cos(emb) + rotated * jnp.sin(emb)
+    scale = yarn.attention_factor
+    return x * (jnp.cos(emb) * scale) + rotated * (jnp.sin(emb) * scale)
 
 
 def route(

@@ -55,13 +55,13 @@ masked products of `_attend_copies`.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, NamedTuple, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from stoix_tpu.networks.olmoe import _stack, moe, rms_norm, rope, rope_angles
+from stoix_tpu.networks.olmoe import Yarn, _stack, moe, rms_norm, rope, rope_angles
 from stoix_tpu.observability import SCOPES, annotate
 from stoix_tpu.ops.pallas_attention import (
     BLOCK_MASK_RESIDUALS,
@@ -101,6 +101,7 @@ def init_cache(
 def gqa_qkv(
     layer: Dict[str, jax.Array], normed: jax.Array, positions: jax.Array, num_heads: int,
     num_kv_heads: int, head_dim: int, rope_theta: float, rms_eps: float,
+    rotary_dim: Optional[int] = None, yarn: Optional[Yarn] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Grouped-query projections with the family's per-head q/k norm: normed
     [..., D], positions [...] -> q [..., heads, head_dim], k and v [...,
@@ -111,9 +112,13 @@ def gqa_qkv(
     pass), norm and rotation are ONE Pallas pass over each projection's rows
     where they lie (`ops/qk_norm_rope.py`), q written a head a sublane as the
     block-mask kernels read it; elsewhere `rms_norm` + `rope`.
-    networks/lfm2.py's attention layers project through it too."""
+    networks/lfm2.py's attention layers project through it too; with
+    `rotary_dim` (only a head's first dims are rotated) or `yarn` (blended
+    frequencies, scaled cos and sin) the rotation is `rope`'s, on the plain
+    path: the kernel pairs a lane with the one half a HEAD away."""
     heads = lambda t, n: t.reshape(t.shape[:-1] + (n, head_dim))
-    if normed.ndim > 1 and norm_rope_form(normed.shape[-2], head_dim) == "kernel":
+    whole_head = rotary_dim in (None, head_dim) and yarn is None
+    if whole_head and normed.ndim > 1 and norm_rope_form(normed.shape[-2], head_dim) == "kernel":
         rows = normed.shape[-2]
         angles = rope_angles(positions, head_dim, rope_theta).reshape(-1, rows, head_dim)
 
@@ -127,7 +132,7 @@ def gqa_qkv(
         return q, k, heads(normed @ layer["wv"], num_kv_heads)
     q = rms_norm(heads(normed @ layer["wq"], num_heads), layer["q_norm"], rms_eps)
     k = rms_norm(heads(normed @ layer["wk"], num_kv_heads), layer["k_norm"], rms_eps)
-    rotate = lambda t: rope(t, positions, rope_theta)
+    rotate = lambda t: rope(t, positions, rope_theta, rotary_dim, yarn)
     return rotate(q), rotate(k), heads(normed @ layer["wv"], num_kv_heads)
 
 

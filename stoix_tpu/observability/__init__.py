@@ -86,6 +86,7 @@ from stoix_tpu.observability.trace import (  # noqa: F401
     LATENT_SCOPES,
     SCOPES,
     SetupClock,
+    WINDOW_SCOPES,
     annotate,
     get_recorder,
     is_enabled,

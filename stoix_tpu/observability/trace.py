@@ -85,6 +85,15 @@ SCOPES = {
     # inside it: the recurrence alone (ops/delta_rule.py) — the chunked form in the update,
     # one token against its matrix state in the decode
     "delta_rule": "delta_rule",
+    # A window layer (networks/lfm2.py, `sliding_attention`), under `rollout`,
+    # `ppo_epoch` and in the evaluator alike; a full layer beside it keeps `attention`,
+    # its attend under `attention_scores`.
+    # operator norm, q/k/v projections, q/k norm, rotation, the ring's write, the attend,
+    # the gate, W_o
+    "window_mixer": "window_mixer",
+    # inside it: the attend alone — the banded flash kernel pair in the update, the
+    # ring's read in the decode
+    "window_attend": "window_attend",
 }
 
 # The scopes of the token policy's block: only the systems built on
@@ -102,6 +111,9 @@ LATENT_SCOPES = ("latent_project", "latent_attend", "shared_expert")
 # What a delta-rule linear-attention layer adds: only a stack with a
 # `delta_attention` layer carries these.
 DELTA_SCOPES = ("delta_mixer", "delta_conv", "delta_rule")
+# What a window layer adds: only a stack with a `sliding_attention` layer
+# carries these.
+WINDOW_SCOPES = ("window_mixer", "window_attend")
 
 # Host spans that recur in steady state, by the thread that opens them. A
 # trace reduction attributes a device-idle gap to the innermost of these open

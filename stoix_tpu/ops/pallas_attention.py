@@ -51,7 +51,7 @@ path selects it.
 from __future__ import annotations
 
 import functools
-from typing import List, NamedTuple, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -113,13 +113,22 @@ def _init_carry(block_q: int, head_dim: int):
     )
 
 
-def _walk(q_tile, block_q: int, block_k: int, kv_tiles: int, kv_len: int, causal: bool, fold, carry):
+def _walk(
+    q_tile, block_q: int, block_k: int, kv_tiles: int, kv_len: int, causal: bool, fold, carry,
+    window: Optional[int] = None,
+):
     """`fold(columns of a key tile, mask or None, carry)` over the key tiles
     query tile `q_tile` sees: first tiles [0, whole), which hold only pairs
     that are allowed (every key real, and at or before the tile's first query
     when `causal`) and need no mask, then [whole, last), which hold some.
     Tiles from `last` on lie wholly in the future and are not visited. A mask
-    is [keys, queries]: the scores lie so in both kernels."""
+    is [keys, queries]: the scores lie so in both kernels.
+
+    With `window` W (causal: query t sees the keys 0 <= t - j < W) the walk
+    starts at the first tile the band reaches, the tile of key `first query
+    - W + 1`; tiles on the band's lower edge are masked, those wholly inside
+    it (at or after the tile's LAST query's oldest key) are not, those on the
+    diagonal are masked as before. Tiles before the band are not visited."""
     whole, last = kv_len // block_k, kv_tiles
     if causal:
         first = q_tile * block_q
@@ -134,14 +143,24 @@ def _walk(q_tile, block_q: int, block_k: int, kv_tiles: int, kv_len: int, causal
             mask = k_pos < kv_len  # strip the padded tail
             if causal:
                 mask = jnp.logical_and(mask, q_pos >= k_pos)
+            if window is not None:
+                mask = jnp.logical_and(mask, q_pos - k_pos < window)
         return fold(pl.ds(pl.multiple_of(j * block_k, block_k), block_k), mask, carry)
 
+    if window is not None:
+        start = jnp.maximum(first - window + 1, 0) // block_k
+        inside = jnp.maximum(first + block_q - window + block_k - 1, 0) // block_k
+        inside = jnp.clip(inside, start, whole)  # (no tile lies wholly inside a narrow band)
+        carry = jax.lax.fori_loop(start, inside, functools.partial(step, masked=True), carry)
+        carry = jax.lax.fori_loop(inside, whole, functools.partial(step, masked=False), carry)
+        return jax.lax.fori_loop(whole, last, functools.partial(step, masked=True), carry)
     carry = jax.lax.fori_loop(0, whole, functools.partial(step, masked=False), carry)
     return jax.lax.fori_loop(whole, last, functools.partial(step, masked=True), carry)
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float, block_k: int, causal: bool, kv_len: int
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float, block_k: int, causal: bool, kv_len: int,
+    window: Optional[int],
 ):
     """One query tile of some heads of one sequence, whose keys and values
     stay in VMEM across its query tiles. Every operand is [heads, width,
@@ -151,7 +170,8 @@ def _flash_kernel(
     the result [width, queries] is a plain product of the values with them."""
     heads, _, block_q = q_ref.shape
     walk = functools.partial(
-        _walk, pl.program_id(2), block_q, block_k, k_ref.shape[2] // block_k, kv_len, causal
+        _walk, pl.program_id(2), block_q, block_k, k_ref.shape[2] // block_k, kv_len, causal,
+        window=window,
     )
 
     def one_head(h, _):
@@ -162,9 +182,14 @@ def _flash_kernel(
             scores = _dot(k_ref[h, :, cols].astype(jnp.float32), q, _TN)  # [Bk, Bq]
             if mask is not None:
                 scores = jnp.where(mask, scores, _MASKED_SCORE)
-            # No row is empty in the first tile seen (a query sees key 0), so
-            # the maximum is a real score from there on and a masked one's
-            # weight is exp(-huge) = 0.
+            # A causal walk's first tile holds key 0, which every query sees,
+            # so the maximum is a real score from there on and a masked one's
+            # weight is exp(-huge) = 0. A banded walk's first tile may lie
+            # wholly before a late query's band: that query leaves it with
+            # maximum -huge, weights exp(0) = 1 and a sum of garbage, and the
+            # first tile that holds one of its keys (the band's newest key is
+            # the query's own, so there is one) scales all of it by alpha =
+            # exp(-huge - real) = 0.
             m_new = jnp.maximum(m_acc, jnp.max(scores, axis=0, keepdims=True))
             p = jnp.exp(scores - m_new)
             alpha = jnp.exp(m_acc - m_new)
@@ -188,7 +213,7 @@ def _flash_kernel(
 
 def _flash_bwd_kernel(
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref,
-    *, scale: float, block_k: int, causal: bool, kv_len: int,
+    *, scale: float, block_k: int, causal: bool, kv_len: int, window: Optional[int],
 ):
     """One query tile: dq of its queries, and its share of the sequence's dk
     and dv, which stay in VMEM (float32) across the query tiles. The weights
@@ -204,7 +229,7 @@ def _flash_bwd_kernel(
         dv_ref[...] = jnp.zeros_like(dv_ref)
 
     walk = functools.partial(
-        _walk, q_tile, block_q, block_k, k_ref.shape[2] // block_k, kv_len, causal
+        _walk, q_tile, block_q, block_k, k_ref.shape[2] // block_k, kv_len, causal, window=window
     )
     def one_head(h, _):
         q = q_ref[h].astype(jnp.float32) * scale  # [D, Bq]
@@ -282,7 +307,7 @@ def _heads_a_step(h: int, d: int, d_v: int, kv_len: int, itemsize: int) -> int:
     return max(fits, default=1)
 
 
-def _flash_call(kernel, name, causal, block_q, block_k, interpret, q, k, v, more, outs):
+def _flash_call(kernel, name, causal, block_q, block_k, interpret, q, k, v, more, outs, window=None):
     """The call both kernels share: a grid of (sequence, heads a step, query
     tile) over q, k, v [B, S, H, D | D_v] seen as [B, H, width, S]. That is
     how XLA:TPU lays a `[.., heads, width]` activation whose width is not a
@@ -292,7 +317,8 @@ def _flash_call(kernel, name, causal, block_q, block_k, interpret, q, k, v, more
     holds them to that), and only a length that is no whole number of tiles is
     copied, to pad it. `more` are further operands and `outs` the results,
     each named by kind: "q" / "v" a query tile D / D_v wide, "k" / "kv" the
-    sequence's keys D / D_v wide, "lse" a row a head."""
+    sequence's keys D / D_v wide, "lse" a row a head. `window` is the banded
+    walk's (`_walk`)."""
     b, s, h, d = q.shape
     d_v = v.shape[-1]
     block_q, block_k = _tile(block_q, s), _tile(block_k, s)
@@ -315,7 +341,9 @@ def _flash_call(kernel, name, causal, block_q, block_k, interpret, q, k, v, more
         x if kind == "lse" else lay(x, block_q) for kind, x in more
     ]
     results = pl.pallas_call(
-        functools.partial(kernel, scale=d**-0.5, block_k=block_k, causal=causal, kv_len=s),
+        functools.partial(
+            kernel, scale=d**-0.5, block_k=block_k, causal=causal, kv_len=s, window=window
+        ),
         grid=(b, h // heads, s_q // block_q),
         in_specs=[kinds[kind][0] for kind in ["q", "k", "kv"] + [kind for kind, _ in more]],
         out_specs=[kinds[kind][0] for kind in outs],
@@ -334,12 +362,12 @@ def _flash_call(kernel, name, causal, block_q, block_k, interpret, q, k, v, more
     ]
 
 
-def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
+def _flash_forward(q, k, v, causal, block_q, block_k, interpret, window=None):
     """-> (out [B, S, H, D_v], log-sum-exp [B, H / heads a step, heads a step,
     padded S] float32)."""
     return _flash_call(
         _flash_kernel, "flash_attention", causal, block_q, block_k, interpret, q, k, v, [],
-        ["v", "lse"],
+        ["v", "lse"], window,
     )
 
 
@@ -352,23 +380,23 @@ def _backward_form_gauge():
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, block_q, block_k, interpret):
-    return _flash_forward(q, k, v, causal, block_q, block_k, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, block_q, block_k, interpret, window):
+    return _flash_forward(q, k, v, causal, block_q, block_k, interpret, window)[0]
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
-    out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret)
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window):
+    out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, residuals, d_out):
+def _flash_bwd(causal, block_q, block_k, interpret, window, residuals, d_out):
     for form, took in (("pallas", 1.0), ("plain", 0.0)):
         _backward_form_gauge().set(took, {"form": form})
     q, k, v, out, lse = residuals
     dq, dk, dv = _flash_call(
         _flash_bwd_kernel, "flash_attention_bwd", causal, block_q, block_k, interpret, q, k, v,
-        [("v", out), ("v", d_out), ("lse", lse)], ["q", "k", "kv"],
+        [("v", out), ("v", d_out), ("lse", lse)], ["q", "k", "kv"], window,
     )
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
@@ -377,7 +405,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret", "window")
 )
 def flash_attention(
     q: jax.Array,
@@ -387,6 +415,7 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Fused online-softmax attention. [B, S, H, D] -> [B, S, H, D].
 
@@ -402,20 +431,30 @@ def flash_attention(
     results positions-minor, as XLA:TPU lays them between the projections
     and here (`_flash_call`); several heads a grid step (`_heads_a_step`), a
     tile chosen from the length (`_tile`), key tiles past the diagonal not
-    visited when `causal`. Operands are multiplied at DEFAULT precision and
+    visited when `causal`. With `window` W (causal only) query t sees the
+    keys 0 <= t - j < W, and in both kernels the key tiles before the band
+    are not visited either (`_walk`); a window that holds the whole sequence
+    is the causal program. Operands are multiplied at DEFAULT precision and
     accumulated in float32; the soft-max statistics are float32.
     `interpret` runs the Pallas interpreter (slow; a test asks for it).
     """
-    return _flash(q, k, v, causal, block_q, block_k, interpret)
+    if window is not None and not causal:
+        raise ValueError("a window is a band below the diagonal: it needs causal=True")
+    if window is not None and window >= q.shape[1]:
+        window = None
+    return _flash(q, k, v, causal, block_q, block_k, interpret, window)
 
 
-def best_attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False):
+def best_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False, window: Optional[int] = None
+):
     """Backend dispatch: the Pallas kernel on TPU, pure-JAX elsewhere. Both
     branches are differentiable; which one a program took is read from its
-    jaxpr (`pallas_call`), which is what chip_smoke.py checks."""
+    jaxpr (`pallas_call`), which is what chip_smoke.py checks. `window`: the
+    banded mask of both."""
     if jax.default_backend() == "tpu":
-        return flash_attention(q, k, v, causal=causal)
-    return full_attention(q, k, v, causal=causal)
+        return flash_attention(q, k, v, causal=causal, window=window)
+    return full_attention(q, k, v, causal=causal, window=window)
 
 
 def _flash_chunk_kernel(
@@ -1154,3 +1193,111 @@ def latent_decode_attention(
         name="latent_decode_attention",
         interpret=interpret,
     )(lengths, longest, q, rows)
+
+
+# --------------------------------------------------------------------------- #
+# Grouped-query attention's decode: a group's queries against ONE row a
+# position a key/value head, over a growing cache or a ring
+# --------------------------------------------------------------------------- #
+
+
+def _gqa_decode_kernel(
+    last_ref, longest_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+    *, scale: float, block: int, sequences: int, kv_heads: int,
+):
+    """One block of `block` rows of `sequences` sequences' caches: for every
+    key/value head its group's scores against the block's keys, the online
+    softmax, and the weights times the block's values. Blocks past the last
+    live row of these sequences are neither fetched anew (the index map
+    repeats the last live block) nor computed."""
+    first, j = pl.program_id(0) * sequences, pl.program_id(1)
+    group = q_ref.shape[2]
+
+    @pl.when(j == 0)
+    def _start():
+        m_scr[...] = jnp.full_like(m_scr, _MASKED_SCORE)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j * block <= longest_ref[pl.program_id(0)])
+    def _fold():
+        at = j * block + jax.lax.broadcasted_iota(jnp.int32, (group, block), 1)
+        row_at = j * block + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+        for n in range(sequences):
+            last = last_ref[first + n]
+            for g in range(kv_heads):
+                # A row past the live ones was never written and may hold
+                # anything (a NaN times a weight of 0 is a NaN): it counts as zeros.
+                keys = jnp.where(row_at <= last, k_ref[:, n, g, :], 0.0)  # [block, head_dim]
+                values = jnp.where(row_at <= last, v_ref[:, n, g, :], 0.0)
+                scores = _dot(q_ref[n, g], keys, _NT) * scale  # [group, block]
+                scores = jnp.where(at <= last, scores, _MASKED_SCORE)
+                m_prev = m_scr[n, g]
+                m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+                p = jnp.exp(scores - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_scr[n, g] = alpha * l_scr[n, g] + jnp.sum(p, axis=1, keepdims=True)
+                acc_scr[n, g] = alpha * acc_scr[n, g] + _dot(p, values, _NN)
+                m_scr[n, g] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+def gqa_decode_attention(
+    q: jax.Array, cache_k: jax.Array, cache_v: jax.Array, last: jax.Array, *,
+    block: int = 128, interpret: bool = False,
+) -> jax.Array:
+    """softmax(q k^T / sqrt(head_dim)) v over each sequence's rows <= `last`
+    [B] (its last live row): q [B, kv_heads, group, head_dim] grouped queries
+    against caches [S, B, kv_heads, head_dim] (position-major, a growing cache
+    or a ring: the rows' order does not matter) -> [B, kv_heads, group,
+    head_dim].
+
+    The decode of grouped-query attention at 6 or 8 queries a key/value head:
+    a head's scores and values are two small matrix products a block ([group,
+    d] x [d, block] and [group, block] x [block, d]) on the MXU, and the
+    kernel is bound by reading the float32 rows once, the live blocks alone —
+    `networks/olmoe.py::_attend_cache`'s multiply-and-reduce does the same
+    sums on the vector unit, which at one to four queries a row is free
+    beside the read and at eight is not. Operands are multiplied as they
+    come, at DEFAULT precision, and accumulated in float32; the softmax is
+    float32, online over the blocks. `interpret` runs the Pallas interpreter
+    (a test asks for it)."""
+    batch, kv_heads, group, head_dim = q.shape
+    max_len = cache_k.shape[0]
+    if max_len % block:
+        raise ValueError(f"blocks of {block} rows do not tile a cache of {max_len}")
+    sequences = _LATENT_SEQUENCES if batch % _LATENT_SEQUENCES == 0 else 1
+    last = last.astype(jnp.int32)
+    longest = jnp.max(last.reshape(batch // sequences, sequences), axis=1)  # a grid step's sequences
+    kernel = functools.partial(
+        _gqa_decode_kernel, scale=head_dim**-0.5, block=block, sequences=sequences, kv_heads=kv_heads
+    )
+    rows = pl.BlockSpec(
+        (block, sequences, kv_heads, head_dim),
+        lambda b, j, last_ref, longest_ref: (jnp.minimum(j, longest_ref[b] // block), b, 0, 0),
+    )
+    heads = pl.BlockSpec((sequences, kv_heads, group, head_dim), lambda b, j, *_: (b, 0, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(batch // sequences, max_len // block),
+            in_specs=[heads, rows, rows],
+            out_specs=heads,
+            scratch_shapes=[
+                pltpu.VMEM((sequences, kv_heads, group, 1), jnp.float32),
+                pltpu.VMEM((sequences, kv_heads, group, 1), jnp.float32),
+                pltpu.VMEM((sequences, kv_heads, group, head_dim), jnp.float32),
+            ],
+        ),
+        out_shape=_out_struct(q.shape, q.dtype, q, cache_k, cache_v),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT
+        ),
+        name="gqa_decode_attention",
+        interpret=interpret,
+    )(last, longest, q, cache_k, cache_v)
+

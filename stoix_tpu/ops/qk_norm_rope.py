@@ -55,6 +55,10 @@ _LANES, _SUBLANES = 128, 8
 # twice each for the pipeline, inside the 16 MiB a kernel gets unasked (256
 # rows with 32 heads unrolled were not).
 _ROWS = 128
+# A row tile up to this many bytes (32 heads of 128) runs in the VMEM a kernel
+# gets unasked; a wider one (64 heads: 4 MiB in, out and, backward, the
+# cotangent, each held twice) asks for twelve tiles' worth.
+_UNASKED_TILE_BYTES = 2 * 1024 * 1024
 
 
 def norm_rope_form(rows: int, head_dim: int) -> str:
@@ -131,6 +135,7 @@ def _call(kernel, name, spec, x, operands, kinds, outs):
     batch, rows, width = x.shape
     head_dim = width // heads
     tiles = pl.cdiv(rows, _ROWS)
+    tile_bytes = _ROWS * width * 4
     shapes = {
         "rows": x.shape, "result": _result_shape(x.shape, heads),
         "partial": (batch, tiles, _SUBLANES, head_dim),
@@ -149,7 +154,10 @@ def _call(kernel, name, spec, x, operands, kinds, outs):
         in_specs=[blocks[kind] for kind in kinds],
         out_specs=[blocks[kind] for kind in outs],
         out_shape=[_out_struct(shapes[kind], jnp.float32, *operands) for kind in outs],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            **({"vmem_limit_bytes": 12 * tile_bytes} if tile_bytes > _UNASKED_TILE_BYTES else {}),
+        ),
         name=name,
         interpret=interpret,
     )(*operands)

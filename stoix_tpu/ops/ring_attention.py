@@ -39,16 +39,23 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 
 def full_attention(
-    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False
+    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Plain softmax attention. [B, S, H, D] -> [B, S, H, D]; with values of
-    another head size D_v than q's and k's, [B, S, H, D_v] (the scale is q's)."""
+    another head size D_v than q's and k's, [B, S, H, D_v] (the scale is q's).
+    With `window` W (causal only) query t sees the keys 0 <= t - j < W: the
+    banded mask."""
     scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         s_q, s_k = scores.shape[-2], scores.shape[-1]
         mask = jnp.tril(jnp.ones((s_q, s_k), bool))
+        if window is not None and window < s_k:
+            mask = mask & ~jnp.tril(jnp.ones((s_q, s_k), bool), -window)
         scores = jnp.where(mask, scores, -jnp.inf)
+    elif window is not None:
+        raise ValueError("a window is a band below the diagonal: it needs causal=True")
     weights = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 

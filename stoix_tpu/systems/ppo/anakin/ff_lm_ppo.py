@@ -1,7 +1,8 @@
 """Anakin PPO with a token policy: a decoder (`network=olmoe`: OLMoE blocks
-and a KV cache; `network=lfm2_moe`, `kanana2_moe`, `ling3_flash_moe`:
-networks/lfm2.py's stack of convolution, attention, latent-attention and
-delta-rule layers and a carry of each kind of state) acts step by step
+and a KV cache; `network=lfm2_moe`, `kanana2_moe`, `ling3_flash_moe`,
+`laguna_xs2_moe`: networks/lfm2.py's stack of convolution, full and window
+attention, latent-attention and delta-rule layers and a carry of each kind
+of state) acts step by step
 through its carry in the rollout and is updated teacher-forced over whole
 sequences.
 
@@ -330,9 +331,10 @@ def _carry_gauge() -> Any:
     return get_registry().gauge(
         "stoix_tpu_lm_carry_bytes",
         "bytes of the token policy's decode carry on one shard as the learner was set up, by "
-        "kind of state: kv (keys and values, a row a position), conv_tail (a short "
-        "convolution's last inputs), latent (latent attention's compressed rows) or delta_state "
-        "(a delta-rule layer's matrix a head and its convolutions' last inputs)",
+        "kind of state: kv (keys and values, a row a position), window_kv (a window layer's ring "
+        "of sliding_window rows), conv_tail (a short convolution's last inputs), latent (latent "
+        "attention's compressed rows) or delta_state (a delta-rule layer's matrix a head and its "
+        "convolutions' last inputs)",
     )
 
 

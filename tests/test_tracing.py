@@ -21,6 +21,7 @@ from stoix_tpu.base_types import (
 )
 from stoix_tpu.observability import (
     BLOCK_SCOPES, DELTA_SCOPES, DIFFUSION_SCOPES, HYBRID_SCOPES, LATENT_SCOPES, SCOPES,
+    WINDOW_SCOPES,
 )
 from stoix_tpu.utils import config as config_lib
 
@@ -128,7 +129,8 @@ def program_scopes(devices):
     # (the token policies' scopes: tests/test_lm_ppo.py, tests/test_sdar_ppo.py and
     # tests/test_lfm2_ppo.py, tests/test_kanana2_ppo.py, each on its own learner)
     [("anakin_learner", key) for key in sorted(SCOPES)
-     if key not in BLOCK_SCOPES + DELTA_SCOPES + DIFFUSION_SCOPES + HYBRID_SCOPES + LATENT_SCOPES]
+     if key not in BLOCK_SCOPES + DELTA_SCOPES + DIFFUSION_SCOPES + HYBRID_SCOPES + LATENT_SCOPES
+     + WINDOW_SCOPES]
     + [("sebulba_learner", key)
        for key in ("gae", "update_epoch", "update_minibatch", "minibatch_shuffle")]
     + [("sebulba_act_fn", "rollout_policy")],
