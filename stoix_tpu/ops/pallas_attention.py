@@ -1201,17 +1201,28 @@ def latent_decode_attention(
 # --------------------------------------------------------------------------- #
 
 
+# Sequences a grid step: a block of 128 rows of 8 sequences is 4 MiB of keys
+# and 4 MiB of values, each held twice while the next is fetched.
+_GQA_SEQUENCES = 8
+
+
 def _gqa_decode_kernel(
     last_ref, longest_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     *, scale: float, block: int, sequences: int, kv_heads: int,
 ):
-    """One block of `block` rows of `sequences` sequences' caches: for every
-    key/value head its group's scores against the block's keys, the online
-    softmax, and the weights times the block's values. Blocks past the last
-    live row of these sequences are neither fetched anew (the index map
-    repeats the last live block) nor computed."""
+    """One block of `block` rows of `sequences` sequences' caches. A sequence's
+    block [block, kv_heads, head_dim] is read as it lies, as [block * kv_heads,
+    head_dim] (column `r * kv_heads + g` is row r of key/value head g: with
+    eight heads every (8, 128) tile stays where it is), so ALL its query heads
+    take one product with the keys, an online softmax over the columns and one
+    product with the values; a query head keeps the columns of its own
+    key/value head up to the last live row, every other column is masked and
+    weighs exactly 0. Blocks past the last live row of these sequences are
+    neither fetched anew (the index map repeats the last live block) nor
+    computed."""
     first, j = pl.program_id(0) * sequences, pl.program_id(1)
-    group = q_ref.shape[2]
+    heads, head_dim = q_ref.shape[1:]
+    columns = block * kv_heads
 
     @pl.when(j == 0)
     def _start():
@@ -1221,24 +1232,28 @@ def _gqa_decode_kernel(
 
     @pl.when(j * block <= longest_ref[pl.program_id(0)])
     def _fold():
-        at = j * block + jax.lax.broadcasted_iota(jnp.int32, (group, block), 1)
-        row_at = j * block + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+        # Once a grid step: a query head's own columns by their number, the
+        # others by one no sequence reaches.
+        column = jax.lax.broadcasted_iota(jnp.int32, (heads, columns), 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, (heads, columns), 0)
+        own = jax.lax.rem(column, kv_heads) == jax.lax.div(head, heads // kv_heads)
+        own_column = jnp.where(own, column, np.iinfo(np.int32).max)
+        row = jax.lax.broadcasted_iota(jnp.int32, (block, 1, 1), 0)
         for n in range(sequences):
-            last = last_ref[first + n]
-            for g in range(kv_heads):
-                # A row past the live ones was never written and may hold
-                # anything (a NaN times a weight of 0 is a NaN): it counts as zeros.
-                keys = jnp.where(row_at <= last, k_ref[:, n, g, :], 0.0)  # [block, head_dim]
-                values = jnp.where(row_at <= last, v_ref[:, n, g, :], 0.0)
-                scores = _dot(q_ref[n, g], keys, _NT) * scale  # [group, block]
-                scores = jnp.where(at <= last, scores, _MASKED_SCORE)
-                m_prev = m_scr[n, g]
-                m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
-                p = jnp.exp(scores - m_new)
-                alpha = jnp.exp(m_prev - m_new)
-                l_scr[n, g] = alpha * l_scr[n, g] + jnp.sum(p, axis=1, keepdims=True)
-                acc_scr[n, g] = alpha * acc_scr[n, g] + _dot(p, values, _NN)
-                m_scr[n, g] = m_new
+            live = last_ref[first + n] + 1 - j * block  # this block's live rows (0 or fewer: none)
+            # A row past the live ones was never written and may hold
+            # anything (a NaN times a weight of 0 is a NaN): it counts as zeros.
+            keys = jnp.where(row < live, k_ref[:, n], 0.0).reshape(columns, head_dim)
+            values = jnp.where(row < live, v_ref[:, n], 0.0).reshape(columns, head_dim)
+            scores = _dot(q_ref[n], keys, _NT) * scale  # [heads, columns]
+            scores = jnp.where(own_column < live * kv_heads, scores, _MASKED_SCORE)
+            m_prev = m_scr[n]
+            m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+            p = jnp.exp(scores - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[n] = alpha * l_scr[n] + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[n] = alpha * acc_scr[n] + _dot(p, values, _NN)
+            m_scr[n] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
@@ -1255,21 +1270,31 @@ def gqa_decode_attention(
     or a ring: the rows' order does not matter) -> [B, kv_heads, group,
     head_dim].
 
-    The decode of grouped-query attention at 6 or 8 queries a key/value head:
-    a head's scores and values are two small matrix products a block ([group,
-    d] x [d, block] and [group, block] x [block, d]) on the MXU, and the
-    kernel is bound by reading the float32 rows once, the live blocks alone —
+    The decode of grouped-query attention at 6 or 8 queries a key/value head.
+    A grid step fetches `block` rows of `_GQA_SEQUENCES` sequences; a
+    sequence's rows [block, kv_heads, head_dim] are read as they lie, as
+    [block * kv_heads, head_dim] — at eight key/value heads each (8, 128)
+    tile is one row's heads and stays where it is — so all the query heads
+    take ONE product with the keys and one with the values on the MXU, under
+    a mask that leaves a head its own key/value head's live rows, and the
+    kernel is bound by reading the float32 rows once, the live blocks alone.
+    Reading one key/value head at a time (`k_ref[:, n, g, :]`: one sublane of
+    each of 128 tiles, 64 such operands a grid step) took longer than the
+    block's DMA: a ring of 512 rows at 32 sequences, written and attended as
+    a scan's carry, took 0.222 ms a step and takes 0.195, of which 0.164 are
+    its bytes at the chip's peak (PERF.md section 6, PR 45).
     `networks/olmoe.py::_attend_cache`'s multiply-and-reduce does the same
     sums on the vector unit, which at one to four queries a row is free
-    beside the read and at eight is not. Operands are multiplied as they
-    come, at DEFAULT precision, and accumulated in float32; the softmax is
-    float32, online over the blocks. `interpret` runs the Pallas interpreter
-    (a test asks for it)."""
+    beside the read and at eight is not (0.633). Operands are multiplied as
+    they come, at DEFAULT precision, and accumulated in float32; the softmax
+    is float32, online over the blocks. `interpret` runs the Pallas
+    interpreter (a test asks for it)."""
     batch, kv_heads, group, head_dim = q.shape
+    heads = kv_heads * group
     max_len = cache_k.shape[0]
     if max_len % block:
         raise ValueError(f"blocks of {block} rows do not tile a cache of {max_len}")
-    sequences = _LATENT_SEQUENCES if batch % _LATENT_SEQUENCES == 0 else 1
+    sequences = _GQA_SEQUENCES if batch % _GQA_SEQUENCES == 0 else 1
     last = last.astype(jnp.int32)
     longest = jnp.max(last.reshape(batch // sequences, sequences), axis=1)  # a grid step's sequences
     kernel = functools.partial(
@@ -1279,25 +1304,25 @@ def gqa_decode_attention(
         (block, sequences, kv_heads, head_dim),
         lambda b, j, last_ref, longest_ref: (jnp.minimum(j, longest_ref[b] // block), b, 0, 0),
     )
-    heads = pl.BlockSpec((sequences, kv_heads, group, head_dim), lambda b, j, *_: (b, 0, 0, 0))
-    return pl.pallas_call(
+    queries = pl.BlockSpec((sequences, heads, head_dim), lambda b, j, *_: (b, 0, 0))
+    attended = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(batch // sequences, max_len // block),
-            in_specs=[heads, rows, rows],
-            out_specs=heads,
+            in_specs=[queries, rows, rows],
+            out_specs=queries,
             scratch_shapes=[
-                pltpu.VMEM((sequences, kv_heads, group, 1), jnp.float32),
-                pltpu.VMEM((sequences, kv_heads, group, 1), jnp.float32),
-                pltpu.VMEM((sequences, kv_heads, group, head_dim), jnp.float32),
+                pltpu.VMEM((sequences, heads, 1), jnp.float32),
+                pltpu.VMEM((sequences, heads, 1), jnp.float32),
+                pltpu.VMEM((sequences, heads, head_dim), jnp.float32),
             ],
         ),
-        out_shape=_out_struct(q.shape, q.dtype, q, cache_k, cache_v),
+        out_shape=_out_struct((batch, heads, head_dim), q.dtype, q, cache_k, cache_v),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT
         ),
         name="gqa_decode_attention",
         interpret=interpret,
-    )(last, longest, q, cache_k, cache_v)
-
+    )(last, longest, q.reshape(batch, heads, head_dim), cache_k, cache_v)
+    return attended.reshape(q.shape)

@@ -388,15 +388,21 @@ def test_the_gate_multiplies_each_heads_result_in_both_entry_points(window):
         _close(out, got[:, t])
 
 
-@pytest.mark.parametrize("rows,batch,group", [(256, 8, 6), (128, 3, 8)])
+def _caches(key, rows, batch, kv_heads):
+    return tuple(jax.random.normal(k, (rows, batch, kv_heads, 128)) for k in jax.random.split(key))
+
+
+# (rows, sequences, key/value heads, group): two heads at eight and at one sequence a grid step, and
+# the cell's layout — eight heads, a sublane tile, at both its groups — with two grid rows turning.
+@pytest.mark.parametrize("rows,batch,kv_heads,group", [(256, 8, 2, 6), (128, 3, 2, 8), (256, 16, 8, 6), (256, 16, 8, 8)])
 @pytest.mark.parametrize("last", ["apart", "full", "one_row"])
-def test_the_decode_kernel_is_the_plain_attend(rows, batch, group, last):
+def test_the_decode_kernel_is_the_plain_attend(rows, batch, kv_heads, group, last):
     """`gqa_decode_attention` (Pallas interpreter) against `_attend_cache`:
     sequences at different live rows, every row live (a wrapped ring), one row
     live; rows past the live ones may hold anything."""
-    keys = jax.random.split(jax.random.PRNGKey(8), 3)
-    q = jax.random.normal(keys[0], (batch, 2, group, 128))
-    cache_k, cache_v = (jax.random.normal(key, (rows, batch, 2, 128)) for key in keys[1:])
+    keys = jax.random.split(jax.random.PRNGKey(8))
+    q = jax.random.normal(keys[0], (batch, kv_heads, group, 128))
+    cache_k, cache_v = _caches(keys[1], rows, batch, kv_heads)
     lasts = {
         "apart": jnp.arange(batch) * 37 % rows, "full": jnp.full((batch,), rows - 1),
         "one_row": jnp.zeros((batch,), jnp.int32),
@@ -408,6 +414,28 @@ def test_the_decode_kernel_is_the_plain_attend(rows, batch, group, last):
         q, poisoned(cache_k), poisoned(cache_v), lasts, interpret=True
     )
     _close(got, want)
+
+
+@pytest.mark.parametrize("others", ["huge", "other_rows"])
+@pytest.mark.parametrize("group", [6, 8])
+def test_a_heads_result_is_of_its_own_key_value_head_alone(group, others):
+    """The kernel multiplies a sequence's queries with the rows of ALL its
+    key/value heads at once and masks what is not a head's own: whatever the
+    other heads' LIVE rows hold — 1e30, which a weight of exactly 0 must meet,
+    or other rows — a head's result is bit for bit what it was."""
+    rows, batch, kv_heads, head = 256, 8, 8, 3
+    keys = jax.random.split(jax.random.PRNGKey(9), 3)
+    q = jax.random.normal(keys[0], (batch, kv_heads, group, 128))
+    cache_k, cache_v = _caches(keys[1], rows, batch, kv_heads)
+    lasts = jnp.arange(batch) * 37 % rows
+    attend = lambda k, v: pallas_attention.gqa_decode_attention(q, k, v, lasts, interpret=True)
+    before = attend(cache_k, cache_v)
+    other_k, other_v = _caches(keys[2], rows, batch, kv_heads) if others == "other_rows" else (1e30, -1e30)
+    own = (jnp.arange(kv_heads) == head)[None, None, :, None]
+    after = attend(jnp.where(own, cache_k, other_k), jnp.where(own, cache_v, other_v))
+    np.testing.assert_array_equal(np.asarray(after[:, head]), np.asarray(before[:, head]))
+    _close(before, olmoe._attend_cache(q, cache_k, cache_v, lasts))
+    assert bool(jnp.isfinite(after).all())
 
 
 def test_the_decode_takes_the_kernel_on_the_chip_for_heads_of_whole_lanes(monkeypatch):

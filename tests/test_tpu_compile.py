@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from stoix_tpu.networks import lfm2
 from stoix_tpu.ops import pallas_attention
 from stoix_tpu.utils import config as config_lib
 
@@ -312,3 +313,26 @@ def test_the_update_attention_reads_its_operands_where_they_lie(one_chip, monkey
         if re.search(r"= f32\[16,(512,\d+(,\d+)?|\d+,\d+,512)\]\{[^}]*\} copy\(", line)
     ]
     assert len(copies) == (4 if cell == "olmoe" else 0)
+
+
+# The benchmark's window-and-full cell (Laguna-XS.2): three window layers of
+# 64 query heads against a ring of 512 rows and two full layers of 48 against a
+# growing cache of 1,024, 8 key/value heads of 128, 32 sequences in the rollout
+# and 16 in the evaluator.
+GQA_DECODE_SHAPES = [(512, 32, 8), (512, 16, 8), (1024, 32, 6), (1024, 16, 6)]
+
+
+@pytest.mark.parametrize("rows,sequences,group", GQA_DECODE_SHAPES)
+def test_mosaic_takes_the_decode_kernel_at_the_cells_shapes(one_chip, monkeypatch, rows, sequences, group):
+    """`attend_rows` steered onto the TPU's branch: one `gqa_decode_attention`
+    call under the name the benchmark finds it by, the caches read where they
+    lie (a sequence's block is whole tiles, so nothing of a cache's size is
+    copied or laid out anew around the kernel)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    attend = lambda q, k, v: lfm2.attend_rows(q, k, v, jnp.int32(rows - 1))
+    cache = (rows, sequences, 8, 128)
+    text = _compiled(attend, one_chip, (sequences, 8, group, 128), cache, cache).as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and "gqa_decode_attention" in calls[0]
+    assert not re.findall(rf"= [a-z0-9]+\[{rows},{sequences},8,128\]\{{[^}}]*\}} (copy|transpose)\(", text)
+    assert not re.search(rf"bf16\[{rows},{sequences},8,128\]", text)  # no lower-precision copy of the rows
