@@ -9,7 +9,7 @@ import functools
 from typing import Any, Callable, NamedTuple
 
 import jax
-import jax.numpy as jnp
+import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -146,26 +146,40 @@ def actor_critic_system(
     -> the jitted update over `CoreLearnerState`; `make_source(ctx)` -> the
     batch source that feeds it."""
 
+    # Networks and learner state are each the output of ONE jitted program:
+    # built op by op they were some 90 eager compilations, none of them long
+    # enough for the persistent cache to keep.
     def init_networks(config: Any, probe_envs: Any, key: jax.Array):
         actor, critic = networks_builder(config, probe_envs)
-        key, a_key, c_key = jax.random.split(key, 3)
-        obs0 = jax.tree.map(lambda x: jnp.asarray(x), probe_envs.reset(seed=0).observation)
-        params = ActorCriticParams(actor.init(a_key, obs0), critic.init(c_key, obs0))
+        # On the host: a JAX twin's observation is committed to its CPU device,
+        # and the init would follow it there.
+        obs0 = jax.tree.map(np.asarray, probe_envs.reset(seed=0).observation)
+
+        @jax.jit
+        def init_params(key: jax.Array, obs0: Any):
+            key, a_key, c_key = jax.random.split(key, 3)
+            return ActorCriticParams(actor.init(a_key, obs0), critic.init(c_key, obs0)), key
+
+        params, key = init_params(key, obs0)
         return (actor, critic, params, obs0), key
 
     def setup_learner(config: Any, networks: Any, key: jax.Array, learner_mesh: Any):
         actor, critic, params, obs0 = networks
         actor_optim = _adam(float(config.system.actor_lr), config)
         critic_optim = _adam(float(config.system.critic_lr), config)
-        opt_states = ActorCriticOptStates(
-            actor_optim.init(params.actor_params), critic_optim.init(params.critic_params)
-        )
+        # Eager: the key handed back stays where the runner's own splits are.
         key, learn_key = jax.random.split(key)
-        obs0_single = jax.tree.map(lambda x: jnp.asarray(x)[0], obs0.agent_view)
-        obs_stats = running_statistics.init_state(obs0_single)
-        state = jax.device_put(
-            CoreLearnerState(params, opt_states, learn_key, obs_stats),
-            NamedSharding(learner_mesh, P()),
+
+        def init_state(params: ActorCriticParams, learn_key: jax.Array) -> CoreLearnerState:
+            opt_states = ActorCriticOptStates(
+                actor_optim.init(params.actor_params), critic_optim.init(params.critic_params)
+            )
+            obs0_single = jax.tree.map(lambda x: x[0], obs0.agent_view)
+            obs_stats = running_statistics.init_state(obs0_single)
+            return CoreLearnerState(params, opt_states, learn_key, obs_stats)
+
+        state = jax.jit(init_state, out_shardings=NamedSharding(learner_mesh, P()))(
+            params, learn_key
         )
         learn_step = learn_step_builder(
             actor.apply, critic.apply, (actor_optim.update, critic_optim.update),

@@ -75,13 +75,36 @@ def make_step_keys(key: jax.Array, mesh: Mesh, config: Any) -> jax.Array:
     return jax.random.split(key, n_shards * update_batch).reshape(n_shards, update_batch, -1)
 
 
-def place_learner_state(learner_state: Any, mesh: Mesh, state_specs: Any) -> Any:
-    """Device-put the state pytree with per-subtree PartitionSpecs."""
-    shardings = jax.tree.map(
+def _state_shardings(mesh: Mesh, state_specs: Any) -> Any:
+    return jax.tree.map(
         lambda spec: NamedSharding(mesh, spec), state_specs,
         is_leaf=lambda s: isinstance(s, P),
     )
-    return jax.device_put(learner_state, shardings)
+
+
+def place_learner_state(learner_state: Any, mesh: Mesh, state_specs: Any) -> Any:
+    """Device-put the state pytree with per-subtree PartitionSpecs."""
+    return jax.device_put(learner_state, _state_shardings(mesh, state_specs))
+
+
+def build_learner_state(
+    init_fn: Callable[[jax.Array], Any], key: jax.Array, mesh: Mesh, state_specs: Any
+) -> Any:
+    """`init_fn(key)` as ONE jitted program whose outputs are made on their
+    per-subtree PartitionSpecs: one compilation where an eager construction
+    makes one an op (some 180 for an MLP PPO state), each device computes its
+    own shards, and no host-built copy is held beside the placed one.
+
+    The program runs once, so nothing folded at compile time pays for itself,
+    and XLA's constant folding evaluates on the host whatever an env's reset
+    derives from constants alone, for the whole batch of envs: 22 of the 24
+    seconds of the program's first compilation at 262,144 Ant envs on a v5e's
+    host. It is compiled without that pass."""
+    return jax.jit(
+        init_fn,
+        out_shardings=_state_shardings(mesh, state_specs),
+        compiler_options={"xla_disable_hlo_passes": "constant_folding"},
+    )(key)
 
 
 def shardmap_learner(

@@ -52,7 +52,7 @@ from stoix_tpu.ops import (
 )
 from stoix_tpu.parallel import is_coordinator
 from stoix_tpu.resilience import guards
-from stoix_tpu.utils import config as config_lib
+from stoix_tpu.utils import compilecache, config as config_lib
 from stoix_tpu.utils.jax_utils import count_parameters
 from stoix_tpu.systems.runner import AnakinSetup, run_anakin_experiment
 from stoix_tpu.utils.training import make_learning_rate
@@ -501,16 +501,6 @@ def learner_setup(
         optax.adam(critic_lr, eps=1e-5),
     )
 
-    key, actor_key, critic_key, env_key = jax.random.split(keys, 4)
-    # The runner times this whole function as set-up's `learner_setup`; the
-    # span marks the network-init part of it in a profiler trace.
-    with span("network_init"):
-        dummy_obs = jax.tree.map(lambda x: x[None], env.observation_value())
-        actor_params = actor_network.init(actor_key, dummy_obs)
-        critic_params = critic_network.init(critic_key, dummy_obs)
-        actor_opt_state = actor_optim.init(actor_params)
-        critic_opt_state = critic_optim.init(critic_params)
-
     apply_fns = (actor_network.apply, critic_network.apply)
     update_fns = (actor_optim.update, critic_optim.update)
     learn_per_shard = get_learner_fn(env, apply_fns, update_fns, config, policy_loss_fn)
@@ -526,34 +516,53 @@ def learner_setup(
         obs_stats=P(),
         kl_beta=P(),
     )
-    env_state, timestep = anakin.reset_envs_for_anakin(env, config, env_key)
-    obs_stats = running_statistics.init_state(env.observation_value().agent_view)
-    learner_state = PPOLearnerState(
-        params=anakin.broadcast_to_update_batch(
-            ActorCriticParams(actor_params, critic_params), update_batch
-        ),
-        opt_states=anakin.broadcast_to_update_batch(
-            ActorCriticOptStates(actor_opt_state, critic_opt_state), update_batch
-        ),
-        key=anakin.make_step_keys(key, mesh, config),
-        env_state=env_state,
-        timestep=timestep,
-        obs_stats=anakin.broadcast_to_update_batch(obs_stats, update_batch),
-        kl_beta=anakin.broadcast_to_update_batch(
-            # 3.0 matches the penalty loss's historical default so a config
-            # omitting kl_beta keeps the KL penalty ACTIVE (0.0 would
-            # silently disable it). Unused state for clip/DPO losses.
-            jnp.asarray(float(config.system.get("kl_beta", 3.0))), update_batch
-        ),
-    )
-    learner_state = anakin.place_learner_state(learner_state, mesh, state_specs)
+
+    def init_state(key: jax.Array) -> PPOLearnerState:
+        """key -> the whole initial learner state, as one traceable function."""
+        key, actor_key, critic_key, env_key = jax.random.split(key, 4)
+        dummy_obs = jax.tree.map(lambda x: x[None], env.observation_value())
+        actor_params = actor_network.init(actor_key, dummy_obs)
+        critic_params = critic_network.init(critic_key, dummy_obs)
+        env_state, timestep = anakin.reset_envs_for_anakin(env, config, env_key)
+        obs_stats = running_statistics.init_state(env.observation_value().agent_view)
+        return PPOLearnerState(
+            params=anakin.broadcast_to_update_batch(
+                ActorCriticParams(actor_params, critic_params), update_batch
+            ),
+            opt_states=anakin.broadcast_to_update_batch(
+                ActorCriticOptStates(
+                    actor_optim.init(actor_params), critic_optim.init(critic_params)
+                ),
+                update_batch,
+            ),
+            key=anakin.make_step_keys(key, mesh, config),
+            env_state=env_state,
+            timestep=timestep,
+            obs_stats=anakin.broadcast_to_update_batch(obs_stats, update_batch),
+            kl_beta=anakin.broadcast_to_update_batch(
+                # 3.0 matches the penalty loss's historical default so a config
+                # omitting kl_beta keeps the KL penalty ACTIVE (0.0 would
+                # silently disable it). Unused state for clip/DPO losses.
+                jnp.asarray(float(config.system.get("kl_beta", 3.0))), update_batch
+            ),
+        )
+
+    # The runner times this whole function as set-up's `learner_setup`; the
+    # span marks the state's build in a profiler trace. ONE jitted program
+    # makes the state on its shardings: built op by op it was some 180 eager
+    # compilations (14 s of the Ant cell's set-up on a v5e), none of them long
+    # enough for the persistent cache to keep.
+    with span("network_init"):
+        learner_state = anakin.build_learner_state(init_state, keys, mesh, state_specs)
     learn = anakin.shardmap_learner(learn_per_shard, mesh, state_specs)
 
     if is_coordinator():
-        n_params = count_parameters(actor_params) + count_parameters(critic_params)
+        programs, compilations = compilecache.compile_counts()
         get_logger("stoix_tpu.setup").info(
-            "[setup] %s parameters | mesh %s | %s global envs",
-            f"{n_params:,}", dict(mesh.shape), config.arch.total_num_envs,
+            "[setup] %s parameters | mesh %s | %s global envs | %d programs, %d compilations "
+            "so far",
+            f"{count_parameters(learner_state.params) // update_batch:,}", dict(mesh.shape),
+            config.arch.total_num_envs, programs, compilations,
         )
 
     normalize_obs = bool(config.system.get("normalize_observations", False))

@@ -106,6 +106,20 @@ def _retrieval_counter():
     )
 
 
+def _compiles_counter():
+    return get_registry().counter(
+        COMPILES_METRIC, "Backend compilations (or cache reads) of each program"
+    )
+
+
+def compile_counts() -> Tuple[int, int]:
+    """(programs, backend compilations or cache reads) of this process so far,
+    as `stoix_tpu_compiles_total{program}` has them: what a set-up log line
+    prints, so that an eager op creeping back into set-up shows in a run's log."""
+    counts = [count for _, count in _compiles_counter().labels_and_values()]
+    return len(counts), int(sum(counts))
+
+
 class _CompileStages:
     """The `jax.monitoring` duration listener behind
     `stoix_tpu_compile_seconds_total{program, stage}`: every second jax spends
@@ -168,9 +182,7 @@ class _CompileStages:
             "lower, backend: a compile or a cache read); a stage inside another counted once",
         ).inc(max(0.0, duration - inside), {"program": program, "stage": stage})
         if stage == "backend":
-            get_registry().counter(
-                COMPILES_METRIC, "Backend compilations (or cache reads) of each program"
-            ).inc(1.0, {"program": program})
+            _compiles_counter().inc(1.0, {"program": program})
 
 
 def install_cache_metrics_listener() -> None:
