@@ -15,6 +15,17 @@ ops a step: the policy, not the env, is the work.
 `action_mask` has ONE entry, not `vocab_size`: every token is legal, and a
 mask of 50,304 ones an env a step would be 200 KB of traffic through every
 wrapper's select for nothing.
+
+With `prompt_length` P > 0 every episode also has a PROMPT: P prefix tokens
+(ids from the vocabulary) that come before the task token — positions 0 ..
+P - 1 of the sequence the policy conditions on, the task token at P. The
+episode is still `length` actions and the reward still their rule; the
+prefix is context, not scored. It is drawn from the key the reset leaves in
+the state and read with `prompt(state)`, not carried: the state is no wider
+for it, and no P ids a step pass through the observation or through any
+wrapper's select (3,072 ids a step are the traffic the one-entry mask was
+written to avoid). P = 0 is the env as it was, bit for
+bit.
 """
 
 from __future__ import annotations
@@ -44,9 +55,12 @@ class TokenTaskState(NamedTuple):
 
 
 class TokenTask(Environment):
-    def __init__(self, vocab_size: int = 50304, length: int = 512, modulus: int = 2):
+    def __init__(
+        self, vocab_size: int = 50304, length: int = 512, modulus: int = 2, prompt_length: int = 0
+    ):
         self.vocab_size = int(vocab_size)
         self.length = int(length)
+        self.prompt_length = int(prompt_length)
         self._modulus = int(modulus)
 
     def observation_space(self) -> Observation:
@@ -71,6 +85,19 @@ class TokenTask(Environment):
         task = jax.random.randint(sub, (), 0, self.vocab_size, jnp.int32)
         state = TokenTaskState(key, task, jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
         return state, restart(self._obs(state))
+
+    def prompt(self, state: TokenTaskState) -> jax.Array:
+        """The episode's prefix tokens, int32 [..., `prompt_length`], from this
+        env's own state (`wrappers.unwrapped_state` of a wrapped one), one
+        episode's or a batch of them: a function of the episode's reset key,
+        the same at every step of the episode and new after an auto-reset."""
+        if not isinstance(state, TokenTaskState):  # (a wrapper's state has a `key` of its own)
+            raise TypeError(f"prompt() reads this env's own state, not {type(state).__name__}")
+        keys = state.key.reshape(-1, state.key.shape[-1])
+        draw = lambda key: jax.random.randint(
+            jax.random.fold_in(key, 1), (self.prompt_length,), 0, self.vocab_size, jnp.int32
+        )
+        return jax.vmap(draw)(keys).reshape(*state.key.shape[:-1], self.prompt_length)
 
     def step(self, state: TokenTaskState, action: jax.Array) -> Tuple[TokenTaskState, TimeStep]:
         action = jnp.asarray(action, jnp.int32)

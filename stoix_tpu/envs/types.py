@@ -130,6 +130,12 @@ class Observation(NamedTuple):
     step_count: jax.Array
 
 
+# The prefix of an episode metric that is a record made once a SEQUENCE (its
+# leading axes are not `is_terminal_step`'s), for whoever reads a window from
+# outside; `get_final_step_metrics` leaves it out by this name.
+ONCE_A_SEQUENCE = "sequence_"
+
+
 def get_final_step_metrics(metrics: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
     """Filter episode metrics to completed episodes only.
 
@@ -138,7 +144,8 @@ def get_final_step_metrics(metrics: Dict[str, jax.Array]) -> Dict[str, jax.Array
     as 1-D host-side arrays. Used by the host logging loop (reference
     ff_ppo.py:624-629 via stoa's helper). A leaf with further axes behind
     those of is_terminal_step (a record of a block of tokens a step) keeps
-    them: [episodes, ...].
+    them: [episodes, ...]. A key under `ONCE_A_SEQUENCE` is a record made once a
+    sequence, not once a step (a rollout's prompt): no step's, and left out.
     """
     import numpy as np
 
@@ -147,6 +154,8 @@ def get_final_step_metrics(metrics: Dict[str, jax.Array]) -> Dict[str, jax.Array
     out: Dict[str, jax.Array] = {}
     for k, v in metrics.items():
         if k == "is_terminal_step":
+            continue
+        if k.startswith(ONCE_A_SEQUENCE):
             continue
         v = np.asarray(v)
         out[k] = v.reshape((is_final.size,) + v.shape[terminal.ndim:])[is_final]

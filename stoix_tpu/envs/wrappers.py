@@ -158,6 +158,15 @@ class AutoResetWrapper(Wrapper):
         return AutoResetState(next_inner, key), ts
 
 
+def unwrapped_state(state: Any) -> Any:
+    """The wrapped env's own state inside any stack of these wrappers' (each
+    keeps the state it wraps in an `inner` field; a wrapper that adds no
+    state passes it through), one episode's or a batch of them."""
+    while hasattr(state, "inner"):
+        state = state.inner
+    return state
+
+
 def _reseed(state: Any, key: jax.Array) -> Any:
     """Replace `key` fields in a (nested) NamedTuple env state with fresh keys.
 

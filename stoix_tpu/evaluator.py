@@ -240,6 +240,7 @@ def get_rnn_evaluator_fn(
     mesh: Mesh,
     init_carry: Callable[[int], Any],
     eval_multiplier: int = 1,
+    start_carry: Optional[Callable[[Any, Any], Any]] = None,
 ):
     """Evaluator for a policy that carries state through the episode
     (reference evaluator.py:209-344): an RNN's hidden vector, a context
@@ -250,6 +251,11 @@ def get_rnn_evaluator_fn(
     `init_carry(E)` — which is what a policy whose layers work on the whole
     batch (a sort of all tokens by expert) needs. A core written for ONE
     episode is vmapped by its caller (`per_episode_evaluator_setup`).
+
+    A core whose first carry depends on the policy and on the episodes (a
+    cache prefilled with each episode's prompt) gives `start_carry(params,
+    the shard's reset env states [E, ...]) -> carry`, which then stands in
+    for `init_carry(E)`.
 
     Episodes run until the longest ends. A finished episode's env state,
     timestep and key are frozen; its carry is not (nothing reads it again),
@@ -284,9 +290,8 @@ def get_rnn_evaluator_fn(
             )
             return frozen, hstate
 
-        final, _ = jax.lax.while_loop(
-            cond, body, ((env_state, timestep, split[:, 1]), init_carry(per_shard))
-        )
+        hstate = init_carry(per_shard) if start_carry is None else start_carry(params, env_state)
+        final, _ = jax.lax.while_loop(cond, body, ((env_state, timestep, split[:, 1]), hstate))
         metrics = final[1].extras["episode_metrics"]
         return {
             "episode_return": metrics["episode_return"],
@@ -312,12 +317,14 @@ def carry_evaluator_setup(init_carry: Optional[Callable[[int], Any]] = None):
     (evaluator, absolute-metric evaluator) over `get_rnn_evaluator_fn`.
     Without `init_carry` the carry is the one the system's `act_fn` declares
     (`act_fn.init_carry`: what its network built, known only once the
-    learner is set up)."""
+    learner is set up); an `act_fn.start_carry`, where the system declares
+    one, is `get_rnn_evaluator_fn`'s."""
 
     def setup(eval_env: Environment, act_fn: Any, config: Any, mesh: Mesh) -> Tuple[Any, Any]:
         init = init_carry or act_fn.init_carry
         make = lambda multiplier: get_rnn_evaluator_fn(
-            eval_env, act_fn, config, mesh, init, eval_multiplier=multiplier
+            eval_env, act_fn, config, mesh, init, eval_multiplier=multiplier,
+            start_carry=getattr(act_fn, "start_carry", None),
         )
         return make(1), make(int(config.arch.get("absolute_metric_multiplier", 10)))
 

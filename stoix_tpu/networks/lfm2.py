@@ -2,7 +2,8 @@
 differ — gated short convolutions beside grouped-query attention, two dense
 feed-forwards and then routed ones — with one decode carry over both kinds of
 state; one expert-parallel rank's share of each routed layer and of the
-vocabulary. Two entry points over ONE set of parameters, as networks/olmoe.py.
+vocabulary. Three entry points over ONE set of parameters: networks/olmoe.py's
+two, and `prefill` for a sequence that starts with a prompt.
 
 Published layer (`model_type` `lfm2_moe`,
 https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json;
@@ -29,13 +30,18 @@ the benchmark compare this file with). Every layer l, no bias anywhere:
     model gives a layer its own head count (`num_heads_per_layer`), a layer
     KIND its own rotation (`rope_parameters`: theta, the rotated part of a
     head, YaRN) and every attention layer a sigmoid gate a head before W_o
-    (`attention_gate`).
+    (`attention_gate`). Another (Mellum2) runs the two kinds at ONE head count
+    with no gate, its window 1,024 and its period ending with the full layer.
   * feed-forward of the first `num_dense_layers` layers (`DenseMLP`): one
     SwiGLU of width `dense_width`; of the others (`RoutedMLP`): float32
-    sigmoid scores over ALL `num_experts`, the top-k CHOSEN by score +
-    `expert_bias`, WEIGHTED by the scores themselves at the chosen experts
-    over (their sum + 1e-6), times `routed_scaling_factor`; SwiGLU experts of
-    width `expert_width`, no shared expert (networks/olmoe.py::moe).
+    scores over ALL `num_experts` — `router_scoring` `sigmoid`, each expert
+    by itself (this model and three more), or `softmax` over all of them
+    (Mellum2) —, the top-k CHOSEN by score + `expert_bias` (by the score
+    alone where `router_selection_bias` is false: such a router has no
+    `expert_bias` leaf), WEIGHTED by the scores themselves at the chosen
+    experts over (their sum + `router_epsilon`, 1e-6 here), times
+    `routed_scaling_factor`; SwiGLU experts of width `expert_width`, no
+    shared expert (networks/olmoe.py::moe).
   * final RMSNorm; the head is the embedding's transpose (tied).
 
 The chip's share: `experts_held` experts from `expert_offset` on are here
@@ -47,7 +53,8 @@ layer. Nothing stands in for the other ranks or their exchange.
 The stack is data: `layer_types` names each layer's mixer and
 `num_dense_layers` says which feed-forwards are dense. A `Block` is a mixer,
 a feed-forward and their two norms; a mixer has `forward` (whole sequence)
-and `step` (one token against its state).
+and `step` (one token against its state), and the two attention kinds also
+`prefill` (whole prefix, keeping the state it leaves).
 
   * `Lfm2LM.forward(tokens [B, T])` — teacher-forced.
   * `Lfm2LM.step(carry, token [B])` — one decode step. `Lfm2Carry` holds one
@@ -57,16 +64,39 @@ and `step` (one token against its state).
     `init_carry(batch, max_len)` and `reset_carry(carry, done)` are the
     network's own: a new sequence starts at length 0 with a zero tail; cache
     entries at or beyond `length` are never read.
+  * `Lfm2LM.forward(tokens, n)` — the same pass with logits and hidden of
+    the LAST n positions alone (a response after its prompt): the head is a
+    function of a position, and 3,072 prefix positions of 12,288 logits a
+    sequence are computed for nothing otherwise.
+  * `Lfm2LM.prefill(carry, tokens [B, P])` — `forward`'s pass over a prefix
+    (the causal and the banded attention's forward) that also KEEPS each
+    attention layer's rotated keys and values as its decode state — a full
+    layer's rows 0 .. P - 1 of its cache, a window layer's LAST min(P, W)
+    positions at their ring places (position t at t % W, whatever P is to W)
+    — and returns the carry at length P with the routed layers' stats; no
+    head and no value. Every prefix is P long, so a carry of one position
+    for all sequences stays one. A `conv`, `latent_attention` or
+    `delta_attention` layer has no prefill yet and says so by name.
 
 Parameters, by name (the reference reads them by these names):
   embed [V, D]; layer_<i>/{operator_norm [D], ffn_norm [D], mixer/{in_proj
   [D, 3D], conv [K, D], out_proj [D, D]} or mixer/{wq [D, H*hd], wk wv [D,
   KV*hd], wo [H*hd, D], q_norm k_norm [hd]}, ffn/{w1 w3 [D, F], w2 [F, D]} or
-  ffn/{router [D, E], expert_bias [E], gate up [held, D, Fm], down [held, Fm,
-  D]}}; final_norm [D].
+  ffn/{router [D, E], expert_bias [E] (where the router has a selection bias),
+  gate up [held, D, Fm], down [held, Fm, D]}}; final_norm [D]; lm_head [D, V]
+  where the head is untied.
 Initialisation is normal(0.02), norms start at one; `expert_bias` is a seeded
 normal(`expert_bias_scale`) buffer that only the CHOICE of experts reads, so
-it takes no gradient and an optimiser step leaves it as it was.
+it takes no gradient and an optimiser step leaves it as it was. The embedding
+alone takes another deviation where `embedding_init_std` says so: the first
+RMSNorm brings a 0.02 row to unit size, but the residual keeps it at 0.02 an
+entry beside an attention result of 0.17 (eight query heads a key/value head
+add up coherently through W_o: a gain of 3.5), so behind a prefix of
+thousands of tokens every later layer reads the context's mean, all
+positions of a sequence choose the same experts, and how many of them are
+HELD here is one draw a (sequence, layer). A deviation of 1.0 keeps the
+token's own row the larger part of the residual (the benchmark's
+prefilled-prompt cell sets it; PERF.md section 6, PR 47).
 """
 
 from __future__ import annotations
@@ -230,21 +260,46 @@ class GroupedQueryAttention(nn.Module):
         """attended [..., H, head_dim] times sigmoid(u W_g) a head, where `gate`."""
         return attended * jax.nn.sigmoid(u @ self.wg)[..., None] if self.gate else attended
 
-    def forward(self, u: jax.Array) -> jax.Array:
+    def _sequence(self, u: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        """u [B, T, D] from position 0 on -> (the mixer's result [B, T, D], the
+        rotated keys and the values [B, T, kv_heads, head_dim])."""
         batch, length, _ = u.shape
         q, k, v = self._qkv(u, jnp.broadcast_to(jnp.arange(length), (batch, length)))
         # `best_attention` (the Pallas flash kernel on a TPU) takes as many
         # key/value heads as query heads: each is repeated for its group.
         group = self.num_heads // self.num_kv_heads
-        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+        keys, values = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
         if self.window:
             banded = jax.default_backend() == "tpu" and self.window < length
             for form, took in (("banded", banded), ("masked", not banded)):
                 _window_form_gauge().set(float(took), {"form": form})
         with annotate(SCOPES[self.attend_scope]):
             # [B, T, heads, head_dim]
-            attended = best_attention(q, k, v, causal=True, window=self.window)
-        return self._gated(attended, u).reshape(batch, length, -1) @ self.wo
+            attended = best_attention(q, keys, values, causal=True, window=self.window)
+        return self._gated(attended, u).reshape(batch, length, -1) @ self.wo, k, v
+
+    def forward(self, u: jax.Array) -> jax.Array:
+        return self._sequence(u)[0]
+
+    def prefill(self, u: jax.Array, state: Any):
+        """`forward` over a prefix u [B, P, D] that also KEEPS its rotated keys
+        and its values as the decode state of an empty `state`: a `KV`'s rows
+        0 .. P - 1; of a `WindowKV` of W rows the LAST min(P, W) positions,
+        position t at t % W as `step` writes them, whatever P is to W."""
+        length, size = u.shape[1], state.k.shape[0]
+        out, k, v = self._sequence(u)
+        if not self.window and length > size:
+            raise ValueError(f"a prefix of {length} positions does not fit a cache of {size} rows")
+
+        def rows(x: jax.Array) -> jax.Array:
+            x = jnp.swapaxes(x, 0, 1)  # position-major, as the state
+            if length < size:
+                return x
+            # The newest `size` positions, the oldest of them at ITS place.
+            return jnp.roll(x[length - size:], (length - size) % size, axis=0)
+
+        write = lambda cache, new: jax.lax.dynamic_update_slice(cache, rows(new), (0, 0, 0, 0))
+        return out, type(state)(write(state.k, k), write(state.v, v))
 
     def step(self, u: jax.Array, state: Any, length: jax.Array):
         """u [B, D] against a `KV` of `max_len` rows or, with `window`, a
@@ -284,10 +339,13 @@ class DenseMLP(nn.Module):
 
 
 class RoutedMLP(nn.Module):
-    """The held experts' part of the sigmoid-routed layer on f [N, D], plus,
-    with `shared_width`, the shared expert every token passes (a `DenseMLP`
-    under `shared`): what every rank computes alike, so the ranks' parts add
-    up to the uncut layer with it counted once."""
+    """The held experts' part of the routed layer on f [N, D] — scored as
+    `score` says (`sigmoid`: each expert by itself; `softmax`: over all of
+    them), the top-k chosen by score + `expert_bias` where the router has a
+    `selection_bias` (else by the score alone, and there is no such leaf) —
+    plus, with `shared_width`, the shared expert every token passes (a
+    `DenseMLP` under `shared`): what every rank computes alike, so the ranks'
+    parts add up to the uncut layer with it counted once."""
 
     hidden_size: int
     num_experts: int  # the router's width: every expert of the layer
@@ -301,13 +359,16 @@ class RoutedMLP(nn.Module):
     shared_width: int = 0
     groups: int = 1  # `n_group`: the experts lie in so many groups, of which the
     top_groups: int = 1  # `topk_group` best are open to a token's choice
+    score: str = "sigmoid"  # or "softmax"
+    selection_bias: bool = True
 
     def setup(self) -> None:
         d, e, held, f = self.hidden_size, self.num_experts, self.experts_held, self.width
         self.router = self.param("router", _INIT, (d, e))
-        self.expert_bias = self.param(
-            "expert_bias", nn.initializers.normal(self.bias_scale), (e,)
-        )
+        if self.selection_bias:
+            self.expert_bias = self.param(
+                "expert_bias", nn.initializers.normal(self.bias_scale), (e,)
+            )
         self.gate = self.param("gate", _INIT, (held, d, f))
         self.up = self.param("up", _INIT, (held, d, f))
         self.down = self.param("down", _INIT, (held, f, d))
@@ -319,7 +380,8 @@ class RoutedMLP(nn.Module):
             out, stats = moe(
                 f, self.router, self.gate, self.up, self.down, self.experts_per_token,
                 held=(self.expert_offset, self.experts_held), renormalise=True,
-                held_room_sigmas=_HELD_ROOM_SIGMAS, score="sigmoid", bias=self.expert_bias,
+                held_room_sigmas=_HELD_ROOM_SIGMAS, score=self.score,
+                bias=self.expert_bias if self.selection_bias else None,
                 epsilon=self.epsilon, scale=self.scaling_factor, groups=self.groups,
                 top_groups=self.top_groups,
             )
@@ -351,6 +413,18 @@ class Block(nn.Module):
         with annotate(SCOPES[self.mixer.trace_scope]):
             h = x + self.mixer.forward(rms_norm(x, self.operator_norm, self.rms_eps))
         return self._ffn(h)
+
+    def prefill(self, x: jax.Array, state: Any):
+        """`forward` over a prefix that also fills the mixer's empty `state`."""
+        if not hasattr(self.mixer, "prefill"):
+            raise NotImplementedError(
+                f"a {type(self.mixer).__name__} mixer has no prefill yet: a prompt is written "
+                "into the decode state of full_attention and sliding_attention layers only"
+            )
+        with annotate(SCOPES[self.mixer.trace_scope]):
+            mixed, state = self.mixer.prefill(rms_norm(x, self.operator_norm, self.rms_eps), state)
+            h = x + mixed
+        return (*self._ffn(h), state)
 
     def step(self, x: jax.Array, state: Any, length: jax.Array):
         with annotate(SCOPES[self.mixer.trace_scope]):
@@ -414,8 +488,17 @@ class Lfm2LM(nn.Module):
     sliding_window: int = 0
     num_heads_per_layer: Optional[Sequence[int]] = None
     rope_parameters: Optional[Mapping[str, Mapping[str, Any]]] = None
+    # ... of a `mellum` stack: a softmax router (`router_scoring`; "sigmoid" is the
+    # four stacks above) with no selection bias, so no `expert_bias` leaf ...
+    router_scoring: str = "sigmoid"
+    router_selection_bias: bool = True
     # ... and of the head: the embedding's transpose, or a matrix of its own.
     tie_word_embeddings: bool = True
+    # The embedding's initial standard deviation (every other matrix: 0.02).
+    # At 0.02 a row is an eighth of the first attention layer's result, so from
+    # a long prefix every position of a sequence routes as its context's mean
+    # does; at 1.0 the token decides (the module docstring, Initialisation).
+    embedding_init_std: float = 0.02
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -482,10 +565,15 @@ class Lfm2LM(nn.Module):
             self.experts_per_token, self.expert_width, self.routed_scaling_factor,
             self.expert_bias_scale, self.router_epsilon,
             self.n_shared_experts * self.expert_width, self.n_group, self.topk_group,
+            score=self.router_scoring, selection_bias=self.router_selection_bias,
         )
 
     def setup(self) -> None:
-        self.embed = self.param("embed", _INIT, (self.vocab_size, self.hidden_size))
+        embed_init = (
+            _INIT if self.embedding_init_std == 0.02
+            else nn.initializers.normal(self.embedding_init_std)
+        )
+        self.embed = self.param("embed", embed_init, (self.vocab_size, self.hidden_size))
         self.layers = [
             Block(self._mixer(kind, i), self._ffn(i), self.hidden_size, self.rms_eps, name=f"layer_{i}")
             for i, kind in enumerate(self.layer_types)
@@ -499,14 +587,38 @@ class Lfm2LM(nn.Module):
             hidden = rms_norm(x, self.final_norm, self.rms_eps)
             return hidden @ (self.embed.T if self.tie_word_embeddings else self.lm_head), hidden
 
-    def forward(self, tokens: jax.Array) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    def forward(
+        self, tokens: jax.Array, head_positions: Optional[int] = None
+    ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+        """With `head_positions` n, logits and hidden are of the LAST n
+        positions alone (a response after its prompt): the stats stay over
+        every position."""
         x = jnp.take(self.embed, tokens, axis=0)
         stats = []
         for layer in self.layers:
             x, layer_stats = layer.forward(x)
             stats.append(layer_stats)
+        if head_positions is not None:
+            x = x[:, x.shape[1] - head_positions:]
         logits, hidden = self._head(x)
         return logits, hidden, _stack([s for s in stats if s is not None])
+
+    def prefill(
+        self, carry: Lfm2Carry, tokens: jax.Array
+    ) -> Tuple[Lfm2Carry, Dict[str, jax.Array]]:
+        """The teacher-forced pass over a prefix `tokens` [B, P] that writes
+        every layer's state into an EMPTY `carry` and returns it at length P:
+        what P `step`s from position 0 would leave, in one pass and with no
+        head. Every sequence's prefix is P long, so a carry of one position
+        for all of them stays one."""
+        x = jnp.take(self.embed, tokens, axis=0)
+        states, stats = [], []
+        for layer, state in zip(self.layers, carry.layers):
+            x, layer_stats, state = layer.prefill(x, state)
+            states.append(state)
+            stats.append(layer_stats)
+        carry = Lfm2Carry(tuple(states), carry.length + tokens.shape[1])
+        return carry, _stack([s for s in stats if s is not None])
 
     def step(
         self, carry: Lfm2Carry, token: jax.Array

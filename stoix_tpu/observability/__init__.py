@@ -84,6 +84,7 @@ from stoix_tpu.observability.trace import (  # noqa: F401
     HOST_SPANS,
     HYBRID_SCOPES,
     LATENT_SCOPES,
+    PROMPT_SCOPES,
     SCOPES,
     SetupClock,
     WINDOW_SCOPES,

@@ -94,6 +94,12 @@ SCOPES = {
     # inside it: the attend alone — the banded flash kernel pair in the update, the
     # ring's read in the decode
     "window_attend": "window_attend",
+    # A rollout that starts from a prompt (systems/ppo/anakin/ff_lm_ppo.py with
+    # `env.prompt_length` > 0): the teacher-forced pass over the prefix that writes
+    # every layer's decode state, BESIDE `rollout` in the learner (not under it:
+    # `rollout` is decode steps alone) and in the evaluator; the mixers' and the
+    # feed-forwards' scopes lie inside it as they do inside `ppo_epoch`.
+    "prefill": "prefill",
 }
 
 # The scopes of the token policy's block: only the systems built on
@@ -114,6 +120,9 @@ DELTA_SCOPES = ("delta_mixer", "delta_conv", "delta_rule")
 # What a window layer adds: only a stack with a `sliding_attention` layer
 # carries these.
 WINDOW_SCOPES = ("window_mixer", "window_attend")
+# What a rollout from a prompt adds: only a token system whose env has a
+# `prompt_length` carries it.
+PROMPT_SCOPES = ("prefill",)
 
 # Host spans that recur in steady state, by the thread that opens them. A
 # trace reduction attributes a device-idle gap to the innermost of these open

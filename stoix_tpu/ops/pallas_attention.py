@@ -1270,7 +1270,8 @@ def gqa_decode_attention(
     or a ring: the rows' order does not matter) -> [B, kv_heads, group,
     head_dim].
 
-    The decode of grouped-query attention at 6 or 8 queries a key/value head.
+    The decode of grouped-query attention at 6 or 8 queries a key/value head,
+    on 8 or 4 key/value heads.
     A grid step fetches `block` rows of `_GQA_SEQUENCES` sequences; a
     sequence's rows [block, kv_heads, head_dim] are read as they lie, as
     [block * kv_heads, head_dim] — at eight key/value heads each (8, 128)
@@ -1283,6 +1284,13 @@ def gqa_decode_attention(
     block's DMA: a ring of 512 rows at 32 sequences, written and attended as
     a scan's carry, took 0.222 ms a step and takes 0.195, of which 0.164 are
     its bytes at the chip's peak (PERF.md section 6, PR 45).
+    At FOUR key/value heads of eight queries (Mellum2: an (8, 128) tile would
+    be two rows' heads) XLA hands the caches over tiled (4, 128), so nothing
+    is padded in HBM, and the kernel is the same program: in the cell that
+    decodes 16 sequences three windows deep it takes 0.097 ms a step for a
+    ring of 1,024 rows (0.082 are its bytes at the chip's peak) and 0.303 ms
+    for a growing cache at 3,328 live rows on average (0.266), 84% and 88% of
+    the byte bound (PERF.md section 6, PR 47).
     `networks/olmoe.py::_attend_cache`'s multiply-and-reduce does the same
     sums on the vector unit, which at one to four queries a row is free
     beside the read and at eight is not (0.633). Operands are multiplied as

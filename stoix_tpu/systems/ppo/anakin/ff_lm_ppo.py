@@ -1,22 +1,24 @@
 """Anakin PPO with a token policy: a decoder (`network=olmoe`: OLMoE blocks
 and a KV cache; `network=lfm2_moe`, `kanana2_moe`, `ling3_flash_moe`,
-`laguna_xs2_moe`: networks/lfm2.py's stack of convolution, full and window
-attention, latent-attention and delta-rule layers and a carry of each kind
-of state) acts step by step
+`laguna_xs2_moe`, `mellum2_moe`: networks/lfm2.py's stack of convolution, full
+and window attention, latent-attention and delta-rule layers and a carry of
+each kind of state) acts step by step
 through its carry in the rollout and is updated teacher-forced over whole
 sequences.
 
 The first system in which the policy, not the env, is the work (the LM
-post-training shape: generate a batch of fixed-length responses, score them
-with a verifiable reward, one pass of minibatch updates). Scaffolding — mesh,
-shard_map, GAE, epoch/minibatch scans, `run_anakin_experiment` — is the
-canonical ff_ppo template's; what differs:
+post-training shape: generate a batch of fixed-length responses — from an
+empty context or each from its prompt —, score them with a verifiable reward,
+one pass of minibatch updates). Scaffolding — mesh, shard_map, GAE,
+epoch/minibatch scans, `run_anakin_experiment` — is the canonical ff_ppo
+template's; what differs:
 
-  * ONE trunk behind two entry points over the same parameters: `step`
-    (one decode step through the carry a rollout step, and the evaluator's
-    greedy decode) and `forward` (teacher-forced, in the loss). The network
-    declares its carry: `init_carry(batch, max_len)`, `reset_carry(carry,
-    done)`; this file names no network class.
+  * ONE trunk behind its entry points over the same parameters: `step` (one
+    decode step through the carry a rollout step, and the evaluator's greedy
+    decode), `forward` (teacher-forced, in the loss) and, where the env has a
+    prompt, `prefill` (the teacher-forced pass over the prefix that writes
+    the carry). The network declares its carry: `init_carry(batch, max_len)`,
+    `reset_carry(carry, done)`; this file names no network class.
     `ActorCriticParams.critic_params` holds only the scalar value head on the
     trunk's final hidden state; one loss, one backward pass.
   * The transition stores token ids, log-prob, value, reward, done — not
@@ -30,19 +32,39 @@ canonical ff_ppo template's; what differs:
     the pairs that landed on it, and what a selection bias re-routed.
   * The rollout's record rides out with the episode metrics
     (`rollout_action`, `rollout_log_prob`, `rollout_value`, [T, E] like
-    them): what was generated and what the policy said of it, for whoever
-    audits a window from outside (the benchmark's reference does).
+    them; with a prompt also `sequence_prompt` [P, E], once a sequence:
+    envs/types.py `ONCE_A_SEQUENCE`): what was generated, from what, and
+    what the policy said of it, for whoever audits a window from outside
+    (the benchmark's reference does).
 
-v1 contract, checked at set-up: the env's episode length equals
-`system.rollout_length`, so every rollout starts at a reset with an empty
-cache and the teacher-forced pass needs no stored prefix; every sequence of a
-batch is then at the same position at every step, in the rollout and in the
-evaluator's decode alike, which is what licenses the carry of ONE position
-that `network_functions` asks the network for; the cache lives for
-the rollout only and is not part of the learner state (2 GB at the published
-widths, dead through the update). `arch.update_batch_size` must be 1: a
-sort of all tokens by expert does not vmap, so there is no in-shard replica
-axis and the state carries no [U] dimension.
+A PROMPT (`env.prompt_length` P > 0, envs/token_task.py): every episode has P
+prefix tokens before its task token. The rollout is then `init_cache` ->
+`prefill` (one teacher-forced pass over the prefixes, no head and no value,
+under its own scope `prefill`, which lies BESIDE `rollout` and not under it:
+`rollout` holds decode steps alone, with a prompt or without) -> the scan of
+`rollout_length` decode steps,
+whose inputs sit at positions P .. P + G - 1; the record gains the prefix once
+a sequence, not a step; a minibatch's teacher-forced sequence is [prefix ;
+response tokens] (P + G) with the head on the G response positions, and
+PPO's clip, the value loss, the entropy and GAE are the G response steps' as
+without one; the router's pairs of the prefill are counted like the rollout's
+(TRAIN `prefill_routed_pairs_per_token`, `prefill_held_pairs_per_token`). The
+evaluator's episodes start from their prompts prefilled alike
+(`act_fn.start_carry`, evaluator.py). With P = 0 nothing of this is traced:
+those learners are the programs they were.
+
+The contract, checked at set-up: the env's episode length equals
+`system.rollout_length`, so every rollout starts at a reset — with an empty
+carry or with the one its prefill wrote — and the teacher-forced pass needs
+nothing but the rollout's own record; every prompt is as long as the next;
+every sequence of a batch is then at the same position at every step, in the
+rollout and in the evaluator's decode alike, which is what licenses the carry
+of ONE position that `network_functions` asks the network for; the carry
+lives for the rollout only and is not part of the learner state (2 GB at the
+OLMoE cell's widths, dead through the update). `arch.update_batch_size` must
+be 1: a sort of all tokens by expert does not vmap, so there is no in-shard
+replica axis and the state carries no [U] dimension. Prompts of unequal
+length, packed sequences and episodes that span rollouts are not supported.
 
 Layout (S = data shards, E = envs a shard):
   params / opt_states:    [...]          P()        (replicated)
@@ -61,6 +83,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from stoix_tpu import envs
 from stoix_tpu.base_types import ActorCriticOptStates, ActorCriticParams, ExperimentOutput
+from stoix_tpu.envs import wrappers
+from stoix_tpu.envs.types import ONCE_A_SEQUENCE
 from stoix_tpu.evaluator import carry_evaluator_setup
 from stoix_tpu.networks import olmoe
 from stoix_tpu.observability import SCOPES, annotate, get_logger, get_registry, span
@@ -98,11 +122,16 @@ class LMTransition(NamedTuple):
 class LMNetworks(NamedTuple):
     """The two entry points and the value head, as pure functions."""
 
-    forward: Callable  # (actor_params, tokens [B, T]) -> (logits, hidden, stats)
+    # (actor_params, tokens [B, T]) -> (logits, hidden, stats); with a third argument n,
+    # logits and hidden of the LAST n positions alone
+    forward: Callable
     step: Callable  # (actor_params, cache, token [B]) -> (logits, hidden, cache, stats)
     value: Callable  # (critic_params, hidden) -> value
     init_cache: Callable  # (batch) -> the network's decode carry, empty
     reset_cache: Callable  # (carry, done [B]) -> carry: a new sequence where done
+    # (actor_params, an empty carry, prefix [B, P]) -> (the carry at length P, stats): asked
+    # for only where the env has a prompt
+    prefill: Callable
     routed_layers: int  # layers with a router: the stats' leading axis
     # (offset, count) of the experts held here, of a network that holds one
     # expert-parallel rank's share; None: every expert is here.
@@ -122,8 +151,15 @@ def lm_ppo_loss(
     """The loss on one minibatch of whole sequences (leaves [sequences, T]:
     token, action, log_prob, value, advantage, target): PPO clip, clipped
     value loss, entropy of the full categorical, the router's load-balancing
-    loss. stoix_tpu/reference/olmoe.py::ppo_loss is its plain twin."""
-    logits, hidden, stats = networks.forward(params.actor_params, batch["token"])
+    loss. stoix_tpu/reference/olmoe.py::ppo_loss is its plain twin. With a
+    `prefix` [sequences, P] the teacher-forced sequence is [prefix ; token]
+    and the head runs on its last T positions: clip, value loss and entropy
+    are the response's alone, the router's statistics every position's."""
+    tokens, head_positions = batch["token"], ()
+    if "prefix" in batch:
+        tokens = jnp.concatenate([batch["prefix"], tokens], axis=1)
+        head_positions = (batch["token"].shape[1],)
+    logits, hidden, stats = networks.forward(params.actor_params, tokens, *head_positions)
     value = networks.value(params.critic_params, hidden)
     with annotate(SCOPES["lm_head"]):
         policy = Categorical(logits)
@@ -131,7 +167,7 @@ def lm_ppo_loss(
         entropy = policy.entropy().mean()
     loss_actor = losses.ppo_clip_loss(log_prob, batch["log_prob"], batch["advantage"], clip_eps)
     value_loss = losses.clipped_value_loss(value, batch["value"], batch["target"], clip_eps)
-    layers, num_tokens = stats["expert_count"].shape[0], batch["token"].size
+    layers, num_tokens = stats["expert_count"].shape[0], tokens.size
     aux_loss = olmoe.load_balancing_loss(stats, num_tokens)
     total = loss_actor - ent_coef * entropy + vf_coef * value_loss + aux_coef * aux_loss
     load = jnp.sum(stats["expert_count"], axis=0).astype(jnp.float32)  # [experts], over layers
@@ -172,6 +208,7 @@ def get_learner_fn(
     vf_coef = float(config.system.vf_coef)
     aux_coef = float(config.system.router_aux_loss_coef)
     rollout_length = int(config.system.rollout_length)
+    prompt_length = int(getattr(env, "prompt_length", 0))
     num_layers = networks.routed_layers
 
     def _pairs(stats: Dict[str, jax.Array]) -> Any:
@@ -204,15 +241,28 @@ def get_learner_fn(
             )
             return (key, env_state, timestep, cache), (transition, _pairs(stats))
 
+        prompt = None
+        if prompt_length:
+            # The episode's prefix, written into every layer's state by one
+            # teacher-forced pass BEFORE the rollout's scope, not under it (what
+            # reads `rollout` reads decode steps alone, as without a prompt): the
+            # scan starts at position `prompt_length`.
+            prefix = env.prompt(wrappers.unwrapped_state(env_state))
+            with annotate(SCOPES["prefill"]):
+                cache, stats = networks.prefill(
+                    params.actor_params, networks.init_cache(timestep.reward.shape[0]), prefix
+                )
+            prompt = (prefix, _pairs(stats))
         # The scope is on the scan, not on its body: the loop op itself then
         # carries it, and with it the grouped-matmul kernels inside, which
         # XLA:TPU emits without a framework path of their own.
         with annotate(SCOPES["rollout"]):
-            cache = networks.init_cache(timestep.reward.shape[0])
+            if not prompt_length:
+                cache = networks.init_cache(timestep.reward.shape[0])
             (key, env_state, timestep, _), (traj, routed) = jax.lax.scan(
                 _env_step, (key, env_state, timestep, cache), None, rollout_length
             )
-        return key, env_state, timestep, traj, jax.tree.map(jnp.sum, routed)
+        return key, env_state, timestep, traj, jax.tree.map(jnp.sum, routed), prompt
 
     @annotate(SCOPES["update_minibatch"])
     def _update_minibatch(train_state: Tuple, batch: Dict[str, jax.Array]):
@@ -232,7 +282,7 @@ def get_learner_fn(
 
     def _update_step(learner_state: LMPPOLearnerState, _: Any):
         params, opt_states = learner_state.params, learner_state.opt_states
-        key, env_state, timestep, traj, routed = _rollout(
+        key, env_state, timestep, traj, routed, prompt = _rollout(
             params, learner_state.key, learner_state.env_state, learner_state.timestep
         )
 
@@ -258,6 +308,8 @@ def get_learner_fn(
             "value": traj.value, "advantage": advantages, "target": targets,
         }
         data = jax.tree.map(lambda x: jnp.swapaxes(x, 0, 1)[:, None], data)
+        if prompt is not None:
+            data["prefix"] = prompt[0][:, None]  # once a sequence: [E, 1, P]
         minibatch_epoch = shuffled_minibatch_epoch(
             _update_minibatch, (params, opt_states), data, config.system.num_minibatches
         )
@@ -285,6 +337,18 @@ def get_learner_fn(
             "rollout_action": traj.action, "rollout_log_prob": traj.log_prob,
             "rollout_value": traj.value,
         }
+        if prompt is not None:
+            prefix, prefilled = prompt
+            # [P, E], sequences last like the rest
+            record[ONCE_A_SEQUENCE + "prompt"] = prefix.T
+            if networks.held is not None:
+                prefilled, held = prefilled
+                info["prefill_held_pairs_per_token"] = held.astype(jnp.float32) / (
+                    num_layers * prefix.size
+                )
+            info["prefill_routed_pairs_per_token"] = prefilled.astype(jnp.float32) / (
+                num_layers * prefix.size
+            )
         return learner_state, ({**traj.info, **record}, info)
 
     def learner_fn(learner_state: LMPPOLearnerState) -> ExperimentOutput:
@@ -313,15 +377,19 @@ def build_networks(env: envs.Environment, config: Any) -> Tuple[Any, Any]:
 
 def network_functions(actor: Any, critic: Any, max_len: int) -> LMNetworks:
     """The network's entry points and what it declares of itself: its carry
-    (`init_carry`, `reset_carry`), its routed layers, the share it holds. The
-    carry is the one of sequences that move together: `learner_setup` checks
-    that every episode is exactly one rollout."""
+    (`init_carry`, `reset_carry`) of `max_len` positions (prompt and
+    response), its routed layers, the share it holds. The carry is the one of
+    sequences that move together: `learner_setup` checks that every episode
+    is exactly one rollout, and every prompt is as long as the next."""
     return LMNetworks(
-        forward=lambda params, tokens: actor.apply(params, tokens, method="forward"),
+        forward=lambda params, tokens, *head_positions: actor.apply(
+            params, tokens, *head_positions, method="forward"
+        ),
         step=lambda params, cache, token: actor.apply(params, cache, token, method="step"),
         value=critic.apply,
         init_cache=lambda batch: actor.init_carry(batch, max_len, together=True),
         reset_cache=actor.reset_carry,
+        prefill=lambda params, cache, tokens: actor.apply(params, cache, tokens, method="prefill"),
         routed_layers=int(actor.routed_layers),
         held=actor.held,
     )
@@ -335,6 +403,15 @@ def _carry_gauge() -> Any:
         "of sliding_window rows), conv_tail (a short convolution's last inputs), latent (latent "
         "attention's compressed rows) or delta_state (a delta-rule layer's matrix a head and its "
         "convolutions' last inputs)",
+    )
+
+
+def _prompt_gauge() -> Any:
+    return get_registry().gauge(
+        "stoix_tpu_lm_prompt_tokens",
+        "prefix tokens a sequence that the token policy prefills before it decodes, as the "
+        "learner was set up (the env's prompt_length; 0: every rollout starts from an empty "
+        "carry)",
     )
 
 
@@ -380,8 +457,10 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
         raise ValueError(
             f"ff_lm_ppo needs the env's episode length ({int(env.length)}) to equal "
             f"system.rollout_length ({rollout_length}): every rollout is one whole sequence "
-            "from an empty cache (episodes that span rollouts are not supported yet)"
+            "from an empty cache or from its prefilled prompt (episodes that span rollouts "
+            "are not supported yet)"
         )
+    prompt_length = int(getattr(env, "prompt_length", 0))
     if int(config.arch.get("update_batch_size", 1)) != 1:
         raise ValueError("ff_lm_ppo has no in-shard replica axis: arch.update_batch_size must be 1")
     n_shards = int(mesh.shape["data"])
@@ -395,7 +474,7 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
     config.system.action_dim = env.num_actions
 
     actor, critic = build_networks(env, config)
-    networks = network_functions(actor, critic, rollout_length)
+    networks = network_functions(actor, critic, prompt_length + rollout_length)
     epochs, minibatches = int(config.system.epochs), int(config.system.num_minibatches)
     make_optim = lambda lr: optax.chain(
         optax.clip_by_global_norm(float(config.system.max_grad_norm)),
@@ -433,17 +512,19 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
 
     for labels, _ in _carry_gauge().labels_and_values():  # an earlier learner's kinds
         _carry_gauge().remove(dict(labels))
-    for kind, size in actor.carry_bytes(envs_per_shard, rollout_length).items():
+    for kind, size in actor.carry_bytes(envs_per_shard, prompt_length + rollout_length).items():
         _carry_gauge().set(size, {"kind": kind})
+    _prompt_gauge().set(prompt_length)
     together = jax.eval_shape(lambda: networks.init_cache(envs_per_shard)).length.ndim == 0
     for form, took in (("slice", together), ("scatter", not together)):
         _cache_write_gauge().set(float(took), {"form": form})
 
     if is_coordinator():
         get_logger("stoix_tpu.setup").info(
-            "[setup] %s parameters | mesh %s | %s sequences x %s tokens an update",
+            "[setup] %s parameters | mesh %s | %s sequences x %s tokens an update%s",
             f"{count_parameters(learner_state.params):,}", dict(mesh.shape),
             config.arch.total_num_envs, rollout_length,
+            f" after a prompt of {prompt_length}" if prompt_length else "",
         )
 
     greedy = bool(config.arch.get("evaluation_greedy", False))
@@ -460,6 +541,16 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
         return cache, action
 
     act_fn.init_carry = networks.init_cache  # the evaluator's carry is the network's own
+    if prompt_length:
+
+        def start_carry(params: Any, env_state: Any) -> Any:
+            """The evaluator's carry at the first step of its episodes: their
+            prompts prefilled, as the rollout's."""
+            prefix = env.prompt(wrappers.unwrapped_state(env_state))
+            with annotate(SCOPES["prefill"]):
+                return networks.prefill(params, networks.init_cache(prefix.shape[0]), prefix)[0]
+
+        act_fn.start_carry = start_carry
 
     return AnakinSetup(
         learn=learn,

@@ -20,8 +20,8 @@ from stoix_tpu.base_types import (
     PPOTransition,
 )
 from stoix_tpu.observability import (
-    BLOCK_SCOPES, DELTA_SCOPES, DIFFUSION_SCOPES, HYBRID_SCOPES, LATENT_SCOPES, SCOPES,
-    WINDOW_SCOPES,
+    BLOCK_SCOPES, DELTA_SCOPES, DIFFUSION_SCOPES, HYBRID_SCOPES, LATENT_SCOPES, PROMPT_SCOPES,
+    SCOPES, WINDOW_SCOPES,
 )
 from stoix_tpu.utils import config as config_lib
 
@@ -127,10 +127,11 @@ def program_scopes(devices):
 @pytest.mark.parametrize(
     "program,scope",
     # (the token policies' scopes: tests/test_lm_ppo.py, tests/test_sdar_ppo.py and
-    # tests/test_lfm2_ppo.py, tests/test_kanana2_ppo.py, each on its own learner)
+    # tests/test_lfm2_ppo.py, tests/test_kanana2_ppo.py, tests/test_mellum2_ppo.py, each on
+    # its own learner)
     [("anakin_learner", key) for key in sorted(SCOPES)
      if key not in BLOCK_SCOPES + DELTA_SCOPES + DIFFUSION_SCOPES + HYBRID_SCOPES + LATENT_SCOPES
-     + WINDOW_SCOPES]
+     + PROMPT_SCOPES + WINDOW_SCOPES]
     + [("sebulba_learner", key)
        for key in ("gae", "update_epoch", "update_minibatch", "minibatch_shuffle")]
     + [("sebulba_act_fn", "rollout_policy")],
