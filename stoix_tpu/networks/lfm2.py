@@ -110,7 +110,8 @@ import jax.numpy as jnp
 from stoix_tpu.networks.kda import DeltaState, KimiDeltaAttention
 from stoix_tpu.networks.mla import Latent, LatentAttention
 from stoix_tpu.networks.olmoe import (
-    Yarn, _attend_cache, _stack, init_length, moe, reset_length, rms_norm, write_cache_rows,
+    Yarn, _attend_cache, _stack, held_chunk_rows, held_swiglu_form, init_length, moe,
+    reset_length, rms_norm, write_cache_rows,
 )
 from stoix_tpu.networks.sdar import gqa_qkv
 from stoix_tpu.observability import SCOPES, annotate, get_registry
@@ -683,6 +684,15 @@ class Lfm2LM(nn.Module):
         return Lfm2Carry(
             tuple(fresh(state) for state in carry.layers), reset_length(carry.length, done)
         )
+
+    @nn.nowrap
+    def held_swiglu_form(self, tokens: int) -> str:
+        """The form the held experts' SwiGLU takes in a pass over `tokens`
+        tokens (`olmoe.held_swiglu_form` of the chunk `RoutedMLP` asks for)."""
+        rows = held_chunk_rows(
+            tokens, self.experts_per_token, self.experts_held, self.num_experts, _HELD_ROOM_SIGMAS
+        )
+        return held_swiglu_form(rows, self.hidden_size, self.expert_width, self.experts_held)
 
     @nn.nowrap
     def carry_bytes(self, batch: int, max_len: int) -> Dict[str, int]:

@@ -23,7 +23,16 @@ experts are SwiGLUs; final RMSNorm and an untied `lm_head`.
 No token is ever dropped and there is no capacity factor: the (token, slot)
 pairs are sorted by expert and the three expert matmuls run as grouped
 matmuls over the ragged groups (`jax.lax.ragged_dot`; XLA:TPU lowers it to a
-native grouped-matmul kernel whose FLOPs are exactly the routed rows').
+native grouped-matmul kernel whose FLOPs are exactly the routed rows'). One
+call takes another form: a decode step's chunk of the HELD experts (a few
+dozen rows against hundreds of MB of float32 weights) is bound by reading the
+weights, which that kernel does at 45 to 76% of their bytes' pace and slowest
+where `width` is no whole number of 256 lanes, so there — on a TPU, by the
+chunk's shape alone (`held_swiglu_form`) — the three products and the SwiGLU
+between them are ONE Pallas pass that streams the weights in blocks that
+divide them (`ops/held_swiglu.py`; PERF.md section 6, PR 48). The update and
+the prefill, whose chunks are thousands of rows, keep the grouped matmuls
+forward and backward.
 The router's matmul and softmax run in float32 at `precision=HIGHEST` so
 that expert choice does not depend on the MXU's bfloat16 pass.
 
@@ -50,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from stoix_tpu.observability import SCOPES, annotate
+from stoix_tpu.ops.held_swiglu import fits, held_swiglu_decode
 from stoix_tpu.ops.pallas_attention import best_attention
 
 # Keys beyond this many cache positions are read in blocks of this size: a
@@ -350,7 +360,7 @@ def _held_rows(
     return _HeldRows(pair // top_k, pair, valid, sizes)
 
 
-def _held_swiglu(
+def _held_swiglu_ragged(
     gathered: jax.Array, gate: jax.Array, up: jax.Array, down: jax.Array, sizes: jax.Array
 ) -> jax.Array:
     with annotate(SCOPES["moe_experts"]):
@@ -358,6 +368,35 @@ def _held_swiglu(
             gathered, up, sizes
         )
         return jax.lax.ragged_dot(hidden, down, sizes)
+
+
+def held_swiglu_form(rows: int, hidden: int, width: int, count: int) -> str:
+    """The form the SwiGLU of `count` held experts `[hidden, width]` takes
+    here for a chunk of `rows` rows: `kernel` (`ops/held_swiglu.py`: one
+    Pallas pass that streams the weights) or `ragged_dot` (three grouped
+    matmuls as XLA compiles them). The kernel only on a TPU; only for a
+    chunk of two `_HELD_DECODE_TILE`s at most, where reading the weights is
+    all the time there is and as far as it was measured (it multiplies
+    every row by every held expert, so its products grow with the rows; the
+    update's and the prefill's chunks of whole `_HELD_CHUNK_TILE`s never
+    come here); only where its blocks fit (`fits`); and only where the
+    grouped-matmul kernel misfits the operands: `width` no whole number of
+    `_GROUPED_MATMUL_LANES`, where it reads the weights at 45 to 57% of
+    their bytes' pace against 58 to 76% at every width that is one (PERF.md
+    section 6, PR 48)."""
+    small = rows <= 2 * _HELD_DECODE_TILE and fits(rows, hidden, width, count)
+    misfit = width % _GROUPED_MATMUL_LANES != 0
+    return "kernel" if jax.default_backend() == "tpu" and small and misfit else "ragged_dot"
+
+
+def _held_swiglu(
+    gathered: jax.Array, gate: jax.Array, up: jax.Array, down: jax.Array, sizes: jax.Array
+) -> jax.Array:
+    """The forward pass's: the form is chosen from the chunk's shape alone."""
+    if held_swiglu_form(gathered.shape[0], *gate.shape[1:], gate.shape[0]) == "ragged_dot":
+        return _held_swiglu_ragged(gathered, gate, up, down, sizes)
+    with annotate(SCOPES["moe_experts"]):
+        return held_swiglu_decode(gathered, gate, up, down, sizes).astype(gathered.dtype)
 
 
 def _chunks(ends: jax.Array, rows: int) -> jax.Array:
@@ -417,7 +456,7 @@ def _held_experts_bwd(rows, residuals, g):
             gathered = jnp.take(x, at.token, axis=0)
             g_rows = jnp.where(at.valid[:, None], jnp.take(g, at.token, axis=0), 0.0)
         routed, vjp = jax.vjp(
-            lambda *operands: _held_swiglu(*operands, at.sizes), gathered, gate, up, down
+            lambda *operands: _held_swiglu_ragged(*operands, at.sizes), gathered, gate, up, down
         )
         with annotate(SCOPES["moe_dispatch"]):
             d_weight_rows = jnp.sum(jnp.where(at.valid[:, None], routed, 0.0) * g_rows, axis=-1)
@@ -451,6 +490,29 @@ _HELD_CHUNK_TILE = 512
 # rows, 0.73 at 160 and 1.37 at 200; 0.53 at 64, 0.67 at 40 and at 72
 # (PERF.md §6, PR 33).
 _HELD_DECODE_TILE = 64
+# The lanes of `width` the grouped-matmul kernel wants in whole numbers at
+# such a chunk: on the v5e one decode step's three `ragged_dot`s read the
+# float32 weights they reach at 58 to 76% of HBM's pace at widths 512, 768 and
+# 1792, at 54 to 57% at [8, 2048, 896] and at 45 to 49% at [8, 2304, 896]
+# (896 = 3.5 x 256; hidden 2304 = 4.5 x 512 alone, at width 768, reads 66 to
+# 76%). There a chunk of up to two `_HELD_DECODE_TILE`s goes through
+# `ops/held_swiglu.py` instead: 0.269 ms a call against 0.434 at [8, 2304,
+# 896], 0.237 against 0.324 at [8, 2048, 896] and, at 128 rows, 0.239 against
+# 0.430 (`held_swiglu_form`; PERF.md section 6, PR 48).
+_GROUPED_MATMUL_LANES = 256
+
+
+def held_chunk_rows(
+    tokens: int, top_k: int, count: int, num_experts: int, room_sigmas: float = 0.0
+) -> int:
+    """Rows of one chunk of the held pairs' loop for `tokens` tokens choosing
+    `top_k` of `num_experts` experts of which `count` are held (`_moe_held`
+    says how it is sized)."""
+    expected = tokens * top_k * count / num_experts
+    small = _HELD_DECODE_TILE if room_sigmas else 8
+    tile = _HELD_CHUNK_TILE if expected >= 8 * _HELD_CHUNK_TILE else small
+    room = max(_HELD_CHUNK_ROOM * expected, expected + room_sigmas * expected**0.5)
+    return min(tokens * top_k, -(-int(room) // tile) * tile)
 
 
 def _moe_held(
@@ -478,11 +540,7 @@ def _moe_held(
         experts = jnp.arange(num_experts, dtype=index.dtype)
         counts = jnp.sum(index.reshape(-1)[:, None] == experts[None, :], axis=0, dtype=jnp.int32)
         ends = jnp.cumsum(jax.lax.dynamic_slice_in_dim(counts, offset, count))
-    expected = tokens * top_k * count / num_experts
-    small = _HELD_DECODE_TILE if room_sigmas else 8
-    tile = _HELD_CHUNK_TILE if expected >= 8 * _HELD_CHUNK_TILE else small
-    room = max(_HELD_CHUNK_ROOM * expected, expected + room_sigmas * expected**0.5)
-    rows = min(tokens * top_k, -(-int(room) // tile) * tile)
+    rows = held_chunk_rows(tokens, top_k, count, num_experts, room_sigmas)
     # The last chunk may reach past the pairs: a slice that does is moved, not cut.
     order = jnp.concatenate([order, jnp.zeros((rows,), jnp.int32)])
     out = _held_experts(x, weights.astype(x.dtype), gate, up, down, order, slot, ends, rows)

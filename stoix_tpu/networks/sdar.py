@@ -61,7 +61,9 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from stoix_tpu.networks.olmoe import Yarn, _stack, moe, rms_norm, rope, rope_angles
+from stoix_tpu.networks.olmoe import (
+    Yarn, _stack, held_chunk_rows, held_swiglu_form, moe, rms_norm, rope, rope_angles,
+)
 from stoix_tpu.observability import SCOPES, annotate
 from stoix_tpu.ops.pallas_attention import (
     BLOCK_MASK_RESIDUALS,
@@ -345,6 +347,12 @@ class SdarLM:
             run_outs.append(out.reshape((rows, per, size) + q.shape[1:]))
         outs.append(jnp.concatenate(run_outs, axis=1).reshape((-1,) + q.shape[1:]))
         return jnp.concatenate(outs).reshape(q.shape[0], -1)
+
+    def held_swiglu_form(self, tokens: int) -> str:
+        """The form the held experts' SwiGLU takes in a pass over `tokens`
+        tokens (`olmoe.held_swiglu_form` of the chunk `_moe` asks for)."""
+        rows = held_chunk_rows(tokens, self.experts_per_token, self.experts_held, self.num_experts)
+        return held_swiglu_form(rows, self.hidden_size, self.expert_width, self.experts_held)
 
     def copies_attention(self, clean: int, copies: int) -> Dict[str, int]:
         """How `trunk_copies` multiplies its scores here, by what it can see

@@ -424,6 +424,31 @@ def _cache_write_gauge() -> Any:
     )
 
 
+def _held_swiglu_gauge() -> Any:
+    return get_registry().gauge(
+        "stoix_tpu_held_swiglu_form",
+        "1 on the form the held experts' SwiGLU takes in a rollout step as the learner was set "
+        "up, 0 on the other: kernel (one weight-streaming Pallas pass over the chunk's rows, "
+        "ops/held_swiglu.py) or ragged_dot (three grouped matmuls as XLA compiles them); chosen "
+        "from the backend and the chunk's shape (networks/olmoe.py::held_swiglu_form); no "
+        "series where every expert is held (moe's other path)",
+    )
+
+
+def set_held_swiglu_gauge(actor: Any, tokens: int) -> Optional[str]:
+    """Record, and return, the form `actor`'s held experts take in a pass
+    over `tokens` tokens (None: it holds every expert)."""
+    gauge = _held_swiglu_gauge()
+    for labels, _ in gauge.labels_and_values():  # an earlier learner's
+        gauge.remove(dict(labels))
+    if actor.held is None:
+        return None
+    taken = actor.held_swiglu_form(tokens)
+    for form in ("kernel", "ragged_dot"):
+        gauge.set(float(taken == form), {"form": form})
+    return taken
+
+
 def make_init_state(
     env: envs.Environment, config: Any, actor: Any, critic: Any,
     optims: Tuple[Any, Any], n_shards: int,
@@ -518,13 +543,15 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
     together = jax.eval_shape(lambda: networks.init_cache(envs_per_shard)).length.ndim == 0
     for form, took in (("slice", together), ("scatter", not together)):
         _cache_write_gauge().set(float(took), {"form": form})
+    held_form = set_held_swiglu_gauge(actor, envs_per_shard)
 
     if is_coordinator():
         get_logger("stoix_tpu.setup").info(
-            "[setup] %s parameters | mesh %s | %s sequences x %s tokens an update%s",
+            "[setup] %s parameters | mesh %s | %s sequences x %s tokens an update%s%s",
             f"{count_parameters(learner_state.params):,}", dict(mesh.shape),
             config.arch.total_num_envs, rollout_length,
             f" after a prompt of {prompt_length}" if prompt_length else "",
+            f" | the held experts' SwiGLU of a rollout step: {held_form}" if held_form else "",
         )
 
     greedy = bool(config.arch.get("evaluation_greedy", False))

@@ -79,7 +79,9 @@ from stoix_tpu.ops.distributions import Categorical
 from stoix_tpu.ops.qk_norm_rope import norm_rope_form
 from stoix_tpu.parallel import is_coordinator
 from stoix_tpu.systems import anakin
-from stoix_tpu.systems.ppo.anakin.ff_lm_ppo import LMPPOLearnerState, held_counts
+from stoix_tpu.systems.ppo.anakin.ff_lm_ppo import (
+    LMPPOLearnerState, held_counts, set_held_swiglu_gauge,
+)
 from stoix_tpu.systems.runner import LAST_RUN_STATS, AnakinSetup, run_anakin_experiment
 from stoix_tpu.utils import config as config_lib
 from stoix_tpu.utils.jax_utils import count_parameters
@@ -581,14 +583,16 @@ def learner_setup(env: envs.Environment, config: Any, mesh: Mesh, key: jax.Array
     for form in ("kernel", "plain"):
         taken = norm_rope_form(update_positions, actor.head_dim) == form
         _norm_rope_gauge().set(float(taken), {"form": form})
+    # ... and the held experts' SwiGLU in a denoise pass over a block of every sequence.
+    held_form = set_held_swiglu_gauge(actor, envs_per_shard * int(env.block_length))
 
     if is_coordinator():
         get_logger("stoix_tpu.setup").info(
             "[setup] %s parameters | mesh %s | %s sequences x %s blocks of %s tokens, %s passes a "
-            "block | experts %s..%s of %s held",
+            "block | experts %s..%s of %s held, their SwiGLU of a pass: %s",
             f"{count_parameters(learner_state.params):,}", dict(mesh.shape),
             config.arch.total_num_envs, int(env.num_blocks), int(env.block_length),
-            int(env.passes), actor.held[0], sum(actor.held) - 1, actor.num_experts,
+            int(env.passes), actor.held[0], sum(actor.held) - 1, actor.num_experts, held_form,
         )
 
     return AnakinSetup(

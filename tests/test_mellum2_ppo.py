@@ -677,6 +677,8 @@ def test_learner_setup_publishes_the_prompt_and_a_carry_of_prompt_and_response(p
     }
     assert [value for _, value in registry.gauge("stoix_tpu_lm_prompt_tokens").labels_and_values()] == [PROMPT]
     assert by(registry.gauge("stoix_tpu_lm_cache_write"), "form") == {"slice": 1.0, "scatter": 0.0}
+    # off a TPU the held experts' SwiGLU keeps its three grouped matmuls
+    assert by(registry.gauge("stoix_tpu_held_swiglu_form"), "form") == {"kernel": 0.0, "ragged_dot": 1.0}
 
 
 # `setup.learn.lower(state).as_text()` of tests/test_laguna_ppo.py's tiny preset (its `_config()`,

@@ -777,6 +777,9 @@ def test_a_short_run_learns_the_block_token_task(devices):
     # and which way q's and k's norm and rotation: `rms_norm` + `rope` off a TPU
     forms = get_registry().gauge("stoix_tpu_qk_norm_rope").labels_and_values()
     assert {dict(labels)["form"]: value for labels, value in forms} == {"kernel": 0.0, "plain": 1.0}
+    # and the held experts' SwiGLU of a denoise pass: the `ragged_dot`s off a TPU
+    forms = get_registry().gauge("stoix_tpu_held_swiglu_form").labels_and_values()
+    assert {dict(labels)["form"]: value for labels, value in forms} == {"kernel": 0.0, "ragged_dot": 1.0}
 
 
 def test_the_benchmark_keeps_a_copy_of_the_reference(model):

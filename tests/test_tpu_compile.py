@@ -336,3 +336,67 @@ def test_mosaic_takes_the_decode_kernel_at_the_cells_shapes(one_chip, monkeypatc
     assert len(calls) == 1 and "gqa_decode_attention" in calls[0]
     assert not re.findall(rf"= [a-z0-9]+\[{rows},{sequences},8,128\]\{{[^}}]*\}} (copy|transpose)\(", text)
     assert not re.search(rf"bf16\[{rows},{sequences},8,128\]", text)  # no lower-precision copy of the rows
+
+
+# The benchmark's prefilled-prompt cell (Mellum2): 16 sequences decode through
+# four routed layers whose 8 held experts of 64 are [2304, 896] (a chunk of 64
+# rows); the Kanana-2 cell's 128 sequences through 16 of 128 at [2048, 768].
+def _held_layer(one_chip, tokens, experts, held, hidden, width, top_k, what):
+    from stoix_tpu.networks import olmoe
+
+    def layer(x, router, gate, up, down):
+        return olmoe.moe(
+            x, router, gate, up, down, top_k, held=(0, held), renormalise=True, held_room_sigmas=5.0
+        )[0]
+
+    weights = ((held, hidden, width), (held, hidden, width), (held, width, hidden))
+    fn = layer if what == "forward" else jax.grad(
+        lambda *operands: jnp.sum(layer(*operands) ** 2), argnums=(0, 2, 3, 4)
+    )
+    return _compiled(fn, one_chip, (tokens, hidden), (hidden, experts), *weights).as_text()
+
+
+def test_mellum2s_decode_step_streams_its_held_experts_through_the_kernel(one_chip, monkeypatch):
+    """One decode step of `moe(..., held=(0, 8))` at `[8, 2304, 896]`, 16
+    tokens, steered onto the TPU's branch of `held_swiglu_form`: the chunk's
+    SwiGLU is ONE `held_swiglu_decode` call under the name the breakdown finds
+    it by, no grouped matmul is left, and no bfloat16 copy of a weight is
+    written (the rounding is in the kernel's registers)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _held_layer(one_chip, 16, 64, 8, 2304, 896, 8, "forward")
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and "held_swiglu_decode" in calls[0]
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    assert not re.search(r"bf16\[8,(2304,896|896,2304)\]", text)
+
+
+def test_mellum2s_update_keeps_its_grouped_matmuls(one_chip, monkeypatch):
+    """The gradient of the same layer over a minibatch of the update (7,168
+    positions: chunks of 9,216 rows, whole 512-row tiles): `ragged-dot`s
+    forward and backward, and no kernel — it has no VJP and needs none."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _held_layer(one_chip, 7168, 64, 8, 2304, 896, 8, "gradient")
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    # XLA's grouped matmul is itself a Mosaic call, `ragged-dot-none`: nine
+    # of them (three forward, six backward) and the table of their groups.
+    assert len(calls) >= 9 and all("ragged-dot" in line for line in calls)
+    assert "held_swiglu_decode" not in text
+
+
+def test_kanana2s_decode_step_is_the_program_it_was(one_chip, monkeypatch):
+    """A decode step at Kanana-2's `[16, 2048, 768]`, 128 tokens: the text the
+    TPU's compiler gives under the rule is the text it gives with the rule
+    taken out (every chunk on the `ragged_dot`s), to the letter."""
+    from stoix_tpu.networks import olmoe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # An instruction as the compiler wrote it, less the place in this file it was traced from.
+    program = lambda text: [
+        re.sub(r", metadata=\{[^}]*\}", "", line) for line in text.splitlines() if " = " in line
+    ]
+    under_the_rule = program(_held_layer(one_chip, 128, 128, 16, 2048, 768, 6, "forward"))
+    monkeypatch.setattr(olmoe, "held_swiglu_form", lambda *shape: "ragged_dot")
+    without = program(_held_layer(one_chip, 128, 128, 16, 2048, 768, 6, "forward"))
+    assert under_the_rule == without and len(without) > 100
+    without = "\n".join(without)
+    assert "held_swiglu_decode" not in without and re.search(r"ragged[-_]dot", without)
